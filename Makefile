@@ -61,10 +61,10 @@ verify:
 		| tee /tmp/_t1.log
 
 bench-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) -m ray_tpu._private.ray_perf --quick \
 		--only single_client_tasks_sync,actor_calls_1_1,put_small_1kb
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) -m ray_tpu._private.serve_perf --probe
 
 # <60 s paged-vs-slot serve.llm smoke (smoke sizing; HEADLINE line
@@ -72,7 +72,7 @@ bench-quick:
 # regression in the serving hot path before a full bench round.  Does
 # NOT touch the checked-in BENCH_serve_llm.json.
 bench-llm-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) bench.py --suite serve_llm --quick
 
 # <60 s KV-tiering smoke (smoke sizing; HEADLINE last): sessions held
@@ -80,13 +80,13 @@ bench-llm-quick:
 # bytes, plus store-resurrect vs re-prefill resume latency with the
 # greedy-parity check in-bench.  Does NOT touch BENCH_serve_llm.json.
 bench-llm-tier-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) bench.py --suite serve_llm_tier --quick
 
 # Object transfer plane GB/s (pull/push, striped, vs stop-and-wait
 # baseline); refreshes the checked-in BENCH_transfer.json artifact.
 bench-transfer:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 600 \
+	env JAX_PLATFORMS=cpu timeout -k 10 600 \
 		$(PY) bench.py --suite transfer --json-out BENCH_transfer.json
 
 # Host collectives on the transfer plane: world-4 allreduce bus GB/s
@@ -94,7 +94,7 @@ bench-transfer:
 # ring baseline), bucket fusion, small-tensor latency, cross-plane
 # bit-parity.  Refreshes the checked-in BENCH_collective.json.
 bench-collective:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 600 \
+	env JAX_PLATFORMS=cpu timeout -k 10 600 \
 		$(PY) bench.py --suite collective \
 		--json-out BENCH_collective.json
 
@@ -102,7 +102,7 @@ bench-collective:
 # last): catches a collective fast-path regression before a full bench
 # round.  Does NOT touch the checked-in BENCH_collective.json.
 bench-collective-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) bench.py --suite collective --quick
 
 # Control-plane scaling curves: coalesced-vs-legacy pubsub broadcast
@@ -111,7 +111,7 @@ bench-collective-quick:
 # grant latency at queue depth, node-view convergence after churn.
 # Refreshes the checked-in BENCH_control_plane.json.
 bench-control:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 600 \
+	env JAX_PLATFORMS=cpu timeout -k 10 600 \
 		$(PY) bench.py --suite control_plane \
 		--json-out BENCH_control_plane.json
 
@@ -119,7 +119,7 @@ bench-control:
 # catches a pubsub-coalescing or scheduling-index regression before a
 # full bench round.  Does NOT touch the checked-in artifact.
 bench-control-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) bench.py --suite control_plane --quick
 
 # Multi-replica serving chaos-soak: concurrent greedy streams across N
@@ -130,7 +130,7 @@ bench-control-quick:
 # accounting, and cold-tenant p99 TTFT within 2x of chaos-off.
 # Refreshes the checked-in BENCH_serve_scale.json.
 bench-serve-scale:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 600 \
+	env JAX_PLATFORMS=cpu timeout -k 10 600 \
 		$(PY) bench.py --suite serve_scale \
 		--json-out BENCH_serve_scale.json
 
@@ -141,7 +141,7 @@ bench-serve-scale:
 # magnitude gate runs in the full suite).  Does NOT touch the
 # checked-in artifact.
 bench-serve-scale-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) bench.py --suite serve_scale --quick
 
 # Streaming data plane: transfer-plane shuffle GB/s vs the legacy
@@ -150,7 +150,7 @@ bench-serve-scale-quick:
 # locality on/off, train-ingest overlap win.  Refreshes the checked-in
 # BENCH_data.json.
 bench-data:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 600 \
+	env JAX_PLATFORMS=cpu timeout -k 10 600 \
 		$(PY) bench.py --suite data --json-out BENCH_data.json
 
 # <60 s data-plane smoke (small blocks; HEADLINE last): exercises the
@@ -158,20 +158,20 @@ bench-data:
 # the ingest wrapper before a full bench round.  Does NOT touch the
 # checked-in artifact.
 bench-data-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) bench.py --suite data --quick
 
 # Always-on tracing overhead A/B (record() ns, RPC hot path, serve
 # streaming soak; paired on/off windows, median statistic).  ASSERTS
 # overhead <= 5% on both system legs.  Refreshes BENCH_trace.json.
 bench-trace:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 600 \
+	env JAX_PLATFORMS=cpu timeout -k 10 600 \
 		$(PY) bench.py --suite trace --json-out BENCH_trace.json
 
 # <60 s tracing-overhead gate for make check: same paired A/B at smoke
 # sizing, same <= 5% assertion.  Does NOT touch the checked-in artifact.
 bench-trace-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) bench.py --suite trace --quick
 
 # End-to-end train plane: gradient-hook overlap (GradientSynchronizer
@@ -181,7 +181,7 @@ bench-trace-quick:
 # baseline, with the metric-series continuity record.  Refreshes the
 # checked-in BENCH_train_e2e.json.
 bench-train:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 600 \
+	env JAX_PLATFORMS=cpu timeout -k 10 600 \
 		$(PY) bench.py --suite train_e2e \
 		--json-out BENCH_train_e2e.json
 
@@ -190,7 +190,7 @@ bench-train:
 # a gradient-overlap or elastic-recovery regression before a full
 # bench round.  Does NOT touch the checked-in artifact.
 bench-train-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 120 \
+	env JAX_PLATFORMS=cpu timeout -k 10 120 \
 		$(PY) bench.py --suite train_e2e --quick
 
 # Cluster autopilot soak: serve + elastic train gang + data soak share
@@ -201,7 +201,7 @@ bench-train-quick:
 # only after the gang is whole, and mean utilization stays > 80%.
 # Refreshes the checked-in BENCH_autopilot.json.
 bench-autopilot:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 600 \
+	env JAX_PLATFORMS=cpu timeout -k 10 600 \
 		$(PY) bench.py --suite autopilot \
 		--json-out BENCH_autopilot.json
 
@@ -209,7 +209,7 @@ bench-autopilot:
 # arbitration-policy or lease-backpressure regression before a full
 # soak.  Does NOT touch the checked-in artifact.
 bench-autopilot-quick:
-	env JAX_PLATFORMS=cpu RT_DISABLE_TPU_DETECTION=1 timeout -k 10 150 \
+	env JAX_PLATFORMS=cpu timeout -k 10 150 \
 		$(PY) bench.py --suite autopilot --quick
 
 # --- chaos battery ----------------------------------------------------
@@ -287,10 +287,10 @@ check: lint verify chaos-smoke bench-quick bench-llm-quick \
 	bench-data-quick bench-trace-quick bench-train-quick \
 	bench-autopilot-quick
 
-store: ray_tpu/_private/_shm_store.so
-
-ray_tpu/_private/_shm_store.so: src/shm_store.cc
-	$(CXX) $(CXXFLAGS) -shared -fPIC -o $@ $<
+# One builder: the library's name carries a digest of its source
+# (shm_store._build_lib), which make's timestamps cannot express.
+store:
+	python -c "from ray_tpu._private.shm_store import _build_lib; print(_build_lib())"
 
 $(BUILD):
 	mkdir -p $(BUILD)
@@ -310,4 +310,4 @@ store-asan: $(BUILD)/store_stress_asan
 sanitize: store-tsan store-asan
 
 clean:
-	rm -rf $(BUILD) ray_tpu/_private/_shm_store.so
+	rm -rf $(BUILD) ray_tpu/_private/_shm_store*.so

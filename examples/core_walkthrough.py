@@ -1,6 +1,6 @@
 """Core API walkthrough: tasks, actors, objects, placement groups.
 
-Run: RT_DISABLE_TPU_DETECTION=1 python examples/core_walkthrough.py
+Run: JAX_PLATFORMS=cpu python examples/core_walkthrough.py
 (reference analogue: the ray-core walkthrough examples)
 """
 

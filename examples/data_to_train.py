@@ -1,7 +1,7 @@
 """Data pipeline -> Train ingest: read files, preprocess, shard to a
 training gang (reference: the AIR "data + train" quickstart shape).
 
-Run: RT_DISABLE_TPU_DETECTION=1 python examples/data_to_train.py
+Run: JAX_PLATFORMS=cpu python examples/data_to_train.py
 """
 
 import os

@@ -1,7 +1,7 @@
 """Continuous-batching LLM serving: one engine, many concurrent
 requests, tokens streamed as they are generated.
 
-Run: RT_DISABLE_TPU_DETECTION=1 python examples/llm_serving.py
+Run: JAX_PLATFORMS=cpu python examples/llm_serving.py
 
 Contrast with serve_llm.py (request-level @serve.batch): here requests
 are batched at ITERATION level — a request joins the running decode

@@ -1,6 +1,6 @@
 """PPO on CartPole with the fluent AlgorithmConfig builder.
 
-Run: RT_DISABLE_TPU_DETECTION=1 python examples/rllib_ppo.py
+Run: JAX_PLATFORMS=cpu python examples/rllib_ppo.py
 """
 
 import ray_tpu

@@ -1,7 +1,7 @@
 """Serve an LLM with KV-cache generation: batched decode on the
 replica's chip, HTTP in front.
 
-Run: RT_DISABLE_TPU_DETECTION=1 python examples/serve_llm.py
+Run: JAX_PLATFORMS=cpu python examples/serve_llm.py
 (toy-sized weights; the same deployment shape serves a real GPT —
 replicas that request num_tpus=1 keep the params resident in HBM)
 """

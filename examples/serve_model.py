@@ -1,6 +1,6 @@
 """Serve a jax model over HTTP with batching and an ASGI ingress.
 
-Run: RT_DISABLE_TPU_DETECTION=1 python examples/serve_model.py
+Run: JAX_PLATFORMS=cpu python examples/serve_model.py
 """
 
 import json

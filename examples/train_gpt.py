@@ -1,9 +1,9 @@
 """Train the flagship GPT with JaxTrainer: gang of workers, mesh from
 ScalingConfig axes, AIR checkpoints.
 
-Run: RT_DISABLE_TPU_DETECTION=1 python examples/train_gpt.py
-(sizes are CPU-safe; on a TPU host drop RT_DISABLE_TPU_DETECTION and
-raise d_model/seq — the same script drives the chip)
+Run: JAX_PLATFORMS=cpu python examples/train_gpt.py
+(toy sizes on a virtual CPU mesh; chip_smoke.py drives the same entry
+points on the chip at the 737M widths, with ScalingConfig(use_tpu=True))
 """
 
 import ray_tpu

@@ -1,7 +1,7 @@
 """Hyperparameter sweep with ASHA early stopping + a TimeoutStopper
 safety net.
 
-Run: RT_DISABLE_TPU_DETECTION=1 python examples/tune_asha.py
+Run: JAX_PLATFORMS=cpu python examples/tune_asha.py
 """
 
 import ray_tpu

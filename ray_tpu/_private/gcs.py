@@ -1134,13 +1134,18 @@ class GcsServer:
                 await asyncio.sleep(0.05)
                 continue
             try:
+                # The reply comes after the actor's constructor has run,
+                # so no deadline here either (see the raylet's
+                # create_actor request): the 120 s above bound the
+                # search for a node, not the user's __init__.  A raylet
+                # that dies closes this connection.
                 reply = await node.conn.request("lease_worker_for_actor", {
                     "actor_id": actor.actor_id,
                     "resources": resources,
                     "pg_id": actor.pg_id,
                     "bundle_index": actor.spec.get("bundle_index"),
                     "spec": actor.spec,
-                }, timeout=max(cfg.worker_register_timeout_s, 60.0))
+                }, timeout=None)
             except Exception as e:
                 logger.warning("actor lease on node %s failed: %s",
                                node.node_id.hex()[:8], e)

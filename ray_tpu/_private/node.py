@@ -82,10 +82,9 @@ class NodeProcesses:
         self.gcs_port = gcs_port
         self.node_name = node_name
         self._register_atexit = register_atexit
-        self._resources, self._labels = detect_node_resources(
+        self._resources = detect_node_resources(
             num_cpus=num_cpus, num_tpus=num_tpus, resources=resources)
-        if labels:
-            self._labels.update(labels)
+        self._labels = dict(labels or {})
         self._object_store_memory = (object_store_memory
                                      or cfg.object_store_memory_bytes)
 
@@ -208,10 +207,9 @@ class InProcessNode:
         self.gcs_server = None
         self.raylet = None
         self.raylet_addr = None
-        self._resources, self._labels = detect_node_resources(
+        self._resources = detect_node_resources(
             num_cpus=num_cpus, num_tpus=num_tpus, resources=resources)
-        if labels:
-            self._labels.update(labels)
+        self._labels = dict(labels or {})
         self._object_store_memory = (object_store_memory
                                      or cfg.object_store_memory_bytes)
         self.node_name = node_name

@@ -15,6 +15,8 @@ memcpy into shared memory themselves).
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import mmap
 import os
 import subprocess
@@ -26,7 +28,27 @@ _LIB_LOCK = locksan.make_lock("shm_store._LIB_LOCK")
 _LIB = None
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "..", "src", "shm_store.cc")
-_SO = os.path.join(os.path.dirname(__file__), "_shm_store.so")
+
+
+def _build_lib() -> str:
+    """Path of the library built from src/shm_store.cc AS IT IS NOW,
+    building it if need be.  The file name carries a digest of the
+    source, so a library left behind by another source (a copied working
+    tree, a checkout whose times say nothing) is never loaded for it."""
+    src = os.path.abspath(_SRC)
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    here = os.path.dirname(os.path.abspath(__file__))
+    so = os.path.join(here, f"_shm_store.{digest}.so")
+    if not os.path.exists(so):
+        tmp = so + f".tmp{os.getpid()}"
+        subprocess.check_call(
+            ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, src])
+        os.replace(tmp, so)
+        for stale in glob.glob(os.path.join(here, "_shm_store*.so")):
+            if stale != so:
+                os.unlink(stale)  # safe under a process that has it mapped
+    return so
 
 
 def _load_lib():
@@ -34,14 +56,7 @@ def _load_lib():
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
-        src = os.path.abspath(_SRC)
-        so = os.path.abspath(_SO)
-        if (not os.path.exists(so)
-                or os.path.getmtime(so) < os.path.getmtime(src)):
-            tmp = so + f".tmp{os.getpid()}"
-            subprocess.check_call(
-                ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp, src])
-            os.replace(tmp, so)
+        so = _build_lib()
         lib = ctypes.CDLL(so)
         lib.store_create.restype = ctypes.c_void_p
         lib.store_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64]

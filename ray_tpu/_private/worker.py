@@ -34,6 +34,7 @@ from concurrent.futures import TimeoutError as CFTimeoutError
 from ray_tpu import exceptions as rexc
 from ray_tpu._private import failpoints, protocol, retry, serialization
 from ray_tpu._private.config import GLOBAL_CONFIG as cfg
+from ray_tpu._private.jax_utils import bind_tpu_chips
 from ray_tpu._private.ids import (ActorID, FunctionID, JobID, NodeID, ObjectID,
                                   TaskID, WorkerID)
 from ray_tpu._private.object_ref import ObjectRef
@@ -2066,6 +2067,8 @@ class CoreWorker:
         restore_env = None
         span = self._enter_span(spec.get("trace"))
         try:
+            if tpu_ids:
+                bind_tpu_chips(tpu_ids)
             restore_env = self._apply_runtime_env(spec.get("runtime_env"))
             fn = self._load_function(spec["fn_id"])
             args, kwargs = self._unpack_args(spec["args"])
@@ -2215,6 +2218,8 @@ class CoreWorker:
 
     def _create_actor_sync(self, spec):
         try:
+            if self._actor_tpu_ids:
+                bind_tpu_chips(self._actor_tpu_ids)
             self._apply_runtime_env(spec.get("runtime_env"))
             cls = self._load_function(spec["class_id"])
             args, kwargs = self._unpack_args(spec["init_args"])

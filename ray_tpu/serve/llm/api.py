@@ -21,7 +21,9 @@ from __future__ import annotations
 import json
 from typing import Any, Dict, List, Optional, Sequence
 
+import ray_tpu
 from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
+from ray_tpu._private.jax_utils import device_facts
 from ray_tpu.serve.exceptions import resumable
 from ray_tpu.serve.llm import kv_transfer
 from ray_tpu.serve.llm.engine import GenerationEngine
@@ -126,6 +128,17 @@ class LLMServer:
 
     def stats(self) -> Dict[str, Any]:
         return self.engine.stats().to_dict()
+
+    def replica_info(self) -> Dict[str, Any]:
+        """Which process and device answered, and how much it has
+        served: jax's own report of the replica's device
+        (jax_utils.device_facts — platform, kind, count, peak bytes,
+        pid, open chip device files), its leased chip ids, and the
+        engine's completed-request count.  A caller that must stay off
+        jax itself (a driver next to TPU workers) checks here that the
+        replica really is on the chip."""
+        return {**device_facts(), "tpu_ids": ray_tpu.get_tpu_ids(),
+                "completed": self.engine.stats().requests_completed}
 
     def autoscale_metrics(self) -> Dict[str, Any]:
         """Saturation gauges for the serve controller's autoscaler

@@ -144,8 +144,7 @@ def test_rt_up_down_process_provider(tmp_path):
     }
     cfg_path = tmp_path / "cluster.yaml"
     cfg_path.write_text(yaml.safe_dump(config))
-    env = dict(os.environ, RT_DISABLE_TPU_DETECTION="1",
-               JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     up = subprocess.run(
         [sys.executable, "-m", "ray_tpu.scripts.cli", "up",
          str(cfg_path)], capture_output=True, text=True, timeout=300,

@@ -84,8 +84,7 @@ def netns_pair():
 
 
 def _env():
-    return dict(os.environ, RT_DISABLE_TPU_DETECTION="1",
-                JAX_PLATFORMS="cpu")
+    return dict(os.environ, JAX_PLATFORMS="cpu")
 
 
 def _in_ns(ns, argv):

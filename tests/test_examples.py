@@ -13,8 +13,7 @@ EXAMPLES = os.path.join(os.path.dirname(os.path.dirname(
 
 def _run(name, timeout=240):
     repo = os.path.dirname(EXAMPLES)
-    env = dict(os.environ, RT_DISABLE_TPU_DETECTION="1",
-               JAX_PLATFORMS="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=repo + os.pathsep + os.environ.get(
                    "PYTHONPATH", ""))
     proc = subprocess.run(

@@ -28,8 +28,7 @@ FAST_SUBSET = ["bandit-linucb", "rps-league", "twostep-qmix",
 
 
 def _battery(include, timeout):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               RT_DISABLE_TPU_DETECTION="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run(
         [sys.executable, "-m", "ray_tpu.rllib.train", "-q",
          "--batch", EXAMPLES] +

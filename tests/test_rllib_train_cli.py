@@ -13,8 +13,7 @@ EXAMPLES = os.path.join(REPO, "ray_tpu", "rllib", "tuned_examples")
 
 
 def _run_cli(*argv, timeout=600):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               RT_DISABLE_TPU_DETECTION="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run(
         [sys.executable, "-m", "ray_tpu.rllib.train", "-q", *argv],
         cwd=REPO, env=env, capture_output=True, text=True,
