@@ -397,9 +397,6 @@ class _Config:
     trace_min_dur_us = _def("trace_min_dur_us", float, 0.0)
     # RPC handlers slower than this record an rpc.slow span (0 disables).
     trace_rpc_slow_ms = _def("trace_rpc_slow_ms", float, 50.0)
-    # Sample 1/N engine decode ticks as engine.decode_tick spans (the
-    # tick runs thousands of times per second; 0 disables tick spans).
-    trace_decode_tick_sample = _def("trace_decode_tick_sample", int, 64)
     # Byte cap on the pickled telemetry KV push (the stale convenience
     # view).  The push must stay control-plane-sized: anything
     # chunk-sized belongs on raw transfer frames, and the authoritative

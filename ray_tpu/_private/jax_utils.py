@@ -1,5 +1,5 @@
-"""Which JAX platform a process uses, which chips it may open, and where
-its compiled programs are kept.
+"""Which JAX platform a process uses, which chips it may open, where its
+compiled programs are kept, and how many of them it has compiled.
 
 A TPU chip belongs to one process at a time, and JAX opens every chip it
 can see when its backend starts.  So the platform, the visible chips and
@@ -14,7 +14,9 @@ to the CPU without a word).
 from __future__ import annotations
 
 import os
+import threading
 
+from ray_tpu._private import tracing
 from ray_tpu._private.resources import detect_tpu_chips
 
 _FORCED = {"value": None}
@@ -137,7 +139,80 @@ def enable_compile_cache() -> str:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         import jax
         jax.config.update("jax_compilation_cache_dir", path)
+    install_compile_listener()
     return path
+
+
+# jax reports each stage of making a program runnable through
+# jax.monitoring: tracing, lowering, and the backend compile.  The last
+# wraps the persistent cache's lookup, so a program loaded from the
+# cache ends a backend-compile event too, after one of _CACHE_LOAD.
+_COMPILE_STAGES = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                   "/jax/core/compile/backend_compile_duration")
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_COMPILES = {"installed": False, "n": 0, "s": 0.0}
+_COMPILES_LOCK = threading.Lock()
+_COMPILING = threading.local()   # per thread: .spans, .loaded
+
+
+def _on_compile_stage(event, start_time, end_time, **kw) -> None:
+    """Time-span listener.  Stages nest (tracing `f` traces the jitted
+    `jnp` functions it calls; an eager op on a constant compiles inside
+    a trace) and the inner ones end first, so each span adds its length
+    less that of the spans it contains: `s` is time on the clock, not a
+    sum over nesting levels."""
+    if event not in _COMPILE_STAGES:
+        return
+    spans = getattr(_COMPILING, "spans", None)
+    if spans is None:
+        spans = _COMPILING.spans = []
+    net = end_time - start_time
+    while spans and spans[-1][0] >= start_time:
+        net -= spans.pop()[1]
+    spans.append((start_time, end_time - start_time))
+    del spans[:-64]
+    backend = event == _COMPILE_STAGES[2]
+    with _COMPILES_LOCK:
+        _COMPILES["s"] += max(0.0, net)
+        _COMPILES["n"] += backend
+    if backend:
+        tracing.record("jax", "jax.compile", start_time,
+                       end_time - start_time,
+                       args={"fun_name": str(kw.get("fun_name", "?")),
+                             "from_cache": getattr(_COMPILING, "loaded",
+                                                   False)})
+        _COMPILING.loaded = False
+
+
+def _on_cache_load(event, duration_secs, **kw) -> None:
+    if event == _CACHE_LOAD:
+        _COMPILING.loaded = True
+
+
+def install_compile_listener() -> None:
+    """Count this process's compiles where they happen.  Idempotent;
+    called by every TPU worker (enable_compile_cache) and by the serving
+    engine's constructor.  From then on `compile_counters()` moves with
+    every program that is traced, lowered, compiled or loaded from the
+    persistent cache, on any thread, and each backend compile or cache
+    load leaves one `jax.compile` event (fun_name, from_cache) in the
+    process's trace ring — a steady-state recompile shows in
+    `rt timeline --cluster` whichever worker it happens in."""
+    with _COMPILES_LOCK:
+        if _COMPILES["installed"]:
+            return
+        _COMPILES["installed"] = True
+    from jax import monitoring
+    monitoring.register_event_time_span_listener(_on_compile_stage)
+    monitoring.register_event_duration_secs_listener(_on_cache_load)
+
+
+def compile_counters() -> tuple:
+    """(jit_compiles, jit_compile_s) of this process since the listener
+    was installed: backend compiles plus cache loads, and the seconds
+    spent tracing, lowering, compiling and loading."""
+    return _COMPILES["n"], _COMPILES["s"]
 
 
 def device_facts() -> dict:

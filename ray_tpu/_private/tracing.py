@@ -15,7 +15,7 @@ Design:
   chrome-trace events with drop-oldest semantics and a drop counter —
   cheap enough to leave always on (an append is one dict build + one
   deque append; the disabled fast path is a single bool check).  The
-  capacity / enablement / sampling knobs are ``RT_TRACE_*`` (see
+  capacity / enablement knobs are ``RT_TRACE_*`` (see
   config.py).
 * **Trace context** rides a contextvar, propagated inside TaskSpecs
   (worker.py) and adopted at execution with a fresh span id, so spans
@@ -41,7 +41,10 @@ Span taxonomy (cat.name — see README "Observability"):
   gcs.*             scheduling decisions, pubsub batch flushes
   rpc.slow          any RPC handler over cfg.trace_rpc_slow_ms
   serve.*           proxy request, router assign/QoS wait, failover
-  engine.*          queue / prefill / first_tick / decode_tick (sampled)
+  engine.*          queue / prefill / first_tick per request; tier_sweep
+                    per sweep that moved pages (per-turn time is in
+                    the engine's stats() counters, never in the ring)
+  jax.compile       one per backend compile or cache load (jax_utils)
   data.*            streaming execute + shuffle exchange
 """
 

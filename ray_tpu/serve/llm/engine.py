@@ -51,6 +51,7 @@ verification are pure scheduling transforms, never result transforms.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import logging
@@ -63,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import jax_utils as _jax_utils
 from ray_tpu._private import locksan
 from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
@@ -75,6 +77,7 @@ from ray_tpu.serve.llm.paging import (TIER_HOST, TIER_POOL, TIER_STORE,
                                       prefix_fingerprints)
 from ray_tpu.serve.llm.scheduler import EngineOverloadedError, FCFSScheduler
 from ray_tpu.util import metrics as _metrics
+from ray_tpu.util import tpu_profiler as _tpu_profiler
 
 logger = logging.getLogger(__name__)
 
@@ -279,6 +282,15 @@ class TokenStream:
                 ev.wait(remain)
 
 
+# The worker loop's phases, named by what the chip is doing meanwhile.
+# The loop is always in exactly one (GenerationEngine._phase), so over
+# any interval their times sum to the thread's wall time.
+LOOP_PHASES = ("idle", "commands", "sweep", "admit", "prefill_dispatch",
+               "tick_dispatch", "device_wait", "emit")
+# ...and each but `idle` is a region of that name in a profiler capture.
+_PHASE_ANNOTATION = {p: "engine." + p for p in LOOP_PHASES if p != "idle"}
+
+
 @dataclasses.dataclass
 class EngineStats:
     queue_depth: int
@@ -303,6 +315,26 @@ class EngineStats:
     kv_demotions: int = 0
     kv_promotions: int = 0
     session_resurrections: int = 0
+    # Where the worker loop's time went, cumulative seconds by phase
+    # (LOOP_PHASES): over any interval the eight differences sum to the
+    # thread's wall time.
+    loop_s_idle: float = 0.0
+    loop_s_commands: float = 0.0
+    loop_s_sweep: float = 0.0
+    loop_s_admit: float = 0.0
+    loop_s_prefill_dispatch: float = 0.0
+    loop_s_tick_dispatch: float = 0.0
+    loop_s_device_wait: float = 0.0
+    loop_s_emit: float = 0.0
+    loop_turns: int = 0               # loop turns that left the idle wait
+    loop_turns_with_chunk: int = 0    # ...that dispatched a prefill chunk
+    token_gaps: int = 0               # tokens out that are no request's first
+    token_gaps_stalled: int = 0       # ...out of a turn that swept pages,
+    #                                   ran a command or compiled
+    kv_sweeps: int = 0                # sweeps/demotions that moved >= 1 page
+    kv_sweep_s: float = 0.0           # ...and the loop time they took
+    jit_compiles: int = 0             # process-wide (jax_utils listener)
+    jit_compile_s: float = 0.0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -612,7 +644,6 @@ class GenerationEngine:
         self._completed = 0
         self._rejected = 0
         self._cancelled = 0
-        self._tick_seq = 0  # decode-tick span sampling counter
         self._committed_blocks = 0   # outstanding worst-case demand
         self._prefix_hits = 0
         self._prefix_misses = 0
@@ -625,6 +656,21 @@ class GenerationEngine:
         # appends) backing the ttft_p99_s gauge in load_info — the SLO
         # attainment signal the autopilot broker arbitrates on.
         self._recent_ttft = collections.deque(maxlen=256)
+        # Loop-time accounting (worker thread writes; stats() reads).
+        self._loop_s = dict.fromkeys(LOOP_PHASES, 0.0)
+        self._phase_name = "idle"
+        self._phase_t: Optional[float] = None   # None: loop not running
+        self._phase_ann = None        # the open profiler annotation
+        self._turns = 0
+        self._turns_with_chunk = 0
+        self._token_gaps = 0
+        self._token_gaps_stalled = 0
+        self._turn_gaps = 0           # gaps emitted in the running turn
+        self._turn_stalled = False    # ...which swept or ran a command
+        self._kv_sweeps = 0
+        self._kv_sweep_s = 0.0
+        self._sweep: Optional[Dict] = None   # the running sweep's account
+        _jax_utils.install_compile_listener()
 
         self._tags = {"engine": name}
         QUEUE_GAUGE.set(0, tags=self._tags)
@@ -808,6 +854,8 @@ class GenerationEngine:
                 if not self._commands:
                     return
                 fn, fut = self._commands.popleft()
+            self._phase("commands")
+            self._turn_stalled = True
             try:
                 res = fn()
             except BaseException as e:  # fail THIS command only
@@ -1011,46 +1059,99 @@ class GenerationEngine:
                 < max(0.05, float(_cfg.serve_kv_tier_sweep_s)):
             return 0
         self._last_sweep = now
-        moved = self._demote_t0(self._prefix.demote_candidates(
-            max(0.0, float(_cfg.serve_kv_demote_idle_s))))
-        moved += self._demote_t1(max(0.0,
-                                     float(_cfg.serve_kv_t2_idle_s)))
-        if self._store is not None \
-                and now - self._last_store_gc >= 60.0:
-            self._last_store_gc = now
-            self._store.sweep(float(_cfg.serve_kv_store_ttl_s))
-        self._update_kv_gauges()
+        with self._sweeping("idle"):
+            moved = self._demote_t0(self._prefix.demote_candidates(
+                max(0.0, float(_cfg.serve_kv_demote_idle_s))))
+            moved += self._demote_t1(max(0.0,
+                                         float(_cfg.serve_kv_t2_idle_s)))
+            if self._store is not None \
+                    and now - self._last_store_gc >= 60.0:
+                self._last_store_gc = now
+                self._store.sweep(float(_cfg.serve_kv_store_ttl_s))
+            self._update_kv_gauges()
         return moved
 
-    def _demote_t0(self, nodes) -> int:
+    @contextlib.contextmanager
+    def _sweeping(self, cause: str):
+        """One demotion pass (`cause`: idle | pressure | flush) under
+        the loop's `sweep` phase, whatever phase it interrupts.  The
+        demote methods account into `self._sweep`; a pass that moved
+        pages counts in kv_sweeps / kv_sweep_s, marks the turn's token
+        gaps as stalled and leaves ONE engine.tier_sweep span — at most
+        one per serve_kv_tier_sweep_s, so the ring never churns.  A
+        pass that found nothing records nothing."""
+        prev = self._phase("sweep")
+        acc = self._sweep = {"pages": 0, "to_t1": 0, "to_t2": 0,
+                             "read_s": 0.0, "compile_s": 0.0,
+                             "frame_s": 0.0, "put_s": 0.0}
+        t0 = time.monotonic()
+        try:
+            yield
+        finally:
+            dur = time.monotonic() - t0
+            self._sweep = None
+            self._phase(prev)
+            if acc["pages"]:
+                self._turn_stalled = True
+                self._kv_sweeps += 1
+                self._kv_sweep_s += dur
+                _tracing.record(
+                    "engine", "engine.tier_sweep", time.time() - dur, dur,
+                    args={"cause": cause, "pages": acc["pages"],
+                          "to_t1": acc["to_t1"], "to_t2": acc["to_t2"],
+                          **{k[:-2] + "_ms": round(acc[k] * 1e3, 3)
+                             for k in ("read_s", "compile_s", "frame_s",
+                                       "put_s")}})
+
+    def _demoted(self, dest: str, frame_s: float, put_s: float) -> None:
+        """Account one page's landing attempt to the running sweep;
+        `dest` "" when it found nowhere to land."""
+        acc = self._sweep
+        acc["frame_s"] += frame_s
+        acc["put_s"] += put_s
+        if dest:
+            acc["pages"] += 1
+            acc["to_" + dest] += 1
+            self._demotions += 1
+            KV_DEMOTIONS_COUNTER.inc(tags={**self._tags, "to": dest})
+
+    def _demote_t0(self, nodes, arena: bool = True) -> int:
         """Move tree-only pool pages (refcount 1, selected by the
         caller) into the arena — or the store when the arena budget is
-        spent.  One batched device read covers the whole set; each
-        node's demotion commits only after its frame landed, so a
-        failed landing just leaves the page hot."""
+        spent, or `arena` is False.  One batched device read covers the
+        whole set; each node's demotion commits only after its frame
+        landed, so a failed landing just leaves the page hot."""
         if not nodes:
             return 0
+        compile_s0 = _jax_utils.compile_counters()[1]
+        t0 = time.monotonic()
         k, v = decode.paged_read_pages_host(
             self._cache, [n.page for n in nodes])
+        # read_s holds the program's compile for a new page count;
+        # compile_s says how much of it that was.
+        self._sweep["read_s"] += time.monotonic() - t0
+        self._sweep["compile_s"] += \
+            _jax_utils.compile_counters()[1] - compile_s0
         moved = 0
         for i, node in enumerate(nodes):
+            t0 = time.monotonic()
             frame = page_frame(k[i], v[i])
             crc = frame_crc(frame)
-            slot = self._tier_arena().put(frame)
+            t1 = time.monotonic()
+            slot = self._tier_arena().put(frame) if arena else None
             if slot is not None:
                 self._prefix.apply_demote(
                     node, TIER_HOST, ("t1", slot, crc, len(frame)))
                 dest = "t1"
             else:
                 fp = self._prefix.path_fp(node)
-                if not self._tier_store().put_page(fp, frame):
-                    continue   # nowhere to land: the page stays hot
-                self._prefix.apply_demote(
-                    node, TIER_STORE, ("t2", fp, crc, len(frame)))
-                dest = "t2"
-            moved += 1
-            self._demotions += 1
-            KV_DEMOTIONS_COUNTER.inc(tags={**self._tags, "to": dest})
+                dest = "t2" if self._tier_store().put_page(fp, frame) \
+                    else ""    # nowhere to land: the page stays hot
+                if dest:
+                    self._prefix.apply_demote(
+                        node, TIER_STORE, ("t2", fp, crc, len(frame)))
+            self._demoted(dest, t1 - t0, time.monotonic() - t1)
+            moved += bool(dest)
         return moved
 
     def _demote_t1(self, min_idle_s: float) -> int:
@@ -1063,17 +1164,19 @@ class GenerationEngine:
         for node in self._prefix.demote_candidates(min_idle_s,
                                                    tier=TIER_HOST):
             _, slot, crc, nbytes = node.payload
+            t0 = time.monotonic()
             frame = self._arena.get(slot)
-            if frame is None or frame_crc(frame) != crc:
-                continue
-            fp = self._prefix.path_fp(node)
-            if not self._tier_store().put_page(fp, frame):
-                continue
-            self._prefix.apply_demote(node, TIER_STORE,
-                                      ("t2", fp, crc, nbytes))
-            moved += 1
-            self._demotions += 1
-            KV_DEMOTIONS_COUNTER.inc(tags={**self._tags, "to": "t2"})
+            ok = frame is not None and frame_crc(frame) == crc
+            t1 = time.monotonic()
+            if ok:
+                fp = self._prefix.path_fp(node)
+                ok = self._tier_store().put_page(fp, frame)
+            if ok:
+                self._prefix.apply_demote(node, TIER_STORE,
+                                          ("t2", fp, crc, nbytes))
+            self._demoted("t2" if ok else "", t1 - t0,
+                          time.monotonic() - t1)
+            moved += bool(ok)
         return moved
 
     def _demote_for_pressure(self, need: int) -> int:
@@ -1087,8 +1190,9 @@ class GenerationEngine:
         short = need - self._alloc.free_pages
         if short <= 0:
             return 0
-        return self._demote_t0(
-            self._prefix.demote_candidates(0.0, limit=short))
+        with self._sweeping("pressure"):
+            return self._demote_t0(
+                self._prefix.demote_candidates(0.0, limit=short))
 
     def kv_flush_to_store(self) -> int:
         """Worker command: demote EVERY demotable page — tree-only
@@ -1097,26 +1201,11 @@ class GenerationEngine:
         dropping, so its sessions resurrect anywhere from T2."""
         if not self._tiering or self._prefix is None:
             return 0
-        store = self._tier_store()
-        flushed = 0
-        nodes = self._prefix.demote_candidates(0.0)
-        if nodes:
-            k, v = decode.paged_read_pages_host(
-                self._cache, [n.page for n in nodes])
-            for i, node in enumerate(nodes):
-                frame = page_frame(k[i], v[i])
-                fp = self._prefix.path_fp(node)
-                if not store.put_page(fp, frame):
-                    continue
-                self._prefix.apply_demote(
-                    node, TIER_STORE,
-                    ("t2", fp, frame_crc(frame), len(frame)))
-                flushed += 1
-                self._demotions += 1
-                KV_DEMOTIONS_COUNTER.inc(tags={**self._tags,
-                                               "to": "t2"})
-        flushed += self._demote_t1(0.0)
-        self._update_kv_gauges()
+        with self._sweeping("flush"):
+            flushed = self._demote_t0(self._prefix.demote_candidates(0.0),
+                                      arena=False)
+            flushed += self._demote_t1(0.0)
+            self._update_kv_gauges()
         return flushed
 
     # ------------------------------------------------------------------
@@ -1282,6 +1371,13 @@ class GenerationEngine:
         now = time.monotonic()
         win = now - self._win_t
         tps = self._win_tokens / win if win > 0.2 else 0.0
+        # The phase the loop is in right now has not been added yet (a
+        # racy read of two fields: off by one phase switch at most).
+        loop_s = dict(self._loop_s)
+        t, name = self._phase_t, self._phase_name
+        if t is not None:
+            loop_s[name] += max(0.0, now - t)
+        jit_compiles, jit_compile_s = _jax_utils.compile_counters()
         return EngineStats(
             queue_depth=self._scheduler.depth
             + (1 if self._prefill is not None else 0),
@@ -1307,7 +1403,16 @@ class GenerationEngine:
                          if self._prefix is not None else 0),
             kv_demotions=self._demotions,
             kv_promotions=self._promotions,
-            session_resurrections=self._resurrections)
+            session_resurrections=self._resurrections,
+            **{f"loop_s_{p}": round(v, 6) for p, v in loop_s.items()},
+            loop_turns=self._turns,
+            loop_turns_with_chunk=self._turns_with_chunk,
+            token_gaps=self._token_gaps,
+            token_gaps_stalled=self._token_gaps_stalled,
+            kv_sweeps=self._kv_sweeps,
+            kv_sweep_s=round(self._kv_sweep_s, 6),
+            jit_compiles=jit_compiles,
+            jit_compile_s=round(jit_compile_s, 6))
 
     # ------------------------------------------------------------------
     # Worker thread
@@ -1318,6 +1423,16 @@ class GenerationEngine:
         except Exception as e:
             logger.exception("engine %s kernel warmup failed", self.name)
             self._fail_all(e)
+        # Loop-time accounting starts with the loop (the warm-up's
+        # compiles above are set-up, not a phase of any turn).
+        self._phase_name, self._phase_t = "idle", time.monotonic()
+        try:
+            self._loop()
+        finally:
+            self._phase("idle")
+            self._phase_t = None
+
+    def _loop(self):
         while True:
             with self._cond:
                 # The idle wait must ALSO break for a due tier sweep:
@@ -1326,9 +1441,11 @@ class GenerationEngine:
                 # the decode pool.
                 while not self._stop and not self._has_work_locked() \
                         and not self._sweep_due():
+                    self._phase("idle")
                     self._cond.wait(timeout=0.1)
                 if self._stop:
                     return
+            compile_s0 = _jax_utils.compile_counters()[1]
             # Commands (KV export/import) run BETWEEN ticks: they own
             # the device + paging state for their duration, and their
             # failures are their caller's, never the batch's.
@@ -1340,6 +1457,38 @@ class GenerationEngine:
             except Exception as e:  # engine-level fault: fail fast,
                 logger.exception("engine %s tick failed", self.name)
                 self._fail_all(e)
+            # Close the turn's books.  Its tokens left at its end, so
+            # whatever stalled the turn — pages swept or demoted, a
+            # command, a compile on any thread — lengthened their gaps.
+            self._turns += 1
+            if self._turn_gaps and (
+                    self._turn_stalled or
+                    _jax_utils.compile_counters()[1] != compile_s0):
+                self._token_gaps_stalled += self._turn_gaps
+            self._turn_gaps = 0
+            self._turn_stalled = False
+
+    def _phase(self, name: str) -> str:
+        """Close the loop's running phase and open `name` (one of
+        LOOP_PHASES); returns the one closed, for a caller that
+        interrupts a phase and resumes it.  The elapsed time goes to
+        the closed phase's counter, so the phases partition the
+        thread's time by construction; no ring event — stats() carries
+        the counters.  Each phase but `idle` is also a region
+        `engine.<name>` in a profiler capture (tpu_profiler.annotate):
+        with no capture running that is one flag check."""
+        now = time.monotonic()
+        prev = self._phase_name
+        self._loop_s[prev] += now - self._phase_t
+        self._phase_t = now
+        if name != prev:
+            if self._phase_ann is not None:
+                self._phase_ann.__exit__(None, None, None)
+            region = _PHASE_ANNOTATION.get(name)
+            self._phase_ann = region and \
+                _tpu_profiler.annotate(region).__enter__()
+            self._phase_name = name
+        return prev
 
     def _warm_kernels(self):
         """Compile the fused tick kernels at worker startup, against the
@@ -1500,6 +1649,7 @@ class GenerationEngine:
         """Advance admission by AT MOST one prefill chunk (the bound on
         how long a tick's decode can be delayed by an arrival)."""
         if self._prefill is None:
+            self._phase("admit")
             slot = self._free_slot()
             if slot is None:
                 return
@@ -1541,6 +1691,7 @@ class GenerationEngine:
                       args={"request_id": req.id,
                             "prefix_hit_tokens": matched_tok})
 
+        self._phase("prefill_dispatch")
         st = self._prefill
         req = st.req
         if req.stream.cancelled:
@@ -1560,6 +1711,7 @@ class GenerationEngine:
             self._cache, jnp.asarray(st.bt_row[None, :]), self.cfg)
         st.next_start = start + width
         st.chunks += 1
+        self._turns_with_chunk += 1   # at most one chunk a turn
         if st.next_start < L:
             return  # more chunks to go; decode proceeds meanwhile
 
@@ -1582,7 +1734,9 @@ class GenerationEngine:
             # chunks are no-ops; this request's duplicates stay private.
             self._prefix.insert(req.prompt,
                                 req.pages[:L // self.page_size])
+        self._phase("device_wait")
         row = np.asarray(logits[0, len(real) - 1])
+        self._phase("emit")
         first = self._sample_host(row, req)
         now = time.monotonic()
         # TTFT stage 3 of 3 — first tick: forcing the prefill logits
@@ -1623,25 +1777,7 @@ class GenerationEngine:
                    if self._slots[s] is not None]
         if not actives:
             return
-        # Sample 1/N ticks as engine.decode_tick spans: the tick runs
-        # thousands of times per second, so recording every one would
-        # be pure ring churn; a sampled span still shows batch width
-        # and tick latency against prefill/transfer activity.  Batch-
-        # level, so no single request's trace claims it.
-        sample = _cfg.trace_decode_tick_sample
-        self._tick_seq += 1
-        t_tick = (time.monotonic()
-                  if sample > 0 and self._tick_seq % sample == 0
-                  and _tracing.enabled() else None)
-        self._decode_tick_inner(actives)
-        if t_tick is not None:
-            _tracing.record("engine", "engine.decode_tick",
-                            time.time() - (time.monotonic() - t_tick),
-                            time.monotonic() - t_tick,
-                            args={"batch": len(actives),
-                                  "sampled_1_in": sample})
-
-    def _decode_tick_inner(self, actives):
+        self._phase("tick_dispatch")
         spec_drafts: Dict[int, List[int]] = {}
         if self.speculate_k:
             for s in actives:
@@ -1663,8 +1799,10 @@ class GenerationEngine:
             self.params, jnp.asarray(self._tok), jnp.asarray(self._pos),
             self._cache, jnp.asarray(self._block_tables), self.cfg,
             with_logits=bool(sample_rows))
+        self._phase("device_wait")
         sampled = np.asarray(sampled)
         logits_np, row_of = self._ship_sample_logits(logits, sample_rows)
+        self._phase("emit")
         now = time.monotonic()
         for s in actives:
             req = self._slots[s]
@@ -1695,8 +1833,10 @@ class GenerationEngine:
             self.params, jnp.asarray(chunk), jnp.asarray(self._pos),
             self._cache, jnp.asarray(self._block_tables), self.cfg,
             with_logits=bool(sample_rows))
+        self._phase("device_wait")
         preds = np.asarray(preds)
         logits_np, row_of = self._ship_sample_logits(logits0, sample_rows)
+        self._phase("emit")
         now = time.monotonic()
         for s in actives:
             req = self._slots[s]
@@ -1764,6 +1904,8 @@ class GenerationEngine:
         else:
             ITL_HISTOGRAM.observe(now - req.last_token_t,
                                   tags=self._tags)
+            self._token_gaps += 1
+            self._turn_gaps += 1
         req.last_token_t = now
         self._tokens_generated += 1
         self._win_tokens += 1

@@ -14,6 +14,16 @@ profile is one directory tree.
         state, m = step(state, tokens)
 
     tpu_profiler.start(); ...; path = tpu_profiler.stop()
+
+A capture of a serving replica also holds the engine loop's phases:
+`serve.llm`'s worker thread wraps each phase of a loop turn in
+`annotate("engine.<phase>")`, so the host plane carries
+`engine.commands`, `engine.sweep`, `engine.admit`,
+`engine.prefill_dispatch`, `engine.tick_dispatch`, `engine.device_wait`
+and `engine.emit` on the profiler's own clock, beside the device plane
+(the idle wait is the absence of all seven).
+`benchmarks/tools/host_gaps.py <file.xplane.pb>` names every idle gap
+of the chip by the phase the host was in.
 """
 
 from __future__ import annotations
@@ -42,14 +52,23 @@ def default_trace_dir() -> str:
 
 
 def start(trace_dir: Optional[str] = None) -> str:
-    """Begin capturing a device trace; returns the trace directory."""
+    """Begin capturing a device trace; returns the trace directory.
+
+    The Python tracer is off and the host tracer at the level that
+    keeps `annotate()`d regions and XLA's own host events: device lines
+    plus named host phases, a small file, and a host that is not slowed
+    by a hook on every Python call — the capture the benchmark reduces
+    (`benchmarks/lib/probes.start_trace` sets the same options)."""
     global _active_dir
     if _active_dir is not None:
         raise RuntimeError(f"a trace is already active: {_active_dir}")
     import jax
     d = trace_dir or default_trace_dir()
     os.makedirs(d, exist_ok=True)
-    jax.profiler.start_trace(d)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(d, profiler_options=opts)
     _active_dir = d
     return d
 
@@ -80,7 +99,8 @@ def trace(trace_dir: Optional[str] = None):
 
 
 def annotate(name: str):
-    """Label a region so it shows up named in the trace (wraps
-    jax.profiler.TraceAnnotation)."""
+    """Label a region so it shows up named in the trace's host plane
+    (wraps jax.profiler.TraceAnnotation).  With no capture running the
+    region costs one check of the profiler's flag (~0.5 us)."""
     import jax
     return jax.profiler.TraceAnnotation(name)
