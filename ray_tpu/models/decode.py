@@ -156,7 +156,13 @@ def init_paged_cache(cfg, num_pages: int, page_size: int) -> Dict:
     same masked static-shape style as the contiguous cache: gather to a
     fixed virtual width, mask columns past the row's position).  The
     serve engine reserves page 0 as a trash page for inactive rows'
-    writes; this initializer doesn't care."""
+    writes; this initializer doesn't care.
+
+    All layers live in ONE array per tensor, and the steps that use the
+    pool never take a layer out of it: paged_chunk_step indexes it at
+    [l, pages] inside its layer scan (see there), page import/export at
+    [:, pages].  On a chip the pool is gigabytes, and a step that
+    formed cache["k"][l] would move a layer's worth of it per layer."""
     shape = (cfg.n_layers, num_pages, page_size, _kv_heads(cfg),
              cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
@@ -215,6 +221,18 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     like the contiguous chunk_step — unmasked columns hold bit-identical
     values to a contiguous cache, so paging is invisible to results.
 
+    The pool rides the layer scan as part of its CARRY — (x, k, v) with
+    k/v the whole [L, P, page, Hkv, Dh] tensors and the layer index l
+    scanned from arange(L) — and each layer scatters its chunk at
+    [l, w_pages, w_offs] and gathers its rows' pages at
+    [l, block_tables]; no per-layer slice cache["k"][l] is formed.  The
+    compiler then updates the donated buffer in place and a call
+    touches only the pages it writes and reads.  Held as the scan's
+    xs/ys instead, every layer's pool is sliced out, updated and
+    stacked into a second pool, which is copied whole after the loop:
+    six pool-sized moves a call and ~4 GiB of temporaries at a 7B
+    model's widths (tests/test_tpu_compile.py holds the line).
+
     Callers must keep pos+t within nblk*page (writes past the table
     would clip into the last block).  Returns (logits [B, t, V] fp32,
     updated cache)."""
@@ -236,15 +254,17 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     mask = (kcols[None, None, :] <= cols[:, :, None]) \
         & (kcols[None, None, :] >= pad_lo[:, None, None])
 
-    def layer(x, inputs):
-        lp, ck_l, cv_l = inputs                  # [P, psz, Hkv, Dh]
+    Hkv, Dh = cache["k"].shape[3:]
+
+    def layer(carry, inputs):
+        x, ck_all, cv_all = carry                # [L, P, psz, Hkv, Dh]
+        lp, l = inputs
         h = _rmsnorm(x, lp["ln1"])
         q, k, v = _qkv(lp, h, positions, cfg)
-        ck_l = ck_l.at[w_pages, w_offs].set(k.astype(ck_l.dtype))
-        cv_l = cv_l.at[w_pages, w_offs].set(v.astype(cv_l.dtype))
-        Hkv, Dh = ck_l.shape[2], ck_l.shape[3]
-        ck = ck_l[block_tables].reshape(B, S, Hkv, Dh)
-        cv = cv_l[block_tables].reshape(B, S, Hkv, Dh)
+        ck_all = ck_all.at[l, w_pages, w_offs].set(k.astype(ck_all.dtype))
+        cv_all = cv_all.at[l, w_pages, w_offs].set(v.astype(cv_all.dtype))
+        ck = ck_all[l, block_tables].reshape(B, S, Hkv, Dh)
+        cv = cv_all[l, block_tables].reshape(B, S, Hkv, Dh)
         rep = q.shape[2] // Hkv
         qg = q.reshape(B, t, Hkv, rep, Dh)
         scores = jnp.einsum("bqgrk,bsgk->bgrqs", qg.astype(jnp.float32),
@@ -256,10 +276,11 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
         out = out.reshape(B, t, q.shape[2], Dh)
         x = x + _attn_out(lp, out, cfg)
         x = _ffn(lp, x, cfg)
-        return x, (ck_l, cv_l)
+        return (x, ck_all, cv_all), None
 
-    x, (ck, cv) = lax.scan(layer, x,
-                           (params["blocks"], cache["k"], cache["v"]))
+    (x, ck, cv), _ = lax.scan(
+        layer, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cache["k"].shape[0])))
     return _final_logits(params, x, cfg), {"k": ck, "v": cv}
 
 

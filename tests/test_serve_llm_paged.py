@@ -253,6 +253,94 @@ def test_paged_writes_touch_only_own_pages():
     assert np.abs(after[:, [4, 5, 6]]).max() > 0
 
 
+def _paged_chunk_step_sliced(params, tokens, pos, cache, block_tables, cfg):
+    """FROZEN copy of paged_chunk_step as it stood before PR 26: each
+    layer's pool is a scanned input, written and gathered as a slice,
+    and stacked back as a scanned output.  The oracle whose bytes the
+    carry-held pool must reproduce; used by nothing else."""
+    from jax import lax
+    B, t = tokens.shape
+    psz = cache["k"].shape[2]
+    S = block_tables.shape[1] * psz
+    pos = jnp.asarray(pos, jnp.int32)
+    cols = jnp.broadcast_to(
+        jnp.reshape(pos, (-1, 1)) + jnp.arange(t)[None, :], (B, t))
+    pad_lo = jnp.zeros((B,), jnp.int32)
+    positions = cols - pad_lo[:, None]
+    x = decode._embed(params, tokens, positions, cfg)
+    w_pages = jnp.take_along_axis(block_tables, cols // psz, axis=1)
+    w_offs = cols % psz
+    kcols = jnp.arange(S)
+    mask = (kcols[None, None, :] <= cols[:, :, None]) \
+        & (kcols[None, None, :] >= pad_lo[:, None, None])
+
+    def layer(x, inputs):
+        lp, ck_l, cv_l = inputs                  # [P, psz, Hkv, Dh]
+        h = decode._rmsnorm(x, lp["ln1"])
+        q, k, v = decode._qkv(lp, h, positions, cfg)
+        ck_l = ck_l.at[w_pages, w_offs].set(k.astype(ck_l.dtype))
+        cv_l = cv_l.at[w_pages, w_offs].set(v.astype(cv_l.dtype))
+        Hkv, Dh = ck_l.shape[2], ck_l.shape[3]
+        ck = ck_l[block_tables].reshape(B, S, Hkv, Dh)
+        cv = cv_l[block_tables].reshape(B, S, Hkv, Dh)
+        rep = q.shape[2] // Hkv
+        qg = q.reshape(B, t, Hkv, rep, Dh)
+        scores = jnp.einsum("bqgrk,bsgk->bgrqs", qg.astype(jnp.float32),
+                            ck.astype(jnp.float32)) \
+            * cfg.head_dim ** -0.5
+        scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bgrqs,bsgk->bqgrk", probs.astype(cv.dtype), cv)
+        out = out.reshape(B, t, q.shape[2], Dh)
+        x = x + decode._attn_out(lp, out, cfg)
+        x = decode._ffn(lp, x, cfg)
+        return x, (ck_l, cv_l)
+
+    x, (ck, cv) = lax.scan(layer, x,
+                           (params["blocks"], cache["k"], cache["v"]))
+    return decode._final_logits(params, x, cfg), {"k": ck, "v": cv}
+
+
+@pytest.mark.parametrize("vector_pos", [False, True],
+                         ids=["scalar_pos", "vector_pos"])
+@pytest.mark.parametrize("t", [1, 4, 8])
+@pytest.mark.parametrize("cfg", [GPT_CFG, LLAMA_CFG], ids=["gpt", "llama"])
+def test_paged_pool_as_carry_keeps_the_sliced_bodys_bytes(cfg, t, vector_pos):
+    """The pool held in the scan's carry gives the same bytes as the
+    per-layer slices did: logits, K and V, through a permuted block
+    table over a pool that already holds something; and in every layer
+    the pages outside the written set stay as they were."""
+    params = _params(cfg)
+    psz, nblk, B = 4, 6, 2
+    shape = decode.init_paged_cache(cfg, 2 * nblk + 1, psz)["k"].shape
+    pool = {n: jax.random.normal(jax.random.PRNGKey(s), shape, cfg.dtype)
+            for n, s in (("k", 60), ("v", 61))}
+    tables = jnp.asarray([[11, 3, 9, 1, 7, 5], [2, 12, 6, 4, 10, 8]],
+                         jnp.int32)
+    tokens = jax.random.randint(jax.random.PRNGKey(62), (B, t), 1,
+                                cfg.vocab_size)
+    starts = np.asarray([5, 13], np.int32)       # row 1 straddles pages
+    pos = jnp.asarray(starts) if vector_pos else jnp.int32(starts[0])
+    new, old = (jax.jit(f, static_argnums=5)(params, tokens, pos, pool,
+                                             tables, cfg)
+                for f in (decode.paged_chunk_step, _paged_chunk_step_sliced))
+    np.testing.assert_array_equal(np.asarray(new[0]), np.asarray(old[0]))
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(new[1][n]),
+                                      np.asarray(old[1][n]))
+    cols = (starts if vector_pos else starts[:1].repeat(B))[:, None] \
+        + np.arange(t)
+    written = set(np.take_along_axis(np.asarray(tables), cols // psz,
+                                     axis=1).ravel().tolist())
+    untouched = [p for p in range(shape[1]) if p not in written]
+    assert len(untouched) >= shape[1] - 2 * 3
+    for n in ("k", "v"):
+        got, was = np.asarray(new[1][n]), np.asarray(pool[n])
+        np.testing.assert_array_equal(got[:, untouched], was[:, untouched])
+        assert (got[:, sorted(written)] != was[:, sorted(written)]).any(
+            axis=(1, 2, 3, 4)).all()             # every layer wrote
+
+
 # ---------------------------------------------------------------------------
 # Engine: the parity property sweep
 

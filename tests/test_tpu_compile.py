@@ -1,5 +1,6 @@
 """The main path's kernels and steps, asked of the TPU's own compiler at
-the 737M GPT's widths — for a v5e that is described, not attached
+the 737M GPT's widths (the paged steps at the benchmark's two
+configurations' too) — for a v5e that is described, not attached
 (on-chip-measurement guide, section 2.3).  Nothing runs; what the chip's
 compiler would refuse (a tiling it cannot lower, too much VMEM, a
 program that does not fit HBM) is refused here, at no chip time.
@@ -10,6 +11,7 @@ are compiled directly.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -18,12 +20,21 @@ import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from ray_tpu.models import decode, gpt  # noqa: E402
+from ray_tpu.models import decode, gpt, llama  # noqa: E402
 from ray_tpu.ops import flash_attention as fa  # noqa: E402
 from ray_tpu.serve.llm import engine  # noqa: E402
 
 CFG = gpt.GPTConfig(vocab_size=32000, d_model=2048, n_heads=16, n_layers=12,
                     d_ff=8192, max_seq=1024, dtype=jnp.bfloat16, remat=False)
+# The benchmark's two configurations (benchmarks/configs/).
+MISTRAL_D16 = llama.LlamaConfig(
+    vocab_size=32768, d_model=4096, n_heads=32, n_kv_heads=8, n_layers=16,
+    d_ff=14336, max_seq=4096, rope_theta=1e6, dtype=jnp.bfloat16,
+    remat=False, use_flash=False)
+INTERNLM2 = llama.LlamaConfig(
+    vocab_size=92544, d_model=2048, n_heads=16, n_kv_heads=8, n_layers=24,
+    d_ff=8192, max_seq=4096, rope_theta=1e6, dtype=jnp.bfloat16,
+    remat=False, use_flash=False)
 
 
 @pytest.fixture(scope="module")
@@ -64,28 +75,58 @@ def test_flash_kernels_compile(chip, shape):
     assert text.count("tpu_custom_call") >= 3
 
 
+def _pool_results(text, pool_shape):
+    """Of an optimised HLO text: the instructions whose result is one
+    layer's pool, and the copies / update-slices whose result is the
+    whole pool."""
+    layer = "bf16[%s]" % ",".join(map(str, pool_shape[1:]))
+    whole = "bf16[%s]" % ",".join(map(str, pool_shape))
+    results = [ln.split(" = ", 1)[1] for ln in text.splitlines()
+               if " = " in ln]
+    per_layer = [r for r in results if r.startswith(layer)]
+    moved = [r for r in results if r.startswith(whole)
+             and re.search(r"\b(copy|dynamic-update-slice)\(", r)]
+    return per_layer, moved
+
+
+# model, engine rows, blocks a row, pages (the engine's kv_pages + the
+# trash page)
+STEP_MODELS = {"gpt737m": (CFG, gpt, 8, 1024 // 16, 8 * 64 + 1),
+               "mistral-d16": (MISTRAL_D16, llama, 16, 4096 // 16, 3585),
+               "internlm2": (INTERNLM2, llama, 16, 4096 // 16, 2049)}
+
+
 @pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
-def test_paged_step_compiles(chip, step):
-    """The engine's own jitted decode tick ([8, 1]) and prefill chunk
-    ([1, 32]) over a page pool of 8 rows x 1024 tokens."""
-    rows, page, blocks = 8, 16, 1024 // 16
+@pytest.mark.parametrize("model", list(STEP_MODELS))
+def test_paged_step_compiles(chip, model, step):
+    """The engine's own jitted decode tick ([rows, 1]) and prefill chunk
+    ([1, 32]): the 737M GPT over 8 rows x 1024 tokens, and the
+    benchmark's two configurations at their real widths and pools.  The
+    pool is gigabytes there, and a step may hold no second one: no
+    temporary the size of a layer's pool, no copy of the whole."""
+    cfg, mod, rows, blocks, pages = STEP_MODELS[model]
     params = _on(chip, jax.eval_shape(
         lambda: jax.tree_util.tree_map(
-            lambda x: x.astype(CFG.dtype),
-            gpt.init_params(CFG, jax.random.PRNGKey(0)))))
+            lambda x: x.astype(cfg.dtype),
+            mod.init_params(cfg, jax.random.PRNGKey(0)))))
     cache = _on(chip, jax.eval_shape(
-        lambda: decode.init_paged_cache(CFG, rows * blocks + 1, page)))
+        lambda: decode.init_paged_cache(cfg, pages, 16)))
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
 
     if step == "decode_tick":
         lowered = engine._paged_tick.lower(
-            params, i32(rows), i32(rows), cache, i32(rows, blocks), CFG,
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
             with_logits=False)
     else:
         lowered = engine._prefill_chunk.lower(
-            params, i32(1, 32), i32(), cache, i32(1, blocks), CFG)
-    mem = lowered.compile().memory_analysis()
-    # params (1.5 GB bf16) + pool (0.8 GB) + temporaries, on a 16 GB chip
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 << 30
+            params, i32(1, 32), i32(), cache, i32(1, blocks), cfg)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # with the pool as the layer scan's xs/ys: 4.19 / 3.94 GiB (Mistral
+    # tick / chunk), 3.38 / 3.13 (InternLM2); as its carry 0.126 / 0.0003
+    assert mem.temp_size_in_bytes < 1 << 29, mem.temp_size_in_bytes / 2**30
+    per_layer, moved = _pool_results(compiled.as_text(), cache["k"].shape)
+    assert not per_layer, per_layer[:4]
+    assert not moved, moved[:4]
