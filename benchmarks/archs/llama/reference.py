@@ -21,15 +21,16 @@ from __future__ import annotations
 RMS_EPS = 1e-6
 
 
-def forward(params, tokens, *, n_heads: int, n_kv_heads: int,
-            rope_theta: float):
+def forward(params, tokens, c):
     """tokens [T] int32 -> logits [T, V] float32; full causal attention
-    over the whole sequence."""
+    over the whole sequence.  `c` is the configuration file's dict."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     f32 = jnp.float32
+    n_heads, n_kv_heads = c["num_attention_heads"], c["num_key_value_heads"]
+    rope_theta = float(c["rope_theta"])
 
     def rms(x, w):
         return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)

@@ -9,14 +9,21 @@ directory, and so is every metric:
     <root>/<paths[0]>/traffic/<traffic>.json    mix or training job
     <root>/<paths[0]>/metrics/<metric>.json     {"reader", "args"} only
     <root>/<paths[0]>/readers/<reader>.py       read(obs, **args) -> float
+    <root>/<paths[0]>/archs/<arch>.py           or a package archs/<arch>/
+
+A configuration names its architecture (key `arch`): the module that
+builds the program's model from the file's sizes, draws its weights,
+holds its plain reference and counts the operations and bytes its calls
+need (the default one's `__init__.py` lists what the harness asks
+for).  The harness looks the name up and never compares it.
 
 BENCHMARK.json alone owns a metric's unit, layer, `moves` and cells.  A
 tagged name (`device_idle_share.chat`) with no file of its own is read
 by its base name's file (`device_idle_share.json`), so a new cell that
 reports a quantity already defined adds entries and no metric file.
 
-A later PR adds a cell, a configuration, a mix or a metric by adding
-files and entries; nothing here is edited for it.
+A later PR adds a cell, a configuration, a mix, a metric or an
+architecture by adding files and entries; nothing here is edited for it.
 """
 
 from __future__ import annotations
@@ -24,10 +31,52 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
-from typing import Any, Dict, List, Optional
+import re
+import sys
+import zlib
+from typing import Any, Dict, List, Optional, Sequence
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_ROOT = os.path.dirname(HERE)
+
+
+def find_module(kind: str, name: str, dirs: Sequence[str]):
+    """The module `<dir>/<kind>/<name>.py`, or the package
+    `<dir>/<kind>/<name>/`, of the first of `dirs` that has one, loaded
+    once a process under a name made from its path (two roots can each
+    bring a `<name>`)."""
+    for base in dirs:
+        flat = os.path.join(base, kind, name + ".py")
+        package = os.path.join(base, kind, name)
+        for path, search in ((flat, None),
+                             (os.path.join(package, "__init__.py"),
+                              [package])):
+            if not os.path.exists(path):
+                continue
+            modname = "benchmarks_%s_%s_%08x" % (
+                kind, re.sub(r"\W", "_", name), zlib.crc32(path.encode()))
+            if modname not in sys.modules:
+                spec = importlib.util.spec_from_file_location(
+                    modname, path, submodule_search_locations=search)
+                mod = importlib.util.module_from_spec(spec)
+                sys.modules[modname] = mod   # a package's `from . import`
+                try:
+                    spec.loader.exec_module(mod)
+                except BaseException:
+                    del sys.modules[modname]
+                    raise
+            return sys.modules[modname]
+    raise KeyError(f"no {kind}/{name}.py and no {kind}/{name}/ under "
+                   f"{list(dirs)}")
+
+
+def arch_of(config: Dict[str, Any], bench_dir: str = HERE):
+    """The architecture module a configuration names; searched like a
+    reader, in `bench_dir` first and the benchmark's own after it.  A
+    worker process calls this with the registry's `dir`, which is all
+    it needs to find what the driver found."""
+    return find_module("archs", config.get("arch", "llama"),
+                       (bench_dir, HERE))
 
 
 class Registry:
@@ -37,7 +86,6 @@ class Registry:
         # file read: for a sweep made by hand (tools/measure.py), never
         # for a judged run.
         self.overrides = overrides or {}
-        self._readers: Dict[str, Any] = {}
         self.root = os.path.abspath(root)
         with open(os.path.join(self.root, "BENCHMARK.json")) as f:
             self.spec = json.load(f)
@@ -85,18 +133,7 @@ class Registry:
         readers directory is searched after the registry's, so a cell
         registered elsewhere (a test's temporary directory) can reuse
         them."""
-        if name in self._readers:
-            return self._readers[name]
-        for base in (self.dir, HERE):
-            path = os.path.join(base, "readers", name + ".py")
-            if os.path.exists(path):
-                spec = importlib.util.spec_from_file_location(
-                    f"benchmarks_reader_{name}", path)
-                mod = importlib.util.module_from_spec(spec)
-                spec.loader.exec_module(mod)
-                self._readers[name] = mod.read
-                return mod.read
-        raise KeyError(f"no reader {name!r}")
+        return find_module("readers", name, (self.dir, HERE)).read
 
     def read_metrics(self, cell: str, group: str, obs: Dict[str, Any]
                      ) -> Dict[str, Dict[str, Any]]:

@@ -10,7 +10,6 @@ the engine's own page pool, with the plain reference.
 
 from __future__ import annotations
 
-import functools
 import os
 from typing import Any, Dict
 
@@ -49,17 +48,21 @@ class BenchLLMServer(LLMServer):
         return True
 
     def probe_check_logits(self, seed: int, prompt_len: int,
-                           n_decode: int) -> Dict[str, Any]:
+                           n_decode: int, config: Dict[str, Any],
+                           bench_dir: str) -> Dict[str, Any]:
         """Prefill `prompt_len` seeded tokens chunk by chunk, then decode
         `n_decode` greedy tokens tick by tick, through the engine's own
         programs and pool (between ticks, on the engine's worker thread);
-        compare every position's logits with the plain reference's one
-        full forward over the same tokens."""
+        compare every position's logits with one full forward of the
+        plain reference of `config`'s architecture over the same
+        tokens."""
         import jax
         import jax.numpy as jnp
 
-        from benchmarks.lib import reference
+        from benchmarks.lib.registry import arch_of
         from ray_tpu.serve.llm import engine as engine_mod
+
+        reference = arch_of(config, bench_dir).reference
 
         eng, cfg = self.engine, self.engine.cfg
         rng = np.random.default_rng([int(seed), 0xC0FFEE])
@@ -107,9 +110,8 @@ class BenchLLMServer(LLMServer):
 
         got, tokens = eng.run_on_worker(through_the_engine, timeout=900.0)
         seq = np.concatenate([prompt, tokens[:n_decode]]).astype(np.int32)
-        ref = np.asarray(jax.jit(functools.partial(
-            reference.forward, n_heads=cfg.n_heads,
-            n_kv_heads=cfg.n_kv_heads, rope_theta=cfg.rope_theta))(
+        ref = np.asarray(jax.jit(
+            lambda params, tokens: reference(params, tokens, config))(
                 eng.params, jnp.asarray(seq)))
         diff = np.abs(got - ref)
         return {"positions": int(len(seq)), "prefill_positions": prompt_len,

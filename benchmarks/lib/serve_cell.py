@@ -41,14 +41,16 @@ class Request:
         self.done = False
 
 
-def deploy(config: Dict[str, Any], seed: int, platform: str, log):
+def deploy(config: Dict[str, Any], seed: int, platform: str, log,
+           bench_dir: str):
     from benchmarks.lib.model import serving_loader
     from benchmarks.lib.replica import bench_deployment
     from ray_tpu import serve
 
     serve.start()
     options = {"num_tpus": 1} if platform == "tpu" else {"num_cpus": 1}
-    dep = bench_deployment(serving_loader(config, seed, platform),
+    dep = bench_deployment(serving_loader(config, seed, platform,
+                                          bench_dir),
                            engine_config=dict(config["serving"]["engine"]),
                            ray_actor_options=options)
     t0 = time.time()
@@ -206,7 +208,7 @@ def run(reg, cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
         if platform == "tpu" and chips < cell["chips"]:
             raise RuntimeError(f"{chips} TPU chip(s) on this host, "
                                f"{cell['chips']} needed")
-        handle, start_s = deploy(config, seed, platform, log)
+        handle, start_s = deploy(config, seed, platform, log, reg.dir)
         info = handle.replica_info.remote().result(timeout=START_TIMEOUT_S)
         if info["platform"] != platform:
             raise RuntimeError(f"the replica computes on "
@@ -245,8 +247,8 @@ def run(reg, cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
             time.sleep(0.2)
         check = config["serving"]["check"]
         obs["check"] = handle.probe_check_logits.remote(
-            seed, check["prompt_len"], check["decode_tokens"]).result(
-                timeout=900)
+            seed, check["prompt_len"], check["decode_tokens"], config,
+            reg.dir).result(timeout=900)
         obs["check"]["tolerance"] = check["tolerance"]
         leftovers += handle.probe_host_files.remote().result(timeout=60)
     finally:

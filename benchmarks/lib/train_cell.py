@@ -1,14 +1,17 @@
 """One run of a training cell: `JaxTrainer` with one worker that holds
 every chip the cell asks for, the mesh from `ScalingConfig`, the
-program's own `llama.make_train_step`, weights and batches from the
-seed on the device.  The loop below runs inside that worker; the driver
-stays off jax.
+program's own train step as the configuration's architecture hands it
+over (`make_train_step`, `param_specs`, `batch_axes`), weights and
+batches from the seed on the device.  The loop below runs inside that
+worker; the driver stays off jax.
 """
 
 from __future__ import annotations
 
 import time
 from typing import Any, Dict, Optional
+
+from benchmarks.lib.registry import arch_of
 
 
 def train_loop(cfg: Dict[str, Any]) -> None:
@@ -21,8 +24,6 @@ def train_loop(cfg: Dict[str, Any]) -> None:
     from benchmarks.lib import model, probes, trace_reduce
     from ray_tpu._private.jax_utils import device_facts
     from ray_tpu.air import session
-    from ray_tpu.models import llama
-    from ray_tpu.models.gpt import BATCH_AXES
 
     facts = device_facts()
     if facts["platform"] != cfg["platform"]:
@@ -31,15 +32,16 @@ def train_loop(cfg: Dict[str, Any]) -> None:
     job, seconds = cfg["job"], cfg["seconds"]
     mesh = session.get_mesh()
     t_init = time.time()
-    state, opt, mcfg = model.train_state(cfg["config"], job, cfg["seed"],
-                                         mesh)
-    step = llama.make_train_step(mcfg, mesh=mesh, optimizer=opt, donate=True)
+    arch = arch_of(cfg["config"], cfg["bench_dir"])
+    state, opt, mcfg = model.train_state(arch, cfg["config"], job,
+                                         cfg["seed"], mesh)
+    step = arch.make_train_step(mcfg, mesh, opt)
     key = model.seed_key(cfg["seed"] + 1)
     B, T = job["batch"], job["seq"]
     make_batch = jax.jit(
         lambda i: jax.random.randint(jax.random.fold_in(key, i),
                                      (B, T + 1), 0, mcfg.vocab_size),
-        out_shardings=NamedSharding(mesh, P(BATCH_AXES, None)))
+        out_shardings=NamedSharding(mesh, P(arch.batch_axes(), None)))
 
     def one(i):
         nonlocal state
@@ -107,6 +109,14 @@ def run(reg, cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
 
     config = reg.config(cell["config"])
     job = reg.traffic(cell["traffic"])
+    arch = arch_of(config, reg.dir)
+    lacks = [n for n in ("param_specs", "make_train_step", "batch_axes")
+             if not hasattr(arch, n)]
+    if lacks:
+        raise RuntimeError(
+            f"cell {cell['name']!r} trains, but the architecture of "
+            f"configuration {cell['config']!r} ({arch.__file__}) only "
+            f"serves: it has no {', '.join(lacks)}")
     ray_tpu.init(**(init_kwargs or {}))
     try:
         chips = int(ray_tpu.cluster_resources().get("TPU", 0))
@@ -122,7 +132,8 @@ def run(reg, cell: Dict[str, Any], seed: int, seconds: float, trace: bool,
             train_loop_config={"config": config, "job": job, "seed": seed,
                                "seconds": seconds, "trace": trace,
                                "platform": platform,
-                               "keep_trace": keep_trace},
+                               "keep_trace": keep_trace,
+                               "bench_dir": reg.dir},
             scaling_config=ScalingConfig(num_workers=1, **scaling))
         report = trainer.fit().metrics
     finally:

@@ -6,8 +6,6 @@ pool is reserved whole at start-up, so `memory_peak_bytes` and
 
 import statistics
 
-from benchmarks.lib import costs
-
 
 def read(obs):
     used = [b for b in obs["replica_info"].get("bytes_in_use") or []
@@ -17,7 +15,7 @@ def read(obs):
         return None
     engine = obs["config"]["serving"]["engine"]
     pool = engine["kv_pages"] * engine["page_size"] \
-        * costs.kv_bytes_per_token(obs["config"])
+        * obs["arch"].kv_bytes_per_token(obs["config"])
     free = statistics.fmean(s["kv_blocks_free"] / s["kv_blocks_total"]
                             for s in samples)
     return (max(used) - free * pool) / 1e9

@@ -1,11 +1,11 @@
 """Share (%) of its roofline that the decode tick reached in the traced
 window: the least time the chip could take for the ticks' needed
-operations and bytes (lib/costs.decode_tick: weights once per tick, the
-context actually attended to) over the traced time of `program`.
-Memory bounds it at these sizes; the bound found is noted."""
+operations and bytes (the architecture's `decode_tick`: weights once
+per tick, the context actually attended to) over the traced time of
+`program`.  Memory bounds it at these sizes; the bound found is noted."""
 
-from benchmarks.lib import costs
 from benchmarks.lib import obs as o
+from benchmarks.lib.costs import min_time
 from benchmarks.lib.peaks import peaks_for
 
 
@@ -20,8 +20,8 @@ def read(obs, program):
             rows += 1
             ctx += r.prompt_len + i
     n = len(runs)
-    least = costs.min_time(
-        costs.decode_tick(obs["config"], rows / n, ctx / n),
+    least = min_time(
+        obs["arch"].decode_tick(obs["config"], rows / n, ctx / n),
         peaks_for(obs["replica_info"]["kind"]))
     o.note(obs, f"{program}_bound", least["bound"])
     return 100 * least["seconds"] * n / sum(runs)

@@ -1,12 +1,13 @@
 """Share (%) of its roofline that the single-row prefill chunk reached:
-the mean least time of a chunk (lib/costs.prefill_chunk, averaged over
-every chunk of every prompt sent in the window; the output head only in
-a prompt's last chunk) over the mean traced time of `program`."""
+the mean least time of a chunk (the architecture's `prefill_chunk`,
+averaged over every chunk of every prompt sent in the window; the output
+head only in a prompt's last chunk) over the mean traced time of
+`program`."""
 
 import statistics
 
-from benchmarks.lib import costs
 from benchmarks.lib import obs as o
+from benchmarks.lib.costs import min_time
 from benchmarks.lib.peaks import peaks_for
 
 
@@ -21,7 +22,7 @@ def read(obs, program):
         if r.sent is None or not o.in_window(obs, r.sent):
             continue
         for start in range(0, r.prompt_len, width):
-            m = costs.min_time(costs.prefill_chunk(
+            m = min_time(obs["arch"].prefill_chunk(
                 obs["config"], min(width, r.prompt_len - start), start,
                 with_head=start + width >= r.prompt_len), peaks)
             least.append(m["seconds"])

@@ -35,7 +35,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmarks.lib import stats, traffic  # noqa: E402
-from benchmarks.lib.registry import Registry  # noqa: E402
+from benchmarks.lib.registry import Registry, arch_of  # noqa: E402
 
 
 def log(msg: str) -> None:
@@ -146,6 +146,8 @@ def run_cell(reg: Registry, workload: str, seed: int, seconds: float,
     """One run; returns the last line's object.  `emit` receives the
     earlier (diagnostic) lines."""
     cell = reg.cell(workload)
+    # a configuration whose architecture has no file fails here, by name
+    arch = arch_of(reg.config(cell["config"]), reg.dir)
     kind = reg.traffic(cell["traffic"])["kind"]
     if kind == "serve":
         from benchmarks.lib import serve_cell as driver
@@ -158,6 +160,7 @@ def run_cell(reg: Registry, workload: str, seed: int, seconds: float,
     obs = driver.run(reg, cell, seed, seconds, trace, platform, T_PROC0, log,
                      init_kwargs=init_kwargs, keep_trace=keep_trace)
     summary = summarise(obs)
+    obs["arch"] = arch                       # the readers' yardstick
     group = "per_layer" if trace else "end_to_end"
     metrics = reg.read_metrics(workload, group, obs)
     missing = [m["name"] for m in reg.metrics_for(workload, group)

@@ -1,8 +1,11 @@
 """The harness rehearsed at toy size on the CPU: the last line's
 contract, a generator whose totals do not depend on the seed, the
-sub-window arithmetic, a measurement run that refuses the CPU, cells
-and metrics added as data, and BENCHMARK.json held to its own files."""
+sub-window arithmetic, a measurement run that refuses the CPU, cells,
+metrics and architectures added as files, and BENCHMARK.json held to
+its own files."""
 
+import ast
+import glob
 import json
 import os
 import re
@@ -13,9 +16,10 @@ import pytest
 
 import toy
 from benchmarks import run as bench_run
-from benchmarks.lib import costs, stats, traffic
+from benchmarks.lib import stats, traffic
+from benchmarks.lib.costs import BF16, min_time
 from benchmarks.lib.peaks import peaks_for
-from benchmarks.lib.registry import Registry
+from benchmarks.lib.registry import Registry, arch_of
 
 REPO = toy.REPO
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -167,14 +171,19 @@ def test_training_is_held_to_the_pinned_loss_curve():
 
 # ---------------------------------------------- cells and metrics are data
 
-def test_a_cell_a_config_a_mix_a_metric_and_a_reader_are_only_new_files(
+def test_a_cell_a_config_a_mix_a_metric_a_reader_and_an_architecture_are_only_new_files(  # noqa: E501
         tmp_path):
     """A later PR adds files and one entry each; nothing is edited."""
     root = toy.build(str(tmp_path))
     b = os.path.join(root, "bm")
     os.makedirs(os.path.join(b, "readers"))
+    os.makedirs(os.path.join(b, "archs"))
     with open(os.path.join(b, "configs", "dummy.json"), "w") as f:
-        json.dump(dict(toy.CONFIG, name="dummy", hidden_size=32), f)
+        json.dump(dict(toy.CONFIG, name="dummy", hidden_size=32,
+                       arch="halved"), f)
+    with open(os.path.join(b, "archs", "halved.py"), "w") as f:
+        f.write("def kv_bytes_per_token(c):\n"
+                "    return c['hidden_size'] // 2\n")
     with open(os.path.join(b, "traffic", "burst.json"), "w") as f:
         json.dump(dict(toy.MIXES["chat"], rate_rps=9.0), f)
     with open(os.path.join(b, "metrics", "answer.burst.json"), "w") as f:
@@ -204,6 +213,21 @@ def test_a_cell_a_config_a_mix_a_metric_and_a_reader_are_only_new_files(
     reg = Registry(root)
     cell = reg.cell("dummy-burst")
     assert reg.config(cell["config"])["hidden_size"] == 32
+    # the configuration's architecture: a flat module of the new root,
+    # found by the name in the file, in the driver and (by the
+    # registry's directory alone) in a worker
+    halved = arch_of(reg.config("dummy"), reg.dir)
+    assert halved.__file__ == os.path.join(b, "archs", "halved.py")
+    assert arch_of(reg.config("dummy"), reg.dir) is halved   # loaded once
+    assert halved.kv_bytes_per_token(reg.config("dummy")) == 16
+    assert reg.reader("hbm_filled")(
+        {"arch": halved, "config": dict(toy.CONFIG, hidden_size=2e9),
+         "replica_info": {"bytes_in_use": [5e9]},
+         "samples": [{"kv_blocks_free": 32, "kv_blocks_total": 64}]}) == \
+        5.0 - 0.5 * 64 * 8                 # the new module's bytes a token
+    # one with no `arch` key is the default's, from the benchmark itself
+    assert arch_of(reg.config("toy"), reg.dir).__file__ == os.path.join(
+        toy.BENCH, "archs", "llama", "__init__.py")
     assert reg.traffic(cell["traffic"])["rate_rps"] == 9.0
     names = [m["name"] for m in reg.metrics_for("dummy-burst", "per_layer")]
     # others list their own cells
@@ -273,6 +297,8 @@ def test_costs_match_the_published_sizes():
     reg = Registry(REPO)
     mistral = reg.config("mistral-7b-v0.3-d16")
     intern = reg.config("internlm2-1.8b")
+    costs = arch_of(mistral, reg.dir)
+    assert costs is arch_of(intern, reg.dir)
     assert costs.layer_matmul_params(mistral) == 218_103_808
     assert costs.total_params(mistral) == 16 * 218_103_808 \
         + 2 * 134_217_728 + 33 * 4096
@@ -281,13 +307,198 @@ def test_costs_match_the_published_sizes():
     assert round(costs.total_params(intern) / 1e9, 2) == 1.89
     assert costs.kv_bytes_per_token(intern) == 96 * 1024
     peaks = peaks_for("TPU v5 lite")
-    tick = costs.min_time(costs.decode_tick(mistral, 10, 3000), peaks)
+    tick = min_time(costs.decode_tick(mistral, 10, 3000), peaks)
     assert tick["bound"] == "memory" and 0.009 < tick["seconds"] < 0.0095
-    chunk = costs.min_time(costs.prefill_chunk(mistral, 32, 512, True),
-                           peaks)
+    chunk = min_time(costs.prefill_chunk(mistral, 32, 512, True), peaks)
     assert chunk["bound"] == "memory"
     # 6 x 1.70e9 matmul parameters + causal attention over 4096
     per_tok = costs.train_flops_per_token(intern, 4096)
     assert 1.13e10 < per_tok < 1.15e10
     with pytest.raises(KeyError):
         peaks_for("TPU v9 imaginary")
+
+
+# --------------------------------------------------------- architectures
+
+def _frozen_init(cfg, key, dtype):
+    """`lib/model._init` as PR 26 had it, verbatim: the weights every
+    recorded reading was taken on."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    L, D, H, Hk, Dh, F = (cfg.n_layers, cfg.d_model, cfg.n_heads,
+                          cfg.n_kv_heads, cfg.head_dim, cfg.d_ff)
+    s = 0.02
+    so = s / np.sqrt(2 * L)
+    k = iter(jax.random.split(key, 8))
+
+    def nrm(shape, scale):
+        return (scale * jax.random.normal(next(k), shape, jnp.float32)
+                ).astype(dtype)
+
+    ones = lambda shape: jnp.ones(shape, jnp.float32)  # noqa: E731
+    return {
+        "wte": nrm((cfg.vocab_size, D), s),
+        "blocks": {
+            "ln1": ones((L, D)), "wq": nrm((L, D, H, Dh), s),
+            "wkv": nrm((L, D, 2, Hk, Dh), s), "wo": nrm((L, H, Dh, D), so),
+            "ln2": ones((L, D)), "w_gate": nrm((L, D, F), s),
+            "w_up": nrm((L, D, F), s), "w_down": nrm((L, F, D), so)},
+        "ln_f": ones((D,)),
+        "wlm": nrm((D, cfg.vocab_size), s),
+    }
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_default_architecture_draws_the_weights_it_always_drew(dtype):
+    """Same seed, same weights, bit for bit: the move changed no key,
+    order, shape, scale or cast."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.lib.model import seed_key
+
+    arch = arch_of(toy.CONFIG)
+    cfg = arch.build(toy.CONFIG, max_seq=128, remat=False)
+    dt = getattr(jnp, dtype)
+    key = seed_key(2**31 + 5)
+    new = jax.jit(lambda k: arch.init(cfg, k, dt))(key)
+    old = jax.jit(lambda k: _frozen_init(cfg, k, dt))(key)
+    assert jax.tree_util.tree_structure(new) == \
+        jax.tree_util.tree_structure(old)
+    for a, b in zip(jax.tree_util.tree_leaves(new),
+                    jax.tree_util.tree_leaves(old)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.array_equal(np.asarray(a.astype(jnp.float32)),
+                              np.asarray(b.astype(jnp.float32)))
+    assert new["wlm"].dtype == dt and float(jnp.abs(new["wlm"]).max()) > 0
+
+
+def test_an_architecture_without_a_file_fails_by_name(tmp_path):
+    root = toy.build(str(tmp_path))
+    with open(os.path.join(root, "bm", "configs", "toy.json"), "w") as f:
+        json.dump(dict(toy.CONFIG, arch="mamba9"), f)
+    reg = Registry(root)
+    with pytest.raises(KeyError, match="archs/mamba9"):
+        arch_of(reg.config("toy"), reg.dir)
+    with pytest.raises(KeyError, match="archs/mamba9"):   # before any start
+        bench_run.run_cell(reg, "mistral7b-chat", seed=1, seconds=1.0,
+                           trace=False, platform="cpu")
+
+
+REFERENCES = sorted(
+    glob.glob(os.path.join(toy.BENCH, "archs", "*", "reference.py"))
+    + glob.glob(os.path.join(toy.HERE, "rehearsal", "archs", "*",
+                             "reference.py")))
+
+
+@pytest.mark.parametrize("path", REFERENCES, ids=[
+    os.path.relpath(p, toy.BENCH) for p in REFERENCES])
+def test_a_plain_reference_imports_nothing_of_the_program(path):
+    """Neither `ray_tpu` nor the harness: jax and the standard library."""
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name.split(".")[0] for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add("." if node.level else node.module.split(".")[0])
+    assert imported <= {"__future__", "jax"}, imported
+
+
+def test_every_architecture_of_the_benchmark_gives_what_the_harness_asks():
+    reg = Registry(REPO)
+    assert len(REFERENCES) >= 2
+    for c in reg.spec["configs"]:
+        arch = arch_of(reg.config(c["name"]), reg.dir)
+        for name in ("build", "init", "reference", "matmul_params",
+                     "total_params", "kv_bytes_per_token", "decode_tick",
+                     "prefill_chunk", "train_flops_per_token"):
+            assert callable(getattr(arch, name)), (c["name"], name)
+
+
+# ------------------------------------- a second architecture, as new files
+
+@pytest.fixture(scope="module")
+def gpt_reg(tmp_path_factory):
+    before = sorted(os.listdir(toy.BENCH)), open(
+        os.path.join(REPO, "BENCHMARK.json")).read()
+    root = toy.add_gpt(toy.build(str(tmp_path_factory.mktemp("gptroot"))))
+    assert before == (sorted(os.listdir(toy.BENCH)), open(
+        os.path.join(REPO, "BENCHMARK.json")).read())
+    assert not os.path.exists(os.path.join(toy.BENCH, "archs", "gpt"))
+    return Registry(root)
+
+
+def test_a_second_architecture_serves_a_cell_against_its_own_reference(
+        gpt_reg):
+    """The GPT block (learned positions, fused wqkv, GELU, two
+    feed-forward matrices): the other arm of the program's decode
+    path, brought by `archs/gpt/` in a temporary root."""
+    lines = []
+    out = bench_run.run_cell(gpt_reg, "gpt-chat", seed=2**31 + 17,
+                             seconds=4.0, trace=False, platform="cpu",
+                             init_kwargs={"num_cpus": 6}, emit=lines.append)
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] > 0
+    assert set(out["metrics"]) == {"itl_p90_ms", "setup_s"}
+    assert all(v["value"] > 0 for v in out["metrics"].values())
+    check = json.loads(lines[0])["check"]
+    assert check["finite"] and check["positions"] == 24
+    assert check["max_abs_diff"] <= 1e-3 and check["mean_abs_diff"] <= 1e-4
+    assert check["argmax_equal"] == 24
+    # and the default architecture's reference does not fit these weights
+    assert check["reference_logit_std"] > 100 * check["max_abs_diff"]
+
+
+def test_the_yardstick_readers_count_with_the_cells_architecture(gpt_reg):
+    """`paged_tick_roofline`, `prefill_chunk_roofline` and `hbm_filled_gb`
+    in a cell of the GPT architecture: two feed-forward matrices a layer,
+    not three; as many KV heads as heads."""
+    from benchmarks.lib.serve_cell import Request
+
+    c = gpt_reg.config("toy-gpt")
+    gpt, default = arch_of(c, gpt_reg.dir), arch_of(toy.CONFIG)
+    D, F, L, V = 64, 128, 2, 256
+    assert gpt.layer_matmul_params(c) == 4 * D * D + 2 * D * F
+    assert default.layer_matmul_params(c) == 4 * D * D + 3 * D * F
+    assert gpt.matmul_params(c) == L * (4 * D * D + 2 * D * F) + D * V
+    assert gpt.kv_bytes_per_token(c) == L * 2 * D * BF16
+    assert gpt.total_params(c) == gpt.matmul_params(c) + (V + 128) * D \
+        + (2 * L + 1) * D
+
+    req = Request({"index": 0, "due": 0.0, "prompt_len": 40, "max_new": 5})
+    req.sent, req.token_times = 10.0, [10.5, 11.0, 11.5, 12.0, 12.5]
+    obs = {"arch": gpt, "config": c, "requests": [req], "t_w": 9.0,
+           "t_end": 20.0, "trace_t0": 10.0, "trace_t1": 13.0,
+           "trace": {"programs": {"jit__paged_tick": [2e-6] * 4,
+                                  "jit__prefill_chunk": [3e-6, 5e-6]}},
+           "replica_info": {"kind": "TPU v5 lite", "bytes_in_use": [3e9]},
+           "samples": [{"kv_blocks_free": 16, "kv_blocks_total": 64}]}
+    got = gpt_reg.read_metrics("gpt-chat", "per_layer", obs)
+    peaks = peaks_for("TPU v5 lite")
+    # four decode ticks of one row, contexts 41..44
+    tick = min_time(gpt.decode_tick(c, 1.0, (41 + 42 + 43 + 44) / 4), peaks)
+    assert got["paged_tick_roofline.gpt"]["value"] == pytest.approx(
+        100 * tick["seconds"] * 4 / 8e-6)
+    chunks = [min_time(gpt.prefill_chunk(c, 32, 0, False), peaks),
+              min_time(gpt.prefill_chunk(c, 8, 32, True), peaks)]
+    assert got["prefill_chunk_roofline.gpt"]["value"] == pytest.approx(
+        100 * (chunks[0]["seconds"] + chunks[1]["seconds"]) / 2 / 4e-6)
+    assert got["hbm_filled_gb.gpt"]["value"] == pytest.approx(
+        (3e9 - 0.25 * 64 * 8 * L * 2 * D * BF16) / 1e9)
+    # the same observations on the default's yardstick read otherwise
+    other = gpt_reg.read_metrics("gpt-chat", "per_layer",
+                                 dict(obs, arch=default))
+    assert other["paged_tick_roofline.gpt"]["value"] > \
+        got["paged_tick_roofline.gpt"]["value"]
+
+
+def test_a_training_cell_on_a_serve_only_architecture_says_so(gpt_reg):
+    with pytest.raises(RuntimeError, match="only serves: it has no "
+                       "param_specs, make_train_step, batch_axes"):
+        bench_run.run_cell(gpt_reg, "gpt-train", seed=1, seconds=1.0,
+                           trace=False, platform="cpu")

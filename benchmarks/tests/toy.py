@@ -47,6 +47,45 @@ MIXES = {
 }
 
 
+GPT_CONFIG = dict(CONFIG, name="toy-gpt", arch="gpt", num_key_value_heads=4)
+
+
+def add_gpt(root: str) -> str:
+    """Into a root that `build` made, a second architecture as a later
+    PR would bring one: `archs/gpt/` (copied from rehearsal/), a
+    configuration that names it, one chat-mix cell and its entries.
+    Only new files and appended entries."""
+    b = os.path.join(root, "bm")
+    shutil.copytree(os.path.join(HERE, "rehearsal", "archs"),
+                    os.path.join(b, "archs"))
+    with open(os.path.join(b, "configs", "toy-gpt.json"), "w") as f:
+        json.dump(GPT_CONFIG, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": "toy-gpt", "source": "none",
+                            "file": "bm/configs/toy-gpt.json",
+                            "reduced": [], "why": "a second architecture"})
+    spec["workloads"] += [
+        {"name": "gpt-chat", "config": "toy-gpt", "traffic": "chat",
+         "chips": 1, "why": "the GPT block under the chat mix"},
+        {"name": "gpt-train", "config": "toy-gpt", "traffic": "train4k",
+         "chips": 4, "why": "refused: the architecture only serves"}]
+    judged = {"itl_p90_ms": "gpt-chat", "train_tok_per_s": "gpt-train"}
+    for m in spec["end_to_end"]:
+        if m["name"] in judged:
+            m["workloads"].append(judged[m["name"]])
+    for base, unit in (("paged_tick_roofline", "%"),
+                       ("prefill_chunk_roofline", "%"),
+                       ("hbm_filled_gb", "GB")):   # no metric file of its own
+        spec["per_layer"].append({
+            "name": base + ".gpt", "unit": unit, "better": "higher",
+            "source": "device_trace", "layer": "Kernels",
+            "moves": "itl_p90_ms", "workloads": ["gpt-chat"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    return root
+
+
 def build(root: str) -> str:
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         real = json.load(f)

@@ -27,6 +27,33 @@ sys.path.insert(0, ROOT)
 from benchmarks.lib import stats  # noqa: E402
 
 
+def run_once(spec: dict, root: str, workload: str, seed: int,
+             seconds: float, trace: int, extra=(), tag: str = "") -> dict:
+    """One run of the benchmark's command in a new process started in
+    `root`; its lines parsed, a line printed here."""
+    cmd = spec["command"] + ["--workload", workload, "--seed", str(seed),
+                             "--seconds", str(seconds), "--trace",
+                             str(trace), *extra]
+    t0 = time.time()
+    r = subprocess.run(cmd, cwd=root, capture_output=True, text=True)
+    lines = [ln for ln in r.stdout.splitlines() if ln.strip()]
+    ok = r.returncode == 0 and lines
+    row = {"seed": seed, "trace": trace, "rc": r.returncode,
+           "wall_s": round(time.time() - t0, 1),
+           "last": json.loads(lines[-1]) if ok else None,
+           "earlier": [json.loads(ln) for ln in lines[:-1]] if ok else []}
+    if not ok:
+        row["stderr_tail"] = r.stderr[-3000:]
+        print(r.stderr[-3000:], file=sys.stderr)
+    last = row["last"] or {}
+    print(f"{tag}seed {seed} trace {trace} rc {r.returncode} wall "
+          f"{row['wall_s']}s correct {last.get('correct')} failed "
+          f"{last.get('failed')} " + " ".join(
+              f"{n}={m['value']:.6g}" for n, m in
+              last.get("metrics", {}).items()), flush=True)
+    return row
+
+
 def main() -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--workload", required=True)
@@ -50,35 +77,16 @@ def main() -> int:
     for k in range(args.sets):
         rows = []
         for seed in seeds:
-            cmd = spec["command"] + ["--workload", args.workload, "--seed",
-                                     str(seed), "--seconds", str(seconds),
-                                     "--trace", str(args.trace)]
-            for item in args.set:
-                cmd += ["--set", item]
+            extra = [x for item in args.set for x in ("--set", item)]
             if args.keep_trace:
-                cmd += ["--keep-trace", args.keep_trace]
-            t0 = time.time()
-            r = subprocess.run(cmd, cwd=ROOT, capture_output=True, text=True)
-            lines = [ln for ln in r.stdout.splitlines() if ln.strip()]
-            row = {"set": k, "seed": seed, "rc": r.returncode,
-                   "wall_s": round(time.time() - t0, 1),
-                   "last": json.loads(lines[-1]) if r.returncode == 0
-                   and lines else None,
-                   "earlier": [json.loads(ln) for ln in lines[:-1]]
-                   if r.returncode == 0 else []}
-            if r.returncode != 0:
-                row["stderr_tail"] = r.stderr[-3000:]
-                print(r.stderr[-3000:], file=sys.stderr)
+                extra += ["--keep-trace", args.keep_trace]
+            row = run_once(spec, ROOT, args.workload, seed, seconds,
+                           args.trace, extra, tag=f"set {k} ")
+            row["set"] = k
             with open(log_path, "a") as f:
                 f.write(json.dumps(row) + "\n")
             vals = {n: m["value"] for n, m in
                     (row["last"] or {}).get("metrics", {}).items()}
-            print(f"set {k} seed {seed} rc {r.returncode} "
-                  f"wall {row['wall_s']}s correct "
-                  f"{(row['last'] or {}).get('correct')} "
-                  f"failed {(row['last'] or {}).get('failed')} "
-                  + " ".join(f"{n}={v:.5g}" for n, v in vals.items()),
-                  flush=True)
             rows.append(vals)
         sets.append(rows)
     names = sorted({n for rows in sets for r in rows for n in r})
