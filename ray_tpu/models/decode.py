@@ -38,7 +38,29 @@ from ray_tpu.models.gpt import _rmsnorm
 
 
 def _is_llama(cfg) -> bool:
+    """Which adapter the DENSE body below uses (llama: RoPE, GQA, SwiGLU;
+    else GPT).  Not the model switch: see paged_model."""
     return isinstance(cfg, llama_mod.LlamaConfig)
+
+
+def paged_model(cfg):
+    """The module that runs `cfg` through a paged cache when it is not
+    the dense body of this file: a config names it as `cfg.paged_model`
+    (models/minicpm_sala.py).  Such a module declares its own cache
+    (`init_paged_cache(cfg, num_pages, page_size, num_slots)`), brings
+    `paged_chunk_step` under the contract of the one below, checks the
+    engine's paging against its layout (`check_paging`), counts the
+    keys a tick's rows read and hold (`attn_keys`) and says whether a
+    prefill chunk selects pages (`chunk_selects`).  None for the dense
+    models."""
+    return getattr(cfg, "paged_model", None)
+
+
+def has_row_state(cfg) -> bool:
+    """True when part of a sequence's state lives outside its pages (a
+    recurrent state per decode row): everything that treats a page as
+    the whole of a sequence's state must refuse such a model."""
+    return bool(getattr(cfg, "row_state", False))
 
 
 def _kv_heads(cfg) -> int:
@@ -83,15 +105,18 @@ def _qkv(lp, h, positions, cfg):
     return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
 
+def _swiglu(lp, h, dt):
+    """The SwiGLU feed-forward of a normed h [..., D] (no residual)."""
+    g = jax.nn.silu(jnp.einsum("...d,df->...f", h, lp["w_gate"].astype(dt)))
+    u = jnp.einsum("...d,df->...f", h, lp["w_up"].astype(dt))
+    return jnp.einsum("...f,fd->...d", g * u, lp["w_down"].astype(dt))
+
+
 def _ffn(lp, x, cfg):
     dt = cfg.dtype
     h = _rmsnorm(x, lp["ln2"])
     if _is_llama(cfg):
-        g = jax.nn.silu(jnp.einsum("btd,df->btf", h,
-                                   lp["w_gate"].astype(dt)))
-        u = jnp.einsum("btd,df->btf", h, lp["w_up"].astype(dt))
-        return x + jnp.einsum("btf,fd->btd", g * u,
-                              lp["w_down"].astype(dt))
+        return x + _swiglu(lp, h, dt)
     hh = jax.nn.gelu(jnp.einsum("btd,df->btf", h, lp["w1"].astype(dt)))
     return x + jnp.einsum("btf,fd->btd", hh, lp["w2"].astype(dt))
 
@@ -147,8 +172,11 @@ def insert_cache_slot(cache: Dict, row_cache: Dict, slot) -> Dict:
                 cache["v"], row_cache["v"][:, :1], (0, slot, 0, 0, 0))}
 
 
-def init_paged_cache(cfg, num_pages: int, page_size: int) -> Dict:
-    """Paged KV pool: k/v [L, P, page_size, Hkv, Dh] in cfg.dtype.
+def init_paged_cache(cfg, num_pages: int, page_size: int,
+                     num_slots: Optional[int] = None) -> Dict:
+    """Paged KV pool: k/v [L, P, page_size, Hkv, Dh] in cfg.dtype (a
+    model with its own paged step declares its own pytree, which may
+    hold per-row state for `num_slots` decode rows beside its pages).
 
     Rows of a batch don't own contiguous cache rows here — each row owns
     a BLOCK TABLE of page ids, and attention gathers its keys/values
@@ -163,6 +191,9 @@ def init_paged_cache(cfg, num_pages: int, page_size: int) -> Dict:
     [l, pages] inside its layer scan (see there), page import/export at
     [:, pages].  On a chip the pool is gigabytes, and a step that
     formed cache["k"][l] would move a layer's worth of it per layer."""
+    model = paged_model(cfg)
+    if model is not None:
+        return model.init_paged_cache(cfg, num_pages, page_size, num_slots)
     shape = (cfg.n_layers, num_pages, page_size, _kv_heads(cfg),
              cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
@@ -206,9 +237,14 @@ def paged_read_pages_host(cache: Dict, page_ids) -> Tuple[Any, Any]:
 
 
 def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
-                     block_tables, cfg, pad_lo=None
+                     block_tables, cfg, pad_lo=None, **row
                      ) -> Tuple[Any, Dict]:
     """Decode a chunk of t tokens [B, t] through a PAGED cache.
+
+    A config that names a `paged_model` is run by that module's step
+    (same arguments and results; `row` carries what only a model with
+    per-row state takes: the decode row a single-row chunk belongs to
+    and how many of its tokens are real).
 
     `block_tables` [B, nblk] maps each row's virtual cache columns to
     pages of the pool: virtual column c lives at
@@ -236,6 +272,13 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     Callers must keep pos+t within nblk*page (writes past the table
     would clip into the last block).  Returns (logits [B, t, V] fp32,
     updated cache)."""
+    model = paged_model(cfg)
+    if model is not None:
+        return model.paged_chunk_step(params, tokens, pos, cache,
+                                      block_tables, cfg, pad_lo=pad_lo,
+                                      **row)
+    if row:
+        raise TypeError(f"the dense paged step takes no {sorted(row)}")
     B, t = tokens.shape
     psz = cache["k"].shape[2]
     nblk = block_tables.shape[1]

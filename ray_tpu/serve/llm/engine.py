@@ -70,6 +70,7 @@ from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 from ray_tpu.models import decode
 from ray_tpu.serve.llm.kv_tier import (HostKVArena, KVPageStore,
+                                       refuse_row_state,
                                        frame_crc, page_frame,
                                        split_frame)
 from ray_tpu.serve.llm.paging import (TIER_HOST, TIER_POOL, TIER_STORE,
@@ -335,6 +336,14 @@ class EngineStats:
     kv_sweep_s: float = 0.0           # ...and the loop time they took
     jit_compiles: int = 0             # process-wide (jax_utils listener)
     jit_compile_s: float = 0.0
+    # What attention reads against what a row holds, summed over decode
+    # rows, ticks and attention layers: their ratio is what page
+    # selection saves (1 for a model that attends to all it holds).
+    attn_keys_attended: int = 0
+    attn_keys_resident: int = 0
+    prefill_tokens: int = 0           # prompt tokens run through prefill
+    prefill_tokens_sparse: int = 0    # ...in chunks that selected pages
+    state_resets: int = 0             # per-row recurrent states zeroed
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -404,7 +413,8 @@ class _Request:
 
 
 class _PrefillState:
-    __slots__ = ("req", "slot", "next_start", "bt_row", "t0", "chunks")
+    __slots__ = ("req", "slot", "next_start", "bt_row", "t0", "chunks",
+                 "sparse_chunks")
 
     def __init__(self, req: _Request, slot: int, start: int, bt_row):
         self.req = req
@@ -412,6 +422,7 @@ class _PrefillState:
         self.next_start = start
         self.t0 = time.monotonic()   # prefill-stage span start
         self.chunks = 0
+        self.sparse_chunks = 0       # chunks that attended to chosen pages
         # The row's block table stays PRIVATE until activation: the
         # fused tick scatters a garbage write for every inactive batch
         # row, and the engine-wide table must keep pointing those rows
@@ -478,9 +489,16 @@ def _paged_verify(params, chunk, pos, cache, block_tables, cfg,
 
 @functools.partial(jax.jit, static_argnames=("cfg",),
                    donate_argnames=("cache",))
-def _prefill_chunk(params, tokens, pos, cache, block_table, cfg):
+def _prefill_chunk(params, tokens, pos, cache, block_table, cfg,
+                   slot=None, valid=None):
+    """One single-row prefill chunk.  `slot` and `valid` are passed only
+    for a model with per-row state (decode.has_row_state): the decode
+    row whose state the chunk carries, and how many of the chunk's
+    tokens are real; left out they mean row 0 and the full width."""
+    row = {} if slot is None and valid is None \
+        else {"slot": slot, "valid": valid}
     return decode.paged_chunk_step(params, tokens, pos, cache,
-                                   block_table, cfg)
+                                   block_table, cfg, **row)
 
 
 def _host_sample(row_logits: np.ndarray, temperature: float, top_k: int,
@@ -554,6 +572,13 @@ class GenerationEngine:
             raise NotImplementedError(
                 "continuous batching supports dense models only "
                 "(decode has no MoE routing cache)")
+        self._model = decode.paged_model(cfg)
+        self._row_state = decode.has_row_state(cfg)
+        for on, what in ((enable_prefix_cache, "the prefix cache "
+                          "(enable_prefix_cache=True)"),
+                         (kv_tiering, "KV tiering (kv_tiering=True)")):
+            if on:
+                refuse_row_state(cfg, what)
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
@@ -577,6 +602,10 @@ class GenerationEngine:
         if self.kv_pages < 1:
             raise ValueError("kv_pages must be >= 1")
         self.prefill_chunk = min(prefill_chunk, self._s_virt)
+        if self._model is not None:
+            self._model.check_paging(cfg, page_size=self.page_size,
+                                     prefill_chunk=self.prefill_chunk,
+                                     speculate_k=self.speculate_k)
         self.default_max_new_tokens = default_max_new_tokens
         self.name = name
         # With kv_commit_factor >= 1 a lone request always fits the cap
@@ -599,7 +628,7 @@ class GenerationEngine:
         # Page 0 is the trash page: every inactive row's block table
         # points at it, so the fused tick's scatter writes land there.
         self._cache = decode.init_paged_cache(
-            cfg, self.kv_pages + 1, self.page_size)
+            cfg, self.kv_pages + 1, self.page_size, num_slots)
         self._alloc = BlockAllocator(self.kv_pages, first_page=1)
         self._prefix = (RadixPrefixCache(
             self.page_size, self._alloc,
@@ -608,6 +637,9 @@ class GenerationEngine:
         # --- KV tier hierarchy (T0 pool / T1 host arena / T2 store) ---
         # One page's at-rest frame: K then V bytes of [L, psz, Hkv, Dh].
         self._page_dtype = np.dtype(cfg.dtype)
+        # (Of the dense pool only: a model that declares its own cache
+        # has no frame yet, and every path that would build one refuses
+        # it by name.)
         self._page_kshape = (cfg.n_layers, self.page_size,
                              decode._kv_heads(cfg), cfg.head_dim)
         self._page_k_nbytes = (int(np.prod(self._page_kshape))
@@ -670,6 +702,11 @@ class GenerationEngine:
         self._kv_sweeps = 0
         self._kv_sweep_s = 0.0
         self._sweep: Optional[Dict] = None   # the running sweep's account
+        self._keys_attended = 0
+        self._keys_resident = 0
+        self._prefill_tokens = 0
+        self._prefill_tokens_sparse = 0
+        self._state_resets = 0
         _jax_utils.install_compile_listener()
 
         self._tags = {"engine": name}
@@ -765,6 +802,8 @@ class GenerationEngine:
         prompt = [int(t) for t in prompt]
         if not prompt:
             raise ValueError("prompt must be non-empty")
+        if session_id is not None:
+            refuse_row_state(self.cfg, "a durable session checkpoint")
         max_new = int(self.default_max_new_tokens
                       if max_new_tokens is None else max_new_tokens)
         if max_new < 1:
@@ -876,6 +915,7 @@ class GenerationEngine:
         unreadable tier frame truncates the export there).  Returns
         {"pages" (the pinned pool pages only), "matched_tokens", "k",
         "v"} or None when nothing is cached."""
+        refuse_row_state(self.cfg, "kv_export")
         if self._prefix is None:
             return None
         tokens = [int(t) for t in tokens]
@@ -937,6 +977,7 @@ class GenerationEngine:
         failure the reservation is released whole — the cache is never
         left referencing a partially written page.  Returns the number
         of pages imported (0 = re-prefill instead)."""
+        refuse_row_state(self.cfg, "kv_import")
         if self._prefix is None:
             return 0
         tokens = [int(t) for t in tokens]
@@ -1259,6 +1300,7 @@ class GenerationEngine:
         so resurrection never trades parity for durability).  Returns
         {"tokens", "rng_state", "imported", "cached_pages"} or None
         when no manifest exists."""
+        refuse_row_state(self.cfg, "session_resurrect")
         if not self._tiering or self._prefix is None:
             return None
         man = self._tier_store().get_session(session_id)
@@ -1412,7 +1454,12 @@ class GenerationEngine:
             kv_sweeps=self._kv_sweeps,
             kv_sweep_s=round(self._kv_sweep_s, 6),
             jit_compiles=jit_compiles,
-            jit_compile_s=round(jit_compile_s, 6))
+            jit_compile_s=round(jit_compile_s, 6),
+            attn_keys_attended=self._keys_attended,
+            attn_keys_resident=self._keys_resident,
+            prefill_tokens=self._prefill_tokens,
+            prefill_tokens_sparse=self._prefill_tokens_sparse,
+            state_resets=self._state_resets)
 
     # ------------------------------------------------------------------
     # Worker thread
@@ -1514,7 +1561,18 @@ class GenerationEngine:
         # trash while nothing is admitted).
         _, self._cache = _prefill_chunk(
             self.params, jnp.zeros((1, self.prefill_chunk), jnp.int32),
-            jnp.int32(0), self._cache, bt[:1], self.cfg)
+            jnp.int32(0), self._cache, bt[:1], self.cfg,
+            **self._row_args(0, 0))
+
+    def _row_args(self, slot: int, valid: int) -> Dict:
+        """What a prefill chunk takes beside the dense arguments when the
+        model keeps per-row state: the decode row the request will
+        occupy and the count of real tokens in the chunk (a recurrent
+        state cannot un-see a pad).  Nothing otherwise, so the dense
+        models' program is the one it always was."""
+        if not self._row_state:
+            return {}
+        return {"slot": jnp.int32(slot), "valid": jnp.int32(valid)}
 
     def _has_work_locked(self) -> bool:
         return (self._scheduler.depth > 0 or self._prefill is not None
@@ -1708,9 +1766,17 @@ class GenerationEngine:
         chunk[0, :len(real)] = real
         logits, self._cache = _prefill_chunk(
             self.params, jnp.asarray(chunk), jnp.int32(start),
-            self._cache, jnp.asarray(st.bt_row[None, :]), self.cfg)
+            self._cache, jnp.asarray(st.bt_row[None, :]), self.cfg,
+            **self._row_args(st.slot, len(real)))
         st.next_start = start + width
         st.chunks += 1
+        self._prefill_tokens += len(real)
+        if self._model is not None \
+                and self._model.chunk_selects(self.cfg, start):
+            st.sparse_chunks += 1
+            self._prefill_tokens_sparse += len(real)
+        if self._row_state and start == 0:
+            self._state_resets += 1   # the chunk at 0 zeroes the row's
         self._turns_with_chunk += 1   # at most one chunk a turn
         if st.next_start < L:
             return  # more chunks to go; decode proceeds meanwhile
@@ -1725,6 +1791,7 @@ class GenerationEngine:
         # visible against concurrent decode ticks).
         _span_for(req, "engine.prefill", st.t0, t_fc - st.t0,
                   args={"request_id": req.id, "chunks": st.chunks,
+                        "sparse_chunks": st.sparse_chunks,
                         "prompt_tokens": L,
                         "prefix_hit_tokens": req.prefix_hit_tokens})
         if self._prefix is not None:
@@ -1799,6 +1866,7 @@ class GenerationEngine:
             self.params, jnp.asarray(self._tok), jnp.asarray(self._pos),
             self._cache, jnp.asarray(self._block_tables), self.cfg,
             with_logits=bool(sample_rows))
+        self._count_keys(actives)
         self._phase("device_wait")
         sampled = np.asarray(sampled)
         logits_np, row_of = self._ship_sample_logits(logits, sample_rows)
@@ -1815,6 +1883,18 @@ class GenerationEngine:
             else:
                 t = int(sampled[s])
             self._advance(s, req, [t], now)
+
+    def _count_keys(self, actives) -> None:
+        """attn_keys_*: what this tick's rows hold and what their
+        attention layers read of it (while the device runs the tick).
+        The dense body reads all a row holds, in every layer."""
+        pos = self._pos[actives]
+        if self._model is None:
+            read = held = (int(pos.sum()) + len(actives)) * self.cfg.n_layers
+        else:
+            read, held = self._model.attn_keys(self.cfg, pos)
+        self._keys_attended += read
+        self._keys_resident += held
 
     def _verify_tick(self, actives, spec_drafts):
         """One fused paged_chunk_step verifying every row's pending
@@ -1833,6 +1913,7 @@ class GenerationEngine:
             self.params, jnp.asarray(chunk), jnp.asarray(self._pos),
             self._cache, jnp.asarray(self._block_tables), self.cfg,
             with_logits=bool(sample_rows))
+        self._count_keys(actives)
         self._phase("device_wait")
         preds = np.asarray(preds)
         logits_np, row_of = self._ship_sample_logits(logits0, sample_rows)
@@ -2000,6 +2081,6 @@ class GenerationEngine:
         self._tok[:] = 0
         # Rebuild device state: the donated cache may be mid-flight.
         self._cache = decode.init_paged_cache(
-            self.cfg, self.kv_pages + 1, self.page_size)
+            self.cfg, self.kv_pages + 1, self.page_size, self.num_slots)
         self._reset_paging()
         self._update_occupancy()

@@ -48,6 +48,25 @@ _HDR = struct.Struct("<4sII")
 _MAGIC = b"rtkv"
 
 
+def refuse_row_state(cfg, what: str) -> None:
+    """A frame (page_frame below, and kv_transfer's on the wire) is K
+    then V of every layer for one page: the whole of a sequence's state
+    for the models the tiers, the prefix cache, migration and session
+    checkpoints were built for.  A model that also keeps a recurrent
+    state per decode row (decode.has_row_state) cannot be carried that
+    way: sharing or restoring a prefix would need the state as it stood
+    at the prefix's last page boundary, which nothing snapshots yet.
+    Refused by name rather than served wrong; a no-op for a model whose
+    pages are all of its state."""
+    from ray_tpu.models.decode import has_row_state   # jax: not at import
+    if not has_row_state(cfg):
+        return
+    raise NotImplementedError(
+        f"{what} on a model with per-row recurrent state "
+        f"({type(cfg).__name__}): a page is not the whole of a sequence's "
+        f"state there; missing: state snapshots at page boundaries")
+
+
 def page_frame(k_page: np.ndarray, v_page: np.ndarray) -> bytes:
     """One page's wire/at-rest frame: K bytes then V bytes, contiguous.
     The SAME framing kv_transfer puts on migration frames, so a tier

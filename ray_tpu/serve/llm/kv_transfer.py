@@ -50,7 +50,8 @@ from ray_tpu._private import protocol
 from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 from ray_tpu._private.transfer import run_windowed
-from ray_tpu.serve.llm.kv_tier import frame_crc, page_frame
+from ray_tpu.serve.llm.kv_tier import (frame_crc, page_frame,
+                                       refuse_row_state)
 from ray_tpu.util import metrics as _metrics
 
 logger = logging.getLogger(__name__)
@@ -262,7 +263,9 @@ async def pull_kv_pages(rdv: Dict, tokens: Sequence[int], engine,
     `engine`'s pool.  Returns the number of pages imported; 0 means
     re-prefill (origin had nothing worth shipping, the pool is too hot
     to host the import, or the transfer failed — the pool is NEVER
-    left referencing partial data)."""
+    left referencing partial data).  An engine whose model keeps
+    per-row state is refused: its pages are not a sequence's state."""
+    refuse_row_state(engine.cfg, "a KV migration (pull_kv_pages)")
     t0 = time.monotonic()
     with _tracing.span("serve", "serve.kv_migrate",
                        args={"engine": engine.name,
@@ -396,6 +399,8 @@ def migrate_local(src_engine, dst_engine, tokens: Sequence[int],
     pin/commit/seal sequence as the wire path minus the frames.  Used
     by in-process tests and the bench's crossover leg; returns pages
     imported (0 = re-prefill)."""
+    for eng in (src_engine, dst_engine):
+        refuse_row_state(eng.cfg, "a KV migration (migrate_local)")
     tokens = [int(t) for t in tokens]
     exp = src_engine.run_on_worker(
         lambda: src_engine.kv_export(tokens), timeout=timeout)

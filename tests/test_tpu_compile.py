@@ -130,3 +130,59 @@ def test_paged_step_compiles(chip, model, step):
     per_layer, moved = _pool_results(compiled.as_text(), cache["k"].shape)
     assert not per_layer, per_layer[:4]
     assert not moved, moved[:4]
+
+
+# The third configuration (benchmarks/configs/minicpm-sala-d16.json): a
+# model with its own paged step and cache, built as the benchmark builds
+# it, at the configuration's sizes.
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_sala_paged_step_compiles(chip, step):
+    """Both programs of minicpm-sala-d16: under 1 GiB of temporaries
+    (11.4 GB of weights, pages and state are resident), the page pool
+    never re-laid or copied, and in the tick no array as wide as a row's
+    virtual sequence (`max_seq`): attention reads 128 page slots a row
+    and the scorer one compressed key per 16 tokens."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs", "minicpm-sala-d16.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if step == "decode_tick":
+        lowered = engine._paged_tick.lower(
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
+            with_logits=False)
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes / 2**30
+    # weights 9.39 GiB + pages, compressed keys and state 1.28
+    assert mem.argument_size_in_bytes < 11 * 2**30
+    text = compiled.as_text()
+    pool = "bf16[%s]" % ",".join(map(str, cache["k"].shape))
+    layouts = set(re.findall(re.escape(pool) + r"\{([\d,]+)", text))
+    assert layouts == {"4,3,2,1,0"}, layouts       # never re-laid
+    moved = [ln for ln in text.splitlines()
+             if re.search(r"= " + re.escape(pool) + r"\S* copy\(", ln)]
+    assert not moved, moved[:4]
+    if step == "decode_tick":
+        wide = blocks * e["page_size"]
+        shapes = re.findall(r" = \w+\[([\d,]+)\]", text)
+        assert not [s for s in shapes if str(wide) in s.split(",")]
