@@ -181,8 +181,9 @@ def init_paged_cache(cfg, num_pages: int, page_size: int,
     Rows of a batch don't own contiguous cache rows here — each row owns
     a BLOCK TABLE of page ids, and attention gathers its keys/values
     through the table (vLLM's PagedAttention layout, expressed in the
-    same masked static-shape style as the contiguous cache: gather to a
-    fixed virtual width, mask columns past the row's position).  The
+    same masked static-shape style as the contiguous cache: gather
+    spans of pages up to the width the rows hold, mask columns past the
+    row's position).  The
     serve engine reserves page 0 as a trash page for inactive rows'
     writes; this initializer doesn't care.
 
@@ -236,6 +237,32 @@ def paged_read_pages_host(cache: Dict, page_ids) -> Tuple[Any, Any]:
     return np.ascontiguousarray(k), np.ascontiguousarray(v)
 
 
+# What sizes a span of the dense paged step's attention
+# (paged_span_blocks): the K it gathers for every row of a call, in
+# bytes, and its width in columns.  A turn of the span loop costs ~5 us
+# beside its bytes and a call reads up to one span past its deepest row,
+# so a span is as wide as the gathered K and V stay cheap to hold and
+# no wider than keeps a single row's chunk close to what it has cached.
+# Measured on a v5e (PERF.md section 6, PR 29): 16-row ticks are fastest
+# at 8 MiB (4 and 16 MiB: +5 %, +16 % at rows ~700 deep), a one-row
+# chunk under 1,024 columns at 512 columns (2,048: +2 %).
+_SPAN_BYTES = 8 << 20
+_SPAN_COLS = 512
+
+
+def paged_span_blocks(cache: Dict, rows: int, nblk: int) -> int:
+    """How many consecutive block-table entries one span of the dense
+    paged step's attention covers, from the shapes alone: the pages
+    whose gathered K for all `rows` of the call is _SPAN_BYTES, at most
+    _SPAN_COLS columns and at most the whole table.  A 16-row tick over
+    16-token pages of 8 x 128 bf16 heads walks 16 blocks (256 columns)
+    a span, a single-row chunk 32 blocks.  The engine counts
+    `attn_keys_gathered` with it."""
+    _, _, psz, hkv, dh = cache["k"].shape
+    page = rows * psz * hkv * dh * cache["k"].dtype.itemsize
+    return max(1, min(nblk, _SPAN_BYTES // page, _SPAN_COLS // psz))
+
+
 def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
                      block_tables, cfg, pad_lo=None, **row
                      ) -> Tuple[Any, Dict]:
@@ -252,17 +279,28 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     shared start column — single-row prefill) or a [B] vector (each row
     chunked at its own depth — the fused speculative verify).  Row b's
     chunk K/V is scattered at columns pos[b]..pos[b]+t-1 through its
-    table, then attention gathers the row's pages back to a
-    [B, nblk*page] virtual buffer and masks columns > pos[b]+i exactly
-    like the contiguous chunk_step — unmasked columns hold bit-identical
-    values to a contiguous cache, so paging is invisible to results.
+    table; query i of row b then sees columns pad_lo[b]..pos[b]+i,
+    which hold bit-identical values to a contiguous cache, so paging is
+    invisible to results.
+
+    Attention reads the width the rows HOLD, not the table's: it walks
+    SPANS of paged_span_blocks consecutive table entries up to the
+    deepest row's last column, `ceil((max(pos) + t) / span columns)` of
+    them — a trip count read from `pos` inside the one compiled
+    program, so a call's bytes follow the tokens cached and no table
+    width compiles a program of its own.  Each span's pages are
+    gathered straight from the pool, scored in float32, masked and
+    merged into a running maximum, sum and accumulator (the softmax of
+    the whole row, its sums taken span by span).  The table's tail may
+    be shorter than a span: the slice is then clamped back over columns
+    the span before it covered, and those are masked by their index.
 
     The pool rides the layer scan as part of its CARRY — (x, k, v) with
     k/v the whole [L, P, page, Hkv, Dh] tensors and the layer index l
     scanned from arange(L) — and each layer scatters its chunk at
-    [l, w_pages, w_offs] and gathers its rows' pages at
-    [l, block_tables]; no per-layer slice cache["k"][l] is formed.  The
-    compiler then updates the donated buffer in place and a call
+    [l, w_pages, w_offs] before the span loop, which only reads it, at
+    [l, the span's pages]; no per-layer slice cache["k"][l] is formed.
+    The compiler then updates the donated buffer in place and a call
     touches only the pages it writes and reads.  Held as the scan's
     xs/ys instead, every layer's pool is sliced out, updated and
     stacked into a second pool, which is copied whole after the loop:
@@ -282,7 +320,6 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     B, t = tokens.shape
     psz = cache["k"].shape[2]
     nblk = block_tables.shape[1]
-    S = nblk * psz
     pos = jnp.asarray(pos, jnp.int32)
     offs = jnp.arange(t)
     cols = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)) + offs[None, :],
@@ -293,11 +330,12 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     x = _embed(params, tokens, positions, cfg)
     w_pages = jnp.take_along_axis(block_tables, cols // psz, axis=1)
     w_offs = cols % psz
-    kcols = jnp.arange(S)
-    mask = (kcols[None, None, :] <= cols[:, :, None]) \
-        & (kcols[None, None, :] >= pad_lo[:, None, None])
 
     Hkv, Dh = cache["k"].shape[3:]
+    span = paged_span_blocks(cache, B, nblk)
+    span_cols = span * psz
+    n_spans = (jnp.max(pos) + t + span_cols - 1) // span_cols
+    span_offs = jnp.arange(span_cols)
 
     def layer(carry, inputs):
         x, ck_all, cv_all = carry                # [L, P, psz, Hkv, Dh]
@@ -306,17 +344,41 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
         q, k, v = _qkv(lp, h, positions, cfg)
         ck_all = ck_all.at[l, w_pages, w_offs].set(k.astype(ck_all.dtype))
         cv_all = cv_all.at[l, w_pages, w_offs].set(v.astype(cv_all.dtype))
-        ck = ck_all[l, block_tables].reshape(B, S, Hkv, Dh)
-        cv = cv_all[l, block_tables].reshape(B, S, Hkv, Dh)
         rep = q.shape[2] // Hkv
-        qg = q.reshape(B, t, Hkv, rep, Dh)
-        scores = jnp.einsum("bqgrk,bsgk->bgrqs", qg.astype(jnp.float32),
-                            ck.astype(jnp.float32)) \
-            * cfg.head_dim ** -0.5
-        scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bgrqs,bsgk->bqgrk", probs.astype(cv.dtype), cv)
-        out = out.reshape(B, t, q.shape[2], Dh)
+        qg = q.reshape(B, t, Hkv, rep, Dh).astype(jnp.float32)
+
+        def attend(i, part):
+            top, total, acc = part
+            first = jnp.minimum(i * span, nblk - span)   # as the slice clamps
+            pages = lax.dynamic_slice(block_tables, (0, first), (B, span))
+            ck = ck_all[l, pages].reshape(B, span_cols, Hkv, Dh)
+            cv = cv_all[l, pages].reshape(B, span_cols, Hkv, Dh)
+            kcols = first * psz + span_offs
+            lo = jnp.maximum(pad_lo, i * span_cols)
+            mask = (kcols[None, None, :] <= cols[:, :, None]) \
+                & (kcols[None, None, :] >= lo[:, None, None])
+            scores = jnp.einsum("bqgrk,bsgk->bgrqs", qg,
+                                ck.astype(jnp.float32)) \
+                * cfg.head_dim ** -0.5
+            scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
+            new = jnp.maximum(top, scores.max(-1))
+            # a row none of whose columns has come yet (all of a span
+            # under its pad_lo) keeps -inf: subtract 0 there, not -inf
+            ref = jnp.where(jnp.isfinite(new), new, 0.0)
+            e = jnp.exp(scores - ref[..., None])
+            keep = jnp.exp(top - ref)
+            out = jnp.einsum("bgrqs,bsgk->bgrqk", e.astype(cv.dtype), cv,
+                             preferred_element_type=jnp.float32)
+            return (new, total * keep + e.sum(-1),
+                    acc * keep[..., None] + out)
+
+        stat = jnp.full((B, Hkv, rep, t), -jnp.inf, jnp.float32)
+        _, total, acc = lax.fori_loop(
+            0, n_spans, attend,
+            (stat, jnp.zeros_like(stat),
+             jnp.zeros((B, Hkv, rep, t, Dh), jnp.float32)))
+        out = (acc / total[..., None]).astype(cv_all.dtype)
+        out = jnp.moveaxis(out, 3, 1).reshape(B, t, q.shape[2], Dh)
         x = x + _attn_out(lp, out, cfg)
         x = _ffn(lp, x, cfg)
         return (x, ck_all, cv_all), None

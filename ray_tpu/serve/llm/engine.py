@@ -341,6 +341,11 @@ class EngineStats:
     # selection saves (1 for a model that attends to all it holds).
     attn_keys_attended: int = 0
     attn_keys_resident: int = 0
+    # ...and the columns the same calls pulled from the pool to read
+    # them: every row of the call, whole spans up to the deepest row
+    # (decode.paged_chunk_step); over `resident`, what a tick gathers
+    # for each key its rows hold.
+    attn_keys_gathered: int = 0
     prefill_tokens: int = 0           # prompt tokens run through prefill
     prefill_tokens_sparse: int = 0    # ...in chunks that selected pages
     state_resets: int = 0             # per-row recurrent states zeroed
@@ -629,6 +634,11 @@ class GenerationEngine:
         # points at it, so the fused tick's scatter writes land there.
         self._cache = decode.init_paged_cache(
             cfg, self.kv_pages + 1, self.page_size, num_slots)
+        # Columns one span of a tick's attention gathers for a row (the
+        # dense body's; attn_keys_gathered counts with it).
+        self._tick_span = None if self._model is not None else (
+            self.page_size * decode.paged_span_blocks(
+                self._cache, num_slots, self._max_blocks))
         self._alloc = BlockAllocator(self.kv_pages, first_page=1)
         self._prefix = (RadixPrefixCache(
             self.page_size, self._alloc,
@@ -704,6 +714,7 @@ class GenerationEngine:
         self._sweep: Optional[Dict] = None   # the running sweep's account
         self._keys_attended = 0
         self._keys_resident = 0
+        self._keys_gathered = 0
         self._prefill_tokens = 0
         self._prefill_tokens_sparse = 0
         self._state_resets = 0
@@ -1457,6 +1468,7 @@ class GenerationEngine:
             jit_compile_s=round(jit_compile_s, 6),
             attn_keys_attended=self._keys_attended,
             attn_keys_resident=self._keys_resident,
+            attn_keys_gathered=self._keys_gathered,
             prefill_tokens=self._prefill_tokens,
             prefill_tokens_sparse=self._prefill_tokens_sparse,
             state_resets=self._state_resets)
@@ -1884,17 +1896,26 @@ class GenerationEngine:
                 t = int(sampled[s])
             self._advance(s, req, [t], now)
 
-    def _count_keys(self, actives) -> None:
-        """attn_keys_*: what this tick's rows hold and what their
-        attention layers read of it (while the device runs the tick).
-        The dense body reads all a row holds, in every layer."""
+    def _count_keys(self, actives, t: int = 1) -> None:
+        """attn_keys_*: what this tick's rows hold, what their attention
+        layers read of it and what the call gathered from the pool to
+        read it (while the device runs the tick of `t` tokens a row).
+        The dense body reads all a row holds, in every layer, and
+        gathers for EVERY row of the call whole spans up to the deepest
+        row's last column: the trip count its program reads from the
+        same `_pos`.  A model with its own step reports what it reads."""
         pos = self._pos[actives]
         if self._model is None:
             read = held = (int(pos.sum()) + len(actives)) * self.cfg.n_layers
+            spans = -(-(int(self._pos.max()) + t) // self._tick_span)
+            gathered = self.num_slots * self.cfg.n_layers \
+                * spans * self._tick_span
         else:
             read, held = self._model.attn_keys(self.cfg, pos)
+            gathered = read
         self._keys_attended += read
         self._keys_resident += held
+        self._keys_gathered += gathered
 
     def _verify_tick(self, actives, spec_drafts):
         """One fused paged_chunk_step verifying every row's pending
@@ -1913,7 +1934,7 @@ class GenerationEngine:
             self.params, jnp.asarray(chunk), jnp.asarray(self._pos),
             self._cache, jnp.asarray(self._block_tables), self.cfg,
             with_logits=bool(sample_rows))
-        self._count_keys(actives)
+        self._count_keys(actives, 1 + k)
         self._phase("device_wait")
         preds = np.asarray(preds)
         logits_np, row_of = self._ship_sample_logits(logits0, sample_rows)

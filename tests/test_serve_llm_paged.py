@@ -301,6 +301,21 @@ def _paged_chunk_step_sliced(params, tokens, pos, cache, block_tables, cfg):
     return decode._final_logits(params, x, cfg), {"k": ck, "v": cv}
 
 
+def _same_logits_and_pool(new, old):
+    """(logits, cache) of the span-by-span body against an oracle that
+    takes one softmax over the whole width.  The merge takes the
+    softmax's sums in another order, so from the first attention on the
+    activations differ by float32 rounding: the logits are held to
+    that, and so is what the deeper layers write.  The first layer's
+    K and V are computed before any attention: byte-equal."""
+    np.testing.assert_allclose(np.asarray(new[0]), np.asarray(old[0]),
+                               rtol=1e-5, atol=1e-5)
+    for n in ("k", "v"):
+        got, want = np.asarray(new[1][n]), np.asarray(old[1][n])
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("vector_pos", [False, True],
                          ids=["scalar_pos", "vector_pos"])
 @pytest.mark.parametrize("t", [1, 4, 8])
@@ -324,10 +339,7 @@ def test_paged_pool_as_carry_keeps_the_sliced_bodys_bytes(cfg, t, vector_pos):
     new, old = (jax.jit(f, static_argnums=5)(params, tokens, pos, pool,
                                              tables, cfg)
                 for f in (decode.paged_chunk_step, _paged_chunk_step_sliced))
-    np.testing.assert_array_equal(np.asarray(new[0]), np.asarray(old[0]))
-    for n in ("k", "v"):
-        np.testing.assert_array_equal(np.asarray(new[1][n]),
-                                      np.asarray(old[1][n]))
+    _same_logits_and_pool(new, old)
     cols = (starts if vector_pos else starts[:1].repeat(B))[:, None] \
         + np.arange(t)
     written = set(np.take_along_axis(np.asarray(tables), cols // psz,
@@ -339,6 +351,130 @@ def test_paged_pool_as_carry_keeps_the_sliced_bodys_bytes(cfg, t, vector_pos):
         np.testing.assert_array_equal(got[:, untouched], was[:, untouched])
         assert (got[:, sorted(written)] != was[:, sorted(written)]).any(
             axis=(1, 2, 3, 4)).all()             # every layer wrote
+
+
+def _paged_chunk_step_gathered(params, tokens, pos, cache, block_tables,
+                               cfg, pad_lo=None):
+    """FROZEN copy of the dense paged_chunk_step as it stood from PR 26
+    to PR 28: the pool in the scan's carry, every row's whole table
+    gathered to the virtual width nblk*page in every layer and one
+    softmax over it.  The oracle of the span-by-span body; used by
+    nothing else."""
+    from jax import lax
+    B, t = tokens.shape
+    psz = cache["k"].shape[2]
+    S = block_tables.shape[1] * psz
+    pos = jnp.asarray(pos, jnp.int32)
+    cols = jnp.broadcast_to(
+        jnp.reshape(pos, (-1, 1)) + jnp.arange(t)[None, :], (B, t))
+    if pad_lo is None:
+        pad_lo = jnp.zeros((B,), jnp.int32)
+    positions = cols - pad_lo[:, None]
+    x = decode._embed(params, tokens, positions, cfg)
+    w_pages = jnp.take_along_axis(block_tables, cols // psz, axis=1)
+    w_offs = cols % psz
+    kcols = jnp.arange(S)
+    mask = (kcols[None, None, :] <= cols[:, :, None]) \
+        & (kcols[None, None, :] >= pad_lo[:, None, None])
+    Hkv, Dh = cache["k"].shape[3:]
+
+    def layer(carry, inputs):
+        x, ck_all, cv_all = carry                # [L, P, psz, Hkv, Dh]
+        lp, l = inputs
+        h = decode._rmsnorm(x, lp["ln1"])
+        q, k, v = decode._qkv(lp, h, positions, cfg)
+        ck_all = ck_all.at[l, w_pages, w_offs].set(k.astype(ck_all.dtype))
+        cv_all = cv_all.at[l, w_pages, w_offs].set(v.astype(cv_all.dtype))
+        ck = ck_all[l, block_tables].reshape(B, S, Hkv, Dh)
+        cv = cv_all[l, block_tables].reshape(B, S, Hkv, Dh)
+        rep = q.shape[2] // Hkv
+        qg = q.reshape(B, t, Hkv, rep, Dh)
+        scores = jnp.einsum("bqgrk,bsgk->bgrqs", qg.astype(jnp.float32),
+                            ck.astype(jnp.float32)) \
+            * cfg.head_dim ** -0.5
+        scores = jnp.where(mask[:, None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bgrqs,bsgk->bqgrk", probs.astype(cv.dtype), cv)
+        out = out.reshape(B, t, q.shape[2], Dh)
+        x = x + decode._attn_out(lp, out, cfg)
+        x = decode._ffn(lp, x, cfg)
+        return (x, ck_all, cv_all), None
+
+    (x, ck, cv), _ = lax.scan(
+        layer, (x, cache["k"], cache["v"]),
+        (params["blocks"], jnp.arange(cache["k"].shape[0])))
+    return decode._final_logits(params, x, cfg), {"k": ck, "v": cv}
+
+
+def _span_of(monkeypatch, pool, rows, nblk, blocks):
+    """Make a span of the dense step `blocks` table entries wide for
+    calls of `rows` rows (at the tests' sizes the whole table is far
+    under the bytes a span is sized from)."""
+    _, _, psz, hkv, dh = pool["k"].shape
+    monkeypatch.setattr(decode, "_SPAN_BYTES", blocks * rows * psz * hkv
+                        * dh * pool["k"].dtype.itemsize)
+    assert decode.paged_span_blocks(pool, rows, nblk) == blocks
+
+
+# Spans of 3 blocks = 12 columns.  name: (cfg, blocks a row, t, pos
+# (a scalar or one a row), pad_lo or None, rows on the trash page)
+SPAN_CASES = {
+    "depths_far_apart": (LLAMA_CFG, 7, 1, [1, 25], None, ()),
+    "last_col_of_span": (LLAMA_CFG, 7, 1, 11, None, ()),
+    "first_col_of_next_span": (LLAMA_CFG, 7, 1, 12, None, ()),
+    "second_col_of_next_span": (GPT_CFG, 7, 1, 13, None, ()),
+    "chunk_ends_at_span_edge": (LLAMA_CFG, 7, 4, 8, None, ()),
+    "chunk_straddles_span_edge": (GPT_CFG, 7, 4, [10, 22], None, ()),
+    "chunk_to_the_last_col_ragged_tail": (LLAMA_CFG, 7, 4, [24, 3], None,
+                                          ()),
+    "tick_at_the_last_col_ragged_tail": (GPT_CFG, 7, 1, [27, 0, 14], None,
+                                         ()),
+    "chunk_to_the_last_col_whole_spans": (LLAMA_CFG, 6, 8, 16, None, ()),
+    "chunk_of_8_scalar_pos": (GPT_CFG, 7, 8, 5, None, ()),
+    "chunk_of_8_vector_pos": (LLAMA_CFG, 7, 8, [5, 17], None, ()),
+    "pad_lo_inside_first_span": (GPT_CFG, 7, 1, [9, 20], [3, 7], ()),
+    "pad_lo_past_first_span": (LLAMA_CFG, 7, 4, [15, 21], [2, 14], ()),
+    "inactive_row_on_trash_page": (LLAMA_CFG, 7, 1, [0, 17, 6], None, (0,)),
+    "all_rows_inactive": (GPT_CFG, 7, 1, [0, 0], None, (0, 1)),
+}
+
+
+@pytest.mark.parametrize("case", list(SPAN_CASES))
+def test_paged_span_attention_matches_the_one_shot_body(case, monkeypatch):
+    """Attention walked span by span up to the deepest row gives the
+    logits of one softmax over the whole virtual width (float32
+    rounding: the sums are taken in another order), byte-equal K and V,
+    and leaves every page outside the written set as it was."""
+    cfg, nblk, t, pos, pad_lo, inactive = SPAN_CASES[case]
+    params = _params(cfg)
+    psz, span = 4, 3
+    B = len(pos) if isinstance(pos, list) else 2
+    shape = decode.init_paged_cache(cfg, B * nblk + 1, psz)["k"].shape
+    pool = {n: jax.random.normal(jax.random.PRNGKey(s), shape, cfg.dtype)
+            for n, s in (("k", 70), ("v", 71))}
+    _span_of(monkeypatch, pool, B, nblk, span)
+    tables = 1 + np.random.default_rng(72).permutation(B * nblk).reshape(
+        B, nblk).astype(np.int32)
+    for b in inactive:
+        tables[b] = 0
+    tokens = jax.random.randint(jax.random.PRNGKey(73), (B, t), 1,
+                                cfg.vocab_size)
+    pos_arg = jnp.asarray(pos, jnp.int32)
+    pad = None if pad_lo is None else jnp.asarray(pad_lo, jnp.int32)
+    # a function object of its own each time: a trace made under
+    # another span size is never reused
+    new, old = (jax.jit(lambda *a, f=f: f(*a, cfg, pad_lo=pad))(
+        params, tokens, pos_arg, pool, jnp.asarray(tables))
+        for f in (decode.paged_chunk_step, _paged_chunk_step_gathered))
+    _same_logits_and_pool(new, old)
+    assert np.isfinite(np.asarray(new[0])).all()
+    cols = np.broadcast_to(np.reshape(pos, (-1, 1)), (B, 1)) + np.arange(t)
+    written = set(np.take_along_axis(tables, cols // psz, axis=1).ravel()
+                  .tolist())
+    untouched = [p for p in range(shape[1]) if p not in written]
+    for n in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(new[1][n])[:, untouched],
+                                      np.asarray(pool[n])[:, untouched])
 
 
 # ---------------------------------------------------------------------------
@@ -480,6 +616,79 @@ def test_speculation_accepts_on_predictable_continuation():
     np.testing.assert_array_equal(np.asarray(out), want)
     assert st.spec_accepted_tokens > 0, st
     assert st.spec_drafted_tokens >= st.spec_accepted_tokens
+
+
+# Shapes no other test of this file compiles, so the engine's programs
+# are traced under the span the test sets: 11 blocks a row, 3 a span.
+SPAN_KW = dict(num_slots=2, max_seq=44, prefill_chunk=5, page_size=4,
+               kv_pages=30)
+
+
+def test_attn_keys_gathered_follows_the_deepest_row(monkeypatch):
+    """attn_keys_gathered counts, per tick and layer, whole spans up to
+    the deepest row's last column for EVERY row of the call: a short
+    row beside a long one gathers what the long one needs, a row that
+    crosses a span's edge adds a span, a verify tick reaches 1 + k
+    columns further; attn_keys_resident counts what the rows hold."""
+    L, span = GPT_CFG.n_layers, 12
+    _span_of(monkeypatch, decode.init_paged_cache(GPT_CFG, 31, 4), 2, 11, 3)
+    eng = _parked_engine(**SPAN_KW)
+    assert eng._tick_span == span
+
+    def tick(pos, actives, t=1):
+        before = eng.stats()
+        eng._pos[:] = pos
+        eng._count_keys(actives, t)
+        after = eng.stats()
+        return (after.attn_keys_gathered - before.attn_keys_gathered,
+                after.attn_keys_resident - before.attn_keys_resident)
+
+    assert tick([3, 30], [0, 1]) == (2 * L * 36, (4 + 31) * L)
+    assert tick([3, 0], [0]) == (2 * L * 12, 4 * L)     # one row active
+    assert tick([11, 5], [0, 1]) == (2 * L * 12, (12 + 6) * L)
+    assert tick([12, 5], [0, 1]) == (2 * L * 24, (13 + 6) * L)
+    assert tick([9, 5], [0, 1], t=4) == (2 * L * 24, (10 + 6) * L)
+    assert tick([43, 5], [0, 1]) == (2 * L * 48, (44 + 6) * L)
+
+
+def test_stats_carry_attn_keys_gathered_through_ticks_and_verifies(
+        monkeypatch):
+    """A lone request's ticks are at known depths, so the counter is a
+    sum that can be written down: plain ticks at columns 8..13, then
+    the zero-weight model's verify ticks, which reach 1 + 3 columns."""
+    L, span = GPT_CFG.n_layers, 12
+    _span_of(monkeypatch, decode.init_paged_cache(GPT_CFG, 31, 4), 2, 11, 3)
+
+    def spans(live):
+        return -(-live // span) * span
+
+    async def plain():
+        with GenerationEngine(GPT_PARAMS, GPT_CFG, **SPAN_KW) as eng:
+            await eng.generate(_prompt(80, 8), max_new_tokens=7)
+            return eng.stats()
+
+    st = asyncio.run(plain())
+    # the first token comes from the prefill; six ticks at pos 8..13
+    assert st.attn_keys_gathered == sum(2 * L * spans(p + 1)
+                                        for p in range(8, 14))
+    assert st.attn_keys_resident == sum(p + 1 for p in range(8, 14)) * L
+    assert st.attn_keys_attended == st.attn_keys_resident
+
+    zero = jax.tree_util.tree_map(jnp.zeros_like, GPT_PARAMS)
+    zero["ln_f"] = jnp.ones_like(zero["ln_f"])
+
+    async def verify():
+        with GenerationEngine(zero, GPT_CFG, speculate_k=3,
+                              speculate_ngram=2, **SPAN_KW) as eng:
+            await eng.generate([0] * 8, max_new_tokens=13)
+            return eng.stats()
+
+    st = asyncio.run(verify())
+    # the lookup drafts the one token after the latest match and it
+    # comes true: six verify ticks, two tokens each, from pos 8
+    assert st.spec_accepted_tokens == 6
+    assert st.attn_keys_gathered == sum(2 * L * spans(p + 4)
+                                        for p in range(8, 20, 2))
 
 
 # ---------------------------------------------------------------------------

@@ -96,15 +96,10 @@ STEP_MODELS = {"gpt737m": (CFG, gpt, 8, 1024 // 16, 8 * 64 + 1),
                "internlm2": (INTERNLM2, llama, 16, 4096 // 16, 2049)}
 
 
-@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
-@pytest.mark.parametrize("model", list(STEP_MODELS))
-def test_paged_step_compiles(chip, model, step):
-    """The engine's own jitted decode tick ([rows, 1]) and prefill chunk
-    ([1, 32]): the 737M GPT over 8 rows x 1024 tokens, and the
-    benchmark's two configurations at their real widths and pools.  The
-    pool is gigabytes there, and a step may hold no second one: no
-    temporary the size of a layer's pool, no copy of the whole."""
-    cfg, mod, rows, blocks, pages = STEP_MODELS[model]
+def _compile_step(chip, step, cfg, mod, rows, blocks, pages):
+    """The engine's own jitted decode tick ([rows, 1]) or prefill chunk
+    ([1, 32]) of a dense model, compiled for the described chip from
+    shapes: (the compiled program, the pool's K as a shape)."""
     params = _on(chip, jax.eval_shape(
         lambda: jax.tree_util.tree_map(
             lambda x: x.astype(cfg.dtype),
@@ -122,14 +117,51 @@ def test_paged_step_compiles(chip, model, step):
     else:
         lowered = engine._prefill_chunk.lower(
             params, i32(1, 32), i32(), cache, i32(1, blocks), cfg)
-    compiled = lowered.compile()
+    return lowered.compile(), cache["k"]
+
+
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+@pytest.mark.parametrize("model", list(STEP_MODELS))
+def test_paged_step_compiles(chip, model, step):
+    """The engine's own jitted decode tick ([rows, 1]) and prefill chunk
+    ([1, 32]): the 737M GPT over 8 rows x 1024 tokens, and the
+    benchmark's two configurations at their real widths and pools.  The
+    pool is gigabytes there, and a step may hold no second one: no
+    temporary the size of a layer's pool, no copy of the whole."""
+    cfg, mod, rows, blocks, pages = STEP_MODELS[model]
+    compiled, pool = _compile_step(chip, step, cfg, mod, rows, blocks, pages)
     mem = compiled.memory_analysis()
     # with the pool as the layer scan's xs/ys: 4.19 / 3.94 GiB (Mistral
-    # tick / chunk), 3.38 / 3.13 (InternLM2); as its carry 0.126 / 0.0003
+    # tick / chunk), 3.38 / 3.13 (InternLM2); as its carry 0.126 / 0.0003;
+    # attention span by span (PR 29) 0.0006 / 0.0005
     assert mem.temp_size_in_bytes < 1 << 29, mem.temp_size_in_bytes / 2**30
-    per_layer, moved = _pool_results(compiled.as_text(), cache["k"].shape)
+    per_layer, moved = _pool_results(compiled.as_text(), pool.shape)
     assert not per_layer, per_layer[:4]
     assert not moved, moved[:4]
+
+
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_paged_step_compiles_at_a_32k_width(chip, step):
+    """InternLM2's tick and chunk over a virtual width of 32,768 (2,048
+    blocks a row, the benchmark's 2,048-page pool): attention walks
+    spans of the table up to what the rows hold, so nothing the program
+    keeps is as wide as the table.  The same temporaries as at 4,096
+    (0.0006 / 0.0005 GiB), and no array with a dimension of the width;
+    the body that gathered the virtual width compiled here with 1.03 GiB
+    of temporaries in the tick and 0.063 in the chunk, eight times what
+    it held at 4,096.  What `internlm2-longctx` needs (ROADMAP R0)."""
+    import dataclasses
+    wide = 32768
+    compiled, pool = _compile_step(
+        chip, step, dataclasses.replace(INTERNLM2, max_seq=wide), llama,
+        16, wide // 16, 2049)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 26, mem.temp_size_in_bytes / 2**30
+    text = compiled.as_text()
+    per_layer, moved = _pool_results(text, pool.shape)
+    assert not per_layer and not moved, (per_layer[:4], moved[:4])
+    shapes = re.findall(r" = \w+\[([\d,]+)\]", text)
+    assert not [s for s in shapes if str(wide) in s.split(",")]
 
 
 # The third configuration (benchmarks/configs/minicpm-sala-d16.json): a
