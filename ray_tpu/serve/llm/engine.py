@@ -283,6 +283,17 @@ class TokenStream:
                 ev.wait(remain)
 
 
+# The width of a prefill chunk where the caller names none: the chip's
+# ridge.  A chunk's matmuls read every weight once whatever its width,
+# and in bf16 a parameter is 2 bytes read and 2 FLOPs a token, so a call
+# is bound by the weights' bytes below `peak FLOP/s / peak B/s` tokens
+# (a v5e: 197e12 / 819e9 = 240) and by compute past it: up to there a
+# wider chunk costs little more than a narrow one, and a prompt costs
+# its count of chunks.  256 is the next multiple of every page size in
+# use (16, 64).  Measured on a v5e (PERF.md section 5, PR 33).
+_RIDGE_CHUNK = 256
+
+
 # The worker loop's phases, named by what the chip is doing meanwhile.
 # The loop is always in exactly one (GenerationEngine._phase), so over
 # any interval their times sum to the thread's wall time.
@@ -347,6 +358,8 @@ class EngineStats:
     # for each key its rows hold.
     attn_keys_gathered: int = 0
     prefill_tokens: int = 0           # prompt tokens run through prefill
+    prefill_pad_tokens: int = 0       # ...and the columns those chunks
+    #                                   computed that held no prompt token
     prefill_tokens_sparse: int = 0    # ...in chunks that selected pages
     state_resets: int = 0             # per-row recurrent states zeroed
 
@@ -537,7 +550,22 @@ class GenerationEngine:
       speculate_k / speculate_ngram
                        >0 enables in-engine prompt-lookup speculative
                        decoding for greedy rows (fused verify tick)
-      prefill_chunk    tokens of prompt prefilled per engine tick
+      prefill_chunk    tokens of prompt prefilled per engine tick, the
+                       width of the ONE prefill program an engine
+                       compiles (a prompt's last chunk is padded to
+                       it).  None (the default) is the chip's ridge
+                       width, _RIDGE_CHUNK = 256 tokens: a call reads
+                       every weight once, so up to there a wider chunk
+                       costs little more and a prompt costs its count
+                       of chunks.  Either is clipped to the table's
+                       width, and `engine.prefill_chunk` is the int in
+                       force.  Only a chunk that would run past the
+                       table's end (start > table width - prefill_chunk:
+                       column 3,840 of 4,096 at the default) is
+                       narrowed to what is left, since paged_chunk_step
+                       clips writes past the table into the row's last
+                       block; that narrower program is compiled when a
+                       prompt first reaches it, not at start-up.
       max_queue_len    admission-queue cap; past it submit() raises
                        EngineOverloadedError(reason="queue_full")
       kv_commit_factor submit() bounds OUTSTANDING worst-case page
@@ -552,7 +580,8 @@ class GenerationEngine:
     """
 
     def __init__(self, params, cfg, *, num_slots: int = 4,
-                 max_seq: Optional[int] = None, prefill_chunk: int = 32,
+                 max_seq: Optional[int] = None,
+                 prefill_chunk: Optional[int] = None,
                  max_queue_len: int = 64,
                  default_max_new_tokens: int = 64,
                  name: str = "default",
@@ -564,6 +593,8 @@ class GenerationEngine:
                  kv_store_dir: Optional[str] = None):
         if num_slots < 1:
             raise ValueError("num_slots must be >= 1")
+        if prefill_chunk is None:
+            prefill_chunk = _RIDGE_CHUNK
         if prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         if page_size < 1:
@@ -716,6 +747,7 @@ class GenerationEngine:
         self._keys_resident = 0
         self._keys_gathered = 0
         self._prefill_tokens = 0
+        self._prefill_pad_tokens = 0
         self._prefill_tokens_sparse = 0
         self._state_resets = 0
         _jax_utils.install_compile_listener()
@@ -1470,6 +1502,7 @@ class GenerationEngine:
             attn_keys_resident=self._keys_resident,
             attn_keys_gathered=self._keys_gathered,
             prefill_tokens=self._prefill_tokens,
+            prefill_pad_tokens=self._prefill_pad_tokens,
             prefill_tokens_sparse=self._prefill_tokens_sparse,
             state_resets=self._state_resets)
 
@@ -1783,6 +1816,7 @@ class GenerationEngine:
         st.next_start = start + width
         st.chunks += 1
         self._prefill_tokens += len(real)
+        self._prefill_pad_tokens += width - len(real)
         if self._model is not None \
                 and self._model.chunk_selects(self.cfg, start):
             st.sparse_chunks += 1

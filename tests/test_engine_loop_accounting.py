@@ -10,6 +10,7 @@ where they happen; `benchmarks/readers/stats_delta.py` turns two
 """
 
 import importlib.util
+import json
 import os
 import time
 
@@ -337,6 +338,37 @@ def test_stats_delta_reads(num, den, scale, want):
 ])
 def test_stats_delta_finds_nothing_to_read(obs, num, den):
     assert _stats_delta()(obs, num=num, den=den, scale=1) is None
+
+
+def _metric(name, obs):
+    """metrics/<name>.json read as the harness reads it."""
+    with open(os.path.join(ROOT, "benchmarks", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "stats_delta"
+    return _stats_delta()(obs, **spec["args"])
+
+
+# three prompts of 700 tokens in chunks of 256: 9 chunks, 204 pads
+CHUNKED = {"stats0": {"prefill_tokens": 10, "prefill_pad_tokens": 22,
+                      "loop_turns_with_chunk": 1},
+           "stats1": {"prefill_tokens": 2110, "prefill_pad_tokens": 226,
+                      "loop_turns_with_chunk": 10}}
+
+
+@pytest.mark.parametrize("program,per_chunk,pad_share", [
+    ("with_the_counter", 2100 / 9, 100 * 204 / 2304),
+    ("before_it", 2100 / 9, None),     # a parent commit: nothing, no raise
+])
+def test_prefill_width_metrics_read_the_engines_counters(
+        program, per_chunk, pad_share):
+    obs = CHUNKED if program == "with_the_counter" else {
+        end: {k: v for k, v in st.items() if k != "prefill_pad_tokens"}
+        for end, st in CHUNKED.items()}
+    assert _metric("prefill_tokens_per_chunk", obs) == \
+        pytest.approx(per_chunk)
+    assert _metric("prefill_pad_share", obs) == (
+        None if pad_share is None else pytest.approx(pad_share))
 
 
 # ---------------------------------------------------------------------------

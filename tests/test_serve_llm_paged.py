@@ -26,6 +26,7 @@ import pytest
 from ray_tpu.models import decode, gpt, llama
 from ray_tpu.serve.llm import (BlockAllocator, EngineOverloadedError,
                                GenerationEngine, RadixPrefixCache)
+from ray_tpu.serve.llm import engine as engine_mod
 
 GPT_CFG = gpt.GPTConfig(vocab_size=97, d_model=32, n_heads=4,
                         n_layers=2, d_ff=64, max_seq=64,
@@ -689,6 +690,111 @@ def test_stats_carry_attn_keys_gathered_through_ticks_and_verifies(
     assert st.spec_accepted_tokens == 6
     assert st.attn_keys_gathered == sum(2 * L * spans(p + 4)
                                         for p in range(8, 20, 2))
+
+
+# ---------------------------------------------------------------------------
+# The width of a prefill chunk: named by the caller, or the ridge width
+
+# RoPE, so the table may be wider than any test's prompt: the default
+# width (256) is then in force unclipped, and no chunk below reaches the
+# table's last width.
+WIDE_CFG = llama.LlamaConfig(vocab_size=97, d_model=32, n_heads=4,
+                             n_kv_heads=2, n_layers=2, d_ff=48,
+                             max_seq=1024, dtype=jnp.float32,
+                             remat=False, use_flash=False)
+WIDE_PARAMS = _params(WIDE_CFG)
+WIDE_KW = dict(num_slots=2, max_seq=1024, page_size=4, kv_pages=600,
+               kv_tiering=False)
+SHARED = 12   # tokens of the warm prompt a later one shares: 3 pages
+
+
+def _greedy_cache_free(prompt, n):
+    """n greedy tokens by full forwards of the growing sequence, and the
+    logits the first of them was taken from."""
+    seq, first = list(prompt), None
+    for _ in range(n):
+        logits = np.asarray(llama.forward(
+            WIDE_PARAMS, jnp.asarray([seq]), WIDE_CFG)[0, -1])
+        first = logits if first is None else first
+        seq.append(int(logits.argmax()))
+    return seq[len(prompt):], first
+
+
+@pytest.mark.parametrize(
+    "length", ["shorter", "equal", "multiple_and_rest", "hit_mid_width"])
+@pytest.mark.parametrize("chunk", [8, 32, None],
+                         ids=["chunk8", "chunk32", "default"])
+def test_prefill_chunk_width_keeps_results_and_counts_its_padding(
+        chunk, length, monkeypatch):
+    """Whatever the width of a chunk, named or the default, a prompt's
+    greedy tokens and the logits its first token is taken from are the
+    cache-free forward's; the prompt costs ceil(tokens left to prefill /
+    width) chunks, `prefill_tokens` gains those tokens and
+    `prefill_pad_tokens` the columns the chunks computed beside them."""
+    seen = []
+
+    with GenerationEngine(WIDE_PARAMS, WIDE_CFG, prefill_chunk=chunk,
+                          **WIDE_KW) as eng:
+        width = eng.prefill_chunk
+        assert width == (chunk or engine_mod._RIDGE_CHUNK)
+        sample = eng._sample_host
+        monkeypatch.setattr(
+            eng, "_sample_host",
+            lambda row, req: seen.append(row.copy()) or sample(row, req))
+        L = {"shorter": width - 3, "equal": width,
+             "multiple_and_rest": 2 * width + 3,
+             "hit_mid_width": SHARED + width + 5}[length]
+        prompt = _prompt(900 + L, L, WIDE_CFG)
+        matched = 0
+        if length == "hit_mid_width":
+            # the warm prompt leaves SHARED tokens' pages in the prefix
+            # cache, so the next one starts at column 12: inside a
+            # chunk's width for every width here
+            warm = prompt[:SHARED] + _prompt(7, 3, WIDE_CFG)
+            eng.submit(warm, max_new_tokens=1).result(timeout=120)
+            matched = SHARED
+            seen.clear()
+        s0 = eng.stats()
+        got = eng.submit(prompt, max_new_tokens=3).result(timeout=120)
+        s1 = eng.stats()
+
+    want, first = _greedy_cache_free(prompt, 3)
+    assert list(got) == want
+    np.testing.assert_allclose(seen[0], first, atol=2e-4, rtol=0)
+    chunks = -(-(L - matched) // width)
+    assert s1.prefix_hit_tokens - s0.prefix_hit_tokens == matched
+    assert s1.loop_turns_with_chunk - s0.loop_turns_with_chunk == chunks
+    assert s1.prefill_tokens - s0.prefill_tokens == L - matched
+    assert s1.prefill_pad_tokens - s0.prefill_pad_tokens == \
+        chunks * width - (L - matched)
+
+
+def test_default_prefill_chunk_is_one_program_whatever_the_prompt():
+    """An engine built with no `prefill_chunk` has ONE prefill program:
+    the width its attribute holds (an int), compiled at start-up;
+    prompts of many lengths, padded or several chunks long, compile
+    nothing more while they end before the table's last width."""
+    programs = engine_mod._prefill_chunk._cache_size()
+    # drawn first: the counter is the process's, and jax draws a prompt
+    prompts = [_prompt(n, n, WIDE_CFG)
+               for n in (9, 1, 5, 255, 256, 257, 300, 511, 512, 700)]
+    with GenerationEngine(WIDE_PARAMS, WIDE_CFG, **WIDE_KW) as eng:
+        assert type(eng.prefill_chunk) is int
+        assert eng.prefill_chunk == engine_mod._RIDGE_CHUNK == 256
+        # start-up compiled the tick and the chunk; the first request
+        # compiles what the host does around them
+        eng.submit(prompts[0], max_new_tokens=2).result(timeout=120)
+        warm = eng.stats()
+        for prompt in prompts[1:]:
+            eng.submit(prompt, max_new_tokens=2).result(timeout=120)
+        st = eng.stats()
+    assert st.jit_compiles == warm.jit_compiles
+    assert engine_mod._prefill_chunk._cache_size() <= programs + 1
+    assert st.loop_turns_with_chunk - warm.loop_turns_with_chunk == \
+        1 + 1 + 1 + 1 + 2 + 2 + 2 + 2 + 3
+    # clipped to the table where the table is narrower than the ridge
+    small = _parked_engine(num_slots=1, max_seq=48, page_size=4)
+    assert small.prefill_chunk == small._s_virt == 48
 
 
 # ---------------------------------------------------------------------------

@@ -98,7 +98,8 @@ STEP_MODELS = {"gpt737m": (CFG, gpt, 8, 1024 // 16, 8 * 64 + 1),
 
 def _compile_step(chip, step, cfg, mod, rows, blocks, pages):
     """The engine's own jitted decode tick ([rows, 1]) or prefill chunk
-    ([1, 32]) of a dense model, compiled for the described chip from
+    ([1, 32], or [1, 256]: the width an engine takes when it is given
+    none) of a dense model, compiled for the described chip from
     shapes: (the compiled program, the pool's K as a shape)."""
     params = _on(chip, jax.eval_shape(
         lambda: jax.tree_util.tree_map(
@@ -115,32 +116,39 @@ def _compile_step(chip, step, cfg, mod, rows, blocks, pages):
             params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
             with_logits=False)
     else:
+        width = engine._RIDGE_CHUNK if step == "prefill_chunk_default" \
+            else 32
         lowered = engine._prefill_chunk.lower(
-            params, i32(1, 32), i32(), cache, i32(1, blocks), cfg)
+            params, i32(1, width), i32(), cache, i32(1, blocks), cfg)
     return lowered.compile(), cache["k"]
 
 
-@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+STEPS = ["decode_tick", "prefill_chunk", "prefill_chunk_default"]
+
+
+@pytest.mark.parametrize("step", STEPS)
 @pytest.mark.parametrize("model", list(STEP_MODELS))
 def test_paged_step_compiles(chip, model, step):
     """The engine's own jitted decode tick ([rows, 1]) and prefill chunk
-    ([1, 32]): the 737M GPT over 8 rows x 1024 tokens, and the
-    benchmark's two configurations at their real widths and pools.  The
-    pool is gigabytes there, and a step may hold no second one: no
-    temporary the size of a layer's pool, no copy of the whole."""
+    ([1, 32] and the default [1, 256]): the 737M GPT over 8 rows x 1024
+    tokens, and the benchmark's two configurations at their real widths
+    and pools.  The pool is gigabytes there, and a step may hold no
+    second one: no temporary the size of a layer's pool, no copy of the
+    whole."""
     cfg, mod, rows, blocks, pages = STEP_MODELS[model]
     compiled, pool = _compile_step(chip, step, cfg, mod, rows, blocks, pages)
     mem = compiled.memory_analysis()
     # with the pool as the layer scan's xs/ys: 4.19 / 3.94 GiB (Mistral
     # tick / chunk), 3.38 / 3.13 (InternLM2); as its carry 0.126 / 0.0003;
-    # attention span by span (PR 29) 0.0006 / 0.0005
+    # attention span by span (PR 29) 0.0006 / 0.0005; a 256-token chunk
+    # (PR 33) 0.0007 / 0.0005
     assert mem.temp_size_in_bytes < 1 << 29, mem.temp_size_in_bytes / 2**30
     per_layer, moved = _pool_results(compiled.as_text(), pool.shape)
     assert not per_layer, per_layer[:4]
     assert not moved, moved[:4]
 
 
-@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+@pytest.mark.parametrize("step", STEPS)
 def test_paged_step_compiles_at_a_32k_width(chip, step):
     """InternLM2's tick and chunk over a virtual width of 32,768 (2,048
     blocks a row, the benchmark's 2,048-page pool): attention walks
