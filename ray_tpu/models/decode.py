@@ -46,7 +46,8 @@ def _is_llama(cfg) -> bool:
 def paged_model(cfg):
     """The module that runs `cfg` through a paged cache when it is not
     the dense body of this file: a config names it as `cfg.paged_model`
-    (models/minicpm_sala.py).  Such a module declares its own cache
+    (models/minicpm_sala.py, models/deepseek_v2.py).  Such a module
+    declares its own cache
     (`init_paged_cache(cfg, num_pages, page_size, num_slots)`), brings
     `paged_chunk_step` under the contract of the one below, checks the
     engine's paging against its layout (`check_paging`), counts the
@@ -61,6 +62,15 @@ def has_row_state(cfg) -> bool:
     recurrent state per decode row): everything that treats a page as
     the whole of a sequence's state must refuse such a model."""
     return bool(getattr(cfg, "row_state", False))
+
+
+def pages_are_kv(cfg) -> bool:
+    """False when a model's pages are not K then V of [page, Hkv, Dh]
+    (a latent page: models/deepseek_v2.py).  The radix prefix cache
+    hands out page ids and does not care; what frames a page's bytes
+    (tiers, kv_export / kv_import, migration) must refuse such a model
+    (kv_tier.refuse_unframed)."""
+    return bool(getattr(cfg, "pages_are_kv", True))
 
 
 def _kv_heads(cfg) -> int:

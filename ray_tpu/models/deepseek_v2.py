@@ -1,0 +1,619 @@
+"""DeepSeek-V2: multi-head latent attention (MLA) over a latent page, a
+leading dense SwiGLU layer, then layers of shared + routed SwiGLU experts
+with group-limited top-k routing that drops nothing.  This module is the
+model as the serving engine runs it: a config object, seeded weights,
+the cache it declares, and its own paged step for a prefill chunk and
+for a decode tick.  `models/decode.py` hands a config that names a
+`paged_model` to that module, so the engine's two jitted programs
+(`engine._prefill_chunk`, `engine._paged_tick`) run it as they run every
+model.
+
+The cache (one pytree, `engine._cache`):
+
+  lat   [L, P, page, 640]   one row a token and layer: the compressed
+                            latent (512, normed: all heads' keys and
+                            values), the rotated key part every head
+                            shares (64), and 64 zeros.  576 numbers are
+                            what a token IS (1,152 B); the chip's tile is
+                            128 lanes wide, so they occupy 640 (1,280 B)
+                            whoever pads them.  Kept as two arrays the
+                            64-wide one is padded to 128 by the compiler
+                            (the same bytes), which then re-lays that
+                            whole pool twice a tick (0.5 GB each, by its
+                            own account for a v5e); one array is also one
+                            gather a span, not two
+  moe   [5, 2] int32                 the expert layers' own counters,
+                                     cumulative (COUNTERS; two words
+                                     each, so they do not wrap)
+
+Two attention paths over that one cache.  A prefill chunk EXPANDS: it
+forms k_nope and v of the context from the cached latents (`wk_b`,
+`wv_b`, the two halves of the published kv_b_proj) span by span and
+attends at head width 192 / 128.  A decode tick ABSORBS: `wk_b` goes
+into the query and `wv_b` into the output, so attention runs in the
+512 + 64 latent space and no key or value is ever formed.
+
+The expert layer is told which experts it holds (`experts_held`,
+`expert_offset`): the router scores ALL `n_routed_experts`, the top-k
+are chosen among all of them, and the layer computes
+`shared(x) + sum over (top-k AND held) of w_i expert_i(x)`.  What the
+absent experts would add is left out; nothing stands in for them.
+Routed pairs are sorted by expert and run through one grouped matmul a
+projection (Pallas `megablox.gmm`), which visits only the experts that
+a token chose: an expert no token chose is not read.
+
+Departures from the published modeling file, all relabellings under
+seeded weights: RoPE pairs are (i, i + d/2), not interleaved; kv_b_proj
+is kept as its halves `wk_b` [heads, 128, 512] / `wv_b` [heads, 512, 128],
+head-major as the tick's per-head products read them (latent-major, the
+tick copied both every layer); `q_b_proj` is [rank, heads * 192]
+(heads major); the two shared experts are one SwiGLU of twice the width
+(as published); layers are a tuple, not a stack, so each layer's expert
+weights reach the grouped matmul as an array of their own.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import sys
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+from ray_tpu.models.decode import _swiglu
+from ray_tpu.models.gpt import _rmsnorm
+
+_HI = lax.Precision.HIGHEST
+# Keys one span of attention covers (whole pages), chosen on a v5e at
+# the benchmark's sizes (PERF.md section 6, PR 35).  A tick gathers a
+# span's rows for every row of the call: 64 rows ~3.6k deep take 23.9 /
+# 22.6 / 22.2 / 22.5 ms at 128 / 256 / 512 / 1,024 keys.  A chunk
+# expands a span's keys and values for all heads and scores all its
+# queries against them in float32, [heads, queries, keys]: a 512-token
+# chunk after 3,072 tokens takes 46.3 / 39.7 / 45.9 / 56.8 ms at 64 /
+# 128 / 256 / 512 keys.
+_TICK_SPAN_KEYS = 512
+_CHUNK_SPAN_KEYS = 128
+# The grouped matmul's tiles: rows of routed pairs (a tick of 64 rows
+# reads the same at 32, 64 and 128), and the most of the contraction
+# and of the output a grid step holds.
+_GMM_ROWS = 128
+_GMM_K, _GMM_N = 1024, 512
+
+COUNTERS = ("pairs_routed", "pairs_local", "experts_touched",
+            "experts_held", "load_max")
+_WORD = 30      # a counter is [hi, lo] with lo < 2**30
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepseekV2Config:
+    """Published DeepSeek-V2 sizes by default; `experts_held`,
+    `expert_offset` and `vocab_size` say the share this chip holds.
+    Hashable: the engine passes it as a static argument."""
+    max_seq: int
+    n_layers: int = 60
+    vocab_size: int = 102400
+    d_model: int = 5120
+    n_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    d_ff: int = 12288                 # the leading dense layers
+    first_k_dense: int = 1
+    moe_d_ff: int = 1536
+    n_routed_experts: int = 160       # what the router scores: never cut
+    n_shared_experts: int = 2
+    n_group: int = 8
+    topk_group: int = 3
+    top_k: int = 6
+    routed_scaling_factor: float = 16.0
+    experts_held: Optional[int] = None    # None: all of them
+    expert_offset: int = 0
+    rope_theta: float = 10000.0
+    rope_factor: float = 40.0
+    rope_orig_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.experts_held is None:
+            object.__setattr__(self, "experts_held", self.n_routed_experts)
+        if self.n_routed_experts % self.n_group:
+            raise ValueError("n_routed_experts must be whole groups")
+        if not 0 < self.topk_group <= self.n_group:
+            raise ValueError("topk_group must be 1..n_group")
+        if self.top_k > self.topk_group * (self.n_routed_experts
+                                           // self.n_group):
+            raise ValueError("top_k exceeds the experts of the kept groups")
+        if self.expert_offset < 0 or self.experts_held < 1 \
+                or self.expert_offset + self.experts_held \
+                > self.n_routed_experts:
+            raise ValueError("the held experts must lie among the routed")
+        if not 0 <= self.first_k_dense <= self.n_layers:
+            raise ValueError("first_k_dense must be 0..n_layers")
+
+    @property
+    def head_dim(self) -> int:
+        """A query / key head's width (what the softmax scale is of)."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def n_moe(self) -> int:
+        return self.n_layers - self.first_k_dense
+
+    @property
+    def softmax_scale(self) -> float:
+        """head_dim^-0.5 times YaRN's attention factor squared."""
+        return self.head_dim ** -0.5 * _yarn_mscale(
+            self.rope_factor, self.mscale_all_dim) ** 2
+
+    # -- what models/decode.py and the engine ask a model with its own
+    # paged step ------------------------------------------------------
+    @property
+    def paged_model(self):
+        return sys.modules[__name__]
+
+    # A page here is latents, not K then V of [page, Hkv, Dh]: what
+    # frames pages (tiers, kv_export / kv_import, migration, session
+    # checkpoints) refuses this model by name (kv_tier.refuse_unframed).
+    pages_are_kv = False
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: DeepseekV2Config) -> np.ndarray:
+    """YaRN's rotary frequencies [qk_rope_head_dim / 2]: the published
+    ones where a dimension turns more than `beta_fast` times over the
+    original context, those divided by `rope_factor` where it turns
+    fewer than `beta_slow` times, a linear ramp between."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    exps = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra, inter = base ** -exps, base ** -exps / cfg.rope_factor
+
+    def turns_at(rotations):
+        return dim * math.log(cfg.rope_orig_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(turns_at(cfg.beta_fast)), 0)
+    high = min(math.ceil(turns_at(cfg.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / ((high if high != low else high + 0.001) - low), 0, 1)
+    return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def _rope(x, positions, cfg: DeepseekV2Config):
+    """x [n, ..., d] at positions [n]: pairs (i, i + d/2), in float32."""
+    half = x.shape[-1] // 2
+    ang = positions.astype(jnp.float32)[:, None] * yarn_inv_freq(cfg)[None]
+    ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (half,))
+    m = _yarn_mscale(cfg.rope_factor, cfg.mscale) \
+        / _yarn_mscale(cfg.rope_factor, cfg.mscale_all_dim)
+    cos, sin = jnp.cos(ang) * m, jnp.sin(ang) * m
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                           axis=-1).astype(x.dtype)
+
+
+def _span_pages(keys: int, page_size: int, nblk: int) -> int:
+    return max(1, min(nblk, keys // page_size))
+
+
+def attn_keys(cfg: DeepseekV2Config, pos: np.ndarray) -> Tuple[int, int]:
+    """(keys read, keys held) by one tick's decode rows at positions
+    `pos`, summed over rows and layers: every layer attends to all a
+    row holds."""
+    held = (int(np.asarray(pos).sum()) + len(pos)) * cfg.n_layers
+    return held, held
+
+
+def attn_keys_gathered(cfg: DeepseekV2Config, pos: np.ndarray,
+                       page_size: int, nblk: int) -> int:
+    """Latents one tick pulls from the pool: for EVERY row of the call
+    (`pos` of all decode rows, idle ones at 0) whole spans up to the
+    deepest row's token, the trip count the program reads from `pos`."""
+    cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
+    spans = -(-(int(np.asarray(pos).max()) + 1) // cols)
+    return len(pos) * spans * cols * cfg.n_layers
+
+
+def chunk_selects(cfg: DeepseekV2Config, start: int) -> bool:
+    return False          # attention reads all a row holds
+
+
+def check_paging(cfg: DeepseekV2Config, *, page_size: int,
+                 prefill_chunk: int, speculate_k: int) -> None:
+    if prefill_chunk % page_size:
+        raise ValueError(f"a prefill chunk writes whole latent pages: "
+                         f"prefill_chunk must be a multiple of page_size="
+                         f"{page_size}, got {prefill_chunk}")
+    if speculate_k:
+        raise NotImplementedError(
+            "speculative verify (several tokens a row at per-row "
+            "positions) is not written for the absorbed latent step")
+
+
+# ---------------------------------------------------------------------------
+# Weights and cache
+
+
+def init_params(cfg: DeepseekV2Config, key, dtype=None) -> Dict:
+    """Seeded weights, one dict a layer (normal, std 0.02; projections
+    back into the residual stream 0.02 / sqrt(2 n_layers); the router in
+    float32, as it is applied)."""
+    dtype = dtype or cfg.dtype
+    D, H, F = cfg.d_model, cfg.n_heads, cfg.moe_d_ff
+    qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    s = 0.02
+    so = s / np.sqrt(2 * cfg.n_layers)
+    keys = iter(jax.random.split(key, 2 + 16 * cfg.n_layers))
+
+    def nrm(shape, scale, dt=dtype):
+        return (scale * jax.random.normal(next(keys), shape, jnp.float32)
+                ).astype(dt)
+
+    ones = lambda *shape: jnp.ones(shape, jnp.float32)  # noqa: E731
+
+    def swiglu(width, *lead):
+        return {"w_gate": nrm(lead + (D, width), s),
+                "w_up": nrm(lead + (D, width), s),
+                "w_down": nrm(lead + (width, D), so)}
+
+    def layer(i):
+        lp = {"ln1": ones(D), "wq_a": nrm((D, qr), s), "q_norm": ones(qr),
+              "wq_b": nrm((qr, H * (dn + dr)), s),
+              "wkv_a": nrm((D, kr + dr), s), "kv_norm": ones(kr),
+              "wk_b": nrm((H, dn, kr), s), "wv_b": nrm((H, kr, dv), s),
+              "wo": nrm((H, dv, D), so), "ln2": ones(D)}
+        if i < cfg.first_k_dense:
+            return dict(lp, **swiglu(cfg.d_ff))
+        return dict(lp, router=nrm((D, cfg.n_routed_experts), s,
+                                   jnp.float32),
+                    shared=swiglu(cfg.n_shared_experts * F),
+                    experts=swiglu(F, cfg.experts_held))
+
+    return {"wte": nrm((cfg.vocab_size, D), s),
+            "layers": tuple(layer(i) for i in range(cfg.n_layers)),
+            "ln_f": ones(D), "wlm": nrm((D, cfg.vocab_size), s)}
+
+
+def _lat_width(cfg: DeepseekV2Config) -> int:
+    """A cached row: latent + rotary key part, up to whole tiles."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _lat_row(a, b, cfg: DeepseekV2Config):
+    """[..., 512] and [..., 64] side by side in a row of the cache's
+    width (zeros after them): a token's latent and rotary key part, or a
+    query's two parts against them."""
+    pad = _lat_width(cfg) - a.shape[-1] - b.shape[-1]
+    return jnp.concatenate(
+        [a, b, jnp.zeros(a.shape[:-1] + (pad,), a.dtype)], axis=-1)
+
+
+def init_paged_cache(cfg: DeepseekV2Config, num_pages: int, page_size: int,
+                     num_slots: Optional[int] = None) -> Dict:
+    return {"lat": jnp.zeros((cfg.n_layers, num_pages, page_size,
+                              _lat_width(cfg)), cfg.dtype),
+            "moe": jnp.zeros((len(COUNTERS), 2), jnp.int32)}
+
+
+def read_counters(cache: Dict, cfg: DeepseekV2Config) -> Dict[str, Any]:
+    """The expert layers' cumulative counters as the engine's stats
+    carry them (`moe_<name>`; a host copy of 40 bytes; only the thread
+    that owns the cache may call it: every step donates the cache).
+    `pairs_routed`: tokens x top_k x expert layers, over ticks' live rows
+    and chunks' real tokens; `pairs_local`: those whose expert is held
+    here; `experts_touched` / `experts_held`: held experts with a token /
+    held experts, per tick and expert layer; `load_max`: the busiest
+    held expert's tokens, per call and expert layer, and `load_mean`
+    the mean over the held beside it (= pairs_local / experts held)."""
+    words = np.asarray(cache["moe"]).astype(np.int64)
+    counts = {name: int((hi << _WORD) + lo)
+              for name, (hi, lo) in zip(COUNTERS, words)}
+    counts["load_mean"] = counts["pairs_local"] / cfg.experts_held
+    return counts
+
+
+def _count(counters, adds):
+    lo = counters[:, 1] + jnp.stack(adds).astype(jnp.int32)
+    return jnp.stack([counters[:, 0] + (lo >> _WORD),
+                      lo & ((1 << _WORD) - 1)], axis=1)
+
+
+# ---------------------------------------------------------------------------
+# The expert layer
+
+
+def route(router, h, cfg: DeepseekV2Config):
+    """Group-limited greedy top-k over ALL routed experts, in float32.
+    h [N, D] -> (expert ids [N, top_k], weights [N, top_k] float32):
+    softmax scores; a group scores its best expert; the `topk_group`
+    best groups are kept; the top_k best experts inside them; weights
+    are the scores themselves (not renormalised) times
+    `routed_scaling_factor`."""
+    N = h.shape[0]
+    logits = jnp.einsum("nd,de->ne", h.astype(jnp.float32),
+                        router.astype(jnp.float32), precision=_HI)
+    p = jax.nn.softmax(logits, axis=-1)
+    per = cfg.n_routed_experts // cfg.n_group
+    best = p.reshape(N, cfg.n_group, per).max(-1)
+    kept = lax.top_k(best, cfg.topk_group)[1]                # [N, groups]
+    in_kept = (kept[:, :, None] == jnp.arange(cfg.n_group)[None, None]
+               ).any(1)                                      # [N, n_group]
+    w, ids = lax.top_k(jnp.where(jnp.repeat(in_kept, per, axis=1), p, 0.0),
+                       cfg.top_k)
+    return ids.astype(jnp.int32), w * cfg.routed_scaling_factor
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _tile(width: int, most: int) -> int:
+    """The largest multiple of 128 up to `most` that divides `width`,
+    else the whole of it (toy widths)."""
+    for t in range(most - most % 128, 0, -128):
+        if width % t == 0:
+            return t
+    return width
+
+
+def _grouped(rows, w, sizes, out_dtype, tm):
+    """rows [M, K] sorted by group, w [G, K, N], sizes [G] -> [M, N]:
+    row r of group g times w[g].  Rows past the last group are not
+    visited and come back undefined."""
+    return gmm(rows, w, sizes, preferred_element_type=out_dtype,
+               tiling=(tm, _tile(w.shape[1], _GMM_K),
+                       _tile(w.shape[2], _GMM_N)),
+               interpret=not _on_tpu())
+
+
+def routed_experts(experts, h, ids, weights, live, cfg: DeepseekV2Config):
+    """The held experts' part of the layer: for each token of h [N, D]
+    the sum over its chosen experts THAT ARE HELD HERE of weight x
+    SwiGLU_expert(h); a token none of whose experts is held gets zeros,
+    and so does a token that is not `live` [N] (an idle decode row, a
+    chunk's pad), which also counts nowhere.  Nothing is dropped: the
+    (token, expert) pairs are sorted by expert and every one runs, in
+    one grouped matmul a projection sized for the worst case (all
+    N x top_k pairs local) that visits only the experts chosen.
+    Returns ([N, D] float32, tokens on each held expert [experts_held])."""
+    N, D = h.shape
+    k, E = cfg.top_k, cfg.experts_held
+    dt = h.dtype
+    local = ids - cfg.expert_offset
+    held = (local >= 0) & (local < E) & live[:, None]        # [N, k]
+    P = N * k
+    tm = min(_GMM_ROWS, -(-P // 8) * 8)
+    Pp = -(-P // tm) * tm
+    group = jnp.pad(jnp.where(held, local, E).reshape(P), (0, Pp - P),
+                    constant_values=E)            # E: not here, sorts last
+    order = jnp.argsort(group)
+    sizes = jnp.zeros((E + 1,), jnp.int32).at[group].add(1)[:E]
+    rows = h[jnp.minimum(order, P - 1) // k]                 # [Pp, D]
+    mid = jax.nn.silu(_grouped(rows, experts["w_gate"].astype(dt), sizes,
+                               dt, tm)) \
+        * _grouped(rows, experts["w_up"].astype(dt), sizes, dt, tm)
+    out = _grouped(mid, experts["w_down"].astype(dt), sizes, jnp.float32, tm)
+    out = jnp.where((jnp.arange(Pp) < sizes.sum())[:, None], out, 0.0)
+    back = jnp.zeros((Pp,), jnp.int32).at[order].set(jnp.arange(Pp))[:P]
+    pairs = out[back].reshape(N, k, D)
+    return (pairs * jnp.where(held, weights, 0.0)[..., None]).sum(1), sizes
+
+
+def _ffn(lp, x, live, is_tick, counts, cfg: DeepseekV2Config):
+    """x + FFN(norm(x)): dense SwiGLU in the leading layers, shared +
+    held routed experts after them.  `counts`: this call's additions to
+    COUNTERS so far."""
+    dt = cfg.dtype
+    h = _rmsnorm(x, lp["ln2"])
+    if "router" not in lp:
+        return x + _swiglu(lp, h, dt), counts
+    with jax.named_scope("moe_route"):
+        ids, weights = route(lp["router"], h, cfg)
+    with jax.named_scope("moe_experts"):
+        routed, sizes = routed_experts(lp["experts"], h, ids, weights, live,
+                                       cfg)
+    tick = jnp.int32(is_tick)
+    counts = [c + a for c, a in zip(counts, (
+        live.sum() * cfg.top_k, sizes.sum(), tick * (sizes > 0).sum(),
+        tick * cfg.experts_held, sizes.max()))]
+    return x + (routed + _swiglu(lp["shared"], h, dt)).astype(x.dtype), counts
+
+
+# ---------------------------------------------------------------------------
+# Latent attention, for a single-row chunk of T tokens (x [T, D]) and
+# for a tick of B rows (x [B, D])
+
+
+def _project(lp, x, positions, cfg: DeepseekV2Config):
+    """x [n, D] at positions [n] -> q_nope [n, H, 128], rotated q_pe
+    [n, H, 64], the normed latent [n, 512], the rotated shared key part
+    [n, 64]."""
+    dt = cfg.dtype
+    n, H = x.shape[0], cfg.n_heads
+    h = _rmsnorm(x, lp["ln1"])
+    qa = _rmsnorm(jnp.einsum("nd,dr->nr", h, lp["wq_a"].astype(dt)),
+                  lp["q_norm"])
+    q = jnp.einsum("nr,rf->nf", qa, lp["wq_b"].astype(dt)
+                   ).reshape(n, H, cfg.head_dim)
+    kva = jnp.einsum("nd,dr->nr", h, lp["wkv_a"].astype(dt))
+    ckv = _rmsnorm(kva[:, :cfg.kv_lora_rank], lp["kv_norm"])
+    kpe = _rope(kva[:, cfg.kv_lora_rank:], positions, cfg)
+    q_nope = q[..., :cfg.qk_nope_head_dim]
+    q_pe = _rope(q[..., cfg.qk_nope_head_dim:], positions, cfg)
+    return q_nope, q_pe, ckv, kpe
+
+
+def _merge(part, scores, values_of):
+    """One span into a running softmax: `part` = (maxima, sums,
+    accumulator [..., dv]); `scores` float32, masked with -inf;
+    `values_of(weights)` the span's weighted values in float32."""
+    top, total, acc = part
+    new = jnp.maximum(top, scores.max(-1))
+    ref = jnp.where(jnp.isfinite(new), new, 0.0)
+    e = jnp.exp(scores - ref[..., None])
+    keep = jnp.exp(top - ref)
+    return (new, total * keep + e.sum(-1),
+            acc * keep[..., None] + values_of(e))
+
+
+def _attn_chunk(lp, x, l, cache, bt, start, cfg: DeepseekV2Config):
+    T = x.shape[0]
+    H, psz, kr = cfg.n_heads, cache["lat"].shape[2], cfg.kv_lora_rank
+    dt = cfg.dtype
+    cols = start + jnp.arange(T)
+    q_nope, q_pe, ckv, kpe = _project(lp, x, cols, cfg)
+    pages = lax.dynamic_slice(bt, (start // psz,), (T // psz,))
+    lat = cache["lat"].at[l, pages].set(
+        _lat_row(ckv, kpe, cfg).reshape(T // psz, psz, -1))
+
+    with jax.named_scope("mla_expand_attend"):
+        nblk = bt.shape[0]
+        span = _span_pages(_CHUNK_SPAN_KEYS, psz, nblk)
+        width = span * psz
+        wk_b, wv_b = lp["wk_b"].astype(dt), lp["wv_b"].astype(dt)
+
+        def attend(i, part):
+            first = jnp.minimum(i * span, nblk - span)   # as the slice clamps
+            pg = lax.dynamic_slice(bt, (first,), (span,))
+            rows = lat[l, pg].reshape(width, -1)
+            c, r = rows[:, :kr], rows[:, kr:kr + cfg.qk_rope_head_dim]
+            k_nope = jnp.einsum("sc,hnc->shn", c, wk_b)
+            v = jnp.einsum("sc,hcv->shv", c, wv_b)
+            s = (jnp.einsum("thn,shn->hts", q_nope, k_nope,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("thr,sr->hts", q_pe, r,
+                              preferred_element_type=jnp.float32)
+                 ) * cfg.softmax_scale
+            kcols = first * psz + jnp.arange(width)
+            seen = (kcols[None, :] <= cols[:, None]) \
+                & (kcols[None, :] >= i * width)
+            s = jnp.where(seen[None], s, -jnp.inf)
+            return _merge(part, s, lambda e: jnp.einsum(
+                "hts,shv->htv", e.astype(dt), v,
+                preferred_element_type=jnp.float32))
+
+        stat = jnp.full((H, T), -jnp.inf, jnp.float32)
+        _, total, acc = lax.fori_loop(
+            0, (start + T + width - 1) // width, attend,
+            (stat, jnp.zeros_like(stat),
+             jnp.zeros((H, T, cfg.v_head_dim), jnp.float32)))
+        out = (acc / total[..., None]).astype(dt)             # [H, T, dv]
+    x = x + jnp.einsum("htv,hvd->td", out, lp["wo"].astype(dt))
+    return x, dict(cache, lat=lat)
+
+
+def _attn_tick(lp, x, l, cache, bt, pos, cfg: DeepseekV2Config):
+    B = x.shape[0]
+    H, psz, kr = cfg.n_heads, cache["lat"].shape[2], cfg.kv_lora_rank
+    dt = cfg.dtype
+    q_nope, q_pe, ckv, kpe = _project(lp, x, pos, cfg)
+    page = jnp.take_along_axis(bt, (pos // psz)[:, None], axis=1)[:, 0]
+    lat = cache["lat"].at[l, page, pos % psz].set(_lat_row(ckv, kpe, cfg))
+
+    with jax.named_scope("mla_absorb_attend"):
+        nblk = bt.shape[1]
+        span = _span_pages(_TICK_SPAN_KEYS, psz, nblk)
+        width = span * psz
+        # wk_b into the query, wv_b into the output: scores and values
+        # are taken against the cached rows themselves
+        q_row = _lat_row(jnp.einsum("bhn,hnc->bhc", q_nope,
+                                    lp["wk_b"].astype(dt)), q_pe, cfg)
+
+        def attend(i, part):
+            first = jnp.minimum(i * span, nblk - span)
+            pg = lax.dynamic_slice(bt, (0, first), (B, span))
+            rows = lat[l, pg].reshape(B, width, -1)
+            s = jnp.einsum("bhc,bsc->bhs", q_row, rows,
+                           preferred_element_type=jnp.float32) \
+                * cfg.softmax_scale
+            kcols = first * psz + jnp.arange(width)
+            seen = (kcols[None, :] <= pos[:, None]) \
+                & (kcols[None, :] >= i * width)
+            s = jnp.where(seen[:, None], s, -jnp.inf)
+            return _merge(part, s, lambda e: jnp.einsum(
+                "bhs,bsc->bhc", e.astype(dt), rows[..., :kr],
+                preferred_element_type=jnp.float32))
+
+        stat = jnp.full((B, H), -jnp.inf, jnp.float32)
+        _, total, acc = lax.fori_loop(
+            0, (jnp.max(pos) + width) // width, attend,
+            (stat, jnp.zeros_like(stat),
+             jnp.zeros((B, H, kr), jnp.float32)))
+        o_lat = (acc / total[..., None]).astype(dt)           # [B, H, 512]
+        out = jnp.einsum("bhc,hcv->bhv", o_lat, lp["wv_b"].astype(dt))
+    x = x + jnp.einsum("bhv,hvd->bd", out, lp["wo"].astype(dt))
+    return x, dict(cache, lat=lat)
+
+
+# ---------------------------------------------------------------------------
+# The paged step
+
+
+def _through_layers(params, x, cache, live, is_tick, attn, cfg):
+    counts = [jnp.int32(0)] * len(COUNTERS)
+    for l, lp in enumerate(params["layers"]):
+        x, cache = attn(lp, x, l, cache)
+        x, counts = _ffn(lp, x, live, is_tick, counts, cfg)
+    x = _rmsnorm(x, params["ln_f"])
+    logits = jnp.einsum("nd,dv->nv", x.astype(cfg.dtype),
+                        params["wlm"].astype(cfg.dtype),
+                        preferred_element_type=jnp.float32)
+    return logits, dict(cache, moe=_count(cache["moe"], counts))
+
+
+def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
+                     cfg: DeepseekV2Config, pad_lo=None, slot=None,
+                     valid=None) -> Tuple[Any, Dict]:
+    """The model's paged step, under decode.paged_chunk_step's contract.
+
+    `pos` a scalar: ONE row's chunk of T tokens starting there (T and
+    `pos` whole pages) — single-row prefill, the EXPANDED attention.  It
+    fills the row's latent pages; only the first `valid` tokens (default
+    all) are routed to experts (`slot` is taken and unused: no state
+    lives outside the pages).  `pos` a [B] vector with one token a row:
+    the decode tick, the ABSORBED attention.  Rows at position 0 are
+    idle: their writes land wherever their block table points (the trash
+    page) and they are routed nowhere.
+    Returns (logits [B, t, V] float32, cache)."""
+    if pad_lo is not None:
+        raise NotImplementedError("left-padded rows")
+    B, t = tokens.shape
+    psz = cache["lat"].shape[2]
+    pos = jnp.asarray(pos, jnp.int32)
+    embed = lambda tok: jnp.take(params["wte"], tok, axis=0  # noqa: E731
+                                 ).astype(cfg.dtype)
+    if pos.ndim == 0:
+        if B != 1 or t % psz:
+            raise ValueError(f"a chunk is one row of whole pages of {psz} "
+                             f"tokens, got {tokens.shape}")
+        live = jnp.arange(t) < (t if valid is None
+                                else jnp.asarray(valid, jnp.int32))
+        bt = block_tables[0]
+        logits, cache = _through_layers(
+            params, embed(tokens[0]), cache, live, False,
+            lambda lp, x, l, c: _attn_chunk(lp, x, l, c, bt, pos, cfg), cfg)
+        return logits[None], cache
+    if t != 1:
+        raise NotImplementedError(
+            "several tokens a row at per-row positions (speculative "
+            "verify) are not written for the absorbed latent step")
+    logits, cache = _through_layers(
+        params, embed(tokens[:, 0]), cache, pos > 0, True,
+        lambda lp, x, l, c: _attn_tick(lp, x, l, c, block_tables, pos, cfg),
+        cfg)
+    return logits[:, None], cache

@@ -70,7 +70,7 @@ from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 from ray_tpu.models import decode
 from ray_tpu.serve.llm.kv_tier import (HostKVArena, KVPageStore,
-                                       refuse_row_state,
+                                       refuse_row_state, refuse_unframed,
                                        frame_crc, page_frame,
                                        split_frame)
 from ray_tpu.serve.llm.paging import (TIER_HOST, TIER_POOL, TIER_STORE,
@@ -362,6 +362,19 @@ class EngineStats:
     #                                   computed that held no prompt token
     prefill_tokens_sparse: int = 0    # ...in chunks that selected pages
     state_resets: int = 0             # per-row recurrent states zeroed
+    # A model that routes tokens to experts counts on the device, inside
+    # its cache (its `read_counters`): (token, expert) pairs routed and
+    # those whose expert this replica holds, summed over live rows,
+    # real prompt tokens and expert layers; held experts that got a
+    # token / held experts, per tick and expert layer; the busiest held
+    # expert's tokens and the mean over the held, per call and layer.
+    # As of the last decode tick.  Zeros for a model without experts.
+    moe_pairs_routed: int = 0
+    moe_pairs_local: int = 0
+    moe_experts_touched: int = 0
+    moe_experts_held: int = 0
+    moe_load_max: int = 0
+    moe_load_mean: float = 0.0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -604,17 +617,18 @@ class GenerationEngine:
         if speculate_k and speculate_ngram < 1:
             raise ValueError("speculate_ngram must be >= 1 when "
                              "speculate_k is set")
-        if getattr(cfg, "n_experts", 0):
-            raise NotImplementedError(
-                "continuous batching supports dense models only "
-                "(decode has no MoE routing cache)")
         self._model = decode.paged_model(cfg)
+        if self._model is None and getattr(cfg, "n_experts", 0):
+            raise NotImplementedError(
+                "continuous batching runs the dense body for dense models "
+                "only (it has no expert layer; a model that routes brings "
+                "its own paged step)")
         self._row_state = decode.has_row_state(cfg)
-        for on, what in ((enable_prefix_cache, "the prefix cache "
-                          "(enable_prefix_cache=True)"),
-                         (kv_tiering, "KV tiering (kv_tiering=True)")):
-            if on:
-                refuse_row_state(cfg, what)
+        if enable_prefix_cache:
+            refuse_row_state(cfg, "the prefix cache "
+                                  "(enable_prefix_cache=True)")
+        if kv_tiering:
+            refuse_unframed(cfg, "KV tiering (kv_tiering=True)")
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
@@ -688,7 +702,7 @@ class GenerationEngine:
         self._page_nbytes = 2 * self._page_k_nbytes
         self._tiering = bool(_cfg.serve_kv_tiering
                              if kv_tiering is None else kv_tiering) \
-            and enable_prefix_cache
+            and enable_prefix_cache and decode.pages_are_kv(cfg)
         self._kv_store_dir = kv_store_dir
         self._arena: Optional[HostKVArena] = None   # lazy (worker)
         self._store: Optional[KVPageStore] = None   # lazy (worker)
@@ -750,6 +764,12 @@ class GenerationEngine:
         self._prefill_pad_tokens = 0
         self._prefill_tokens_sparse = 0
         self._state_resets = 0
+        # A routing model's device-side counters, as last fetched by
+        # the worker thread (stats() must not touch a cache that every
+        # step donates).
+        self._read_model_counters = getattr(self._model, "read_counters",
+                                            None)
+        self._model_counters: Dict[str, Any] = {}
         _jax_utils.install_compile_listener()
 
         self._tags = {"engine": name}
@@ -846,7 +866,7 @@ class GenerationEngine:
         if not prompt:
             raise ValueError("prompt must be non-empty")
         if session_id is not None:
-            refuse_row_state(self.cfg, "a durable session checkpoint")
+            refuse_unframed(self.cfg, "a durable session checkpoint")
         max_new = int(self.default_max_new_tokens
                       if max_new_tokens is None else max_new_tokens)
         if max_new < 1:
@@ -958,7 +978,7 @@ class GenerationEngine:
         unreadable tier frame truncates the export there).  Returns
         {"pages" (the pinned pool pages only), "matched_tokens", "k",
         "v"} or None when nothing is cached."""
-        refuse_row_state(self.cfg, "kv_export")
+        refuse_unframed(self.cfg, "kv_export")
         if self._prefix is None:
             return None
         tokens = [int(t) for t in tokens]
@@ -1020,7 +1040,7 @@ class GenerationEngine:
         failure the reservation is released whole — the cache is never
         left referencing a partially written page.  Returns the number
         of pages imported (0 = re-prefill instead)."""
-        refuse_row_state(self.cfg, "kv_import")
+        refuse_unframed(self.cfg, "kv_import")
         if self._prefix is None:
             return 0
         tokens = [int(t) for t in tokens]
@@ -1343,7 +1363,7 @@ class GenerationEngine:
         so resurrection never trades parity for durability).  Returns
         {"tokens", "rng_state", "imported", "cached_pages"} or None
         when no manifest exists."""
-        refuse_row_state(self.cfg, "session_resurrect")
+        refuse_unframed(self.cfg, "session_resurrect")
         if not self._tiering or self._prefix is None:
             return None
         man = self._tier_store().get_session(session_id)
@@ -1504,7 +1524,8 @@ class GenerationEngine:
             prefill_tokens=self._prefill_tokens,
             prefill_pad_tokens=self._prefill_pad_tokens,
             prefill_tokens_sparse=self._prefill_tokens_sparse,
-            state_resets=self._state_resets)
+            state_resets=self._state_resets,
+            **{"moe_" + k: v for k, v in self._model_counters.items()})
 
     # ------------------------------------------------------------------
     # Worker thread
@@ -1611,11 +1632,12 @@ class GenerationEngine:
 
     def _row_args(self, slot: int, valid: int) -> Dict:
         """What a prefill chunk takes beside the dense arguments when the
-        model keeps per-row state: the decode row the request will
+        model brings its own step: the decode row the request will
         occupy and the count of real tokens in the chunk (a recurrent
-        state cannot un-see a pad).  Nothing otherwise, so the dense
-        models' program is the one it always was."""
-        if not self._row_state:
+        state cannot un-see a pad, and a pad is routed to no expert).
+        Nothing otherwise, so the dense models' program is the one it
+        always was."""
+        if self._model is None:
             return {}
         return {"slot": jnp.int32(slot), "valid": jnp.int32(valid)}
 
@@ -1915,6 +1937,11 @@ class GenerationEngine:
         self._count_keys(actives)
         self._phase("device_wait")
         sampled = np.asarray(sampled)
+        if self._read_model_counters is not None:
+            # the tick has ended, so this copies 40 bytes and waits for
+            # nothing; chunks dispatched before it are in the numbers
+            self._model_counters = self._read_model_counters(
+                self._cache, self.cfg)
         logits_np, row_of = self._ship_sample_logits(logits, sample_rows)
         self._phase("emit")
         now = time.monotonic()
@@ -1946,7 +1973,9 @@ class GenerationEngine:
                 * spans * self._tick_span
         else:
             read, held = self._model.attn_keys(self.cfg, pos)
-            gathered = read
+            counted = getattr(self._model, "attn_keys_gathered", None)
+            gathered = read if counted is None else counted(
+                self.cfg, self._pos, self.page_size, self._max_blocks)
         self._keys_attended += read
         self._keys_resident += held
         self._keys_gathered += gathered

@@ -67,6 +67,25 @@ def refuse_row_state(cfg, what: str) -> None:
         f"state there; missing: state snapshots at page boundaries")
 
 
+def refuse_unframed(cfg, what: str) -> None:
+    """Everything that frames pages (this file's tiers, kv_export /
+    kv_import, migration, session checkpoints) calls this first: it
+    refuses a model with per-row state (refuse_row_state) and a model
+    whose pages are not K then V of [page, Hkv, Dh] at all
+    (decode.pages_are_kv: a latent page), by name, until a page is
+    opaque bytes of a size the model declares.  The radix prefix cache
+    only hands out page ids and serves such a model as it is."""
+    refuse_row_state(cfg, what)
+    from ray_tpu.models.decode import pages_are_kv
+    if pages_are_kv(cfg):
+        return
+    raise NotImplementedError(
+        f"{what} on a model whose pages are not K then V "
+        f"({type(cfg).__name__}: a latent page): tiers and the wire frame "
+        f"K-then-V of [page, Hkv, Dh]; missing: a page as opaque bytes of "
+        f"a declared size")
+
+
 def page_frame(k_page: np.ndarray, v_page: np.ndarray) -> bytes:
     """One page's wire/at-rest frame: K bytes then V bytes, contiguous.
     The SAME framing kv_transfer puts on migration frames, so a tier

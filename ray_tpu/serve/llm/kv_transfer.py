@@ -51,7 +51,7 @@ from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 from ray_tpu._private.transfer import run_windowed
 from ray_tpu.serve.llm.kv_tier import (frame_crc, page_frame,
-                                       refuse_row_state)
+                                       refuse_unframed)
 from ray_tpu.util import metrics as _metrics
 
 logger = logging.getLogger(__name__)
@@ -265,7 +265,7 @@ async def pull_kv_pages(rdv: Dict, tokens: Sequence[int], engine,
     to host the import, or the transfer failed — the pool is NEVER
     left referencing partial data).  An engine whose model keeps
     per-row state is refused: its pages are not a sequence's state."""
-    refuse_row_state(engine.cfg, "a KV migration (pull_kv_pages)")
+    refuse_unframed(engine.cfg, "a KV migration (pull_kv_pages)")
     t0 = time.monotonic()
     with _tracing.span("serve", "serve.kv_migrate",
                        args={"engine": engine.name,
@@ -400,7 +400,7 @@ def migrate_local(src_engine, dst_engine, tokens: Sequence[int],
     by in-process tests and the bench's crossover leg; returns pages
     imported (0 = re-prefill)."""
     for eng in (src_engine, dst_engine):
-        refuse_row_state(eng.cfg, "a KV migration (migrate_local)")
+        refuse_unframed(eng.cfg, "a KV migration (migrate_local)")
     tokens = [int(t) for t in tokens]
     exp = src_engine.run_on_worker(
         lambda: src_engine.kv_export(tokens), timeout=timeout)

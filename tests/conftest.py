@@ -46,6 +46,21 @@ def _locksan_no_new_violations():
         + "\n".join(v["message"] for v in new))
 
 
+@pytest.fixture(autouse=True)
+def _serve_replica_context_does_not_leak():
+    """A test that builds a Serve replica in its own process (the unit
+    tests of serve/_private/replica.py) publishes a replica context
+    there; the next test of the same worker finds the one this test
+    started with, whichever files xdist hands that worker."""
+    import sys
+    name = "ray_tpu.serve.context"
+    before = getattr(sys.modules.get(name), "_INTERNAL_REPLICA_CONTEXT",
+                     None)
+    yield
+    if name in sys.modules:
+        sys.modules[name]._INTERNAL_REPLICA_CONTEXT = before
+
+
 @pytest.fixture
 def ray_start_regular():
     """A fresh single-node cluster + connected driver."""

@@ -226,3 +226,69 @@ def test_sala_paged_step_compiles(chip, step):
         wide = blocks * e["page_size"]
         shapes = re.findall(r" = \w+\[([\d,]+)\]", text)
         assert not [s for s in shapes if str(wide) in s.split(",")]
+
+
+# The fourth configuration (benchmarks/configs/deepseek-v2-ep4-d5.json):
+# latent attention over a latent page and an expert layer that holds 40
+# of 160 experts, built as the benchmark builds it, at its sizes.
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_dsv2_paged_step_compiles(chip, step, monkeypatch):
+    """Both programs of deepseek-v2-ep4-d5 with the grouped matmul as
+    the chip runs it (a Pallas kernel, not its interpreter: the model
+    asks jax.default_backend(), which is "cpu" here): 10.3 GB of weights
+    and a 2.5 GB latent pool are resident, a step holds under 0.5 GiB
+    beside them, the pool is never re-laid or copied, and the tick
+    forms no key or value of head width from the latents
+    ([rows, keys, 128, 128 or 192]): it attends in the latent space."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import deepseek_v2
+    monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs",
+                           "deepseek-v2-ep4-d5.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if step == "decode_tick":
+        lowered = engine._paged_tick.lower(
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
+            with_logits=False)
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # 0.016 GiB (tick) / 0.139 (a 512-token chunk)
+    assert mem.temp_size_in_bytes < 1 << 29, mem.temp_size_in_bytes / 2**30
+    # weights 9.62 GiB + the latent pool 2.34
+    assert 11.5 * 2**30 < mem.argument_size_in_bytes < 12.5 * 2**30
+    text = compiled.as_text()
+    # three grouped matmuls an expert layer
+    assert text.count("tpu_custom_call") >= 3 * cfg.n_moe
+    pool = "bf16[%s]" % ",".join(map(str, cache["lat"].shape))
+    layouts = set(re.findall(re.escape(pool) + r"\{([\d,]+)", text))
+    assert layouts == {"3,2,1,0"}, layouts         # never re-laid
+    moved = [ln for ln in text.splitlines()
+             if re.search(r"= " + re.escape(pool) + r"\S* copy\(", ln)]
+    assert not moved, moved[:4]
+    if step == "decode_tick":
+        shapes = [s.split(",") for s in
+                  re.findall(r" = \w+\[([\d,]+)\]", text)]
+        expanded = [s for s in shapes if len(s) == 4 and s[0] == str(rows)
+                    and s[-1] in ("128", "192") and "128" in s[1:3]]
+        assert not expanded, expanded[:4]
