@@ -226,25 +226,69 @@ def paged_write_pages(cache: Dict, page_ids, k_pages, v_pages) -> Dict:
 
 
 @jax.jit
-def paged_read_pages(cache: Dict, page_ids) -> Tuple[Any, Any]:
-    """Gather pool rows `page_ids` [n] as page-major
-    [n, L, page_size, Hkv, Dh] K and V stacks — the export half of a KV
-    migration (device_get of the result is the only host copy)."""
-    return (jnp.moveaxis(cache["k"][:, page_ids], 0, 1),
-            jnp.moveaxis(cache["v"][:, page_ids], 0, 1))
+def paged_read_pages(cache: Dict, page_ids) -> Any:
+    """Gather pool rows `page_ids` [n] as ONE page-major stack
+    [n, 2, L, page_size, Hkv, Dh]: row i is page i's K then its V, so
+    on the host its bytes ARE the page's at-rest and wire frame
+    (kv_tier.page_frame) with no copy to build it.  Compiled per length
+    of `page_ids`: callers go through paged_read_stack, which only
+    ever asks for paged_read_batch(cache) ids."""
+    return jnp.stack([jnp.moveaxis(cache["k"][:, page_ids], 0, 1),
+                      jnp.moveaxis(cache["v"][:, page_ids], 0, 1)], axis=1)
+
+
+# Bytes of K and V one dispatch of paged_read_pages gathers: large
+# enough that a pressure demotion of some tens of pages is one or two
+# dispatches, small enough that its device-to-host copy does not hold
+# the transfer queue for long ahead of a tick's sampled tokens.
+_READ_BYTES = 32 << 20
+
+
+def paged_read_batch(cache: Dict) -> int:
+    """Page ids one dispatch of paged_read_pages takes, from the page's
+    bytes alone: _READ_BYTES of K and V, between 8 and 64 pages (32 for
+    a 1 MiB page).  ONE size for a pool's whole life, so the program
+    compiles once, in the first pass that reads a page."""
+    _, _, psz, hkv, dh = cache["k"].shape
+    page = 2 * cache["k"].shape[0] * psz * hkv * dh \
+        * cache["k"].dtype.itemsize
+    return max(8, min(64, _READ_BYTES // page))
+
+
+def paged_read_stack(cache: Dict, page_ids) -> Any:
+    """Dispatch ONE gather of up to paged_read_batch(cache) page ids in
+    the one compiled shape and return its device stack WITHOUT waiting
+    for it: the ids are padded with the last one repeated, and whoever
+    copies the stack to the host drops the rows past len(page_ids).
+    The pool is an input of the gather and the device runs programs in
+    order, so a step dispatched afterwards that rewrites these pages
+    (through the donated cache) writes after the gather has read them."""
+    import numpy as np
+    ids = np.asarray(page_ids, np.int32)
+    pad = paged_read_batch(cache) - len(ids)
+    if pad < 0:
+        raise ValueError(f"{len(ids)} page ids for a stack of "
+                         f"{len(ids) + pad}")
+    if pad:
+        ids = np.concatenate([ids, np.full(pad, ids[-1], np.int32)])
+    return paged_read_pages(cache, ids)
 
 
 def paged_read_pages_host(cache: Dict, page_ids) -> Tuple[Any, Any]:
-    """paged_read_pages + the host landing: contiguous page-major numpy
-    K/V stacks, ready to frame byte-for-byte (tier demotion, migration
-    export).  One fused device gather however many pages ride along —
-    the demotion sweeper batches a whole sweep into one call, and the
-    promote/demote paths share this copy discipline so their bytes can
-    never diverge from what the wire path ships."""
+    """The gather of `page_ids` (any count: ceil(n / paged_read_batch)
+    stacks) + the host landing, BLOCKING: page-major numpy K and V
+    stacks [n, L, page_size, Hkv, Dh] for a caller that needs the bytes
+    now (migration export).  The same compiled program and the same
+    bytes as the tier demotion's landing, which does not wait (the
+    engine hands each dispatched stack to its lander thread), so what a
+    tier holds can never diverge from what the wire ships."""
     import numpy as np
-    k, v = paged_read_pages(
-        cache, jnp.asarray(np.asarray(page_ids, np.int32)))
-    return np.ascontiguousarray(k), np.ascontiguousarray(v)
+    size = paged_read_batch(cache)
+    parts = [page_ids[lo:lo + size] for lo in range(0, len(page_ids), size)]
+    stacks = [paged_read_stack(cache, part) for part in parts]
+    kv = np.concatenate([np.asarray(stack)[:len(part)]
+                         for stack, part in zip(stacks, parts)])
+    return kv[:, 0], kv[:, 1]
 
 
 # What sizes a span of the dense paged step's attention

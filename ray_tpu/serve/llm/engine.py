@@ -69,10 +69,9 @@ from ray_tpu._private import locksan
 from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
 from ray_tpu.models import decode
-from ray_tpu.serve.llm.kv_tier import (HostKVArena, KVPageStore,
+from ray_tpu.serve.llm.kv_tier import (HostKVArena, KVPageStore, PageLander,
                                        refuse_row_state, refuse_unframed,
-                                       frame_crc, page_frame,
-                                       split_frame)
+                                       frame_crc, split_frame)
 from ray_tpu.serve.llm.paging import (TIER_HOST, TIER_POOL, TIER_STORE,
                                       BlockAllocator, RadixPrefixCache,
                                       prefix_fingerprints)
@@ -345,6 +344,16 @@ class EngineStats:
     #                                   ran a command or compiled
     kv_sweeps: int = 0                # sweeps/demotions that moved >= 1 page
     kv_sweep_s: float = 0.0           # ...and the loop time they took
+    # The host half of a demotion runs on the lander thread
+    # (kv_tier.PageLander); the loop's part above is the dispatch.
+    kv_pages_landed: int = 0          # pages it landed, committed to a tier
+    kv_land_s: float = 0.0            # its busy seconds
+    kv_land_wait_s: float = 0.0       # seconds the LOOP waited on it:
+    #                                   back-pressure, flush, forced sweep
+    kv_land_lost: int = 0             # pages that found nowhere to land
+    #                                   (their nodes were dropped)
+    kv_inflight_matches: int = 0      # matches cut short at a node whose
+    #                                   bytes were still landing
     jit_compiles: int = 0             # process-wide (jax_utils listener)
     jit_compile_s: float = 0.0
     # What attention reads against what a row holds, summed over decode
@@ -706,6 +715,17 @@ class GenerationEngine:
         self._kv_store_dir = kv_store_dir
         self._arena: Optional[HostKVArena] = None   # lazy (worker)
         self._store: Optional[KVPageStore] = None   # lazy (worker)
+        # The host half of a demotion runs on a lander thread, born at
+        # the first demotion (never for an engine without tiers); what
+        # it landed waits in _landed for the worker to commit.
+        self._lander: Optional[PageLander] = None
+        self._landed: collections.deque = collections.deque()
+        self._inflight_cap = 0      # bytes; derived with the lander
+        self._pages_landed = 0
+        self._land_lost = 0
+        self._land_wait_s = 0.0
+        self._land_s_closed = 0.0   # busy seconds of landers now closed
+        self._inflight_matches = 0
         self._last_sweep = time.monotonic()
         self._last_store_gc = time.monotonic()
         self._demotions = 0
@@ -831,6 +851,12 @@ class GenerationEngine:
             if req is not None:
                 req.stream._finish(err)
                 self._slots[s] = None
+        # Pages still landing reach their tier (a store page outlives
+        # the engine) before the arena under the lander is closed.
+        lander, self._lander = self._lander, None
+        if lander is not None:
+            lander.close()
+            self._land_s_closed += lander.busy_s
         self._reset_paging()
         OCCUPANCY_GAUGE.set(0.0, tags=self._tags)
 
@@ -982,6 +1008,7 @@ class GenerationEngine:
         if self._prefix is None:
             return None
         tokens = [int(t) for t in tokens]
+        self._apply_landings()
         nodes, _ = self._prefix.match_nodes(tokens)
         usable, frames = [], {}
         for n in nodes:
@@ -1118,11 +1145,17 @@ class GenerationEngine:
         """CRC-checked at-rest bytes of a demoted node, or None — a
         MISS: the caller truncates its match there and the chunk is
         re-prefilled (bit-identical by determinism).  A page is never
-        imported unverified."""
+        imported unverified.  A node whose bytes are still landing is a
+        miss too (the match does not wait for the lander; counted in
+        kv_inflight_matches): the tail's own prefill then re-publishes
+        the page and the landing, when it arrives, is discarded."""
         payload = node.payload
         if payload is None:
             return None
         kind, key, crc, nbytes = payload
+        if kind == "fl":
+            self._inflight_matches += 1
+            return None
         if kind == "t1":
             frame = (self._arena.get(key)
                      if self._arena is not None else None)
@@ -1166,12 +1199,18 @@ class GenerationEngine:
         with self._sweeping("idle"):
             moved = self._demote_t0(self._prefix.demote_candidates(
                 max(0.0, float(_cfg.serve_kv_demote_idle_s))))
+            if force:
+                # The caller wants the pages moved, not on their way.
+                moved = self._land_drain()
             moved += self._demote_t1(max(0.0,
                                          float(_cfg.serve_kv_t2_idle_s)))
             if self._store is not None \
                     and now - self._last_store_gc >= 60.0:
+                # A listing of every file the store holds (1.1 s for
+                # 3,000 of them on the chip's host): the lander's.
                 self._last_store_gc = now
-                self._store.sweep(float(_cfg.serve_kv_store_ttl_s))
+                self._tier_lander().call(
+                    self._store.sweep, float(_cfg.serve_kv_store_ttl_s))
             self._update_kv_gauges()
         return moved
 
@@ -1180,10 +1219,12 @@ class GenerationEngine:
         """One demotion pass (`cause`: idle | pressure | flush) under
         the loop's `sweep` phase, whatever phase it interrupts.  The
         demote methods account into `self._sweep`; a pass that moved
-        pages counts in kv_sweeps / kv_sweep_s, marks the turn's token
-        gaps as stalled and leaves ONE engine.tier_sweep span — at most
-        one per serve_kv_tier_sweep_s, so the ring never churns.  A
-        pass that found nothing records nothing."""
+        pages (for pool pages: dispatched them on their way) counts in
+        kv_sweeps / kv_sweep_s, marks the turn's token gaps as stalled
+        and leaves ONE engine.tier_sweep span — at most one per
+        serve_kv_tier_sweep_s, so the ring never churns.  A pass that
+        found nothing records nothing.  The span is this thread's part;
+        the lander's is engine.tier_land."""
         prev = self._phase("sweep")
         acc = self._sweep = {"pages": 0, "to_t1": 0, "to_t2": 0,
                              "read_s": 0.0, "compile_s": 0.0,
@@ -1208,8 +1249,9 @@ class GenerationEngine:
                                        "put_s")}})
 
     def _demoted(self, dest: str, frame_s: float, put_s: float) -> None:
-        """Account one page's landing attempt to the running sweep;
-        `dest` "" when it found nowhere to land."""
+        """Account one arena page's move to the store (this thread's
+        own: _demote_t1) to the running sweep; `dest` "" when it found
+        nowhere to land."""
         acc = self._sweep
         acc["frame_s"] += frame_s
         acc["put_s"] += put_s
@@ -1220,42 +1262,118 @@ class GenerationEngine:
             KV_DEMOTIONS_COUNTER.inc(tags={**self._tags, "to": dest})
 
     def _demote_t0(self, nodes, arena: bool = True) -> int:
-        """Move tree-only pool pages (refcount 1, selected by the
-        caller) into the arena — or the store when the arena budget is
-        spent, or `arena` is False.  One batched device read covers the
-        whole set; each node's demotion commits only after its frame
-        landed, so a failed landing just leaves the page hot."""
+        """Start tree-only pool pages (refcount 1, selected by the
+        caller) on their way into the arena — or the store when the
+        arena budget is spent, or `arena` is False.  This thread's part
+        is a DISPATCH: the gather of their bytes in stacks of one
+        compiled shape (decode.paged_read_stack), a reserved
+        arena slot or a store fingerprint for each page, and the pool
+        page's release — the device runs programs in order, so a later
+        step that reuses the page writes after the gather has read it.
+        The nodes are then IN FLIGHT (paging.TIER_FLIGHT); the lander
+        thread copies, checks and stores the bytes and
+        _apply_landings commits each node to its tier, or drops a node
+        whose bytes found nowhere to land.  Device stacks awaiting
+        their copy are bounded (_inflight_cap): past it this thread
+        waits on the lander here, inside the `sweep` phase.  Returns
+        the pages dispatched."""
         if not nodes:
             return 0
+        lander = self._tier_lander()
+        acc = self._sweep
         compile_s0 = _jax_utils.compile_counters()[1]
         t0 = time.monotonic()
-        k, v = decode.paged_read_pages_host(
-            self._cache, [n.page for n in nodes])
-        # read_s holds the program's compile for a new page count;
-        # compile_s says how much of it that was.
-        self._sweep["read_s"] += time.monotonic() - t0
-        self._sweep["compile_s"] += \
-            _jax_utils.compile_counters()[1] - compile_s0
-        moved = 0
-        for i, node in enumerate(nodes):
-            t0 = time.monotonic()
-            frame = page_frame(k[i], v[i])
-            crc = frame_crc(frame)
-            t1 = time.monotonic()
-            slot = self._tier_arena().put(frame) if arena else None
-            if slot is not None:
+        size = decode.paged_read_batch(self._cache)
+        host = self._tier_arena() if arena else None
+        store = None
+        for lo in range(0, len(nodes), size):
+            part = nodes[lo:lo + size]
+            self._land_wait(lander.wait_room, size * self._page_nbytes,
+                            self._inflight_cap)
+            stack = decode.paged_read_stack(
+                self._cache, [n.page for n in part])
+            entries = []
+            for node in part:
+                slot = host.reserve() if host is not None else None
+                fp = None
+                if slot is None:
+                    fp = self._prefix.path_fp(node)
+                    store = self._tier_store()
+                entries.append(
+                    (node, self._prefix.begin_demote(node), slot, fp))
+                acc["to_t2" if slot is None else "to_t1"] += 1
+            acc["pages"] += len(part)
+            lander.submit(stack, entries, host, store)
+        # read_s holds the program's one compile (the first pass that
+        # demotes) and any wait on the lander; compile_s says how much
+        # of it the compile was.
+        acc["read_s"] += time.monotonic() - t0
+        acc["compile_s"] += _jax_utils.compile_counters()[1] - compile_s0
+        return len(nodes)
+
+    def _tier_lander(self) -> PageLander:
+        if self._lander is None:
+            self._lander = PageLander(self.name, self._landed)
+            # What may wait on the device for its copy: a quarter of
+            # the pool's bytes, and no more than a quarter of what the
+            # device says it has free (the CPU says nothing); one stack
+            # always fits.
+            cap = self.kv_pages * self._page_nbytes // 4
+            try:
+                ms = next(iter(self._cache["k"].devices())).memory_stats()
+                cap = min(cap, (ms["bytes_limit"] - ms["bytes_in_use"]) // 4)
+            except (TypeError, KeyError, AttributeError):
+                pass
+            self._inflight_cap = max(cap, 0)
+        return self._lander
+
+    def _land_wait(self, wait, *args) -> None:
+        """Wait on the lander (back-pressure, a drain), counted in
+        kv_land_wait_s."""
+        t0 = time.monotonic()
+        wait(*args)
+        self._land_wait_s += time.monotonic() - t0
+
+    def _land_drain(self) -> int:
+        """Wait until every page in flight has landed, then commit:
+        for a caller that needs the result (flush, the forced sweep).
+        Returns the pages committed."""
+        if self._lander is not None:
+            self._land_wait(self._lander.drain)
+        return self._apply_landings()
+
+    def _apply_landings(self) -> int:
+        """Commit what the lander has landed: each node still holding
+        the ticket it was dispatched with moves to the tier its bytes
+        reached; one that found nowhere to land is dropped with what
+        hangs below it (kv_land_lost); a node evicted or re-published
+        while its bytes were landing is left alone and the slot
+        reserved for it handed back.  The worker runs this at the top
+        of a turn and before every match."""
+        if not self._landed:
+            return 0
+        done = {"t1": 0, "t2": 0}
+        while self._landed:
+            node, ticket, slot, payload = self._landed.popleft()
+            if node.payload is ticket and payload is not None:
                 self._prefix.apply_demote(
-                    node, TIER_HOST, ("t1", slot, crc, len(frame)))
-                dest = "t1"
-            else:
-                fp = self._prefix.path_fp(node)
-                dest = "t2" if self._tier_store().put_page(fp, frame) \
-                    else ""    # nowhere to land: the page stays hot
-                if dest:
-                    self._prefix.apply_demote(
-                        node, TIER_STORE, ("t2", fp, crc, len(frame)))
-            self._demoted(dest, t1 - t0, time.monotonic() - t1)
-            moved += bool(dest)
+                    node, TIER_HOST if payload[0] == "t1" else TIER_STORE,
+                    payload)
+                done[payload[0]] += 1
+                continue
+            if slot is not None and self._arena is not None:
+                self._arena.free(slot)
+            if node.payload is ticket:
+                self._prefix.drop(node)
+                self._land_lost += 1
+        moved = 0
+        for dest, n in done.items():
+            if n:
+                moved += n
+                KV_DEMOTIONS_COUNTER.inc(n, tags={**self._tags, "to": dest})
+        self._demotions += moved
+        self._pages_landed += moved
+        self._update_tier_gauges()
         return moved
 
     def _demote_t1(self, min_idle_s: float) -> int:
@@ -1288,7 +1406,9 @@ class GenerationEngine:
         tree-only pages (their bytes survive in a lower tier and can
         be promoted back) over EVICTING shared prefixes (their bytes
         are gone).  min_idle 0: under pressure anything tree-only is
-        fair game, coldest first."""
+        fair game, coldest first.  The pages are free when this
+        returns — their bytes are on their way (_demote_t0) — so the
+        admission that called goes on after a dispatch."""
         if not self._tiering or self._prefix is None:
             return 0
         short = need - self._alloc.free_pages
@@ -1302,12 +1422,16 @@ class GenerationEngine:
         """Worker command: demote EVERY demotable page — tree-only
         pool pages and all arena slots — straight to the store.  The
         drain/teardown path: a dying replica demotes instead of
-        dropping, so its sessions resurrect anywhere from T2."""
+        dropping, so its sessions resurrect anywhere from T2.  Returns
+        only when every page in flight has landed (or been counted
+        lost): this caller needs durability, so it waits for the
+        lander."""
         if not self._tiering or self._prefix is None:
             return 0
         with self._sweeping("flush"):
-            flushed = self._demote_t0(self._prefix.demote_candidates(0.0),
-                                      arena=False)
+            self._demote_t0(self._prefix.demote_candidates(0.0),
+                            arena=False)
+            flushed = self._land_drain()
             flushed += self._demote_t1(0.0)
             self._update_kv_gauges()
         return flushed
@@ -1373,6 +1497,7 @@ class GenerationEngine:
                                  else man.get("tokens") or [])]
         psz = self.page_size
         usable = len(toks) // psz
+        self._apply_landings()
         nodes, _ = self._prefix.match_nodes(toks)
         depth_lo = len(nodes)
         imported = 0
@@ -1483,6 +1608,7 @@ class GenerationEngine:
         if t is not None:
             loop_s[name] += max(0.0, now - t)
         jit_compiles, jit_compile_s = _jax_utils.compile_counters()
+        lander = self._lander
         return EngineStats(
             queue_depth=self._scheduler.depth
             + (1 if self._prefill is not None else 0),
@@ -1516,6 +1642,12 @@ class GenerationEngine:
             token_gaps_stalled=self._token_gaps_stalled,
             kv_sweeps=self._kv_sweeps,
             kv_sweep_s=round(self._kv_sweep_s, 6),
+            kv_pages_landed=self._pages_landed,
+            kv_land_s=round(self._land_s_closed
+                            + (lander.busy_s if lander else 0.0), 6),
+            kv_land_wait_s=round(self._land_wait_s, 6),
+            kv_land_lost=self._land_lost,
+            kv_inflight_matches=self._inflight_matches,
             jit_compiles=jit_compiles,
             jit_compile_s=round(jit_compile_s, 6),
             attn_keys_attended=self._keys_attended,
@@ -1563,6 +1695,9 @@ class GenerationEngine:
             # the device + paging state for their duration, and their
             # failures are their caller's, never the batch's.
             self._drain_commands()
+            if self._landed:
+                self._phase("commands")
+                self._apply_landings()
             try:
                 self._maybe_sweep_tiers()
                 self._admit_one_chunk()
@@ -1643,7 +1778,7 @@ class GenerationEngine:
 
     def _has_work_locked(self) -> bool:
         return (self._scheduler.depth > 0 or self._prefill is not None
-                or bool(self._commands)
+                or bool(self._commands) or bool(self._landed)
                 or any(r is not None for r in self._slots))
 
     def _free_slot(self) -> Optional[int]:
@@ -1677,6 +1812,7 @@ class GenerationEngine:
         if self._prefix is not None:
             # Cap at L-1: at least one prompt token must run through
             # tail prefill — logits come from computation, not cache.
+            self._apply_landings()
             nodes, _ = self._prefix.match_nodes(req.prompt,
                                                 max_tokens=L - 1)
             for n in nodes:
@@ -1750,9 +1886,11 @@ class GenerationEngine:
                     self._alloc.decref(p)
                 self._update_kv_gauges()
                 raise
-            for (node, _), page in zip(promote, landing):
+            for (node, _), page in reversed(list(zip(promote, landing))):
                 # The page's allocation ref becomes the TREE's ref;
                 # the request then takes its own, same as a pool hit.
+                # (Deepest first: of one path, the deepest node is the
+                # first to demote again.)
                 self._prefix.promote(node, page)
                 self._alloc.incref(page)
             self._promotions += len(promote)
@@ -2121,14 +2259,22 @@ class GenerationEngine:
     def _update_kv_gauges(self):
         KV_BLOCKS_FREE_GAUGE.set(self._alloc.free_pages, tags=self._tags)
         if self._prefix is not None:
-            for tier, count in zip(("t0", "t1", "t2"),
-                                   self._prefix.tier_nodes):
-                KV_TIER_PAGES_GAUGE.set(
-                    count, tags={**self._tags, "tier": tier})
+            self._update_tier_gauges()
             if self._tiering:
                 self._demotable_hint = self._prefix.releasable()
 
+    def _update_tier_gauges(self):
+        for tier, count in zip(("t0", "t1", "t2"),
+                               self._prefix.tier_nodes):
+            KV_TIER_PAGES_GAUGE.set(
+                count, tags={**self._tags, "tier": tier})
+
     def _reset_paging(self):
+        # Nothing may land in the arena closed below, nor be committed
+        # to the tree built here.
+        if self._lander is not None:
+            self._lander.drain()
+        self._landed.clear()
         self._alloc = BlockAllocator(self.kv_pages, first_page=1)
         if self._prefix is not None:
             self._prefix = RadixPrefixCache(

@@ -20,25 +20,35 @@ write is temp-file + rename so a reader can never observe a torn page.
 A failed read is a MISS (the caller re-prefills), never a corrupt
 import — the all-or-nothing bar migration set applies to tiers too.
 
-Single-owner discipline: arena and store methods are called from the
-engine's worker thread (the store's files are additionally shared
-across processes, which the atomic-rename write makes safe).
+Single-owner discipline: the arena's slot list and every tree-facing
+call belong to the engine's worker thread (the store's files are
+additionally shared across processes, which the atomic-rename write
+makes safe).  The host half of a demotion — device-to-host copy, CRC,
+the bytes' landing in a slot the worker reserved or in a store file —
+runs on the engine's PageLander thread, which touches neither the radix
+tree nor the allocator nor the slot list: it posts what landed where
+and the worker commits it.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import mmap
 import os
+import queue
 import struct
 import tempfile
+import threading
 import time
 import uuid
 import zlib
 from typing import Dict, List, Optional
 
 import numpy as np
+
+from ray_tpu._private import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
@@ -93,7 +103,10 @@ def page_frame(k_page: np.ndarray, v_page: np.ndarray) -> bytes:
     return k_page.tobytes() + v_page.tobytes()
 
 
-def frame_crc(frame: bytes) -> int:
+def frame_crc(frame) -> int:
+    """CRC32 of a frame: bytes, or the contiguous uint8 row of a host
+    stack that IS the frame (decode.paged_read_pages).  zlib drops the
+    GIL for anything over a few KiB."""
     return zlib.crc32(frame)
 
 
@@ -135,6 +148,7 @@ class HostKVArena:
         if self._path is None:
             self._file = None
             self._mm = mmap.mmap(-1, size)
+        self._view = np.frombuffer(self._mm, np.uint8)
         self._free: List[int] = list(range(self.capacity - 1, -1, -1))
         self._closed = False
 
@@ -146,16 +160,35 @@ class HostKVArena:
     def used_slots(self) -> int:
         return self.capacity - len(self._free)
 
-    def put(self, frame: bytes) -> Optional[int]:
-        """Stage one page frame; returns its slot or None when the
-        budget is spent (the sweeper then demotes to the store tier
-        instead — the arena is a cache over T2, never a hard wall)."""
-        if self._closed or not self._free \
-                or len(frame) != self.page_nbytes:
+    def reserve(self) -> Optional[int]:
+        """Take a free slot (worker thread: the slot list is its own) or
+        None when the budget is spent (the sweeper then demotes to the
+        store tier instead — the arena is a cache over T2, never a hard
+        wall).  The bytes follow through write(), from any one thread."""
+        if self._closed or not self._free:
             return None
-        slot = self._free.pop()
+        return self._free.pop()
+
+    def write(self, slot: int, frame) -> bool:
+        """Land one page frame (bytes or a contiguous uint8 array) in a
+        reserved slot.  Copied through a numpy view of the mmap, which
+        releases the GIL for the copy's length: a slice assignment into
+        the mmap holds it (2.8 ms a 1 MiB page under gVisor)."""
+        if self._closed or len(frame) != self.page_nbytes:
+            return False
         base = slot * self.page_nbytes
-        self._mm[base:base + len(frame)] = frame
+        np.copyto(self._view[base:base + self.page_nbytes],
+                  np.frombuffer(frame, np.uint8))
+        return True
+
+    def put(self, frame) -> Optional[int]:
+        """reserve() + write(): stage one page frame; its slot, or None
+        when the budget is spent or the frame is not a page."""
+        if len(frame) != self.page_nbytes:
+            return None
+        slot = self.reserve()
+        if slot is not None:
+            self.write(slot, frame)
         return slot
 
     def get(self, slot: int) -> Optional[bytes]:
@@ -173,9 +206,10 @@ class HostKVArena:
         if self._closed:
             return
         self._closed = True
+        self._view = None       # the view pins the mmap's buffer
         try:
             self._mm.close()
-        except (OSError, ValueError):
+        except (OSError, ValueError, BufferError):
             pass
         if self._file is not None:
             try:
@@ -187,6 +221,137 @@ class HostKVArena:
                 os.unlink(self._path)
             except OSError:
                 pass
+
+
+class PageLander:
+    """The host half of a demotion, off the engine's tick thread.
+
+    The worker thread dispatches the device gather of the pages it
+    demotes (decode.paged_read_stack), reserves where each page
+    goes — an arena slot, or a store fingerprint when the arena is
+    spent — and submit()s the still-running device stack with those
+    entries.  This one daemon thread then does what touches host bytes:
+    the device-to-host copy, each page's CRC (a row of the host stack is
+    the page's frame byte for byte), the arena write or the store file.
+    It touches neither the radix tree nor the allocator nor the arena's
+    slot list: for every entry it appends
+    `(node, ticket, slot, payload | None)` to `landed`, which the worker
+    drains and commits (`payload` None: nowhere to land).  Every copy
+    here releases the GIL, so the thread that dispatches ticks is not
+    handed this one's milliseconds.
+
+    Device stacks awaiting their copy are counted in bytes: the worker
+    waits in wait_room() past its cap and in drain() when a caller needs
+    every page landed (flush, the forced sweep, shutdown).  call() queues
+    other host work of the tiers behind them (the store's TTL sweep)."""
+
+    def __init__(self, name: str, landed):
+        self.landed = landed            # deque: append here, worker pops
+        self._jobs: "queue.SimpleQueue" = queue.SimpleQueue()
+        self._cond = threading.Condition()
+        self._pending = 0               # jobs submitted and not finished
+        self._bytes = 0                 # device bytes not yet copied
+        self.busy_s = 0.0               # this thread's working seconds
+        self._thread = threading.Thread(
+            target=self._run, name=f"llm-lander-{name}", daemon=True)
+        self._thread.start()
+
+    def submit(self, stack, entries, arena, store) -> None:
+        """Queue one dispatched gather: `entries[i]` =
+        (node, ticket, slot | None, fingerprint | None) for row i of
+        `stack`; rows past len(entries) are padding."""
+        self._enqueue(functools.partial(
+            self._land, [stack, entries, arena, store]), stack.nbytes)
+
+    def call(self, fn, *args) -> None:
+        """Queue other host work of the tiers that needs no answer (the
+        store's TTL sweep: a listing of thousands of files)."""
+        self._enqueue(functools.partial(fn, *args), 0)
+
+    def _enqueue(self, job, nbytes: int) -> None:
+        with self._cond:
+            self._pending += 1
+            self._bytes += nbytes
+        self._jobs.put(job)
+
+    def wait_room(self, nbytes: int, cap: int) -> None:
+        """Block until `nbytes` more fit under `cap` bytes of device
+        stacks awaiting their copy (one stack always fits)."""
+        with self._cond:
+            while self._bytes and self._bytes + nbytes > cap:
+                self._cond.wait()
+
+    def drain(self) -> None:
+        """Block until every submitted job has been landed."""
+        with self._cond:
+            while self._pending:
+                self._cond.wait()
+
+    def close(self, timeout: float = 30.0) -> None:
+        """Land what is queued, then end the thread."""
+        self._jobs.put(None)
+        self._thread.join(timeout)
+
+    def _run(self):
+        while True:
+            job = self._jobs.get()
+            if job is None:
+                return
+            t0 = time.monotonic()
+            try:
+                job()
+            except BaseException:
+                logger.exception("kv lander: %r failed", job)
+            self.busy_s += time.monotonic() - t0
+            with self._cond:
+                self._pending -= 1
+                self._cond.notify_all()
+
+    def _land(self, job):
+        stack, entries, arena, store = job
+        job[0] = None       # this frame's `stack` is the last reference
+        wall0, t0 = time.time(), time.monotonic()
+        posted = 0
+        crc_s = put_s = 0.0
+        nbytes = stack.nbytes
+        try:
+            try:
+                host = np.asarray(stack)    # the device-to-host copy
+            finally:
+                stack = None                # ...frees the device stack
+                with self._cond:
+                    self._bytes -= nbytes
+                    self._cond.notify_all()
+            frames = host.reshape(len(host), -1).view(np.uint8)
+            t1 = time.monotonic()
+            for node, ticket, slot, fp in entries:
+                frame = frames[posted]
+                ta = time.monotonic()
+                crc = frame_crc(frame)
+                tb = time.monotonic()
+                if slot is not None:
+                    ok = arena.write(slot, frame)
+                    payload = ("t1", slot, crc, len(frame))
+                else:
+                    ok = store is not None \
+                        and store.put_page(fp, frame, crc)
+                    payload = ("t2", fp, crc, len(frame))
+                crc_s += tb - ta
+                put_s += time.monotonic() - tb
+                self.landed.append(
+                    (node, ticket, slot, payload if ok else None))
+                posted += 1
+        finally:
+            # Whatever failed, every entry gets its verdict: the worker
+            # frees the slot and drops the node.
+            for node, ticket, slot, _fp in entries[posted:]:
+                self.landed.append((node, ticket, slot, None))
+        _tracing.record(
+            "engine", "engine.tier_land", wall0, time.monotonic() - t0,
+            args={"pages": len(entries),
+                  "copy_ms": round((t1 - t0) * 1e3, 3),
+                  "frame_ms": round(crc_s * 1e3, 3),
+                  "put_ms": round(put_s * 1e3, 3)})
 
 
 def default_store_dir() -> str:
@@ -201,13 +366,16 @@ def default_store_dir() -> str:
     return os.path.join(tempfile.gettempdir(), f"rt_kv_store-{uid}")
 
 
-def _atomic_write(path: str, payload: bytes) -> bool:
+def _atomic_write(path: str, *parts) -> bool:
     """temp + rename so a concurrent reader (another replica pulling a
-    resurrecting session) can never observe a torn file."""
+    resurrecting session) can never observe a torn file.  `parts` are
+    written one after the other (a header and a page are never joined
+    in memory first)."""
     tmp = f"{path}.tmp.{uuid.uuid4().hex[:8]}"
     try:
         with open(tmp, "wb") as f:
-            f.write(payload)
+            for part in parts:
+                f.write(part)
         os.replace(tmp, path)
         return True
     except OSError:
@@ -268,14 +436,19 @@ class KVPageStore:
     def _page_path(self, fp: str) -> str:
         return os.path.join(self._pages, f"{fp}.kv")
 
-    def put_page(self, fp: str, frame: bytes) -> bool:
+    def put_page(self, fp: str, frame, crc: Optional[int] = None) -> bool:
+        """Store one page frame (bytes or a contiguous uint8 array)
+        under its fingerprint; `crc` is frame_crc(frame) where the
+        caller has it already."""
         path = self._page_path(fp)
         if os.path.exists(path):
             # Content-addressed: an existing entry is the same bytes
             # (deterministic prefill), so rewriting buys nothing.
             return True
-        hdr = _HDR.pack(_MAGIC, zlib.crc32(frame), len(frame))
-        return _atomic_write(path, hdr + frame)
+        if crc is None:
+            crc = zlib.crc32(frame)
+        return _atomic_write(path, _HDR.pack(_MAGIC, crc, len(frame)),
+                             frame)
 
     def get_page(self, fp: str) -> Optional[bytes]:
         return _checked_read(self._page_path(fp))
@@ -293,7 +466,7 @@ class KVPageStore:
     def put_session(self, session_id: str, manifest: Dict) -> bool:
         body = json.dumps(manifest).encode()
         hdr = _HDR.pack(_MAGIC, zlib.crc32(body), len(body))
-        return _atomic_write(self._session_path(session_id), hdr + body)
+        return _atomic_write(self._session_path(session_id), hdr, body)
 
     def get_session(self, session_id: str) -> Optional[Dict]:
         body = _checked_read(self._session_path(session_id))

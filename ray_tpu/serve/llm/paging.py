@@ -25,6 +25,7 @@ worker thread (admission/eviction), never concurrently.
 
 from __future__ import annotations
 
+import collections
 import hashlib
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -39,6 +40,12 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 TIER_POOL = 0
 TIER_HOST = 1
 TIER_STORE = 2
+# In flight: the node's pool page was handed back when the gather of its
+# bytes was DISPATCHED, and the bytes have not landed in a tier yet (the
+# engine's lander thread is copying them).  Not in the pool, not yet in
+# a tier: a match stops here, the sweeper skips it, and only
+# apply_demote / insert's adoption / eviction take it out of the state.
+TIER_FLIGHT = 3
 
 
 def _chunk_fp(parent_fp: str, key: Sequence[int]) -> str:
@@ -143,7 +150,9 @@ class _RadixNode:
         # Tier state: TIER_POOL means `page` is a live pool page id;
         # TIER_HOST/TIER_STORE mean `page` is None and `payload` names
         # where the bytes went — ("t1", slot, crc, nbytes) for an arena
-        # slot, ("t2", key, crc, nbytes) for a store entry.  last_used_t
+        # slot, ("t2", key, crc, nbytes) for a store entry; TIER_FLIGHT
+        # means `payload` is the ticket begin_demote() issued, by whose
+        # identity the landing finds its node unchanged.  last_used_t
         # is the wall-clock twin of the LRU logical clock; the demotion
         # sweeper compares it against the idle knobs.
         self.tier = TIER_POOL
@@ -179,12 +188,53 @@ class RadixPrefixCache:
         # Nodes per tier, maintained incrementally (load_info polls
         # this every autoscale tick — never a tree walk on that path).
         self.tier_nodes: List[int] = [0, 0, 0]
+        self.inflight_nodes = 0     # ...and those in TIER_FLIGHT
+        # The POOL-tier nodes, coldest first: the order demotion takes
+        # them in (last touch, and within one touched path the deepest
+        # first), kept as the paths are touched, so admission under
+        # pressure finds its victims without walking a tree that also
+        # holds every demoted node.
+        self._pool_lru: "collections.OrderedDict[_RadixNode, None]" = \
+            collections.OrderedDict()
+        # ...and the arena's (at most its slots), for the sweep's
+        # second stage.
+        self._host_nodes: Dict[_RadixNode, None] = {}
+        self._tickets = 0
         # Called with a node's payload whenever the tree stops owning
         # it (promotion, adoption by insert, eviction, clear).  The
         # engine points this at the arena's slot-free; T2 payloads are
         # deliberately NOT deleted from the store here (persistence is
         # the point — the store's TTL sweep owns their lifetime).
         self.release_payload: Optional[Callable[[tuple], None]] = None
+
+    def _retier(self, node: _RadixNode, tier: Optional[int]) -> None:
+        """Move `node` to `tier` in the per-tier counts (None: the node
+        leaves the tree)."""
+        if node.tier == TIER_FLIGHT:
+            self.inflight_nodes -= 1
+        else:
+            self.tier_nodes[node.tier] -= 1
+        if node.tier == TIER_POOL:
+            del self._pool_lru[node]
+        elif node.tier == TIER_HOST:
+            del self._host_nodes[node]
+        if tier == TIER_FLIGHT:
+            self.inflight_nodes += 1
+        elif tier is not None:
+            self.tier_nodes[tier] += 1
+        if tier == TIER_POOL:
+            self._pool_lru[node] = None
+        elif tier == TIER_HOST:
+            self._host_nodes[node] = None
+        if tier is not None:
+            node.tier = tier
+
+    def _touch(self, path: Sequence[_RadixNode]) -> None:
+        """`path` (root to leaf) was just used: its pool nodes become
+        the warmest, the deepest of them the coldest among those."""
+        for n in reversed(path):
+            if n.tier == TIER_POOL:
+                self._pool_lru.move_to_end(n)
 
     def _drop_payload(self, node: _RadixNode) -> None:
         if node.payload is not None and self.release_payload is not None:
@@ -238,6 +288,7 @@ class RadixPrefixCache:
             child.last_used_t = now
             out.append(child)
             node = child
+        self._touch(out)
         return out, len(out) * psz
 
     def insert(self, tokens: Sequence[int], pages: Sequence[int]) -> int:
@@ -257,6 +308,7 @@ class RadixPrefixCache:
         now = time.monotonic()
         node = self._root
         added = 0
+        path: List[_RadixNode] = []
         for i, page in enumerate(pages):
             key = tuple(tokens[i * psz:(i + 1) * psz])
             child = node.children.get(key)
@@ -276,6 +328,7 @@ class RadixPrefixCache:
                 self._alloc.incref(page)
                 self.nodes += 1
                 self.tier_nodes[TIER_POOL] += 1
+                self._pool_lru[child] = None
                 added += 1
             elif child.tier != TIER_POOL and page is not None:
                 # Adoption: deterministic prefill/import reproduced this
@@ -283,32 +336,36 @@ class RadixPrefixCache:
                 # None page means the caller is extending BELOW a
                 # demoted ancestor without re-materializing it (store
                 # import); the ancestor keeps its tier payload.
-                self.tier_nodes[child.tier] -= 1
-                self.tier_nodes[TIER_POOL] += 1
-                child.tier = TIER_POOL
+                # (An in-flight node's ticket goes with its payload, so
+                # its landing finds the node changed and is discarded.)
+                self._retier(child, TIER_POOL)
                 child.page = page
                 self._drop_payload(child)
                 self._alloc.incref(page)
             child.last_used = self._clock
             child.last_used_t = now
+            path.append(child)
             node = child
+        self._touch(path)
         return added
 
     # -- tier transitions (engine worker thread only) -------------------
 
     def path_fp(self, node: _RadixNode) -> str:
-        """Full-depth chained fingerprint of the prefix this node caps
-        (the digest index only carries fingerprints to digest_depth;
-        store-tier keys need them at ANY depth, so this recomputes the
-        chain from the root — O(depth), demotion-path only)."""
-        keys: List[tuple] = []
+        """Full-depth chained fingerprint of the prefix this node caps.
+        The digest index only computes fingerprints to digest_depth;
+        store-tier keys need them at ANY depth, so this chains down
+        from the nearest ancestor that has one and KEEPS each on its
+        node (`fp`: the same chain, never indexed past digest_depth) —
+        one hash a node over its life, on the demotion path only."""
+        chain: List[_RadixNode] = []
         n = node
-        while n is not self._root and n is not None:
-            keys.append(n.key)
+        while n is not self._root and n is not None and not n.fp:
+            chain.append(n)
             n = n.parent
-        fp = ""
-        for key in reversed(keys):
-            fp = _chunk_fp(fp, key)
+        fp = n.fp if n is not None else ""
+        for n in reversed(chain):
+            fp = n.fp = _chunk_fp(fp, n.key)
         return fp
 
     def demote_candidates(self, min_idle_s: float,
@@ -322,44 +379,83 @@ class RadixPrefixCache:
         path: anything tree-only is fair game, LRU order."""
         now = time.monotonic()
         out: List[_RadixNode] = []
-        stack = list(self._root.children.values())
-        while stack:
-            n = stack.pop()
-            stack.extend(n.children.values())
-            if n.tier != tier:
-                continue
-            if tier == TIER_POOL and self._alloc.refcount(n.page) != 1:
-                continue
-            if now - n.last_used_t < min_idle_s:
-                continue
-            out.append(n)
+        if tier == TIER_POOL:
+            # _pool_lru IS the order (last touch, deepest first), and a
+            # node's last touch is no later than its successor's: the
+            # scan ends at the first one too fresh, or at `limit`.
+            for n in self._pool_lru:
+                if now - n.last_used_t < min_idle_s \
+                        or (limit is not None and len(out) >= limit):
+                    break
+                if self._alloc.refcount(n.page) == 1:
+                    out.append(n)
+            return out
+        if tier != TIER_HOST:
+            raise ValueError("only pool and arena nodes demote")
+        out = [n for n in self._host_nodes
+               if now - n.last_used_t >= min_idle_s]
         out.sort(key=lambda n: (n.last_used, -n.depth))
         return out if limit is None else out[:limit]
+
+    def begin_demote(self, node: _RadixNode) -> tuple:
+        """A tree-only pool page (caller guaranteed refcount 1) leaves
+        the pool the moment the gather of its bytes is dispatched: the
+        page is freed, the node is IN FLIGHT, and the ticket returned
+        (also the node's payload) is what apply_demote's caller checks
+        by identity when the bytes have landed — an eviction or an
+        adoption in between clears it."""
+        self._alloc.decref(node.page)
+        node.page = None
+        self._retier(node, TIER_FLIGHT)
+        self._tickets += 1      # makes each ticket an object of its own
+        node.payload = ("fl", self._tickets, 0, 0)
+        return node.payload
 
     def apply_demote(self, node: _RadixNode, tier: int,
                      payload: tuple) -> None:
         """Commit one node's demotion AFTER its bytes landed in the
         destination tier: the pool page is freed (T0 source; caller
-        guaranteed refcount 1) or the arena slot released (T1 source),
-        and the node now names `payload` instead."""
+        guaranteed refcount 1), the arena slot released (T1 source) or
+        the ticket retired (in flight), and the node now names
+        `payload` instead."""
         if node.tier == TIER_POOL:
             self._alloc.decref(node.page)
             node.page = None
         else:
             self._drop_payload(node)
-        self.tier_nodes[node.tier] -= 1
-        self.tier_nodes[tier] += 1
-        node.tier = tier
+        self._retier(node, tier)
         node.payload = payload
+
+    def drop(self, node: _RadixNode) -> int:
+        """Take `node` and everything below it out of the tree (an
+        in-flight page whose bytes found nowhere to land: the prefixes
+        through it are unreachable without it).  Pool pages are
+        decref'd, tier payloads released, in-flight tickets cleared so
+        their landings are discarded.  Returns the nodes dropped."""
+        parent = node.parent
+        if parent is None or parent.children.get(node.key) is not node:
+            return 0
+        del parent.children[node.key]
+        dropped, stack = 0, [node]
+        while stack:
+            n = stack.pop()
+            stack.extend(n.children.values())
+            self._unindex(n)
+            if n.tier == TIER_POOL:
+                self._alloc.decref(n.page)
+            else:
+                self._drop_payload(n)
+            self._retier(n, None)
+            self.nodes -= 1
+            dropped += 1
+        return dropped
 
     def promote(self, node: _RadixNode, page: int) -> None:
         """Commit one node's promotion AFTER its bytes landed in pool
         page `page` (freshly alloc'd — its allocation ref becomes the
         tree's ref, mirroring insert()'s accounting)."""
         self._drop_payload(node)
-        self.tier_nodes[node.tier] -= 1
-        self.tier_nodes[TIER_POOL] += 1
-        node.tier = TIER_POOL
+        self._retier(node, TIER_POOL)
         node.page = page
 
     def _unindex(self, node: _RadixNode) -> None:
@@ -391,7 +487,7 @@ class RadixPrefixCache:
             if n.tier > worst:
                 worst = n.tier
             n = n.parent
-        return worst
+        return min(worst, TIER_STORE)   # in flight: as good as demoted
 
     def _pick_maximal(self, top_k: int) -> List["_RadixNode"]:
         """Up to top_k indexed nodes, most recently used first, maximal
@@ -447,14 +543,8 @@ class RadixPrefixCache:
         nothing.  The engine checks this before evicting; when even a
         full wipe cannot cover a reservation, the request waits for
         residents to finish instead and future prefix hits survive."""
-        count, stack = 0, list(self._root.children.values())
-        while stack:
-            n = stack.pop()
-            stack.extend(n.children.values())
-            if n.tier == TIER_POOL \
-                    and self._alloc.refcount(n.page) == 1:
-                count += 1
-        return count
+        refcount = self._alloc.refcount
+        return sum(1 for n in self._pool_lru if refcount(n.page) == 1)
 
     def _leaves(self) -> List[_RadixNode]:
         out, stack = [], list(self._root.children.values())
@@ -506,7 +596,7 @@ class RadixPrefixCache:
                 # Its T2 copy persists in the store; a T1 payload's
                 # arena slot is handed back through the release hook.
                 self._drop_payload(victim)
-            self.tier_nodes[victim.tier] -= 1
+            self._retier(victim, None)
             self.nodes -= 1
             dropped += 1
             if parent is not self._root and not parent.children:
@@ -515,15 +605,5 @@ class RadixPrefixCache:
         return dropped
 
     def clear(self) -> None:
-        stack = list(self._root.children.values())
-        while stack:
-            node = stack.pop()
-            stack.extend(node.children.values())
-            if node.tier == TIER_POOL:
-                self._alloc.decref(node.page)
-            else:
-                self._drop_payload(node)
-        self._root.children.clear()
-        self._fp_index.clear()
-        self.nodes = 0
-        self.tier_nodes = [0, 0, 0]
+        for child in list(self._root.children.values()):
+            self.drop(child)

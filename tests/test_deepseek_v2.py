@@ -584,6 +584,7 @@ def test_the_prefix_cache_shares_latent_pages(model, served):
     b = served.submit(head + [5, 6, 7], max_new_tokens=4).result(timeout=300)
     assert served.stats().prefix_hit_tokens - before == 40
     assert a == b
+    assert served._lander is None       # no tiers: no lander thread
 
 
 # ------------------------------------- the toy configuration as a cell

@@ -480,6 +480,7 @@ def test_the_engine_serves_it_and_counts(model, served, reference):
     assert gain["attn_keys_resident"] % cfg.n_attn == 0
     # a model with its own step reports what it reads as gathered
     assert gain["attn_keys_gathered"] == gain["attn_keys_attended"]
+    assert served._lander is None       # no tiers: no lander thread
 
 
 def test_llmserver_serves_with_the_prefix_cache_off(model):

@@ -14,6 +14,7 @@ path degrades to re-prefill, never to a corrupt cache.
 """
 
 import asyncio
+import threading
 import time
 
 import jax
@@ -28,8 +29,9 @@ from ray_tpu.serve.llm.engine import (EngineOverloadedError,
                                       GenerationEngine)
 from ray_tpu.serve.llm.kv_tier import HostKVArena, KVPageStore, \
     frame_crc, page_frame, split_frame
-from ray_tpu.serve.llm.paging import (TIER_HOST, TIER_POOL, TIER_STORE,
-                                      BlockAllocator, RadixPrefixCache,
+from ray_tpu.serve.llm.paging import (TIER_FLIGHT, TIER_HOST, TIER_POOL,
+                                      TIER_STORE, BlockAllocator,
+                                      RadixPrefixCache,
                                       prefix_fingerprints)
 
 GPT_CFG = gpt.GPTConfig(vocab_size=97, d_model=32, n_heads=4,
@@ -179,6 +181,72 @@ def test_releasable_and_evict_are_tier_aware():
     assert freed == [("t1", 7, 99, 64)]  # payload hook fired
     assert alloc.free_pages == free0 + 2
     assert tree.tier_nodes == [0, 0, 0]
+
+
+def test_demote_candidates_keep_the_walks_order_without_the_walk():
+    """The pool's nodes are kept in demotion order as paths are touched:
+    what demote_candidates hands out — coldest touch first, within one
+    touched path the deepest first, tree-only pages only — is what a
+    walk of the whole tree sorted by (last_used, -depth) gave, through
+    inserts, matches, demotions, promotions and evictions."""
+    import random
+    rnd = random.Random(7)
+    tree, alloc = _tree(pages=64)
+    prompts = [_prompt(100 + i, 4 * rnd.randint(2, 5)) for i in range(8)]
+    prompts += [prompts[0][:8] + _prompt(200, 8),      # shared prefixes
+                prompts[1][:4] + _prompt(201, 12)]
+
+    def walk():
+        out, stack = [], list(tree._root.children.values())
+        while stack:
+            n = stack.pop()
+            stack.extend(n.children.values())
+            if n.tier == TIER_POOL and alloc.refcount(n.page) == 1:
+                out.append(n)
+        return sorted(out, key=lambda n: (n.last_used, -n.depth))
+
+    def check():
+        want = walk()
+        assert tree.demote_candidates(0.0) == want
+        assert tree.demote_candidates(0.0, limit=3) == want[:3]
+        assert tree.releasable() == len(want)
+        assert len(tree._pool_lru) == tree.tier_nodes[TIER_POOL]
+
+    held = []
+    for toks in prompts:
+        have, _ = tree.match(toks)
+        got = alloc.alloc(len(toks) // 4 - len(have))
+        tree.insert(toks, list(have) + got)
+        for p in got:
+            alloc.decref(p)
+        check()
+    for step in range(40):
+        toks = rnd.choice(prompts)
+        what = rnd.randrange(5)
+        if what == 0:
+            tree.match(toks[:4 * rnd.randint(1, len(toks) // 4)])
+        elif what == 1 and tree.demote_candidates(0.0):
+            node = tree.demote_candidates(0.0)[0]
+            tree.apply_demote(node, TIER_HOST, ("t1", step, 0, 64))
+        elif what == 2:
+            tiered = [n for n in tree.match_nodes(toks)[0]
+                      if n.tier == TIER_HOST]
+            for n in tiered:
+                tree.promote(n, alloc.alloc(1)[0])
+        elif what == 3:
+            pages, _ = tree.match(toks)
+            if pages and not held:          # a request holds a prefix
+                held = pages[:1]
+                alloc.incref(held[0])
+        else:
+            for p in held:
+                alloc.decref(p)
+            held = []
+            tree.evict(alloc.free_pages + 1)
+        check()
+    assert tree.demote_candidates(1e9) == []        # all touched just now
+    assert tree.demote_candidates(0.0, tier=TIER_HOST) == sorted(
+        tree._host_nodes, key=lambda n: (n.last_used, -n.depth))
 
 
 # ---------------------------------------------------------------------------
@@ -397,6 +465,353 @@ def test_resurrect_missing_session_is_none_and_corrupt_page_reprefills(
     res, rest = asyncio.run(run())
     assert res["imported"] == 1  # stopped at the poisoned page
     assert rest == _oracle(prompt + want, 4)
+
+
+# ---------------------------------------------------------------------------
+# The lander: a demotion's host half off the tick thread (PR 37)
+
+
+class _Gate:
+    """Holds an engine's lander before it copies a batch to the host,
+    until opened: what the worker thread does meanwhile is what it does
+    while pages are in flight, with no clock in the test."""
+
+    def __init__(self, eng):
+        self.entered = threading.Event()
+        self.open = threading.Event()
+        lander = eng.run_on_worker(eng._tier_lander)
+        land = lander._land
+
+        def gated(job):
+            self.entered.set()
+            assert self.open.wait(120)
+            land(job)
+        lander._land = gated
+
+
+def _pool_frames(eng):
+    """(node, frame, crc) of every demotable pool page, framed the way
+    the synchronous path did it: a blocking read of the pool's own
+    arrays, K bytes then V bytes.  Worker thread."""
+    nodes = eng._prefix.demote_candidates(0.0)
+    pages = [n.page for n in nodes]
+    k = np.asarray(eng._cache["k"][:, pages])
+    v = np.asarray(eng._cache["v"][:, pages])
+    frames = [page_frame(k[:, i], v[:, i]) for i in range(len(nodes))]
+    return [(n, f, frame_crc(f)) for n, f in zip(nodes, frames)]
+
+
+def _lander_threads(eng):
+    return [t for t in threading.enumerate()
+            if t.name == f"llm-lander-{eng.name}"]
+
+
+@pytest.mark.parametrize("dest", ["arena", "store"])
+def test_landed_frame_and_crc_equal_the_synchronous_paths(
+        tmp_path, monkeypatch, dest):
+    """What the lander leaves in the arena (the sweep) or the store
+    (the flush) is, byte for byte and CRC for CRC, the frame the
+    blocking read of the same pool contents gives."""
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 0.0)
+    monkeypatch.setattr(_cfg, "serve_kv_t2_idle_s", 1e9)
+    eng = _engine(name=f"land-{dest}", kv_store_dir=str(tmp_path))
+    with eng:
+        eng.submit(_prompt(31, 22), max_new_tokens=4).result(timeout=120)
+        want = eng.run_on_worker(lambda: _pool_frames(eng))
+        assert len(want) == 5
+        if dest == "arena":
+            assert _sweep(eng) == len(want)
+        else:
+            assert eng.run_on_worker(eng.kv_flush_to_store) == len(want)
+        st = eng.stats()
+        for node, frame, crc in want:
+            kind, key, got_crc, nbytes = node.payload
+            assert (kind, got_crc, nbytes) == (
+                "t1" if dest == "arena" else "t2", crc, len(frame))
+            held = eng._arena.get(key) if dest == "arena" \
+                else eng._tier_store().get_page(key)
+            assert held == frame
+        assert len(_lander_threads(eng)) == 1
+    assert st.kv_pages_landed == st.kv_demotions == len(want)
+    assert st.kv_land_lost == 0 and st.kv_land_s > 0
+    assert _lander_threads(eng) == []      # it ends with the engine
+
+
+def test_pages_overwritten_after_dispatch_land_with_their_old_bytes(
+        tmp_path, monkeypatch):
+    """The pool pages are released when the gather is DISPATCHED, and
+    the next request's prefill chunks rewrite them through the donated
+    cache while the lander has not copied a byte: the frames that land
+    are the old pages', because the device ran the gather first."""
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 1e9)
+    cold, hot = _prompt(32, 24), _prompt(33, 24)
+    want_hot = _oracle(hot, 8)
+    # 8 pages a request; 12 cannot hold both, so the second admission
+    # demotes the first's pages and takes them over (LIFO free list)
+    eng = _engine(name="land-order", kv_pages=12, num_slots=2,
+                  kv_store_dir=str(tmp_path))
+    with eng:
+        eng.submit(cold, max_new_tokens=8).result(timeout=120)
+        old = eng.run_on_worker(lambda: _pool_frames(eng))
+        old_pages = [n.page for n, _, _ in old]
+        gate = _Gate(eng)
+        got_hot = eng.submit(hot, max_new_tokens=8).result(timeout=120)
+        assert gate.entered.wait(120)
+        st = eng.stats()
+        assert st.kv_demotions == 0 and st.kv_sweeps == 1
+        # the pages are another request's now, and hold other bytes
+        demoted = [(n, f) for n, f, _ in old if n.page is None]
+        assert demoted and all(n.tier == TIER_FLIGHT for n, _ in demoted)
+        now = eng.run_on_worker(lambda: {
+            p: page_frame(np.asarray(eng._cache["k"][:, p]),
+                          np.asarray(eng._cache["v"][:, p]))
+            for p in old_pages})
+        rewritten = [f for (n, f, _), p in zip(old, old_pages)
+                     if n.page is None and now[p] != f]
+        assert rewritten, "the hot request reused no demoted page"
+        gate.open.set()
+        eng.run_on_worker(eng._land_drain)
+        for node, frame in demoted:
+            assert node.tier == TIER_HOST
+            assert eng._arena.get(node.payload[1]) == frame
+            assert node.payload[2] == frame_crc(frame)
+        end = eng.stats()
+    assert got_hot == want_hot
+    assert end.kv_pages_landed == len(demoted) and end.kv_land_lost == 0
+
+
+def test_match_at_an_inflight_node_stops_there_and_reprefills(
+        tmp_path, monkeypatch):
+    """A match that reaches a node whose bytes are still landing does
+    not wait: it stops there, the tail is prefilled cold (the output is
+    the oracle's), the re-published page takes the node back and the
+    landing, when it arrives, is discarded with its slot."""
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 0.0)
+    monkeypatch.setattr(_cfg, "serve_kv_tier_sweep_s", 3600.0)
+    head, tail_a, tail_b = _prompt(34, 8), _prompt(35, 8), _prompt(36, 9)
+    want = _oracle(head + tail_b, 6)
+    eng = _engine(name="land-match", kv_store_dir=str(tmp_path))
+    with eng:
+        eng.submit(head + tail_a, max_new_tokens=4).result(timeout=120)
+        gate = _Gate(eng)
+
+        def due_sweep():        # unforced: dispatch and go on
+            eng._last_sweep = float("-inf")
+            return eng._maybe_sweep_tiers()
+        moved = eng.run_on_worker(due_sweep)
+        assert moved == 4 and eng._prefix.inflight_nodes == 4
+        s0 = eng.stats()
+        got = eng.submit(head + tail_b, max_new_tokens=6).result(timeout=120)
+        s1 = eng.stats()
+        assert got == want
+        assert s1.kv_inflight_matches - s0.kv_inflight_matches == 1
+        assert s1.prefix_hit_tokens == s0.prefix_hit_tokens
+        assert s1.prefix_cache_misses - s0.prefix_cache_misses == 1
+        # head's two pages were re-published by the request's own
+        # prefill; tail_a's two are still in flight
+        assert eng._prefix.inflight_nodes == 2
+        free0 = eng._arena.free_slots
+        gate.open.set()
+        eng.run_on_worker(eng._land_drain)
+        assert eng.stats().kv_pages_landed == 2
+        assert eng._arena.free_slots == free0 + 2   # head's slots came back
+        assert eng._prefix.inflight_nodes == 0
+        again = eng.submit(head + tail_b,
+                           max_new_tokens=6).result(timeout=120)
+        s2 = eng.stats()
+    assert again == want
+    assert s2.prefix_hit_tokens - s1.prefix_hit_tokens == 16
+    assert s2.kv_land_lost == 0
+
+
+def test_evicting_an_inflight_node_frees_its_slot_when_it_lands(
+        tmp_path, monkeypatch):
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 0.0)
+    eng = _engine(name="land-evict", kv_store_dir=str(tmp_path))
+    with eng:
+        eng.submit(_prompt(37, 16), max_new_tokens=4).result(timeout=120)
+        gate = _Gate(eng)
+        n = eng.run_on_worker(
+            lambda: eng._demote_for_pressure(eng.kv_pages + 1))
+        arena = eng._arena
+        assert n == 4 and arena.free_slots == arena.capacity - 4
+        # the tree lets go of them while their bytes are on the way
+        dropped = eng.run_on_worker(
+            lambda: eng._prefix.evict(eng.kv_pages + 1))
+        assert dropped == 4 and eng._prefix.nodes == 0
+        assert arena.free_slots == arena.capacity - 4   # still being written
+        gate.open.set()
+        assert eng.run_on_worker(eng._land_drain) == 0
+        assert arena.free_slots == arena.capacity
+        st = eng.stats()
+    assert st.kv_demotions == st.kv_pages_landed == st.kv_land_lost == 0
+    assert st.kv_t1_pages == st.kv_t2_pages == 0
+
+
+def test_flush_returns_only_when_every_inflight_page_is_in_the_store(
+        tmp_path, monkeypatch):
+    """kv_flush_to_store is the drain path: pages dispatched before it
+    (toward the arena) and by it (toward the store) are all store
+    entries when it returns, and not before the lander ran."""
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 1e9)
+    first, second = _prompt(38, 16), _prompt(39, 16)
+    eng = _engine(name="land-flush", kv_store_dir=str(tmp_path))
+    store = KVPageStore(str(tmp_path))
+    with eng:
+        eng.submit(first, max_new_tokens=4).result(timeout=120)
+        gate = _Gate(eng)
+        # four pages on their way to the arena...
+        assert eng.run_on_worker(
+            lambda: eng._demote_for_pressure(eng.kv_pages + 1)) == 4
+        eng.submit(second, max_new_tokens=4).result(timeout=120)
+        # ...and the flush sends four more to the store
+        result = []
+        caller = threading.Thread(target=lambda: result.append(
+            eng.run_on_worker(eng.kv_flush_to_store, timeout=120)))
+        caller.start()
+        assert gate.entered.wait(120)
+        assert caller.is_alive() and not result
+        assert store.stats()["pages"] == 0
+        gate.open.set()
+        caller.join(120)
+        st = eng.stats()
+        for prompt in (first, second):
+            for fp in prefix_fingerprints(prompt, 4, 4):
+                assert store.has_page(fp)
+        assert eng._prefix.tier_nodes == [0, 0, 8]
+        assert eng._prefix.inflight_nodes == 0
+    assert result == [12]       # 8 landed + the 4 arena slots emptied
+    assert st.kv_land_wait_s > 0 and st.kv_land_lost == 0
+
+
+def test_demotions_of_different_page_counts_compile_the_read_once(
+        tmp_path, monkeypatch):
+    from ray_tpu._private import jax_utils
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 0.0)
+    monkeypatch.setattr(_cfg, "serve_kv_t2_idle_s", 1e9)
+    eng = _engine(name="land-once", kv_store_dir=str(tmp_path))
+    with eng:
+        eng.submit(_prompt(40, 16), max_new_tokens=4).result(timeout=120)
+        assert _sweep(eng) == 4         # the one compile lands here
+        eng.submit(_prompt(41, 32), max_new_tokens=4).result(timeout=120)
+        compiles = jax_utils.compile_counters()[0]
+        programs = decode.paged_read_pages._cache_size()
+        assert _sweep(eng) == 8
+        eng.submit(_prompt(42, 8), max_new_tokens=4).result(timeout=120)
+        # ...and the blocking read of an export is the same program
+        exported = eng.run_on_worker(lambda: eng.kv_export(_prompt(42, 8)))
+        assert exported["matched_tokens"] == 8 and len(exported["pages"]) == 2
+        eng.run_on_worker(lambda: eng.kv_export_release(exported["pages"]))
+        assert _sweep(eng) == 2
+        assert jax_utils.compile_counters()[0] == compiles
+        assert decode.paged_read_pages._cache_size() == programs
+
+
+@pytest.mark.parametrize("why", ["tiering_off", "pages_not_kv"])
+def test_an_engine_without_tiers_never_has_a_lander(monkeypatch, why):
+    """kv_tiering=False, or a model whose pages no tier can frame (the
+    engine turns tiering off by itself): pressure evicts, and no lander
+    thread is ever created."""
+    kw = {}
+    if why == "tiering_off":
+        kw["kv_tiering"] = False
+    else:
+        monkeypatch.setattr(decode, "pages_are_kv", lambda cfg: False)
+    before = set(threading.enumerate())
+    eng = _engine(name=f"land-none-{why}", kv_pages=12, num_slots=2, **kw)
+    with eng:
+        assert not eng._tiering
+        for seed in (43, 44, 45):
+            eng.submit(_prompt(seed, 24),
+                       max_new_tokens=8).result(timeout=120)
+        assert eng.run_on_worker(
+            lambda: eng._maybe_sweep_tiers(force=True)) == 0
+        st = eng.stats()
+        born = [t.name for t in set(threading.enumerate()) - before]
+    assert eng._lander is None and _lander_threads(eng) == []
+    assert born == [f"llm-engine-{eng.name}"]
+    assert st.kv_demotions == st.kv_pages_landed == st.kv_sweeps == 0
+
+
+def test_a_landing_with_nowhere_to_land_drops_the_node(tmp_path,
+                                                       monkeypatch):
+    """Arena full and no store: the pages left the pool at dispatch, so
+    their nodes are dropped (counted in kv_land_lost), and the next
+    request for that prefix prefills it again."""
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 0.0)
+    monkeypatch.setattr(HostKVArena, "reserve", lambda self: None)
+    monkeypatch.setattr(KVPageStore, "put_page", lambda *a, **k: False)
+    prompt = _prompt(46, 16)
+    want = _oracle(prompt, 6)
+    eng = _engine(name="land-lost", kv_store_dir=str(tmp_path))
+    with eng:
+        first = eng.submit(prompt, max_new_tokens=6).result(timeout=120)
+        free0 = eng.stats().kv_blocks_free
+        assert _sweep(eng) == 0
+        s0 = eng.stats()
+        assert s0.kv_land_lost == 4 and s0.kv_demotions == 0
+        assert eng._prefix.nodes == 0 and s0.kv_blocks_free == free0 + 4
+        again = eng.submit(prompt, max_new_tokens=6).result(timeout=120)
+        s1 = eng.stats()
+    assert first == again == want
+    assert s1.prefix_hit_tokens == s0.prefix_hit_tokens
+    assert s1.prefix_cache_misses - s0.prefix_cache_misses == 1
+
+
+def test_the_stores_ttl_sweep_runs_on_the_lander(tmp_path, monkeypatch):
+    """Aging the store out is a listing of every file it holds: the
+    sweep hands it to the lander and goes on."""
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 0.0)
+    ran = []
+    sweep = KVPageStore.sweep
+    monkeypatch.setattr(
+        KVPageStore, "sweep", lambda self, ttl: ran.append(
+            (threading.current_thread().name, sweep(self, ttl))))
+    eng = _engine(name="land-gc", kv_store_dir=str(tmp_path))
+    with eng:
+        eng.submit(_prompt(48, 16), max_new_tokens=4).result(timeout=120)
+        assert eng.run_on_worker(eng.kv_flush_to_store) == 4
+        assert not ran
+        eng._last_store_gc = float("-inf")
+        _sweep(eng)
+        eng.run_on_worker(eng._land_drain)
+    assert ran == [("llm-lander-land-gc", 0)]
+
+
+def test_inflight_bytes_are_bounded_by_waiting_on_the_lander(
+        tmp_path, monkeypatch):
+    """Past the bound on device stacks awaiting their copy the worker
+    waits on the lander inside the sweep (kv_land_wait_s); a demotion
+    of more pages than one dispatch carries is several stacks of the
+    one shape, the last padded, and every page lands whole."""
+    monkeypatch.setattr(_cfg, "serve_kv_demote_idle_s", 0.0)
+    monkeypatch.setattr(_cfg, "serve_kv_t2_idle_s", 1e9)
+    monkeypatch.setattr(decode, "_READ_BYTES", 0)      # 8 pages a stack
+    eng = _engine(name="land-bound", kv_store_dir=str(tmp_path))
+    with eng:
+        eng.submit(_prompt(47, 44), max_new_tokens=4).result(timeout=120)
+        want = eng.run_on_worker(lambda: _pool_frames(eng))
+        assert len(want) == 11
+        gate = _Gate(eng)
+        eng._inflight_cap = 1
+        result = []
+        caller = threading.Thread(
+            target=lambda: result.append(eng.run_on_worker(
+                lambda: eng._demote_for_pressure(eng.kv_pages + 1),
+                timeout=120)))
+        caller.start()
+        assert gate.entered.wait(120)
+        # the second stack waits for the first one's copy
+        assert caller.is_alive() and eng._prefix.inflight_nodes == 8
+        gate.open.set()
+        caller.join(120)
+        assert result == [11]
+        eng.run_on_worker(eng._land_drain)
+        for node, frame, crc in want:
+            assert eng._arena.get(node.payload[1]) == frame
+            assert node.payload[2] == crc
+        st = eng.stats()
+    assert st.kv_land_wait_s > 0 and st.kv_pages_landed == 11
 
 
 # ---------------------------------------------------------------------------

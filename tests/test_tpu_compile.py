@@ -148,6 +148,29 @@ def test_paged_step_compiles(chip, model, step):
     assert not moved, moved[:4]
 
 
+@pytest.mark.parametrize("model", ["mistral-d16", "internlm2"])
+def test_tier_read_compiles_at_one_shape_and_moves_no_pool(chip, model):
+    """The gather a demotion dispatches (decode.paged_read_pages), at the
+    benchmark's dense pools and the one count of ids the engine ever
+    asks for: its result is the stack of pages (32 MiB or less), it
+    holds no temporary to speak of beside it, and no instruction's
+    result is a layer's pool or the whole of it."""
+    cfg, _, _, _, pages = STEP_MODELS[model]
+    cache = _on(chip, jax.eval_shape(
+        lambda: decode.init_paged_cache(cfg, pages, 16)))
+    size = decode.paged_read_batch(cache)
+    assert size == {"mistral-d16": 32, "internlm2": 21}[model]
+    ids = jax.ShapeDtypeStruct((size,), jnp.int32, sharding=chip)
+    compiled = decode.paged_read_pages.lower(cache, ids).compile()
+    mem = compiled.memory_analysis()
+    page = 2 * cfg.n_layers * 16 * cfg.n_kv_heads * cfg.head_dim * 2
+    assert size * page <= mem.output_size_in_bytes <= 2 * size * page
+    assert mem.temp_size_in_bytes < 1 << 26, mem.temp_size_in_bytes / 2**20
+    per_layer, moved = _pool_results(compiled.as_text(), cache["k"].shape)
+    assert not per_layer, per_layer[:4]
+    assert not moved, moved[:4]
+
+
 @pytest.mark.parametrize("step", STEPS)
 def test_paged_step_compiles_at_a_32k_width(chip, step):
     """InternLM2's tick and chunk over a virtual width of 32,768 (2,048
