@@ -40,7 +40,11 @@ are chosen among all of them, and the layer computes
 absent experts would add is left out; nothing stands in for them.
 Routed pairs are sorted by expert and run through one grouped matmul a
 projection (Pallas `megablox.gmm`), which visits only the experts that
-a token chose: an expert no token chose is not read.
+a token chose: an expert no token chose is not read.  `routed_experts`,
+`count_routed` and the counters have a second caller, models/exaone_moe.py
+(a sigmoid router of its own over the same grouped matmul): they read
+`cfg.top_k`, `cfg.experts_held`, `cfg.expert_offset` and nothing else
+(it also borrows `_span_pages` and `_merge`, which read no config).
 
 Departures from the published modeling file, all relabellings under
 seeded weights: RoPE pairs are (i, i + d/2), not interleaved; kv_b_proj
@@ -414,6 +418,16 @@ def routed_experts(experts, h, ids, weights, live, cfg: DeepseekV2Config):
     return (pairs * jnp.where(held, weights, 0.0)[..., None]).sum(1), sizes
 
 
+def count_routed(counts, live, sizes, is_tick: bool, cfg):
+    """`counts` (a call's additions to COUNTERS so far) plus one expert
+    layer's: `live` [N] the tokens routed, `sizes` [experts_held] the
+    tokens on each held expert (routed_experts' second result)."""
+    tick = jnp.int32(is_tick)
+    return [c + a for c, a in zip(counts, (
+        live.sum() * cfg.top_k, sizes.sum(), tick * (sizes > 0).sum(),
+        tick * cfg.experts_held, sizes.max()))]
+
+
 def _ffn(lp, x, live, is_tick, counts, cfg: DeepseekV2Config):
     """x + FFN(norm(x)): dense SwiGLU in the leading layers, shared +
     held routed experts after them.  `counts`: this call's additions to
@@ -427,10 +441,7 @@ def _ffn(lp, x, live, is_tick, counts, cfg: DeepseekV2Config):
     with jax.named_scope("moe_experts"):
         routed, sizes = routed_experts(lp["experts"], h, ids, weights, live,
                                        cfg)
-    tick = jnp.int32(is_tick)
-    counts = [c + a for c, a in zip(counts, (
-        live.sum() * cfg.top_k, sizes.sum(), tick * (sizes > 0).sum(),
-        tick * cfg.experts_held, sizes.max()))]
+    counts = count_routed(counts, live, sizes, is_tick, cfg)
     return x + (routed + _swiglu(lp["shared"], h, dt)).astype(x.dtype), counts
 
 

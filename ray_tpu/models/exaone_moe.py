@@ -1,0 +1,553 @@
+"""K-EXAONE (`exaone_moe`): a GQA decoder whose attention layers are of
+two kinds in a fixed pattern — WINDOW layers that attend to the last
+`window` tokens (three in four, with RoPE) and GLOBAL layers that attend
+to everything (one in four, without positions) — over a leading dense
+SwiGLU layer and then layers of one shared + routed SwiGLU experts with
+a sigmoid router that renormalises its top-k.  This module is the model
+as the serving engine runs it: a config object, seeded weights, the
+cache it declares, and its own paged step for a prefill chunk and for a
+decode tick.  `models/decode.py` hands a config that names a
+`paged_model` to that module, so the engine's two jitted programs
+(`engine._prefill_chunk`, `engine._paged_tick`) run it as they run every
+model.
+
+The cache (one pytree, `engine._cache`) holds two kinds of attention
+state:
+
+  k, v    [n_global, P, page, G, Dh]   pages of the GLOBAL layers only:
+                                       they grow with the sequence, and
+                                       they are all the pool and the
+                                       engine's reservation count
+  wk, wv  [n_window, B, W, G, Dh]      a RING per decode row and window
+                                       layer: the token at position p is
+                                       kept at p mod W (keys already
+                                       rotated), so a row holds its last
+                                       W = `window` tokens and nothing
+                                       else, whatever its context
+  moe     [5, 2] int32                 the expert layers' counters
+                                       (deepseek_v2.COUNTERS)
+
+A ring is state per decode row (`row_state`): what treats a page as the
+whole of a sequence's state (prefix cache, tiers, kv_export / kv_import,
+migration, session checkpoints) refuses this model by name
+(kv_tier.refuse_row_state).  Nothing zeroes a ring when a row changes
+hands: entry i of a row at position p holds position p - ((p - i) mod W),
+and an entry whose position would be negative is masked, so what an
+earlier sequence left is never read.
+
+A tick reads, in a window layer, the row's ring and nothing else (W keys
+a row); a prefill chunk of T tokens reads the W ring entries before it
+(the oldest is out of every query's window: W - 1 visible) beside its
+own T keys, in blocks of W queries against 2 W keys, and leaves its last
+W real tokens in the ring.  A global layer walks the row's pages in
+spans, as the dense body of models/decode.py does.
+
+The expert layer is told which experts it holds (`experts_held`,
+`expert_offset`): the router scores ALL `n_routed_experts` with a
+sigmoid, the top-k are chosen among all of them, their weights are
+renormalised over the k chosen WHEREVER THEY LIVE and scaled, and the
+layer computes `shared(x) + sum over (top-k AND held) of w_i expert_i(x)`
+through `deepseek_v2.routed_experts` (the Pallas grouped matmul) and its
+device counters.  What the absent experts would add is left out.
+
+What the published config does not pin, and what was taken (the
+benchmark's configuration file argues each): pre-norm blocks; an RMSNorm
+over each head of q and k; RoPE (pairs (i, i + Dh/2)) in window layers
+only.  The multi-token-prediction layer is not here.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import sys
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from ray_tpu.models import deepseek_v2 as _ds
+from ray_tpu.models.decode import _rope_at, _swiglu
+
+_HI = lax.Precision.HIGHEST
+# Keys one span of a GLOBAL layer's attention covers (whole pages).  A
+# tick gathers a span's pages for every row of the call; a chunk scores
+# all its queries against a span in float32, [heads, queries, keys].
+_TICK_SPAN_KEYS = 256
+_CHUNK_SPAN_KEYS = 256
+
+COUNTERS = _ds.COUNTERS
+read_counters = _ds.read_counters
+# whole pages a span of so many keys covers; one span merged into a
+# running softmax (maxima, sums, accumulator)
+_span_pages = _ds._span_pages
+_merge = _ds._merge
+
+
+@dataclasses.dataclass(frozen=True)
+class ExaoneMoeConfig:
+    """Published K-EXAONE-236B-A23B sizes by default; `experts_held`,
+    `expert_offset`, `vocab_size` and `n_layers` say the share this chip
+    holds.  `sliding_windows` is the published per-layer list (0: a
+    global layer), of which the first `n_layers` entries are run.
+    Hashable: the engine passes it as a static argument."""
+    max_seq: int
+    n_layers: int = 48
+    vocab_size: int = 153600
+    d_model: int = 6144
+    n_heads: int = 64
+    n_kv_heads: int = 8
+    head_dim: int = 128
+    d_ff: int = 18432                 # the leading dense layers
+    first_k_dense: int = 1
+    moe_d_ff: int = 2048
+    n_routed_experts: int = 128       # what the router scores: never cut
+    n_shared_experts: int = 1
+    top_k: int = 8
+    routed_scaling_factor: float = 2.5
+    experts_held: Optional[int] = None    # None: all of them
+    expert_offset: int = 0
+    sliding_windows: Tuple[int, ...] = (128, 128, 128, 0) * 12
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.experts_held is None:
+            object.__setattr__(self, "experts_held", self.n_routed_experts)
+        object.__setattr__(self, "sliding_windows",
+                           tuple(self.sliding_windows)[:self.n_layers])
+        if len(self.sliding_windows) != self.n_layers:
+            raise ValueError("sliding_windows must name every layer run")
+        if len(set(self.sliding_windows) - {0}) > 1:
+            raise ValueError("the window layers share one window")
+        if self.top_k > self.n_routed_experts:
+            raise ValueError("top_k exceeds the routed experts")
+        if self.expert_offset < 0 or self.experts_held < 1 \
+                or self.expert_offset + self.experts_held \
+                > self.n_routed_experts:
+            raise ValueError("the held experts must lie among the routed")
+        if not 0 <= self.first_k_dense <= self.n_layers:
+            raise ValueError("first_k_dense must be 0..n_layers")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError("n_heads must be a multiple of n_kv_heads")
+
+    @property
+    def window(self) -> int:
+        """Tokens a window layer attends to and a ring holds (0: the
+        model has no window layer)."""
+        return max(self.sliding_windows, default=0)
+
+    @property
+    def n_window(self) -> int:
+        return sum(w > 0 for w in self.sliding_windows)
+
+    @property
+    def n_global(self) -> int:
+        return self.n_layers - self.n_window
+
+    @property
+    def n_moe(self) -> int:
+        return self.n_layers - self.first_k_dense
+
+    # -- what models/decode.py and the engine ask a model with its own
+    # paged step ------------------------------------------------------
+    @property
+    def paged_model(self):
+        return sys.modules[__name__]
+
+    row_state = True      # the rings: state per decode row, not paged
+
+
+def _kind_index(cfg: ExaoneMoeConfig, l: int) -> Tuple[bool, int]:
+    """(is layer `l` a window layer, its index among layers of its
+    kind)."""
+    windowed = cfg.sliding_windows[l] > 0
+    return windowed, sum((w > 0) == windowed
+                         for w in cfg.sliding_windows[:l])
+
+
+def attn_keys(cfg: ExaoneMoeConfig, pos: np.ndarray) -> Tuple[int, int]:
+    """(keys read, keys held) by one tick's decode rows at positions
+    `pos`, summed over rows and layers: a global layer holds and reads
+    all `pos + 1`, a window layer `min(pos + 1, window)`: the tick reads
+    all a row holds, and a row holds less than its context."""
+    pos = np.asarray(pos, np.int64)
+    held = int((pos + 1).sum()) * cfg.n_global \
+        + int(np.minimum(pos + 1, cfg.window).sum()) * cfg.n_window
+    return held, held
+
+
+def attn_keys_gathered(cfg: ExaoneMoeConfig, pos: np.ndarray,
+                       page_size: int, nblk: int) -> int:
+    """Keys one tick pulls from the cache: in a global layer, for EVERY
+    row of the call (`pos` of all decode rows, idle ones at 0), whole
+    spans up to the deepest row's token, the trip count the program
+    reads from `pos`; in a window layer every row's whole ring."""
+    cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
+    spans = -(-(int(np.asarray(pos).max()) + 1) // cols)
+    return len(pos) * (spans * cols * cfg.n_global
+                       + cfg.window * cfg.n_window)
+
+
+def chunk_selects(cfg: ExaoneMoeConfig, start: int) -> bool:
+    return False          # no layer chooses pages
+
+
+def check_paging(cfg: ExaoneMoeConfig, *, page_size: int,
+                 prefill_chunk: int, speculate_k: int) -> None:
+    if prefill_chunk % page_size:
+        raise ValueError(f"a prefill chunk writes whole pages of the "
+                         f"global layers: prefill_chunk must be a multiple "
+                         f"of page_size={page_size}, got {prefill_chunk}")
+    if speculate_k:
+        raise NotImplementedError(
+            "speculative verify on a model with per-row window rings "
+            "needs the ring rolled back to the accepted token")
+
+
+# ---------------------------------------------------------------------------
+# Weights and cache
+
+
+def init_params(cfg: ExaoneMoeConfig, key, dtype=None) -> Dict:
+    """Seeded weights, one dict a layer (normal, std 0.02; projections
+    back into the residual stream 0.02 / sqrt(2 n_layers); the router in
+    float32, as it is applied, and its selection bias zero)."""
+    dtype = dtype or cfg.dtype
+    D, H, G, Dh, F = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+                      cfg.head_dim, cfg.moe_d_ff)
+    s = 0.02
+    so = s / np.sqrt(2 * cfg.n_layers)
+    keys = iter(jax.random.split(key, 2 + 16 * cfg.n_layers))
+
+    def nrm(shape, scale, dt=dtype):
+        return (scale * jax.random.normal(next(keys), shape, jnp.float32)
+                ).astype(dt)
+
+    ones = lambda *shape: jnp.ones(shape, jnp.float32)  # noqa: E731
+
+    def swiglu(width, *lead):
+        return {"w_gate": nrm(lead + (D, width), s),
+                "w_up": nrm(lead + (D, width), s),
+                "w_down": nrm(lead + (width, D), so)}
+
+    def layer(i):
+        lp = {"ln1": ones(D), "wq": nrm((D, H, Dh), s),
+              "wkv": nrm((D, 2, G, Dh), s), "qn": ones(Dh), "kn": ones(Dh),
+              "wo": nrm((H, Dh, D), so), "ln2": ones(D)}
+        if i < cfg.first_k_dense:
+            return dict(lp, **swiglu(cfg.d_ff))
+        return dict(lp, router=nrm((D, cfg.n_routed_experts), s,
+                                   jnp.float32),
+                    router_bias=jnp.zeros((cfg.n_routed_experts,),
+                                          jnp.float32),
+                    shared=swiglu(cfg.n_shared_experts * F),
+                    experts=swiglu(F, cfg.experts_held))
+
+    return {"wte": nrm((cfg.vocab_size, D), s),
+            "layers": tuple(layer(i) for i in range(cfg.n_layers)),
+            "ln_f": ones(D), "wlm": nrm((D, cfg.vocab_size), s)}
+
+
+def init_paged_cache(cfg: ExaoneMoeConfig, num_pages: int, page_size: int,
+                     num_slots: Optional[int] = None) -> Dict:
+    G, Dh = cfg.n_kv_heads, cfg.head_dim
+    pages = (cfg.n_global, num_pages, page_size, G, Dh)
+    rings = (cfg.n_window, num_slots or 1, cfg.window, G, Dh)
+    return {"k": jnp.zeros(pages, cfg.dtype), "v": jnp.zeros(pages, cfg.dtype),
+            "wk": jnp.zeros(rings, cfg.dtype),
+            "wv": jnp.zeros(rings, cfg.dtype),
+            "moe": jnp.zeros((len(COUNTERS), 2), jnp.int32)}
+
+
+def _rms(x, scale, cfg: ExaoneMoeConfig):
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + cfg.rms_eps) * scale).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The expert layer
+
+
+def route(router, bias, h, cfg: ExaoneMoeConfig):
+    """Sigmoid scores over ALL routed experts, in float32.  h [N, D] ->
+    (expert ids [N, top_k], weights [N, top_k] float32): the top_k
+    largest of score + bias are chosen; a chosen expert's weight is its
+    score over the sum of the chosen scores (wherever those experts
+    live), times `routed_scaling_factor`."""
+    logits = jnp.einsum("nd,de->ne", h.astype(jnp.float32),
+                        router.astype(jnp.float32), precision=_HI)
+    s = jax.nn.sigmoid(logits)
+    ids = lax.top_k(s + bias[None], cfg.top_k)[1]
+    w = jnp.take_along_axis(s, ids, axis=1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    return ids.astype(jnp.int32), w
+
+
+def _ffn(lp, x, live, is_tick, counts, cfg: ExaoneMoeConfig):
+    """x + FFN(norm(x)): dense SwiGLU in the leading layers, shared +
+    held routed experts after them.  `counts`: this call's additions to
+    COUNTERS so far."""
+    dt = cfg.dtype
+    h = _rms(x, lp["ln2"], cfg)
+    if "router" not in lp:
+        return x + _swiglu(lp, h, dt), counts
+    with jax.named_scope("moe_route"):
+        ids, weights = route(lp["router"], lp["router_bias"], h, cfg)
+    with jax.named_scope("moe_experts"):
+        routed, sizes = _ds.routed_experts(lp["experts"], h, ids, weights,
+                                           live, cfg)
+    counts = _ds.count_routed(counts, live, sizes, is_tick, cfg)
+    return x + (routed + _swiglu(lp["shared"], h, dt)).astype(x.dtype), counts
+
+
+# ---------------------------------------------------------------------------
+# Attention, for a single-row chunk of T tokens (x [T, D]) and for a tick
+# of B rows (x [B, D]).  `i` indexes the layer among the layers of its
+# kind (its pages, or its rings).
+
+
+def _project(lp, x, positions, rotate: bool, cfg: ExaoneMoeConfig):
+    """x [n, D] at positions [n] -> q [n, H, Dh], k, v [n, G, Dh]: q and
+    k normed per head, and rotated in a window layer."""
+    dt = cfg.dtype
+    h = _rms(x, lp["ln1"], cfg)
+    q = jnp.einsum("nd,dhk->nhk", h, lp["wq"].astype(dt))
+    kv = jnp.einsum("nd,dchk->nchk", h, lp["wkv"].astype(dt))
+    q, k = _rms(q, lp["qn"], cfg), _rms(kv[:, 0], lp["kn"], cfg)
+    if rotate:
+        q = _rope_at(q[None], positions[None], cfg.rope_theta)[0]
+        k = _rope_at(k[None], positions[None], cfg.rope_theta)[0]
+    return q, k, kv[:, 1]
+
+
+def _close(lp, x, out, cfg: ExaoneMoeConfig):
+    return x + jnp.einsum("nhk,hkd->nd", out, lp["wo"].astype(cfg.dtype))
+
+
+def _global_chunk(lp, x, i, cache, bt, start, cfg: ExaoneMoeConfig):
+    T = x.shape[0]
+    G, Dh, psz = cfg.n_kv_heads, cfg.head_dim, cache["k"].shape[2]
+    R = cfg.n_heads // G
+    dt = cfg.dtype
+    cols = start + jnp.arange(T)
+    q, k, v = _project(lp, x, cols, False, cfg)
+    pages = lax.dynamic_slice(bt, (start // psz,), (T // psz,))
+    ck = cache["k"].at[i, pages].set(k.reshape(T // psz, psz, G, Dh))
+    cv = cache["v"].at[i, pages].set(v.reshape(T // psz, psz, G, Dh))
+
+    with jax.named_scope("attn_global"):
+        nblk = bt.shape[0]
+        span = _span_pages(_CHUNK_SPAN_KEYS, psz, nblk)
+        width = span * psz
+        qg = q.reshape(T, G, R, Dh)
+
+        def attend(j, part):
+            first = jnp.minimum(j * span, nblk - span)   # as the slice clamps
+            pg = lax.dynamic_slice(bt, (first,), (span,))
+            ks = ck[i, pg].reshape(width, G, Dh)
+            vs = cv[i, pg].reshape(width, G, Dh)
+            s = jnp.einsum("tgrd,sgd->grts", qg, ks,
+                           preferred_element_type=jnp.float32) * Dh ** -0.5
+            kcols = first * psz + jnp.arange(width)
+            seen = (kcols[None, :] <= cols[:, None]) \
+                & (kcols[None, :] >= j * width)
+            s = jnp.where(seen[None, None], s, -jnp.inf)
+            return _merge(part, s, lambda e: jnp.einsum(
+                "grts,sgd->grtd", e.astype(dt), vs,
+                preferred_element_type=jnp.float32))
+
+        stat = jnp.full((G, R, T), -jnp.inf, jnp.float32)
+        _, total, acc = lax.fori_loop(
+            0, (start + T + width - 1) // width, attend,
+            (stat, jnp.zeros_like(stat),
+             jnp.zeros((G, R, T, Dh), jnp.float32)))
+        out = (acc / total[..., None]).astype(dt)             # [G, R, T, Dh]
+        out = jnp.moveaxis(out, 2, 0).reshape(T, G * R, Dh)
+    return _close(lp, x, out, cfg), dict(cache, k=ck, v=cv)
+
+
+def _global_tick(lp, x, i, cache, bt, pos, cfg: ExaoneMoeConfig):
+    B = x.shape[0]
+    G, Dh, psz = cfg.n_kv_heads, cfg.head_dim, cache["k"].shape[2]
+    R = cfg.n_heads // G
+    dt = cfg.dtype
+    q, k, v = _project(lp, x, pos, False, cfg)
+    page = jnp.take_along_axis(bt, (pos // psz)[:, None], axis=1)[:, 0]
+    ck = cache["k"].at[i, page, pos % psz].set(k)
+    cv = cache["v"].at[i, page, pos % psz].set(v)
+
+    with jax.named_scope("attn_global"):
+        nblk = bt.shape[1]
+        span = _span_pages(_TICK_SPAN_KEYS, psz, nblk)
+        width = span * psz
+        qg = q.reshape(B, G, R, Dh)
+
+        def attend(j, part):
+            first = jnp.minimum(j * span, nblk - span)
+            pg = lax.dynamic_slice(bt, (0, first), (B, span))
+            ks = ck[i, pg].reshape(B, width, G, Dh)
+            vs = cv[i, pg].reshape(B, width, G, Dh)
+            s = jnp.einsum("bgrd,bsgd->bgrs", qg, ks,
+                           preferred_element_type=jnp.float32) * Dh ** -0.5
+            kcols = first * psz + jnp.arange(width)
+            seen = (kcols[None, :] <= pos[:, None]) \
+                & (kcols[None, :] >= j * width)
+            s = jnp.where(seen[:, None, None], s, -jnp.inf)
+            return _merge(part, s, lambda e: jnp.einsum(
+                "bgrs,bsgd->bgrd", e.astype(dt), vs,
+                preferred_element_type=jnp.float32))
+
+        stat = jnp.full((B, G, R), -jnp.inf, jnp.float32)
+        _, total, acc = lax.fori_loop(
+            0, (jnp.max(pos) + width) // width, attend,
+            (stat, jnp.zeros_like(stat),
+             jnp.zeros((B, G, R, Dh), jnp.float32)))
+        out = (acc / total[..., None]).astype(dt).reshape(B, G * R, Dh)
+    return _close(lp, x, out, cfg), dict(cache, k=ck, v=cv)
+
+
+def _window_attend(qg, k, v, qpos, kpos, W, dt):
+    """qg [..., n, G, R, Dh] at positions qpos [..., n] over keys k, v
+    [..., s, G, Dh] at positions kpos [..., s] (negative: nothing is
+    there): key s is visible to query t iff 0 <= t - s < W."""
+    s = jnp.einsum("...ngrd,...sgd->...grns", qg, k,
+                   preferred_element_type=jnp.float32) * qg.shape[-1] ** -0.5
+    back = qpos[..., :, None] - kpos[..., None, :]
+    seen = (back >= 0) & (back < W) & (kpos[..., None, :] >= 0)
+    s = jnp.where(seen[..., None, None, :, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1).astype(dt)
+    return jnp.einsum("...grns,...sgd->...ngrd", p, v)
+
+
+def _window_chunk(lp, x, i, cache, start, slot, valid, cfg: ExaoneMoeConfig):
+    T = x.shape[0]
+    G, Dh, W = cfg.n_kv_heads, cfg.head_dim, cfg.window
+    R = cfg.n_heads // G
+    dt = cfg.dtype
+    cols = start + jnp.arange(T)
+    q, k, v = _project(lp, x, cols, True, cfg)
+
+    with jax.named_scope("attn_window"):
+        # the W tokens before the chunk, in position order
+        before = (start + jnp.arange(W)) % W
+        kall = jnp.concatenate([cache["wk"][i, slot][before], k])
+        vall = jnp.concatenate([cache["wv"][i, slot][before], v])
+        kpos = start - W + jnp.arange(W + T)
+        qb = W if T % W == 0 else T          # queries a block
+        qg = q.reshape(T // qb, qb, G, R, Dh)
+
+        def block(j):
+            # block j's queries see the qb keys of their own block and
+            # the W before it: kall[j qb : j qb + W + qb]
+            take = lambda a: lax.dynamic_slice_in_dim(  # noqa: E731
+                a, j * qb, W + qb)
+            return _window_attend(qg[j], take(kall), take(vall),
+                                  start + j * qb + jnp.arange(qb),
+                                  take(kpos), W, dt)
+        out = lax.map(block, jnp.arange(T // qb)).reshape(T, G * R, Dh)
+
+        # the ring after the chunk: entry e holds the last REAL position
+        # congruent to e, from the chunk where that lies inside it
+        last = start + valid - 1
+        at = last - (last - jnp.arange(W)) % W
+        mine = (at >= start)[:, None, None]
+        src = jnp.clip(at - start, 0, T - 1)
+        wk = cache["wk"].at[i, slot].set(
+            jnp.where(mine, k[src], cache["wk"][i, slot]))
+        wv = cache["wv"].at[i, slot].set(
+            jnp.where(mine, v[src], cache["wv"][i, slot]))
+    return _close(lp, x, out, cfg), dict(cache, wk=wk, wv=wv)
+
+
+def _window_tick(lp, x, i, cache, pos, cfg: ExaoneMoeConfig):
+    B = x.shape[0]
+    G, Dh, W = cfg.n_kv_heads, cfg.head_dim, cfg.window
+    R = cfg.n_heads // G
+    q, k, v = _project(lp, x, pos, True, cfg)
+
+    with jax.named_scope("attn_window"):
+        # a row at position 0 is idle, or the row a prefill is filling:
+        # its ring stays as it is
+        at = jnp.where(pos > 0, pos % W, W)
+        rows = jnp.arange(B)
+        wk = cache["wk"].at[i, rows, at].set(k, mode="drop")
+        wv = cache["wv"].at[i, rows, at].set(v, mode="drop")
+        entries = jnp.arange(W)[None, :]
+        kpos = pos[:, None] - (pos[:, None] - entries) % W
+        out = _window_attend(q.reshape(B, 1, G, R, Dh), wk[i], wv[i],
+                             pos[:, None], kpos, W, cfg.dtype)
+    return _close(lp, x, out.reshape(B, G * R, Dh), cfg), \
+        dict(cache, wk=wk, wv=wv)
+
+
+# ---------------------------------------------------------------------------
+# The paged step
+
+
+def _through_layers(params, x, cache, live, is_tick, attend, cfg):
+    counts = [jnp.int32(0)] * len(COUNTERS)
+    for l, lp in enumerate(params["layers"]):
+        x, cache = attend(lp, x, *_kind_index(cfg, l), cache)
+        x, counts = _ffn(lp, x, live, is_tick, counts, cfg)
+    x = _rms(x, params["ln_f"], cfg)
+    logits = jnp.einsum("nd,dv->nv", x.astype(cfg.dtype),
+                        params["wlm"].astype(cfg.dtype),
+                        preferred_element_type=jnp.float32)
+    return logits, dict(cache, moe=_ds._count(cache["moe"], counts))
+
+
+def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
+                     cfg: ExaoneMoeConfig, pad_lo=None, slot=None,
+                     valid=None) -> Tuple[Any, Dict]:
+    """The model's paged step, under decode.paged_chunk_step's contract.
+
+    `pos` a scalar: ONE row's chunk of T tokens starting there (T and
+    `pos` whole pages) — single-row prefill.  It fills the row's pages
+    of the global layers and leaves the last `window` of its first
+    `valid` tokens (default all) in the rings of decode row `slot`
+    (default 0); only those tokens are routed to experts.  `pos` a [B]
+    vector with one token a row: the decode tick.  Rows at position 0
+    are idle: their page writes land wherever their block table points
+    (the trash page), their rings are not written, and they are routed
+    nowhere.
+    Returns (logits [B, t, V] float32, cache)."""
+    if pad_lo is not None:
+        raise NotImplementedError("left-padded rows")
+    B, t = tokens.shape
+    psz = cache["k"].shape[2]
+    pos = jnp.asarray(pos, jnp.int32)
+    embed = lambda tok: jnp.take(params["wte"], tok, axis=0  # noqa: E731
+                                 ).astype(cfg.dtype)
+    if pos.ndim == 0:
+        if B != 1 or t % psz:
+            raise ValueError(f"a chunk is one row of whole pages of {psz} "
+                             f"tokens, got {tokens.shape}")
+        slot = jnp.int32(0) if slot is None else jnp.asarray(slot, jnp.int32)
+        valid = jnp.int32(t) if valid is None \
+            else jnp.asarray(valid, jnp.int32)
+        bt = block_tables[0]
+
+        def attend(lp, x, windowed, i, c):
+            if windowed:
+                return _window_chunk(lp, x, i, c, pos, slot, valid, cfg)
+            return _global_chunk(lp, x, i, c, bt, pos, cfg)
+        logits, cache = _through_layers(
+            params, embed(tokens[0]), cache, jnp.arange(t) < valid, False,
+            attend, cfg)
+        return logits[None], cache
+    if t != 1:
+        raise NotImplementedError(
+            "several tokens a row at per-row positions (speculative "
+            "verify) need the window rings rolled back on rejection")
+
+    def attend(lp, x, windowed, i, c):
+        if windowed:
+            return _window_tick(lp, x, i, c, pos, cfg)
+        return _global_tick(lp, x, i, c, block_tables, pos, cfg)
+    logits, cache = _through_layers(
+        params, embed(tokens[:, 0]), cache, pos > 0, True, attend, cfg)
+    return logits[:, None], cache

@@ -366,6 +366,10 @@ class EngineStats:
     # (decode.paged_chunk_step); over `resident`, what a tick gathers
     # for each key its rows hold.
     attn_keys_gathered: int = 0
+    # ...and the tokens those rows have SEEN, times the layers that
+    # attend: `resident` over it is the share of its context a row still
+    # holds (1 unless some layer forgets: a window layer's ring).
+    attn_keys_context: int = 0
     prefill_tokens: int = 0           # prompt tokens run through prefill
     prefill_pad_tokens: int = 0       # ...and the columns those chunks
     #                                   computed that held no prompt token
@@ -780,6 +784,7 @@ class GenerationEngine:
         self._keys_attended = 0
         self._keys_resident = 0
         self._keys_gathered = 0
+        self._keys_context = 0
         self._prefill_tokens = 0
         self._prefill_pad_tokens = 0
         self._prefill_tokens_sparse = 0
@@ -1653,6 +1658,7 @@ class GenerationEngine:
             attn_keys_attended=self._keys_attended,
             attn_keys_resident=self._keys_resident,
             attn_keys_gathered=self._keys_gathered,
+            attn_keys_context=self._keys_context,
             prefill_tokens=self._prefill_tokens,
             prefill_pad_tokens=self._prefill_pad_tokens,
             prefill_tokens_sparse=self._prefill_tokens_sparse,
@@ -2117,6 +2123,10 @@ class GenerationEngine:
         self._keys_attended += read
         self._keys_resident += held
         self._keys_gathered += gathered
+        # (a model that mixes in layers of another kind says how many
+        # attend: `n_attn`)
+        self._keys_context += (int(pos.sum()) + len(actives)) \
+            * getattr(self.cfg, "n_attn", self.cfg.n_layers)
 
     def _verify_tick(self, actives, spec_drafts):
         """One fused paged_chunk_step verifying every row's pending

@@ -315,3 +315,71 @@ def test_dsv2_paged_step_compiles(chip, step, monkeypatch):
         expanded = [s for s in shapes if len(s) == 4 and s[0] == str(rows)
                     and s[-1] in ("128", "192") and "128" in s[1:3]]
         assert not expanded, expanded[:4]
+
+
+# The fifth configuration (benchmarks/configs/k-exaone-ep8-d5.json):
+# window layers that hold a ring of 128 tokens a decode row beside one
+# global layer that pages, and an expert layer that holds 16 of 128
+# experts, built as the benchmark builds it, at its sizes.
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_kexaone_paged_step_compiles(chip, step, monkeypatch):
+    """Both programs of k-exaone-ep8-d5 with the grouped matmul as the
+    chip runs it: 7.4 GB of weights, the ONE global layer's pool and
+    0.27 GB of rings are resident, a step holds under 0.5 GiB beside
+    them, neither the pool nor the rings are re-laid or copied, and no
+    array of a window layer's attention is as wide as the table: the
+    tick scores [rows, heads, 128 ring entries], never max_seq."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import deepseek_v2
+    monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs", "k-exaone-ep8-d5.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+    assert cache["k"].shape[0] == 1 and cache["wk"].shape[:3] == (
+        4, rows, 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if step == "decode_tick":
+        lowered = engine._paged_tick.lower(
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
+            with_logits=False)
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # 0.042 GiB (tick) / 0.112 (a 512-token chunk)
+    assert mem.temp_size_in_bytes < 1 << 29, mem.temp_size_in_bytes / 2**30
+    # weights 6.91 GiB + the global layer's pool + rings 0.25
+    pool = 2 * cache["k"].size * 2 / 2**30
+    assert 7.1 + pool < mem.argument_size_in_bytes / 2**30 < 7.3 + pool
+    text = compiled.as_text()
+    # three grouped matmuls an expert layer
+    assert text.count("tpu_custom_call") >= 3 * cfg.n_moe
+    for name in ("k", "wk"):
+        held = "bf16[%s]" % ",".join(map(str, cache[name].shape))
+        layouts = set(re.findall(re.escape(held) + r"\{([\d,]+)", text))
+        assert layouts == {"4,3,2,1,0"}, (name, layouts)   # never re-laid
+        moved = [ln for ln in text.splitlines()
+                 if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
+        assert not moved, moved[:4]
+    # nothing is as wide as the table (14,336 columns, 224 blocks of
+    # pages but for the block tables themselves)
+    shapes = [s.split(",") for s in re.findall(r" = \w+\[([\d,]+)\]", text)]
+    wide = [s for s in shapes if str(blocks * e["page_size"]) in s]
+    assert not wide, wide[:4]
