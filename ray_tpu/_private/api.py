@@ -11,9 +11,11 @@ import atexit
 import inspect
 import os
 import threading
+import time
 
 from ray_tpu import exceptions as rexc
 from ray_tpu._private import protocol
+from ray_tpu._private import tracing as _tracing
 from ray_tpu._private import worker as worker_mod
 from ray_tpu._private.config import apply_system_config
 from ray_tpu._private.node import InProcessNode, new_session_dir
@@ -57,6 +59,7 @@ def init(address: str | None = None, *, num_cpus=None, num_tpus=None,
     process (reference: ray.init(local_mode=True)) — no workers, no
     store; for debugging and runtime-free unit tests."""
     global _head_node
+    t_init = time.time()
     with _state_lock:
         if worker_mod.global_worker is not None and \
                 worker_mod.global_worker.connected:
@@ -118,6 +121,13 @@ def init(address: str | None = None, *, num_cpus=None, num_tpus=None,
         except Exception:
             pass  # usage stats must never block init
         atexit.register(shutdown)
+        # What precedes any deploy or job, once a driver: GCS, raylet
+        # and this worker up (a trace of its own).
+        _tracing.record(
+            "rt", "rt.init", t_init, time.time() - t_init,
+            trace={"trace_id": _tracing.fresh_id(),
+                   "span_id": _tracing.fresh_id(), "parent_id": None},
+            args={"head": address is None})
         return cw
 
 

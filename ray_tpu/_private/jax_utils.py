@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
 from ray_tpu._private import tracing
 from ray_tpu._private.resources import detect_tpu_chips
@@ -116,6 +117,33 @@ def bind_tpu_chips(ids) -> None:
                            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
                            "TPU_PROCESS_BOUNDS": "1,1,1"})
     _BOUND["ids"] = ids
+
+
+def open_backend() -> None:
+    """In a TPU worker (the one kind of process pinned to `tpu`: its
+    first jax call opens the lease's chips, whoever makes it), make that
+    call here, once, and leave a `jax.backend_init` span under the
+    worker's boot: LLMServer and the JaxTrainer's mesh builder come
+    here first, so the chip's opening has a name and a length instead
+    of hiding in a loader's first array.  Any other process is left
+    alone: its code may still configure jax before first use."""
+    if os.environ.get("JAX_PLATFORMS") != "tpu" or _BOUND.get("opened"):
+        return
+    import jax
+    t0 = time.time()
+    devices = jax.devices()
+    _BOUND["opened"] = True
+    tracing.start_record(
+        "jax", "jax.backend_init", t0, time.time(),
+        trace=tracing.start_link(),
+        args={"platform": devices[0].platform, "devices": len(devices)})
+
+
+def tree_nbytes(tree) -> int:
+    """Bytes the arrays of a pytree hold (params, a KV cache)."""
+    import jax
+    return int(sum(getattr(leaf, "nbytes", 0)
+                   for leaf in jax.tree_util.tree_leaves(tree)))
 
 
 def compile_cache_dir() -> str:

@@ -54,6 +54,9 @@ class WorkerHandle:
         self.actor_id = None
         self.registered = asyncio.Event()
         self.last_idle = time.monotonic()
+        # How the process came to be ("zygote" fork / "cold" start);
+        # a lease that finds the worker in the pool calls it "reused".
+        self.how = "cold"
 
 
 class _ContainerProcHandle:
@@ -772,6 +775,7 @@ class Raylet:
             # proc is attached asynchronously when the fork reply lands;
             # _wait_registered tolerates proc=None meanwhile.
             w = WorkerHandle(worker_id, None, kind=kind)
+            w.how = "zygote"
             self.workers[worker_id] = w
             asyncio.get_running_loop().create_task(
                 self._fork_worker(w, env, logfile))
@@ -934,6 +938,7 @@ class Raylet:
             w.pid = pid
         except Exception as e:
             logger.warning("zygote fork failed (%s); cold-starting", e)
+            w.how = "cold"
             if w.worker_id not in self.workers:
                 return  # already reaped
             os.makedirs(os.path.dirname(logfile), exist_ok=True)
@@ -975,6 +980,7 @@ class Raylet:
         while idle:
             w = idle.pop()
             if w.conn is not None and not w.conn.closed:
+                w.how = "reused"
                 return w
         if len(self.workers) >= cfg.max_workers_per_node:
             return None
@@ -992,6 +998,7 @@ class Raylet:
             if idle:
                 w = idle.pop()
                 if w.conn is not None and not w.conn.closed:
+                    w.how = "reused"
                     return w
             w = self._spawn_worker(kind, env_key=env_key,
                                    env_spec=env_spec)
@@ -1524,6 +1531,7 @@ class Raylet:
         from ray_tpu.runtime_env import env_spec as _env_spec
         from ray_tpu.runtime_env import worker_env_key
         espec = _env_spec(renv)
+        t_asked = time.time()
         w = await self._get_ready_worker(
             kind,
             env_key=self._local_env_key(worker_env_key(renv), espec),
@@ -1531,6 +1539,20 @@ class Raylet:
         if w is None:
             self._release(resources, pg_key)
             return {"ok": False, "reason": "no worker"}
+        # The wait for a worker, under the creation task's trace (once
+        # an actor): the worker gets the same two timestamps for its
+        # start's books and the span id to hang its own boot under.
+        trace = (body.get("spec") or {}).get("trace")
+        worker_start = {"how": w.how, "t0": t_asked, "t1": time.time(),
+                        "span_id": _tracing.fresh_id()}
+        if trace:
+            _tracing.record(
+                "raylet", "raylet.worker_start", t_asked,
+                worker_start["t1"] - t_asked,
+                trace={"trace_id": trace["trace_id"],
+                       "span_id": worker_start["span_id"],
+                       "parent_id": trace.get("parent_id")},
+                args={"how": w.how, "kind": kind})
         lease_id = os.urandom(8)
         lease = Lease(lease_id, w, resources, pg_key)
         self.leases[lease_id] = lease
@@ -1547,6 +1569,7 @@ class Raylet:
                 "spec": body["spec"],
                 "lease_id": lease_id,
                 "tpu_ids": tpu_ids,
+                "worker_start": worker_start,
             }, timeout=None)
         except Exception as e:
             await self._on_worker_dead(w, f"actor creation failed: {e}")

@@ -46,6 +46,35 @@ Span taxonomy (cat.name — see README "Observability"):
                     the engine's stats() counters, never in the ring)
   jax.compile       one per backend compile or cache load (jax_utils)
   data.*            streaming execute + shuffle exchange
+
+A start's spans, once a process or once a replica, one trace id a
+replica start ("A start's books" below; README "Observability"):
+  rt.init               driver: GCS, raylet and the driver's worker up
+  serve.start           driver: the controller (and proxy) asked for
+  serve.replica_start   controller, the root: the decision to start a
+                        replica -> the controller knows it is ready
+                        (deployment, replica_tag, ok)
+  raylet.worker_start   raylet: an actor's lease asked -> its worker
+                        registered (how = zygote / cold / reused, kind)
+  worker.boot           worker: the process's own start (its fork,
+                        under a zygote) -> ready for its first task
+                        (import_s); recorded with the first actor
+  jax.backend_init      worker: jax_utils.open_backend, the first
+                        jax.devices() (a TPU worker opens its chips)
+  task.create_actor     worker: the actor-creation task (class)
+  serve.replica_init    replica: RTServeReplica.__init__
+  serve.replica_unpickle  replica: the deployment's definition
+                        unpickled, so its modules imported
+  llm.load_model        replica: LLMServer's model_loader()
+                        (param_bytes)
+  engine.build          replica: GenerationEngine.__init__
+                        (cache_bytes)
+  engine.warm           engine thread: _warm_kernels (programs,
+                        after_ready_s), one child a program:
+                        engine.warm.tick / .verify / .chunk
+  train.worker_group_start  trainer: a gang's workers asked for ->
+                        the backend's on_start returned (the runtime's
+                        three spans link under it)
 """
 
 from __future__ import annotations
@@ -82,15 +111,23 @@ _PID = os.getpid()
 # so the record() hot path pays ONE identity check, not a module lookup
 # + probe per event.
 _LIVE_EXPORT = None
+# When this process began, where the process itself saw it: its fork
+# (a zygote's child), else None and process_start() asks the kernel.
+_T_FORK = None
+_T_IMPORT = time.time()
 
 
 def _reseed_id_base():
     """At-fork hook: zygote-forked workers must not mint the parent's
-    id stream (same rationale as ids._reseed_id_bases)."""
-    global _ID_BASE, _id_counter, _PID
+    id stream (same rationale as ids._reseed_id_bases), nor keep its
+    start time or its start's books."""
+    global _ID_BASE, _id_counter, _PID, _T_FORK, _BOOKS_LINK
     _ID_BASE = os.urandom(5).hex()
     _id_counter = itertools.count(1).__next__
     _PID = os.getpid()
+    _T_FORK = time.time()
+    _BOOKS.clear()
+    _BOOKS_LINK = None
 
 
 os.register_at_fork(after_in_child=_reseed_id_base)
@@ -346,13 +383,15 @@ class _SpanHandle:
 
 @contextmanager
 def span(cat: str, name: str, args: dict | None = None,
-         root: bool = False):
+         root: bool = False, _record=None):
     """Record a complete event covering the with-body, as a child of
     the active span (or a fresh root when none is active or
     ``root=True``).  The context is installed for the body, so nested
     spans / submitted tasks / plane RPCs link as children — including
     across processes.  Always manages context even when recording is
-    disabled (continuity is semantic, the ring is observability)."""
+    disabled (continuity is semantic, the ring is observability).
+    ``_record`` (start_span's) is called with (cat, name, t0, t1,
+    trace=, args=) in place of record(), enabled or not."""
     ctx = None if root else _TRACE.get()
     trace_id = fresh_id() if ctx is None else ctx[0]
     parent_id = None if ctx is None else ctx[1]
@@ -366,11 +405,191 @@ def span(cat: str, name: str, args: dict | None = None,
         yield h
     finally:
         _TRACE.reset(token)
-        if _ENABLED:
-            record(cat, name, t0, time.time() - t0,
-                   trace={"trace_id": trace_id, "span_id": span_id,
-                          "parent_id": parent_id},
-                   args=h.args or None)
+        if _record is not None or _ENABLED:
+            link = {"trace_id": trace_id, "span_id": span_id,
+                    "parent_id": parent_id}
+            t1 = time.time()
+            if _record is not None:
+                _record(cat, name, t0, t1, trace=link, args=h.args or None)
+            else:
+                record(cat, name, t0, t1 - t0, trace=link,
+                       args=h.args or None)
+
+
+# ------------------------------------------------------ a start's books
+
+# The spans of a start (taxonomy above) and the phase each one is in the
+# books.  A replica's ring is full within a benchmark window and drops
+# its oldest events first, a start's; so the (t0, t1) every such span
+# was made from is also kept here, a dozen entries a process, and
+# LLMServer.replica_info() hands them out as seconds under "start".
+_BOOK_PHASES = {"serve.replica_start": "root",
+                 "raylet.worker_start": "spawn",
+                 "worker.boot": "boot",
+                 "jax.backend_init": "backend_init",
+                 "serve.replica_init": "init",
+                 "serve.replica_unpickle": "unpickle",
+                 "llm.load_model": "load",
+                 "engine.build": "build",
+                 "engine.warm": "warm"}
+_BOOKS: dict = {}      # phase -> (t0, t1) epoch seconds; "trace_id", "how"
+_BOOKS_LINK = None     # (trace_id, worker.boot's span id) once linked
+
+
+def process_start() -> float:
+    """When this process began, on the epoch clock: its fork for a
+    zygote's child, else what the kernel says (to a clock tick), else
+    this module's import."""
+    if _T_FORK is not None:
+        return _T_FORK
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            age = float(f.read().split()[0]) \
+                - ticks / os.sysconf("SC_CLK_TCK")
+        t = time.time() - age
+        if 0.0 <= age and t <= _T_IMPORT:
+            return t
+    except (OSError, ValueError, IndexError):
+        pass
+    return _T_IMPORT
+
+
+def start_note(phase: str, value) -> None:
+    """Keep in this process's books what another process measured (the
+    raylet's lease, the controller's root: their (t0, t1)) or a fact
+    of the start ("trace_id", "how")."""
+    _BOOKS[phase] = value
+
+
+def start_ready() -> None:
+    """The boot that worker_main noted ends now, unless it has ended."""
+    boot = _BOOKS.get("boot")
+    if boot is not None and boot[1] is None:
+        _BOOKS["boot"] = (boot[0], time.time())
+
+
+def start_noted(phase: str):
+    """What the books hold for `phase`, or None."""
+    return _BOOKS.get(phase)
+
+
+def start_record(cat: str, name: str, t0: float, t1: float,
+                 trace: dict | None = None,
+                 args: dict | None = None) -> None:
+    """One span of a start from its two timestamps, and the same two
+    numbers in the books under the phase the name stands for: one
+    source for both, whether or not the ring records."""
+    phase = _BOOK_PHASES.get(name)
+    if phase is not None:
+        _BOOKS[phase] = (t0, t1)
+    record(cat, name, t0, t1 - t0, trace=trace, args=args)
+
+
+@contextmanager
+def start_span(cat: str, name: str, args: dict | None = None):
+    """span() for a phase of a start: made by start_record, so the
+    books hold its two timestamps whether or not the ring records; a
+    body that raises leaves its error on the span."""
+    with span(cat, name, args, _record=start_record) as h:
+        try:
+            yield h
+        except BaseException as e:
+            h.args["error"] = repr(e)[:200]
+            raise
+
+
+def start_begin(trace: dict | None, lease: dict | None) -> None:
+    """An actor's creation reached this worker: open its start's books
+    (whatever an earlier actor of a reused worker left goes, the boot
+    stays), note what the raylet measured (`lease`: how, t0, t1 and the
+    span id of its raylet.worker_start) and, once a process, record the
+    worker.boot span that worker_main could only note: under the span
+    that waited for this worker."""
+    global _BOOKS_LINK
+    start_ready()
+    for key in [k for k in _BOOKS if k not in ("boot", "import_s")]:
+        del _BOOKS[key]
+    if not trace:
+        return
+    _BOOKS["trace_id"] = trace["trace_id"]
+    lease = lease or {}
+    if "t0" in lease:
+        _BOOKS["how"] = lease.get("how")
+        _BOOKS["spawn"] = (lease["t0"], lease["t1"])
+    boot = _BOOKS.get("boot")
+    if _BOOKS_LINK is not None or boot is None:
+        return
+    span_id = fresh_id()
+    _BOOKS_LINK = (trace["trace_id"], span_id)
+    start_record("worker", "worker.boot", boot[0], boot[1],
+                 trace={"trace_id": trace["trace_id"], "span_id": span_id,
+                        "parent_id": lease.get("span_id",
+                                               trace.get("parent_id"))},
+                 args={"import_s": round(_BOOKS.get("import_s", 0.0), 6)})
+
+
+def start_link() -> dict | None:
+    """Linkage for a span of this process's start that no with-block
+    made (jax.backend_init): a child of the active span where that is
+    of the start's own trace (an LLMServer opens its chip inside
+    llm.load_model), else of worker.boot (a train worker opens it in a
+    later task of another trace)."""
+    ctx = _TRACE.get()
+    if ctx is not None and (_BOOKS_LINK is None
+                            or ctx[0] == _BOOKS_LINK[0]):
+        return child_span()
+    if _BOOKS_LINK is None:
+        return None
+    return {"trace_id": _BOOKS_LINK[0], "span_id": fresh_id(),
+            "parent_id": _BOOKS_LINK[1]}
+
+
+def start_seconds(iv: dict) -> dict:
+    """A start's phases in seconds from their (t0, t1).  `spawn`, `boot`,
+    `unpickle`, `load` and `build` do not overlap and lie inside the
+    root, so with `unaccounted` (the root's length less those five: its
+    self time) they add up to the root: `boot` is the part of
+    worker.boot inside raylet.worker_start (all of a fresh worker's;
+    none of a reused one's, which booted before the lease was asked),
+    `spawn` the rest of raylet.worker_start.  `warm` runs on the
+    engine's thread beside the root's tail and past it:
+    `warm_after_ready` is how long after serve.replica_init ended it
+    ended; `backend_init` is the chip's
+    opening, part of whichever phase made the process's first jax call
+    (`load` for an LLMServer).  A phase not (yet) known reads None."""
+    def _len(key):
+        v = iv.get(key)
+        return None if v is None or v[1] is None else v[1] - v[0]
+
+    out = dict.fromkeys(("spawn", "boot", "unpickle", "load", "build",
+                         "warm", "warm_after_ready", "unaccounted",
+                         "backend_init"))
+    for key in ("boot", "unpickle", "load", "build", "warm",
+                "backend_init"):
+        out[key] = _len(key)
+    spawn, boot = iv.get("spawn"), iv.get("boot")
+    if spawn is not None:
+        out["boot"] = 0.0 if boot is None else max(
+            0.0, min(boot[1], spawn[1]) - max(boot[0], spawn[0]))
+        out["spawn"] = spawn[1] - spawn[0] - out["boot"]
+    if iv.get("warm") is not None and iv.get("init") is not None:
+        out["warm_after_ready"] = max(0.0, iv["warm"][1] - iv["init"][1])
+    if iv.get("root") is not None:
+        out["root"] = _len("root")
+        out["unaccounted"] = out["root"] - sum(
+            out[k] or 0.0
+            for k in ("spawn", "boot", "unpickle", "load", "build"))
+    return {k: v if v is None else round(v, 6) for k, v in out.items()}
+
+
+def start_books() -> dict:
+    """This process's start as LLMServer.replica_info() returns it:
+    the trace id, how the worker came to be, and start_seconds of the
+    timestamps kept here."""
+    return {"trace_id": _BOOKS.get("trace_id"), "how": _BOOKS.get("how"),
+            **start_seconds(_BOOKS)}
 
 
 def _maybe_export(ev: dict) -> None:
@@ -488,7 +707,8 @@ def assemble(events: list, trace_id: str) -> dict:
             continue
         a = e.get("args") or {}
         s = {"name": e.get("name"), "cat": e.get("cat"),
-             "pid": e.get("pid"), "ts": e.get("ts", 0.0),
+             "pid": e.get("pid"), "tid": e.get("tid"),
+             "ts": e.get("ts", 0.0),
              "dur": e.get("dur", 0.0),
              "span_id": a.get("span_id"),
              "parent_id": a.get("parent_id"),
@@ -499,6 +719,7 @@ def assemble(events: list, trace_id: str) -> dict:
         spans.append(s)
         if s["span_id"]:
             by_id[s["span_id"]] = s
+    spans.extend(_adopt_compiles(events, spans))
     roots = []
     for s in spans:
         parent = by_id.get(s["parent_id"]) if s["parent_id"] else None
@@ -522,6 +743,33 @@ def assemble(events: list, trace_id: str) -> dict:
             "processes": sorted({s["pid"] for s in spans}),
             "annotations": notes,
             "breakdown": _breakdown(spans)}
+
+
+def _adopt_compiles(events: list, spans: list) -> list:
+    """`jax.compile` events carry no trace (jax's listener has no
+    context): each one that lies inside a span of this trace, by time
+    and on the span's own thread, joins it as a child of the shortest
+    such span, so a start's tree says which program was compiled, or
+    loaded from the persistent cache, inside which phase."""
+    out = []
+    for e in events:
+        a = e.get("args") or {}
+        if e.get("name") != "jax.compile" or a.get("trace_id"):
+            continue
+        t0, t1 = e.get("ts", 0.0), e.get("ts", 0.0) + e.get("dur", 0.0)
+        inside = [s for s in spans
+                  if s["pid"] == e.get("pid") and s["tid"] == e.get("tid")
+                  and s["span_id"] and s["ts"] <= t0
+                  and t1 <= s["ts"] + s["dur"]]
+        if inside:
+            parent = min(inside, key=lambda s: s["dur"])
+            out.append({"name": "jax.compile", "cat": e.get("cat"),
+                        "pid": e.get("pid"), "tid": e.get("tid"),
+                        "ts": t0, "dur": e.get("dur", 0.0),
+                        "span_id": None,
+                        "parent_id": parent["span_id"],
+                        "args": dict(a), "children": []})
+    return out
 
 
 def _breakdown(spans: list) -> dict:
@@ -562,6 +810,25 @@ def _breakdown(spans: list) -> dict:
                        "ttft_ms": round(q + p + f, 3)}
         if rid is not None:
             out["ttft"]["request_id"] = rid
+    # A replica's start (the root is the controller's span): the
+    # phases' seconds as the replica's own books have them
+    # (start_seconds), and what was compiled or loaded inside which.
+    if any(s["name"] == "serve.replica_start" for s in spans):
+        iv, by_id = {}, {}
+        for s in spans:
+            by_id[s["span_id"]] = s
+            phase = _BOOK_PHASES.get(s["name"])
+            if phase is not None and phase not in iv:
+                iv[phase] = (s["ts"] / 1e6, (s["ts"] + s["dur"]) / 1e6)
+        out["start"] = {
+            k + "_s": v for k, v in start_seconds(iv).items()}
+        out["start"]["compiles"] = [
+            {"fun_name": s["args"].get("fun_name"),
+             "from_cache": bool(s["args"].get("from_cache")),
+             "s": round(s["dur"] / 1e6, 6),
+             "inside": by_id.get(s["parent_id"], {}).get("name")}
+            for s in sorted(spans, key=lambda s: s["ts"])
+            if s["name"] == "jax.compile"]
     return out
 
 
@@ -598,6 +865,23 @@ def format_trace(tree: dict) -> str:
         lines.append(f"  TTFT {t['ttft_ms']}ms = queue {t['queue_ms']}ms"
                      f" + prefill {t['prefill_ms']}ms + first tick "
                      f"{t['first_tick_ms']}ms")
+    if bd.get("start"):
+        t = bd["start"]
+
+        def _s(key):
+            v = t.get(key + "_s")
+            return "?" if v is None else f"{v:.3f}s"
+        lines.append(
+            f"  start {_s('root')} = spawn {_s('spawn')} + boot "
+            f"{_s('boot')} + unpickle {_s('unpickle')} + load "
+            f"{_s('load')} + build {_s('build')} + unaccounted "
+            f"{_s('unaccounted')}; warm {_s('warm')}, ending "
+            f"{_s('warm_after_ready')} after ready; the chip's opening "
+            f"{_s('backend_init')}")
+        for c in t["compiles"]:
+            lines.append(
+                f"    {'loaded  ' if c['from_cache'] else 'compiled'} "
+                f"{c['s']:.3f}s  {c['fun_name']}  ({c['inside']})")
     lines.append("  stages:")
     for name, agg in bd["stages"].items():
         lines.append(f"    {name}: n={agg['count']} "

@@ -2210,13 +2210,34 @@ class CoreWorker:
         self._actor_tpu_ids = list(body.get("tpu_ids") or [])
         try:
             result = await self.loop.run_in_executor(
-                self._task_pool, self._create_actor_sync, spec)
+                self._task_pool, self._create_actor_sync, spec,
+                body.get("worker_start"))
             return result
         except Exception as e:
             return {"ok": False, "error": repr(e),
                     "error_blob": _error_blob(e, traceback.format_exc())}
 
-    def _create_actor_sync(self, spec):
+    def _create_actor_sync(self, spec, worker_start=None):
+        # The creation task runs under its submitter's trace like any
+        # task, so what the constructor records (a serve replica's
+        # start) links under it; `worker_start` is what the raylet
+        # measured while this lease waited for a worker.
+        t0 = time.time()
+        trace = spec.get("trace")
+        _tracing.start_begin(trace, worker_start)
+        outer = _TRACE.get()
+        span = self._enter_span(trace)
+        try:
+            return self._construct_actor(spec)
+        finally:
+            _TRACE.set(outer)
+            if span is not None:
+                _tracing.record(
+                    "task", "task.create_actor", t0, time.time() - t0,
+                    trace=span,
+                    args={"class": spec.get("class_name", "")})
+
+    def _construct_actor(self, spec):
         try:
             if self._actor_tpu_ids:
                 bind_tpu_chips(self._actor_tpu_ids)
@@ -2973,6 +2994,12 @@ class CoreWorker:
             bundle_index=(opts.get("placement_group_bundle_index")
                           if pg is not None else None),
         )
+        # The creation task carries its submitter's trace as any task
+        # does: the raylet that waits for a worker and the worker that
+        # runs the constructor record under it.
+        spec["trace"] = _trace_for_submit()
+        if "flow" in spec["trace"]:
+            _tracing.flow_start(spec["trace"]["flow"])
         reply = self._run(self._gcs_request("create_actor", {
             "actor_id": actor_id, "spec": spec, "job_id": self.job_id}))
         if not reply.get("ok"):

@@ -9,9 +9,12 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import time
 
 
 async def _amain():
+    from ray_tpu._private import tracing
+    t_proc = tracing.process_start()
     if os.environ.get("JAX_PLATFORMS") == "tpu":
         # A TPU worker (raylet._worker_env_for) is the one kind of
         # process that compiles for the chip.
@@ -21,6 +24,7 @@ async def _amain():
     from ray_tpu._private.ids import WorkerID
     from ray_tpu._private.worker import CoreWorker, MODE_WORKER
 
+    t_imported = time.time()
     gcs_addr = (os.environ["RT_GCS_HOST"], int(os.environ["RT_GCS_PORT"]))
     raylet_addr = (os.environ["RT_RAYLET_HOST"],
                    int(os.environ["RT_RAYLET_PORT"]))
@@ -38,7 +42,14 @@ async def _amain():
         host=host,
     )
     worker_mod.global_worker = cw
+    # The boot, for the books: it ends when this worker is ready for
+    # its first task (or when that task comes, if it comes first), and
+    # its span (worker.boot) is made when the first actor brings a trace
+    # to link it into (tracing.start_begin).
+    tracing.start_note("boot", (t_proc, None))
+    tracing.start_note("import_s", t_imported - t_proc)
     await cw.start_worker_async()
+    tracing.start_ready()
     await asyncio.Event().wait()
 
 

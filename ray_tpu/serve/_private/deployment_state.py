@@ -49,6 +49,10 @@ class ReplicaWrapper:
         self._actor = None
         self._ready_ref = None
         self._drain_ref = None
+        # The start's root span, serve.replica_start: (trace id, span
+        # id) and when the decision to start was taken.
+        self._start_ctx = (_tracing.fresh_id(), _tracing.fresh_id())
+        self._start_t0 = 0.0
 
     def start(self):
         from ray_tpu.serve._private.replica import RTServeReplica
@@ -58,14 +62,24 @@ class ReplicaWrapper:
                         f"SERVE_REPLICA::{self.replica_tag}")
         opts.setdefault("max_concurrency", 1000)
         cls = ray_tpu.remote(RTServeReplica)
-        self._actor = cls.options(**opts).remote(
-            self.deployment_name, self.replica_tag,
-            self._replica_config.deployment_def,
-            self._replica_config.init_args,
-            self._replica_config.init_kwargs,
-            self._config.user_config, self.version)
-        # Readiness probe: resolves when __init__ + reconfigure finished.
-        self._ready_ref = self._actor.get_metadata.remote()
+        # One trace a start: the creation task and the readiness probe
+        # are submitted under the root's context, so the raylet's wait
+        # for a worker, the worker's boot and everything the
+        # constructor records link under it.
+        self._start_t0 = time.time()
+        token = _tracing.set_current(*self._start_ctx)
+        try:
+            self._actor = cls.options(**opts).remote(
+                self.deployment_name, self.replica_tag,
+                self._replica_config.deployment_def,
+                self._replica_config.init_args,
+                self._replica_config.init_kwargs,
+                self._config.user_config, self.version)
+            # Readiness probe: resolves when __init__ + reconfigure
+            # finished (an engine's warm-up may still be running).
+            self._ready_ref = self._actor.get_metadata.remote()
+        finally:
+            _tracing.reset_current(token)
 
     def check_ready(self) -> Optional[bool]:
         """None = still starting, True = ready, False = failed."""
@@ -75,11 +89,34 @@ class ReplicaWrapper:
         try:
             ray_tpu.get(self._ready_ref, timeout=1)
             self.state = RUNNING
+            self._end_start(True)
             return True
         except Exception as e:
             logger.warning("replica %s failed to start: %s",
                            self.replica_tag, e)
+            self._end_start(False)
             return False
+
+    def _end_start(self, ok: bool):
+        """Close the root span where the controller learns how the
+        start ended, say its id beside the replica's tag (`rt trace
+        <id>` prints the start), and hand the root's two timestamps to
+        the replica for its start's books."""
+        t1 = time.time()
+        trace_id, span_id = self._start_ctx
+        _tracing.record(
+            "serve", "serve.replica_start", self._start_t0,
+            t1 - self._start_t0,
+            trace={"trace_id": trace_id, "span_id": span_id,
+                   "parent_id": None},
+            args={"deployment": self.deployment_name,
+                  "replica_tag": self.replica_tag, "ok": ok})
+        logger.info("replica %s %s after %.2fs (start trace %s)",
+                    self.replica_tag, "ready" if ok else "failed",
+                    t1 - self._start_t0, trace_id)
+        if ok:
+            self._actor.start_acknowledged.options(num_returns=0).remote(
+                self._start_t0, t1)
 
     def reconfigure(self, user_config, version: str):
         self.version = version

@@ -52,6 +52,12 @@ class RTServeReplica:
     def __init__(self, deployment_name: str, replica_tag: str,
                  serialized_def: bytes, init_args: tuple,
                  init_kwargs: dict, user_config: Any, version: str):
+        with _tracing.start_span("serve", "serve.replica_init"):
+            self._init(deployment_name, replica_tag, serialized_def,
+                       init_args, init_kwargs, user_config, version)
+
+    def _init(self, deployment_name, replica_tag, serialized_def,
+              init_args, init_kwargs, user_config, version):
         self.deployment_name = deployment_name
         self.replica_tag = replica_tag
         self.version = version
@@ -66,7 +72,11 @@ class RTServeReplica:
         from concurrent.futures import ThreadPoolExecutor
         self._sync_pool = ThreadPoolExecutor(
             max_workers=8, thread_name_prefix=f"replica-{replica_tag}")
-        body = cloudpickle.loads(serialized_def)
+        # Unpickling the definition imports its modules (for an
+        # LLMServer the engine, the models and what they import of
+        # jax): seconds of a start that are nobody's else.
+        with _tracing.start_span("serve", "serve.replica_unpickle"):
+            body = cloudpickle.loads(serialized_def)
         # Publish the replica context BEFORE user __init__ runs, so the
         # constructor itself can call serve.get_replica_context()
         # (reference: replica.py sets it in create_replica_wrapper).
@@ -81,6 +91,11 @@ class RTServeReplica:
             deployment_name, replica_tag, servable_object=self.callable)
         if user_config is not None:
             self._reconfigure_sync(user_config)
+
+    def start_acknowledged(self, t0: float, t1: float):
+        """The controller's word that it knows this replica is ready:
+        the root span's two timestamps, for the start's books."""
+        _tracing.start_note("root", (t0, t1))
 
     def _reconfigure_sync(self, user_config):
         rc = getattr(self.callable, "reconfigure", None)

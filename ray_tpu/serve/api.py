@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, List, Optional, Union
 import cloudpickle
 
 import ray_tpu
+from ray_tpu._private import tracing as _tracing
 from ray_tpu.serve.config import (AutoscalingConfig, DeploymentConfig,
                                   ReplicaConfig)
 from ray_tpu.serve.handle import DeploymentHandle
@@ -32,9 +33,14 @@ def start(detached: bool = True, http_options: Optional[Dict] = None,
           _start_proxy: bool = False):
     """Start (or connect to) the Serve instance: the controller actor and,
     optionally, the HTTP proxy."""
-    controller = _get_or_create_controller()
-    if _start_proxy:
-        _ensure_http_proxy(controller, http_options or {})
+    # Once a driver; the controller's (and a proxy's) own start — the
+    # raylet's wait for a worker, the worker's boot, the creation task —
+    # links under it, and may outlast it: the controller is asked for
+    # here, not waited for.
+    with _tracing.span("serve", "serve.start"):
+        controller = _get_or_create_controller()
+        if _start_proxy:
+            _ensure_http_proxy(controller, http_options or {})
     return controller
 
 

@@ -22,8 +22,10 @@ import json
 from typing import Any, Dict, List, Optional, Sequence
 
 import ray_tpu
+from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import GLOBAL_CONFIG as _cfg
-from ray_tpu._private.jax_utils import device_facts
+from ray_tpu._private.jax_utils import (device_facts, open_backend,
+                                        tree_nbytes)
 from ray_tpu.serve.exceptions import resumable
 from ray_tpu.serve.llm import kv_transfer
 from ray_tpu.serve.llm.engine import GenerationEngine
@@ -53,7 +55,12 @@ class LLMServer:
 
     def __init__(self, model_loader, engine_config: Optional[Dict] = None,
                  default_generation: Optional[Dict] = None):
-        params, cfg = model_loader()
+        with _tracing.start_span("llm", "llm.load_model") as span:
+            # The chip is opened here, by name (a jax.backend_init
+            # span), not somewhere inside the loader's first jax call.
+            open_backend()
+            params, cfg = model_loader()
+            span.args["param_bytes"] = tree_nbytes(params)
         self._defaults = dict(default_generation or {})
         self.engine = GenerationEngine(params, cfg,
                                        **(engine_config or {}))
@@ -133,12 +140,16 @@ class LLMServer:
         """Which process and device answered, and how much it has
         served: jax's own report of the replica's device
         (jax_utils.device_facts — platform, kind, count, peak bytes,
-        pid, open chip device files), its leased chip ids, and the
-        engine's completed-request count.  A caller that must stay off
-        jax itself (a driver next to TPU workers) checks here that the
-        replica really is on the chip."""
+        pid, open chip device files), its leased chip ids, the engine's
+        completed-request count, and under "start" where this replica's
+        start went: its trace id (`rt trace <id>`) and each phase's
+        seconds (tracing.start_seconds; a phase still running, as the
+        warm-up is right after HEALTHY, reads None).  A caller that
+        must stay off jax itself (a driver next to TPU workers) checks
+        here that the replica really is on the chip."""
         return {**device_facts(), "tpu_ids": ray_tpu.get_tpu_ids(),
-                "completed": self.engine.stats().requests_completed}
+                "completed": self.engine.stats().requests_completed,
+                "start": _tracing.start_books()}
 
     def autoscale_metrics(self) -> Dict[str, Any]:
         """Saturation gauges for the serve controller's autoscaler

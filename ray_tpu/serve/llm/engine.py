@@ -617,6 +617,11 @@ class GenerationEngine:
                  kv_commit_factor: float = 4.0,
                  kv_tiering: Optional[bool] = None,
                  kv_store_dir: Optional[str] = None):
+        # A start's spans (engine.build here, engine.warm on the worker
+        # thread, which has no contextvar of its own) link under
+        # whoever constructs the engine, as a request's do.
+        t_build = time.time()
+        self._start_trace = _tracing.current_dict()
         if num_slots < 1:
             raise ValueError("num_slots must be >= 1")
         if prefill_chunk is None:
@@ -802,6 +807,19 @@ class GenerationEngine:
         OCCUPANCY_GAUGE.set(0.0, tags=self._tags)
         KV_BLOCKS_TOTAL_GAUGE.set(self.kv_pages, tags=self._tags)
         KV_BLOCKS_FREE_GAUGE.set(self.kv_pages, tags=self._tags)
+        _tracing.start_record(
+            "engine", "engine.build", t_build, time.time(),
+            trace=self._start_link(),
+            args={"cache_bytes": _jax_utils.tree_nbytes(self._cache)})
+
+    def _start_link(self, parent_id: Optional[str] = None):
+        """Linkage of one span of this engine's start: a child of the
+        constructor's caller, or of `parent_id`."""
+        tr = self._start_trace
+        if tr is None:
+            return None
+        return {"trace_id": tr["trace_id"], "span_id": _tracing.fresh_id(),
+                "parent_id": parent_id or tr.get("parent_id")}
 
     # ------------------------------------------------------------------
     # Public API
@@ -1751,25 +1769,51 @@ class GenerationEngine:
         request never pays XLA compilation of the decode tick, nor does
         the first DRAFT pay the verify kernel's (it would otherwise land
         mid-generation, a latency spike the bench used to misreport as
-        speculation overhead)."""
+        speculation overhead).
+
+        Leaves the start's last spans: engine.warm, and one child a
+        program with the seconds its call took (trace, compile or load
+        from the persistent cache, dispatch)."""
+        programs = []   # (span name, t0, t1)
+        t_warm = time.time()
         tok = jnp.zeros((self.num_slots,), jnp.int32)
         pos = jnp.zeros((self.num_slots,), jnp.int32)
         bt = jnp.asarray(self._block_tables)
+        t0 = time.time()
         _, _, self._cache = _paged_tick(
             self.params, tok, pos, self._cache, bt, self.cfg,
             with_logits=False)
+        programs.append(("engine.warm.tick", t0, time.time()))
         if self.speculate_k:
             chunk = jnp.zeros((self.num_slots, 1 + self.speculate_k),
                               jnp.int32)
+            t0 = time.time()
             _, _, self._cache = _paged_verify(
                 self.params, chunk, pos, self._cache, bt, self.cfg,
                 with_logits=False)
+            programs.append(("engine.warm.verify", t0, time.time()))
         # ...and the standard-width prefill chunk (row 0's table is all
         # trash while nothing is admitted).
+        t0 = time.time()
         _, self._cache = _prefill_chunk(
             self.params, jnp.zeros((1, self.prefill_chunk), jnp.int32),
             jnp.int32(0), self._cache, bt[:1], self.cfg,
             **self._row_args(0, 0))
+        programs.append(("engine.warm.chunk", t0, time.time()))
+        t1 = time.time()
+        link = self._start_link()
+        args = {"programs": len(programs)}
+        ready = _tracing.start_noted("init")
+        if ready is not None:
+            # Readiness does not wait for the warm-up: how long after
+            # the replica's constructor returned did it end?
+            args["after_ready_s"] = round(max(0.0, t1 - ready[1]), 6)
+        _tracing.start_record("engine", "engine.warm", t_warm, t1,
+                              trace=link, args=args)
+        for name, p0, p1 in programs:
+            _tracing.record(
+                "engine", name, p0, p1 - p0,
+                trace=link and self._start_link(link["span_id"]))
 
     def _row_args(self, slot: int, valid: int) -> Dict:
         """What a prefill chunk takes beside the dense arguments when the

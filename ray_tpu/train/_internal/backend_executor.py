@@ -23,6 +23,7 @@ import time
 from typing import Callable, List, Optional, Tuple
 
 import ray_tpu
+from ray_tpu._private import tracing as _tracing
 from ray_tpu._private.config import GLOBAL_CONFIG as cfg
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import ScalingConfig
@@ -187,6 +188,15 @@ class BackendExecutor:
         self._joiners = []
         self._resize_target = None
         self._want_override = None
+        # One root a gang's start: every worker's creation task is
+        # submitted under it, so the raylet's wait for each worker
+        # (raylet.worker_start), the worker's boot and its chip's
+        # opening (jax.backend_init) link here.
+        with _tracing.span("train", "train.worker_group_start",
+                           args={"workers": sc.num_workers}, root=True):
+            self._start_gang(sc)
+
+    def _start_gang(self, sc):
         self.worker_group = WorkerGroup(
             sc.num_workers, sc._resources, self._placement_group)
         # A gang-wide host collective group for data-parallel gradient

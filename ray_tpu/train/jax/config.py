@@ -84,12 +84,14 @@ class JaxBackend(Backend):
         virtual = cfg.virtual_cpu_devices if cfg else 0
 
         def _build():
-            from ray_tpu._private.jax_utils import cpu_mesh_devices
+            from ray_tpu._private.jax_utils import (cpu_mesh_devices,
+                                                    open_backend)
             from ray_tpu.parallel.mesh import make_mesh
             import jax
             if virtual:
                 devices = cpu_mesh_devices(virtual)
             else:
+                open_backend()   # a TPU worker's jax.backend_init span
                 devices = jax.devices()
             if sc is None:
                 return None
