@@ -151,20 +151,59 @@ RESURRECTIONS_COUNTER = _metrics.Counter(
     tag_keys=("engine",))
 
 
+def _set_all(setters):
+    for set_event in setters:
+        set_event()
+
+
+def _fire_wakeups(wakeups) -> int:
+    """Wake the readers whose `(loop, set_event)` wake-ups were taken
+    from their streams: ONE `call_soon_threadsafe` into each distinct
+    event loop, whose callback sets every event of that loop, and the
+    sync readers' events (loop None) set directly.  Returns the calls
+    made.  A consumer that abandoned its wait and closed its loop
+    loses its own wake-ups, which are moot, and must neither keep
+    another loop's from being made nor poison the calling thread."""
+    calls = 0
+    by_loop: Dict[Any, List] = {}
+    for loop, set_event in wakeups:
+        if loop is None:
+            set_event()
+            calls += 1
+        else:
+            by_loop.setdefault(loop, []).append(set_event)
+    for loop, setters in by_loop.items():
+        try:
+            loop.call_soon_threadsafe(_set_all, setters)
+        except RuntimeError:
+            continue
+        calls += 1
+    return calls
+
+
 class TokenStream:
     """Per-request stream of generated token ids.
 
     Producer is the engine's worker thread; consumers may be sync
     (`for tok in stream`, `stream.result()`) or async
     (`async for tok in stream`, `await stream.collect()`) on any event
-    loop — waiters are woken through loop.call_soon_threadsafe, so no
-    consumer loop ever blocks on the device."""
+    loop.  A reader that finds nothing buffered registers a wake-up,
+    `(its loop, event.set)`, and waits.  The engine's worker thread
+    does not make that call row by row: `_push` / `_finish` hand the
+    taken wake-ups to the engine's batch, and when every row of the
+    emit phase has been advanced the engine makes ONE
+    `loop.call_soon_threadsafe` per distinct loop for all of them
+    (`_fire_wakeups`, from `GenerationEngine._flush_emits`): before it
+    leaves the phase, so a token waits for no dispatch and no device
+    result, and no consumer loop ever blocks on the device.  Any other
+    caller (no batch) fires at once."""
 
     def __init__(self, request_id: str):
         self.request_id = request_id
         self._buf: collections.deque = collections.deque()
         self._lock = locksan.make_lock("TokenStream._lock")
-        self._wakeups: List = []   # zero-arg callables, fired once each
+        # (loop or None, zero-arg event setter), fired once each
+        self._wakeups: List = []
         self._done = False
         self._error: Optional[BaseException] = None
         self._cancel = threading.Event()
@@ -172,29 +211,30 @@ class TokenStream:
 
     # -- producer side (engine worker thread) --
 
-    def _push(self, token: int):
+    def _push(self, token: int, batch: Optional[List] = None):
         with self._lock:
             self._buf.append(token)
             wakeups, self._wakeups = self._wakeups, []
-        self._fire(wakeups)
+        self._hand(wakeups, batch)
 
-    def _finish(self, error: Optional[BaseException] = None):
+    def _finish(self, error: Optional[BaseException] = None,
+                batch: Optional[List] = None):
         with self._lock:
             self._done = True
             self._error = error
             wakeups, self._wakeups = self._wakeups, []
-        self._fire(wakeups)
+        self._hand(wakeups, batch)
 
     @staticmethod
-    def _fire(wakeups):
-        for w in wakeups:
-            try:
-                w()
-            except RuntimeError:
-                # A consumer abandoned its wait and closed its event
-                # loop; its wakeup is moot and must not poison the
-                # engine's worker thread.
-                pass
+    def _hand(wakeups, batch):
+        """The taken wake-ups join the caller's batch, which it fires
+        when its rows are all advanced; with no batch they fire now."""
+        if not wakeups:
+            return
+        if batch is None:
+            _fire_wakeups(wakeups)
+        else:
+            batch.extend(wakeups)
 
     # -- consumer side --
 
@@ -227,8 +267,7 @@ class TokenStream:
         while True:
             loop = asyncio.get_running_loop()
             ev = asyncio.Event()
-            kind, val = self._pop_or_register(
-                lambda: loop.call_soon_threadsafe(ev.set))
+            kind, val = self._pop_or_register((loop, ev.set))
             if kind == "tok":
                 return val
             if kind == "end":
@@ -243,7 +282,7 @@ class TokenStream:
     def __next__(self):
         while True:
             ev = threading.Event()
-            kind, val = self._pop_or_register(ev.set)
+            kind, val = self._pop_or_register((None, ev.set))
             if kind == "tok":
                 return val
             if kind == "end":
@@ -264,7 +303,7 @@ class TokenStream:
         out = self._partial  # resume whatever an earlier timeout drained
         while True:
             ev = threading.Event()
-            kind, val = self._pop_or_register(ev.set)
+            kind, val = self._pop_or_register((None, ev.set))
             if kind == "tok":
                 out.append(val)
             elif kind == "end":
@@ -342,6 +381,11 @@ class EngineStats:
     token_gaps: int = 0               # tokens out that are no request's first
     token_gaps_stalled: int = 0       # ...out of a turn that swept pages,
     #                                   ran a command or compiled
+    # Calls a flush made to wake readers: one into each event loop with
+    # a waiting reader + one a waiting sync reader.  `tokens_generated`
+    # over it is the rows one wake carries (~ the rows of a full batch
+    # streaming to one loop; 1 with a single reader).
+    stream_wakes: int = 0
     kv_sweeps: int = 0                # sweeps/demotions that moved >= 1 page
     kv_sweep_s: float = 0.0           # ...and the loop time they took
     # The host half of a demotion runs on the lander thread
@@ -768,6 +812,13 @@ class GenerationEngine:
         self._spec_accepted = 0
         self._win_t = time.monotonic()
         self._win_tokens = 0
+        # What an emit phase leaves for its one flush (_flush_emits;
+        # worker thread only): the wake-ups its pushes and finishes took
+        # from their streams, and the books of its tokens.
+        self._wakes: List = []
+        self._stream_wakes = 0
+        self._emit_tokens = 0
+        self._emit_gaps: List[float] = []
         # Recent per-request TTFT samples (bounded ring, worker thread
         # appends) backing the ttft_p99_s gauge in load_info — the SLO
         # attainment signal the autopilot broker arbitrates on.
@@ -803,6 +854,10 @@ class GenerationEngine:
         _jax_utils.install_compile_listener()
 
         self._tags = {"engine": name}
+        self._ttft_hist = TTFT_HISTOGRAM.series(self._tags)
+        self._itl_hist = ITL_HISTOGRAM.series(self._tags)
+        self._tokens_counter = TOKENS_COUNTER.series(self._tags)
+        self._throughput_gauge = THROUGHPUT_GAUGE.series(self._tags)
         QUEUE_GAUGE.set(0, tags=self._tags)
         OCCUPANCY_GAUGE.set(0.0, tags=self._tags)
         KV_BLOCKS_TOTAL_GAUGE.set(self.kv_pages, tags=self._tags)
@@ -857,9 +912,20 @@ class GenerationEngine:
         for _fn, fut in commands:
             if not fut.done():
                 fut.set_exception(RuntimeError("engine stopped"))
+        # This thread's own batch, fired once and left out of
+        # `stream_wakes`: a worker that outlived the join still owns the
+        # engine's batch and its counters.
+        wakes: List = []
         for req in leftovers:
-            req.stream._finish(err)
-        if t is not None and t.is_alive():
+            req.stream._finish(err, wakes)
+        wedged = t is not None and t.is_alive()
+        if not wedged:
+            for s, req in enumerate(self._slots):
+                if req is not None:
+                    req.stream._finish(err, wakes)
+                    self._slots[s] = None
+        _fire_wakeups(wakes)
+        if wedged:
             # join() timed out: the worker is wedged mid-tick and still
             # OWNS the slot table, cache, and paging state.  Mutating
             # them from here would race a live thread (found by
@@ -870,10 +936,6 @@ class GenerationEngine:
                 "slot/paging state for it to tear down", self.name,
                 timeout)
             return
-        for s, req in enumerate(self._slots):
-            if req is not None:
-                req.stream._finish(err)
-                self._slots[s] = None
         # Pages still landing reach their tier (a store page outlives
         # the engine) before the arena under the lander is closed.
         lander, self._lander = self._lander, None
@@ -1663,6 +1725,7 @@ class GenerationEngine:
             loop_turns_with_chunk=self._turns_with_chunk,
             token_gaps=self._token_gaps,
             token_gaps_stalled=self._token_gaps_stalled,
+            stream_wakes=self._stream_wakes,
             kv_sweeps=self._kv_sweeps,
             kv_sweep_s=round(self._kv_sweep_s, 6),
             kv_pages_landed=self._pages_landed,
@@ -1975,6 +2038,7 @@ class GenerationEngine:
                     req = self._scheduler.next_request()
                     QUEUE_GAUGE.set(self._scheduler.depth,
                                     tags=self._tags)
+            self._flush_emits()   # the cancelled wait for no dispatch
             if req is None:
                 return
             reserved = self._try_reserve(req)
@@ -2012,6 +2076,7 @@ class GenerationEngine:
                 self._prefill = None
             self._release_pages(req)
             self._finish_request(req, "cancelled")
+            self._flush_emits()
             return
         L = len(req.prompt)
         start = st.next_start
@@ -2060,7 +2125,18 @@ class GenerationEngine:
         self._phase("device_wait")
         row = np.asarray(logits[0, len(real) - 1])
         self._phase("emit")
-        first = self._sample_host(row, req)
+        try:
+            self._emit_first(st, req, self._sample_host(row, req), t_fc)
+        finally:
+            # The first token's reader is woken HERE, before this turn's
+            # tick is dispatched: it waits for no device work.
+            self._flush_emits()
+
+    def _emit_first(self, st: _PrefillState, req: _Request, first: int,
+                    t_fc: float):
+        """A finished prefill's first token: out to its stream, and the
+        request into the decode batch unless it ends here."""
+        L = len(req.prompt)
         now = time.monotonic()
         # TTFT stage 3 of 3 — first tick: forcing the prefill logits
         # off-device + sampling the first token.  queue + prefill +
@@ -2133,17 +2209,20 @@ class GenerationEngine:
         logits_np, row_of = self._ship_sample_logits(logits, sample_rows)
         self._phase("emit")
         now = time.monotonic()
-        for s in actives:
-            req = self._slots[s]
-            if req.stream.cancelled:
-                self._evict(s, "cancelled")
-                continue
-            if req.temperature > 0:
-                t = _host_sample(logits_np[row_of[s]], req.temperature,
-                                 req.top_k, req.rng)
-            else:
-                t = int(sampled[s])
-            self._advance(s, req, [t], now)
+        try:
+            for s in actives:
+                req = self._slots[s]
+                if req.stream.cancelled:
+                    self._evict(s, "cancelled")
+                    continue
+                if req.temperature > 0:
+                    t = _host_sample(logits_np[row_of[s]],
+                                     req.temperature, req.top_k, req.rng)
+                else:
+                    t = int(sampled[s])
+                self._advance(s, req, [t], now)
+        finally:
+            self._flush_emits()
 
     def _count_keys(self, actives, t: int = 1) -> None:
         """attn_keys_*: what this tick's rows hold, what their attention
@@ -2195,28 +2274,32 @@ class GenerationEngine:
         logits_np, row_of = self._ship_sample_logits(logits0, sample_rows)
         self._phase("emit")
         now = time.monotonic()
-        for s in actives:
-            req = self._slots[s]
-            if req.stream.cancelled:
-                self._evict(s, "cancelled")
-                continue
-            if req.temperature > 0:
-                t = _host_sample(logits_np[row_of[s]], req.temperature,
-                                 req.top_k, req.rng)
-                self._advance(s, req, [t], now)
-                continue
-            d = spec_drafts.get(s, [])
-            m = 0
-            while m < len(d) and preds[s, m] == d[m]:
-                m += 1
-            # The bonus prediction always rides along, so produced
-            # length is m+1; cap so the row never exceeds max_new.
-            m = min(m, req.max_new_tokens - req.emitted - 1)
-            self._spec_drafted += len(d)
-            self._spec_accepted += m
-            if m:
-                SPEC_ACCEPTED_COUNTER.inc(m, tags=self._tags)
-            self._advance(s, req, list(d[:m]) + [int(preds[s, m])], now)
+        try:
+            for s in actives:
+                req = self._slots[s]
+                if req.stream.cancelled:
+                    self._evict(s, "cancelled")
+                    continue
+                if req.temperature > 0:
+                    t = _host_sample(logits_np[row_of[s]],
+                                     req.temperature, req.top_k, req.rng)
+                    self._advance(s, req, [t], now)
+                    continue
+                d = spec_drafts.get(s, [])
+                m = 0
+                while m < len(d) and preds[s, m] == d[m]:
+                    m += 1
+                # The bonus prediction always rides along, so produced
+                # length is m+1; cap so the row never exceeds max_new.
+                m = min(m, req.max_new_tokens - req.emitted - 1)
+                self._spec_drafted += len(d)
+                self._spec_accepted += m
+                if m:
+                    SPEC_ACCEPTED_COUNTER.inc(m, tags=self._tags)
+                self._advance(s, req,
+                              list(d[:m]) + [int(preds[s, m])], now)
+        finally:
+            self._flush_emits()
 
     def _ship_sample_logits(self, logits, sample_rows):
         """Host transfer scales with the SAMPLING rows, not the whole
@@ -2253,27 +2336,50 @@ class GenerationEngine:
         return int(row_logits.argmax())
 
     def _emit(self, req: _Request, token: int, now: float):
+        """One token out to its stream.  The reader's wake-up and the
+        token's exported metrics wait for the phase's flush
+        (_flush_emits); what stats() reports is counted here, so a
+        reader the flush wakes never finds its token uncounted."""
         req.emitted += 1
         if req.first_token_t is None:
             req.first_token_t = now
-            TTFT_HISTOGRAM.observe(now - req.submit_t, tags=self._tags)
+            self._ttft_hist.observe(now - req.submit_t)
             self._recent_ttft.append(now - req.submit_t)
         else:
-            ITL_HISTOGRAM.observe(now - req.last_token_t,
-                                  tags=self._tags)
+            self._emit_gaps.append(now - req.last_token_t)
             self._token_gaps += 1
             self._turn_gaps += 1
         req.last_token_t = now
         self._tokens_generated += 1
-        self._win_tokens += 1
-        TOKENS_COUNTER.inc(tags=self._tags)
+        self._emit_tokens += 1
+        req.stream._push(token, self._wakes)
+
+    def _flush_emits(self):
+        """Close an emit phase, before the thread leaves it for any
+        dispatch or device result.  Wake the readers of every stream
+        the phase pushed to or finished: one call into each event loop
+        (_fire_wakeups), a finish after its row's last token.  Then the
+        exported metrics of the phase's tokens, once for all of them:
+        one take of each metric's lock.  A phase that emitted nothing
+        pays two truth tests."""
+        if self._wakes:
+            wakes, self._wakes = self._wakes, []
+            self._stream_wakes += _fire_wakeups(wakes)
+        n = self._emit_tokens
+        if not n:
+            return
+        self._emit_tokens = 0
+        self._tokens_counter.inc(n)
+        if self._emit_gaps:
+            gaps, self._emit_gaps = self._emit_gaps, []
+            self._itl_hist.observe_many(gaps)
+        self._win_tokens += n
+        now = time.monotonic()
         if now - self._win_t >= 0.5:
-            THROUGHPUT_GAUGE.set(
-                self._win_tokens / (now - self._win_t),
-                tags=self._tags)
+            self._throughput_gauge.set(
+                self._win_tokens / (now - self._win_t))
             self._win_t = now
             self._win_tokens = 0
-        req.stream._push(token)
 
     def _evict(self, slot: int, status: str):
         """Eviction is pure accounting: point the row back at the trash
@@ -2303,7 +2409,7 @@ class GenerationEngine:
             self._committed_blocks = max(
                 0, self._committed_blocks - req.n_blocks)
         REQUESTS_COUNTER.inc(tags={**self._tags, "status": status})
-        req.stream._finish()
+        req.stream._finish(batch=self._wakes)
 
     def _update_occupancy(self):
         OCCUPANCY_GAUGE.set(
@@ -2353,14 +2459,17 @@ class GenerationEngine:
             if not fut.done():
                 fut.set_exception(err)
         if pf is not None:
-            pf.req.stream._finish(err)
+            pf.req.stream._finish(err, self._wakes)
         for req in leftovers:
-            req.stream._finish(err)
+            req.stream._finish(err, self._wakes)
         for s in range(self.num_slots):
             req = self._slots[s]
             if req is not None:
                 self._slots[s] = None
-                req.stream._finish(err)
+                req.stream._finish(err, self._wakes)
+        # Before the device state is rebuilt: a reader whose token was
+        # pushed ahead of the fault finds it, then the error.
+        self._flush_emits()
         self._pos[:] = 0
         self._tok[:] = 0
         # Rebuild device state: the donated cache may be mid-flight.

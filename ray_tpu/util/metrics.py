@@ -9,6 +9,7 @@ Prometheus exposition text from those snapshots.
 
 from __future__ import annotations
 
+import bisect
 import threading
 import time
 
@@ -125,26 +126,62 @@ class Histogram(Metric):
 
     def observe(self, value: float,
                 tags: Optional[Dict[str, str]] = None):
-        key = self._label_values(tags)
+        self._observe(self._label_values(tags), (value,))
+
+    def observe_many(self, values,
+                     tags: Optional[Dict[str, str]] = None):
+        """Record every value of `values` under one take of the lock:
+        the same buckets, sum and count as observe() called for each
+        in turn (a hot path that gathers a batch of observations, e.g.
+        the LLM engine's inter-token gaps of one turn)."""
+        self._observe(self._label_values(tags), values)
+
+    def _observe(self, key: tuple, values):
+        if not values:
+            return
         with self._lock:
             entry = self._values.get(key)
             if not isinstance(entry, dict):
                 entry = self._values[key] = {
                     "buckets": [0] * (len(self.boundaries) + 1),
                     "sum": 0.0, "count": 0}
-            idx = len(self.boundaries)
-            for i, b in enumerate(self.boundaries):
-                if value <= b:
-                    idx = i
-                    break
-            entry["buckets"][idx] += 1
-            entry["sum"] += value
-            entry["count"] += 1
+            buckets, total = entry["buckets"], entry["sum"]
+            for value in values:
+                # the first boundary the value does not exceed
+                # (boundaries ascend); past the last, the +Inf bucket
+                buckets[bisect.bisect_left(self.boundaries, value)] += 1
+                total += value
+            entry["sum"] = total
+            entry["count"] += len(values)
+
+    def series(self, tags: Optional[Dict[str, str]] = None
+               ) -> "_HistogramSeries":
+        """Pre-resolved handle for ONE label combination (see
+        Metric.series).  Unlike a scalar's, it registers nothing until
+        the first observation: an empty histogram has no snapshot."""
+        return _HistogramSeries(self, self._label_values(tags))
 
     def snapshot(self) -> dict:
         snap = super().snapshot()
         snap["boundaries"] = self.boundaries
         return snap
+
+
+class _HistogramSeries:
+    """Single-series view of a Histogram: observations without the
+    per-call tag merge/validation."""
+
+    __slots__ = ("_hist", "_key")
+
+    def __init__(self, hist: Histogram, key: tuple):
+        self._hist = hist
+        self._key = key
+
+    def observe(self, value: float):
+        self._hist._observe(self._key, (value,))
+
+    def observe_many(self, values):
+        self._hist._observe(self._key, values)
 
 
 def registry_snapshot() -> List[dict]:
