@@ -24,6 +24,12 @@ Mix file keys (JSON):
   prompt_len / output_len   {"dist": "lognormal", "median", "sigma",
                   "min", "max"} | {"dist": "fixed", "value"}
   gaps            {"dist": "exponential"} | {"dist": "fixed"}
+  order_seed      optional: the orders inside the blocks are drawn from
+                  this number instead of `--seed`, so every run offers
+                  its requests in ONE order and `--seed` chooses the
+                  token ids and the weights alone.  For a mix whose
+                  window holds so few requests that the order is the
+                  work (`longdoc`: ~15 requests a window)
 """
 
 from __future__ import annotations
@@ -68,7 +74,7 @@ def schedule(mix: Dict[str, Any], seed: int, seconds: float,
     start of load; None in a closed loop), "prompt_len", "max_new"}].
     In an open loop a block lasts exactly seconds / blocks_per_window."""
     n = block_size(mix, seconds)
-    rng = random.Random(seed)
+    rng = random.Random(mix.get("order_seed", seed))
     prompts = [int(round(x)) for x in quantile_grid(mix["prompt_len"], n)]
     outputs = [int(round(x)) for x in quantile_grid(mix["output_len"], n)]
     open_loop = mix["loop"] == "open"
