@@ -78,6 +78,8 @@ _TICK_SPAN_KEYS = 256
 _CHUNK_SPAN_KEYS = 256
 
 COUNTERS = _ds.COUNTERS
+# what of `init_paged_cache` is state per decode row (engine.stats())
+ROW_STATE_KEYS = ("wk", "wv")
 read_counters = _ds.read_counters
 # whole pages a span of so many keys covers; one span merged into a
 # running softmax (maxima, sums, accumulator)

@@ -35,6 +35,8 @@ What the engine has to know: a row's state is zeroed by the chunk that
 starts at position 0 (inside the program); the chunk writes the state of
 `slot` and stops moving it after `valid` tokens; the tick leaves rows at
 position 0 (idle rows, and the row a prefill is filling) untouched.
+(The same contract holds exaone_moe.py's window rings and jamba.py's
+scan state and convolution tail: its third user.)
 """
 
 from __future__ import annotations
@@ -53,6 +55,8 @@ from ray_tpu.models.decode import _rope_at, _swiglu
 from ray_tpu.models.gpt import _rmsnorm
 
 ATTN, LIN = "minicpm4", "lightning-attn"
+# what of `init_paged_cache` is state per decode row (engine.stats())
+ROW_STATE_KEYS = ("state",)
 _HI = lax.Precision.HIGHEST
 _DENSE_SPAN_KEYS = 4096      # keys one softmax part of a dense chunk spans
 

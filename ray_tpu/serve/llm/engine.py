@@ -419,6 +419,10 @@ class EngineStats:
     #                                   computed that held no prompt token
     prefill_tokens_sparse: int = 0    # ...in chunks that selected pages
     state_resets: int = 0             # per-row recurrent states zeroed
+    # Bytes of the cache that are state per decode row (the entries the
+    # model names in ROW_STATE_KEYS): resident whatever the traffic, and
+    # in no page count.  0 for a model whose pages are all its state.
+    row_state_bytes: int = 0
     # A model that routes tokens to experts counts on the device, inside
     # its cache (its `read_counters`): (token, expert) pairs routed and
     # those whose expert this replica holds, summed over live rows,
@@ -845,6 +849,9 @@ class GenerationEngine:
         self._prefill_pad_tokens = 0
         self._prefill_tokens_sparse = 0
         self._state_resets = 0
+        self._row_state_bytes = sum(
+            int(self._cache[k].nbytes)
+            for k in getattr(self._model, "ROW_STATE_KEYS", ()))
         # A routing model's device-side counters, as last fetched by
         # the worker thread (stats() must not touch a cache that every
         # step donates).
@@ -1744,6 +1751,7 @@ class GenerationEngine:
             prefill_pad_tokens=self._prefill_pad_tokens,
             prefill_tokens_sparse=self._prefill_tokens_sparse,
             state_resets=self._state_resets,
+            row_state_bytes=self._row_state_bytes,
             **{"moe_" + k: v for k, v in self._model_counters.items()})
 
     # ------------------------------------------------------------------

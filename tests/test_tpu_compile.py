@@ -383,3 +383,96 @@ def test_kexaone_paged_step_compiles(chip, step, monkeypatch):
     shapes = [s.split(",") for s in re.findall(r" = \w+\[([\d,]+)\]", text)]
     wide = [s for s in shapes if str(blocks * e["page_size"]) in s]
     assert not wide, wide[:4]
+
+
+# The sixth configuration (benchmarks/configs/jamba2-3b.json): 26 Mamba
+# layers that hold a float32 scan state and a convolution tail a decode
+# row beside two attention layers that page one key-value head, built as
+# the benchmark builds it, at its sizes.
+def test_ssm_kernels_compile(chip):
+    """The chunk's selective scan and the tick's step as the chip runs
+    them, at the mixer's widths (256 tokens or 128 rows, 5,120 channels,
+    16 states): one Pallas call each; the scan holds nothing beside its
+    arguments, the step updates 26 layers' states in place."""
+    from ray_tpu.ops import ssm
+
+    def on(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    f32 = jnp.float32
+    compiled = jax.jit(ssm.scan_pallas).lower(
+        on(f32, 256, 5120), on(f32, 256, 5120), on(f32, 256, 16),
+        on(f32, 256, 16), on(f32, 16, 5120), on(f32, 16, 5120)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 24
+    compiled = jax.jit(ssm.step_pallas, donate_argnums=5).lower(
+        on(f32, 128, 5120), on(f32, 128, 5120), on(f32, 128, 16),
+        on(f32, 128, 16), on(f32, 16, 5120), on(f32, 26, 128, 16, 5120),
+        on(jnp.int32), on(jnp.bool_, 128)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 24
+    assert mem.alias_size_in_bytes == 26 * 128 * 16 * 5120 * 4
+
+
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_jamba_paged_step_compiles(chip, step, monkeypatch):
+    """Both programs of jamba2-3b with the scan as the chip runs it:
+    6.06 GB of weights, the two attention layers' pool and 1.19 GB of
+    state and tails are resident, a step holds under 0.25 GiB beside
+    them (no [tokens, 16, 5120] float32 product, no copy of the state),
+    and neither the pool nor the state is re-laid or copied."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.ops import ssm
+    monkeypatch.setattr(ssm, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs", "jamba2-3b.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+    assert cache["k"].shape == (2, 6145, 1, 64, 128)
+    assert cache["ssm"].shape == (26, rows, 16, 5120)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if step == "decode_tick":
+        lowered = engine._paged_tick.lower(
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
+            with_logits=False)
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    # 0.002 GiB (tick) / 0.13 (a 256-token chunk)
+    assert mem.temp_size_in_bytes < 1 << 28, mem.temp_size_in_bytes / 2**30
+    # weights 5.65 GiB + pool 0.375 + state and tails 1.11
+    assert 7.0 < mem.argument_size_in_bytes / 2**30 < 7.3
+    text = compiled.as_text()
+    # one kernel a run of Mamba layers: the chunk's scan, the tick's step
+    assert text.count("tpu_custom_call") == 3
+    for name, order in (("k", "4,3,2,1,0"), ("ssm", "3,2,1,0"),
+                        ("conv", "2,1,0")):
+        held = "%s[%s]" % ({"float32": "f32", "bfloat16": "bf16"}[
+            cache[name].dtype.name], ",".join(map(str, cache[name].shape)))
+        layouts = set(re.findall(re.escape(held) + r"\{([\d,]+)", text))
+        assert layouts == {order}, (name, layouts)         # never re-laid
+        moved = [ln for ln in text.splitlines()
+                 if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
+        assert not moved, moved[:4]
+    # nothing is as wide as the table (3,072 columns) but the tables
+    shapes = [s.split(",") for s in re.findall(r" = \w+\[([\d,]+)\]", text)]
+    wide = [s for s in shapes if str(blocks * e["page_size"]) in s]
+    assert not wide, wide[:4]
