@@ -248,10 +248,10 @@ def paged_read_batch(cache: Dict) -> int:
     """Page ids one dispatch of paged_read_pages takes, from the page's
     bytes alone: _READ_BYTES of K and V, between 8 and 64 pages (32 for
     a 1 MiB page).  ONE size for a pool's whole life, so the program
-    compiles once, in the first pass that reads a page."""
-    _, _, psz, hkv, dh = cache["k"].shape
-    page = 2 * cache["k"].shape[0] * psz * hkv * dh \
-        * cache["k"].dtype.itemsize
+    compiles once, in the first pass that reads a page.  A page's bytes
+    are both arrays' (a value may be narrower than a key)."""
+    page = sum(cache[n].size * cache[n].dtype.itemsize
+               for n in ("k", "v")) // cache["k"].shape[1]
     return max(8, min(64, _READ_BYTES // page))
 
 
@@ -311,7 +311,9 @@ def paged_span_blocks(cache: Dict, rows: int, nblk: int) -> int:
     _SPAN_COLS columns and at most the whole table.  A 16-row tick over
     16-token pages of 8 x 128 bf16 heads walks 16 blocks (256 columns)
     a span, a single-row chunk 32 blocks.  The engine counts
-    `attn_keys_gathered` with it."""
+    `attn_keys_gathered` with it.  Of the DENSE pool only (one head
+    count and width for every layer): a model with its own paged step
+    sizes its own spans."""
     _, _, psz, hkv, dh = cache["k"].shape
     page = rows * psz * hkv * dh * cache["k"].dtype.itemsize
     return max(1, min(nblk, _SPAN_BYTES // page, _SPAN_COLS // psz))

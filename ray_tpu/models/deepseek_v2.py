@@ -89,6 +89,7 @@ _CHUNK_SPAN_KEYS = 128
 _GMM_ROWS = 128
 _GMM_K, _GMM_N = 1024, 512
 
+PAGE_KEYS = ("lat",)    # what of `init_paged_cache` is the pool
 COUNTERS = ("pairs_routed", "pairs_local", "experts_touched",
             "experts_held", "load_max")
 _WORD = 30      # a counter is [hi, lo] with lo < 2**30

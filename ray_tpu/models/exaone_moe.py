@@ -78,14 +78,52 @@ _TICK_SPAN_KEYS = 256
 _CHUNK_SPAN_KEYS = 256
 
 COUNTERS = _ds.COUNTERS
-# what of `init_paged_cache` is state per decode row (engine.stats())
+# what of `init_paged_cache` is the pool (a page's bytes are these
+# arrays' together) and what is state per decode row (engine.stats())
+PAGE_KEYS = ("k", "v")
 ROW_STATE_KEYS = ("wk", "wv")
-read_counters = _ds.read_counters
-snapshot_counters = _ds.snapshot_counters
+# A sink's share of a head's softmax is counted in units of 2^-10, so
+# that the counters stay whole numbers (deepseek_v2._count).
+_SINK_UNIT = 1 << 10
 # whole pages a span of so many keys covers; one span merged into a
 # running softmax (maxima, sums, accumulator)
 _span_pages = _ds._span_pages
 _merge = _ds._merge
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One kind of attention layer as the attention functions below read
+    it, so that a model whose two kinds differ in more than the window
+    (models/mimo_v2_flash.py: other head counts, keys wider than values,
+    a theta each, a sink) runs through the same functions.  The defaults
+    are K-EXAONE's."""
+    n_kv_heads: int
+    head_dim: int                         # a query's and a key's width
+    v_head_dim: int                       # a value's
+    window: int = 0                       # 0: attends to all (pages)
+    rope_theta: Optional[float] = None    # None: no positions
+    rotary_dim: Optional[int] = None      # None: the whole head
+    qk_norm: bool = True
+    v_scale: float = 1.0
+    sink: bool = False    # a learned score per head in the denominator
+    flat: bool = False    # the cache keeps a token's heads side by side
+
+
+def _kept(kind: AttnKind, width: int) -> Tuple[int, ...]:
+    """The trailing shape a cache array keeps a token's heads of `width`
+    in: [heads, width] (K-EXAONE's [8, 128]) or, for a kind that says
+    `flat`, the heads side by side, [heads x width]: the same numbers in
+    the same order.  A kind says so whose keys are no whole number of
+    the chip's 128 lanes wide (MiMo's 4 x 192 = 768, and its values with
+    them): the chip's compiler gives an array that ends in [4, 192] a
+    layout of its own with the PAGES innermost, and a step then re-lays
+    the whole pool on its way in and out (2 GiB of temporaries a tick at
+    mimo-v2-flash-ep16-d7's sizes; tests/test_tpu_compile.py holds that
+    it does not).  A reader reshapes what it gathered, never the pool."""
+    if kind.flat:
+        return (kind.n_kv_heads * width,)
+    return (kind.n_kv_heads, width)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,6 +192,12 @@ class ExaoneMoeConfig:
     def n_moe(self) -> int:
         return self.n_layers - self.first_k_dense
 
+    def kind(self, windowed: bool) -> AttnKind:
+        """Both kinds share heads and widths; a window layer rotates."""
+        return AttnKind(self.n_kv_heads, self.head_dim, self.head_dim,
+                        window=self.window if windowed else 0,
+                        rope_theta=self.rope_theta if windowed else None)
+
     # -- what models/decode.py and the engine ask a model with its own
     # paged step ------------------------------------------------------
     @property
@@ -182,16 +226,27 @@ def attn_keys(cfg: ExaoneMoeConfig, pos: np.ndarray) -> Tuple[int, int]:
     return held, held
 
 
+def attn_keys_paged(cfg: ExaoneMoeConfig, pos: np.ndarray,
+                    all_pos: np.ndarray, page_size: int, nblk: int
+                    ) -> Tuple[int, int]:
+    """(keys gathered, keys held) in the GLOBAL layers alone by one tick
+    whose active rows stand at `pos`: the layers whose keys live in
+    pages.  Gathered: for EVERY row of the call (`all_pos` of all decode
+    rows, idle ones at 0), whole spans up to the deepest row's token,
+    the trip count the program reads from the positions."""
+    cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
+    spans = -(-(int(np.asarray(all_pos).max()) + 1) // cols)
+    held = int((np.asarray(pos, np.int64) + 1).sum())
+    return len(all_pos) * spans * cols * cfg.n_global, held * cfg.n_global
+
+
 def attn_keys_gathered(cfg: ExaoneMoeConfig, pos: np.ndarray,
                        page_size: int, nblk: int) -> int:
-    """Keys one tick pulls from the cache: in a global layer, for EVERY
-    row of the call (`pos` of all decode rows, idle ones at 0), whole
-    spans up to the deepest row's token, the trip count the program
-    reads from `pos`; in a window layer every row's whole ring."""
-    cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
-    spans = -(-(int(np.asarray(pos).max()) + 1) // cols)
-    return len(pos) * (spans * cols * cfg.n_global
-                       + cfg.window * cfg.n_window)
+    """Keys one tick pulls from the cache (`pos` of all decode rows): in
+    a global layer whole spans (attn_keys_paged); in a window layer
+    every row's whole ring."""
+    return attn_keys_paged(cfg, pos, pos, page_size, nblk)[0] \
+        + len(pos) * cfg.window * cfg.n_window
 
 
 def chunk_selects(cfg: ExaoneMoeConfig, start: int) -> bool:
@@ -214,13 +269,14 @@ def check_paging(cfg: ExaoneMoeConfig, *, page_size: int,
 # Weights and cache
 
 
-def init_params(cfg: ExaoneMoeConfig, key, dtype=None) -> Dict:
-    """Seeded weights, one dict a layer (normal, std 0.02; projections
-    back into the residual stream 0.02 / sqrt(2 n_layers); the router in
-    float32, as it is applied, and its selection bias zero)."""
-    dtype = dtype or cfg.dtype
-    D, H, G, Dh, F = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
-                      cfg.head_dim, cfg.moe_d_ff)
+def seeded_draws(cfg, key, dtype):
+    """What a model of this family draws its seeded weights with:
+    (`nrm(shape, scale, dt=dtype)`, a normal in float32 cast to `dt`,
+    each call a key of its own in the order of the calls; `swiglu(width,
+    *lead)`, three of them, std `s` in and `so` out; `s` 0.02; `so`
+    0.02 / sqrt(2 n_layers), for projections back into the residual
+    stream)."""
+    D = cfg.d_model
     s = 0.02
     so = s / np.sqrt(2 * cfg.n_layers)
     keys = iter(jax.random.split(key, 2 + 16 * cfg.n_layers))
@@ -229,12 +285,22 @@ def init_params(cfg: ExaoneMoeConfig, key, dtype=None) -> Dict:
         return (scale * jax.random.normal(next(keys), shape, jnp.float32)
                 ).astype(dt)
 
-    ones = lambda *shape: jnp.ones(shape, jnp.float32)  # noqa: E731
-
     def swiglu(width, *lead):
         return {"w_gate": nrm(lead + (D, width), s),
                 "w_up": nrm(lead + (D, width), s),
                 "w_down": nrm(lead + (width, D), so)}
+    return nrm, swiglu, s, so
+
+
+def init_params(cfg: ExaoneMoeConfig, key, dtype=None) -> Dict:
+    """Seeded weights, one dict a layer (normal, std 0.02; projections
+    back into the residual stream 0.02 / sqrt(2 n_layers); the router in
+    float32, as it is applied, and its selection bias zero)."""
+    dtype = dtype or cfg.dtype
+    D, H, G, Dh, F = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+                      cfg.head_dim, cfg.moe_d_ff)
+    nrm, swiglu, s, so = seeded_draws(cfg, key, dtype)
+    ones = lambda *shape: jnp.ones(shape, jnp.float32)  # noqa: E731
 
     def layer(i):
         lp = {"ln1": ones(D), "wq": nrm((D, H, Dh), s),
@@ -256,13 +322,44 @@ def init_params(cfg: ExaoneMoeConfig, key, dtype=None) -> Dict:
 
 def init_paged_cache(cfg: ExaoneMoeConfig, num_pages: int, page_size: int,
                      num_slots: Optional[int] = None) -> Dict:
-    G, Dh = cfg.n_kv_heads, cfg.head_dim
-    pages = (cfg.n_global, num_pages, page_size, G, Dh)
-    rings = (cfg.n_window, num_slots or 1, cfg.window, G, Dh)
-    return {"k": jnp.zeros(pages, cfg.dtype), "v": jnp.zeros(pages, cfg.dtype),
-            "wk": jnp.zeros(rings, cfg.dtype),
-            "wv": jnp.zeros(rings, cfg.dtype),
-            "moe": jnp.zeros((len(COUNTERS), 2), jnp.int32)}
+    def pair(lead, kind):
+        return (jnp.zeros(lead + _kept(kind, kind.head_dim), cfg.dtype),
+                jnp.zeros(lead + _kept(kind, kind.v_head_dim), cfg.dtype))
+    windowed = cfg.kind(True)
+    k, v = pair((cfg.n_global, num_pages, page_size), cfg.kind(False))
+    wk, wv = pair((cfg.n_window, num_slots or 1, cfg.window), windowed)
+    cache = {"k": k, "v": v, "wk": wk, "wv": wv,
+             "moe": jnp.zeros((len(COUNTERS), 2), jnp.int32)}
+    if windowed.sink:
+        # [share of the softmax the sinks took, in _SINK_UNIT; the
+        # (token, head, window layer) softmaxes counted], as `moe`
+        cache["sink"] = jnp.zeros((2, 2), jnp.int32)
+    return cache
+
+
+def snapshot_counters(cache: Dict) -> Dict:
+    """deepseek_v2.snapshot_counters, with the sinks' two beside the
+    expert layers' where the model has them."""
+    snap = _ds.snapshot_counters(cache)
+    if "sink" in cache:
+        snap["sink"] = jnp.copy(cache["sink"])
+        snap["sink"].copy_to_host_async()
+    return snap
+
+
+def read_counters(cache: Dict, cfg) -> Dict[str, Any]:
+    """The expert layers' counters (deepseek_v2.read_counters) and,
+    where window softmaxes have a sink, `attn_sink_mass`: the share of
+    its softmax a sink took, summed over ticks' live rows, chunks' real
+    tokens, heads and window layers, and `attn_sink_softmaxes`: how many
+    softmaxes that sums.  Their ratio is the mean share a sink takes."""
+    counts = _ds.read_counters(cache, cfg)
+    if "sink" in cache:
+        mass, heads = (int((hi << _ds._WORD) + lo) for hi, lo in
+                       np.asarray(cache["sink"]).astype(np.int64))
+        counts["attn_sink_mass"] = mass / _SINK_UNIT
+        counts["attn_sink_softmaxes"] = heads
+    return counts
 
 
 def _rms(x, scale, cfg: ExaoneMoeConfig):
@@ -291,9 +388,9 @@ def route(router, bias, h, cfg: ExaoneMoeConfig):
 
 
 def _ffn(lp, x, live, is_tick, counts, cfg: ExaoneMoeConfig):
-    """x + FFN(norm(x)): dense SwiGLU in the leading layers, shared +
-    held routed experts after them.  `counts`: this call's additions to
-    COUNTERS so far."""
+    """x + FFN(norm(x)): dense SwiGLU in the leading layers, the shared
+    expert (where the layer has one) + held routed experts after them.
+    `counts`: this call's additions to COUNTERS so far."""
     dt = cfg.dtype
     h = _rms(x, lp["ln2"], cfg)
     if "router" not in lp:
@@ -304,43 +401,67 @@ def _ffn(lp, x, live, is_tick, counts, cfg: ExaoneMoeConfig):
         routed, sizes = _ds.routed_experts(lp["experts"], h, ids, weights,
                                            live, cfg)
     counts = _ds.count_routed(counts, live, sizes, is_tick, cfg)
-    return x + (routed + _swiglu(lp["shared"], h, dt)).astype(x.dtype), counts
+    if "shared" in lp:
+        routed = routed + _swiglu(lp["shared"], h, dt)
+    return x + routed.astype(x.dtype), counts
 
 
 # ---------------------------------------------------------------------------
 # Attention, for a single-row chunk of T tokens (x [T, D]) and for a tick
 # of B rows (x [B, D]).  `i` indexes the layer among the layers of its
-# kind (its pages, or its rings).
+# kind (its pages, or its rings); `kind` is that kind's AttnKind.
 
 
-def _project(lp, x, positions, rotate: bool, cfg: ExaoneMoeConfig):
-    """x [n, D] at positions [n] -> q [n, H, Dh], k, v [n, G, Dh]: q and
-    k normed per head, and rotated in a window layer."""
+def _rotate(x, positions, kind: AttnKind):
+    """RoPE over the first `rotary_dim` of a head (all of it by
+    default), pairs (i, i + rotary_dim / 2); the rest passes."""
+    rd = kind.rotary_dim
+    if rd is None or rd == x.shape[-1]:
+        return _rope_at(x[None], positions[None], kind.rope_theta)[0]
+    turned = _rope_at(x[None, ..., :rd], positions[None], kind.rope_theta)[0]
+    return jnp.concatenate([turned, x[..., rd:]], axis=-1)
+
+
+def _project(lp, x, positions, kind: AttnKind, cfg):
+    """x [n, D] at positions [n] -> q [n, H, Dh], k [n, G, Dh], v
+    [n, G, Dv]: q and k normed per head where the kind norms them,
+    rotated where it has positions, v scaled where it has a scale.  The
+    k and v projections are one array `wkv` where they are as wide, or
+    `wk` and `wv`."""
     dt = cfg.dtype
     h = _rms(x, lp["ln1"], cfg)
     q = jnp.einsum("nd,dhk->nhk", h, lp["wq"].astype(dt))
-    kv = jnp.einsum("nd,dchk->nchk", h, lp["wkv"].astype(dt))
-    q, k = _rms(q, lp["qn"], cfg), _rms(kv[:, 0], lp["kn"], cfg)
-    if rotate:
-        q = _rope_at(q[None], positions[None], cfg.rope_theta)[0]
-        k = _rope_at(k[None], positions[None], cfg.rope_theta)[0]
-    return q, k, kv[:, 1]
+    if "wkv" in lp:
+        kv = jnp.einsum("nd,dchk->nchk", h, lp["wkv"].astype(dt))
+        k, v = kv[:, 0], kv[:, 1]
+    else:
+        k = jnp.einsum("nd,dhk->nhk", h, lp["wk"].astype(dt))
+        v = jnp.einsum("nd,dhk->nhk", h, lp["wv"].astype(dt))
+    if kind.qk_norm:
+        q, k = _rms(q, lp["qn"], cfg), _rms(k, lp["kn"], cfg)
+    if kind.rope_theta is not None:
+        q, k = _rotate(q, positions, kind), _rotate(k, positions, kind)
+    if kind.v_scale != 1.0:
+        v = v * kind.v_scale
+    return q, k, v
 
 
-def _close(lp, x, out, cfg: ExaoneMoeConfig):
+def _close(lp, x, out, cfg):
     return x + jnp.einsum("nhk,hkd->nd", out, lp["wo"].astype(cfg.dtype))
 
 
-def _global_chunk(lp, x, i, cache, bt, start, cfg: ExaoneMoeConfig):
+def _global_chunk(lp, x, i, cache, bt, start, kind: AttnKind, cfg):
     T = x.shape[0]
-    G, Dh, psz = cfg.n_kv_heads, cfg.head_dim, cache["k"].shape[2]
+    G, Dh, Dv = kind.n_kv_heads, kind.head_dim, kind.v_head_dim
+    psz = cache["k"].shape[2]
     R = cfg.n_heads // G
     dt = cfg.dtype
     cols = start + jnp.arange(T)
-    q, k, v = _project(lp, x, cols, False, cfg)
+    q, k, v = _project(lp, x, cols, kind, cfg)
     pages = lax.dynamic_slice(bt, (start // psz,), (T // psz,))
-    ck = cache["k"].at[i, pages].set(k.reshape(T // psz, psz, G, Dh))
-    cv = cache["v"].at[i, pages].set(v.reshape(T // psz, psz, G, Dh))
+    paged = (T // psz, psz)
+    ck = cache["k"].at[i, pages].set(k.reshape(paged + _kept(kind, Dh)))
+    cv = cache["v"].at[i, pages].set(v.reshape(paged + _kept(kind, Dv)))
 
     with jax.named_scope("attn_global"):
         nblk = bt.shape[0]
@@ -352,7 +473,7 @@ def _global_chunk(lp, x, i, cache, bt, start, cfg: ExaoneMoeConfig):
             first = jnp.minimum(j * span, nblk - span)   # as the slice clamps
             pg = lax.dynamic_slice(bt, (first,), (span,))
             ks = ck[i, pg].reshape(width, G, Dh)
-            vs = cv[i, pg].reshape(width, G, Dh)
+            vs = cv[i, pg].reshape(width, G, Dv)
             s = jnp.einsum("tgrd,sgd->grts", qg, ks,
                            preferred_element_type=jnp.float32) * Dh ** -0.5
             kcols = first * psz + jnp.arange(width)
@@ -367,34 +488,48 @@ def _global_chunk(lp, x, i, cache, bt, start, cfg: ExaoneMoeConfig):
         _, total, acc = lax.fori_loop(
             0, (start + T + width - 1) // width, attend,
             (stat, jnp.zeros_like(stat),
-             jnp.zeros((G, R, T, Dh), jnp.float32)))
-        out = (acc / total[..., None]).astype(dt)             # [G, R, T, Dh]
-        out = jnp.moveaxis(out, 2, 0).reshape(T, G * R, Dh)
+             jnp.zeros((G, R, T, Dv), jnp.float32)))
+        out = (acc / total[..., None]).astype(dt)             # [G, R, T, Dv]
+        out = jnp.moveaxis(out, 2, 0).reshape(T, G * R, Dv)
     return _close(lp, x, out, cfg), dict(cache, k=ck, v=cv)
 
 
-def _global_tick(lp, x, i, cache, bt, pos, cfg: ExaoneMoeConfig):
+def _global_tick(lp, x, i, cache, bt, pos, kind: AttnKind, cfg):
     B = x.shape[0]
-    G, Dh, psz = cfg.n_kv_heads, cfg.head_dim, cache["k"].shape[2]
+    G, Dh, Dv = kind.n_kv_heads, kind.head_dim, kind.v_head_dim
+    psz = cache["k"].shape[2]
     R = cfg.n_heads // G
     dt = cfg.dtype
-    q, k, v = _project(lp, x, pos, False, cfg)
+    q, k, v = _project(lp, x, pos, kind, cfg)
     page = jnp.take_along_axis(bt, (pos // psz)[:, None], axis=1)[:, 0]
-    ck = cache["k"].at[i, page, pos % psz].set(k)
-    cv = cache["v"].at[i, page, pos % psz].set(v)
+    ck = cache["k"].at[i, page, pos % psz].set(
+        k.reshape((B,) + _kept(kind, Dh)))
+    cv = cache["v"].at[i, page, pos % psz].set(
+        v.reshape((B,) + _kept(kind, Dv)))
 
     with jax.named_scope("attn_global"):
         nblk = bt.shape[1]
         span = _span_pages(_TICK_SPAN_KEYS, psz, nblk)
         width = span * psz
         qg = q.reshape(B, G, R, Dh)
+        kept = _kept(kind, Dh)
+        if kind.flat:
+            # Keys whose heads lie side by side are scored as they lie:
+            # a head's query stands in its own head's lanes of a row as
+            # wide as all of them, zeros elsewhere.  G x the multiplies,
+            # in a call the gathered bytes bound; re-laying every
+            # gathered span to [G, Dh] instead made a 64-row tick 37.5
+            # ms, not 25.2 (PERF.md section 6, PR 51).
+            qg = jnp.einsum("bgrd,gh->bgrhd", qg, jnp.eye(G, dtype=dt)
+                            ).reshape((B, G, R) + kept)
+        scores = "bgrd,bsd->bgrs" if kind.flat else "bgrd,bsgd->bgrs"
 
         def attend(j, part):
             first = jnp.minimum(j * span, nblk - span)
             pg = lax.dynamic_slice(bt, (0, first), (B, span))
-            ks = ck[i, pg].reshape(B, width, G, Dh)
-            vs = cv[i, pg].reshape(B, width, G, Dh)
-            s = jnp.einsum("bgrd,bsgd->bgrs", qg, ks,
+            ks = ck[i, pg].reshape((B, width) + kept)
+            vs = cv[i, pg].reshape(B, width, G, Dv)
+            s = jnp.einsum(scores, qg, ks,
                            preferred_element_type=jnp.float32) * Dh ** -0.5
             kcols = first * psz + jnp.arange(width)
             seen = (kcols[None, :] <= pos[:, None]) \
@@ -408,37 +543,64 @@ def _global_tick(lp, x, i, cache, bt, pos, cfg: ExaoneMoeConfig):
         _, total, acc = lax.fori_loop(
             0, (jnp.max(pos) + width) // width, attend,
             (stat, jnp.zeros_like(stat),
-             jnp.zeros((B, G, R, Dh), jnp.float32)))
-        out = (acc / total[..., None]).astype(dt).reshape(B, G * R, Dh)
+             jnp.zeros((B, G, R, Dv), jnp.float32)))
+        out = (acc / total[..., None]).astype(dt).reshape(B, G * R, Dv)
     return _close(lp, x, out, cfg), dict(cache, k=ck, v=cv)
 
 
-def _window_attend(qg, k, v, qpos, kpos, W, dt):
-    """qg [..., n, G, R, Dh] at positions qpos [..., n] over keys k, v
-    [..., s, G, Dh] at positions kpos [..., s] (negative: nothing is
-    there): key s is visible to query t iff 0 <= t - s < W."""
+def _window_attend(qg, k, v, qpos, kpos, W, dt, sink=None):
+    """qg [..., n, G, R, Dh] at positions qpos [..., n] over keys k
+    [..., s, G, Dh], values v [..., s, G, Dv] at positions kpos [..., s]
+    (negative: nothing is there): key s is visible to query t iff
+    0 <= t - s < W.  `sink` [G, R] float32, where the kind has one: a
+    learned score per head that joins the softmax's denominator and has
+    no value, so a head's weights sum to less than 1.  Returns (out
+    [..., n, G, R, Dv], the share of each softmax its sink took
+    [..., G, R, n]; None without a sink)."""
     s = jnp.einsum("...ngrd,...sgd->...grns", qg, k,
                    preferred_element_type=jnp.float32) * qg.shape[-1] ** -0.5
     back = qpos[..., :, None] - kpos[..., None, :]
     seen = (back >= 0) & (back < W) & (kpos[..., None, :] >= 0)
     s = jnp.where(seen[..., None, None, :, :], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1).astype(dt)
-    return jnp.einsum("...grns,...sgd->...ngrd", p, v)
+    if sink is None:
+        p, share = jax.nn.softmax(s, axis=-1), None
+    else:
+        sink = sink[:, :, None]                              # [G, R, 1]
+        top = jnp.maximum(s.max(-1), sink)
+        e = jnp.exp(s - top[..., None])
+        drain = jnp.exp(sink - top)
+        total = e.sum(-1) + drain
+        p, share = e / total[..., None], drain / total
+    return jnp.einsum("...grns,...sgd->...ngrd", p.astype(dt), v), share
 
 
-def _window_chunk(lp, x, i, cache, start, slot, valid, cfg: ExaoneMoeConfig):
+def _count_sinks(cache, share, live, cfg):
+    """Add one window layer's sinks to the cache's counters: `share`
+    [..., G, R, n] with `live` [..., n] the queries that count."""
+    if share is None:
+        return cache
+    mass = (share * live[..., None, None, :]).sum()
+    return dict(cache, sink=_ds._count(cache["sink"], [
+        jnp.round(mass * _SINK_UNIT), live.sum() * cfg.n_heads]))
+
+
+def _window_chunk(lp, x, i, cache, start, slot, valid, kind: AttnKind, cfg):
     T = x.shape[0]
-    G, Dh, W = cfg.n_kv_heads, cfg.head_dim, cfg.window
+    G, Dh, Dv, W = (kind.n_kv_heads, kind.head_dim, kind.v_head_dim,
+                    kind.window)
     R = cfg.n_heads // G
     dt = cfg.dtype
     cols = start + jnp.arange(T)
-    q, k, v = _project(lp, x, cols, True, cfg)
+    q, k, v = _project(lp, x, cols, kind, cfg)
+    sink = lp["sink"].reshape(G, R) if kind.sink else None
 
     with jax.named_scope("attn_window"):
         # the W tokens before the chunk, in position order
         before = (start + jnp.arange(W)) % W
-        kall = jnp.concatenate([cache["wk"][i, slot][before], k])
-        vall = jnp.concatenate([cache["wv"][i, slot][before], v])
+        kall = jnp.concatenate(
+            [cache["wk"][i, slot].reshape(W, G, Dh)[before], k])
+        vall = jnp.concatenate(
+            [cache["wv"][i, slot].reshape(W, G, Dv)[before], v])
         kpos = start - W + jnp.arange(W + T)
         qb = W if T % W == 0 else T          # queries a block
         qg = q.reshape(T // qb, qb, G, R, Dh)
@@ -450,41 +612,53 @@ def _window_chunk(lp, x, i, cache, start, slot, valid, cfg: ExaoneMoeConfig):
                 a, j * qb, W + qb)
             return _window_attend(qg[j], take(kall), take(vall),
                                   start + j * qb + jnp.arange(qb),
-                                  take(kpos), W, dt)
-        out = lax.map(block, jnp.arange(T // qb)).reshape(T, G * R, Dh)
+                                  take(kpos), W, dt, sink)
+        out, share = lax.map(block, jnp.arange(T // qb))
+        out = out.reshape(T, G * R, Dv)
 
         # the ring after the chunk: entry e holds the last REAL position
         # congruent to e, from the chunk where that lies inside it
         last = start + valid - 1
         at = last - (last - jnp.arange(W)) % W
-        mine = (at >= start)[:, None, None]
         src = jnp.clip(at - start, 0, T - 1)
-        wk = cache["wk"].at[i, slot].set(
-            jnp.where(mine, k[src], cache["wk"][i, slot]))
-        wv = cache["wv"].at[i, slot].set(
-            jnp.where(mine, v[src], cache["wv"][i, slot]))
-    return _close(lp, x, out, cfg), dict(cache, wk=wk, wv=wv)
+        # (an entry the chunk does not reach is written nowhere)
+        entries = jnp.where(at >= start, jnp.arange(W), W)
+        wk = cache["wk"].at[i, slot, entries].set(
+            k[src].reshape((W,) + _kept(kind, Dh)), mode="drop")
+        wv = cache["wv"].at[i, slot, entries].set(
+            v[src].reshape((W,) + _kept(kind, Dv)), mode="drop")
+        cache = _count_sinks(dict(cache, wk=wk, wv=wv), share,
+                             (jnp.arange(T) < valid).reshape(T // qb, qb),
+                             cfg)
+    return _close(lp, x, out, cfg), cache
 
 
-def _window_tick(lp, x, i, cache, pos, cfg: ExaoneMoeConfig):
+def _window_tick(lp, x, i, cache, pos, kind: AttnKind, cfg):
     B = x.shape[0]
-    G, Dh, W = cfg.n_kv_heads, cfg.head_dim, cfg.window
+    G, Dh, Dv, W = (kind.n_kv_heads, kind.head_dim, kind.v_head_dim,
+                    kind.window)
     R = cfg.n_heads // G
-    q, k, v = _project(lp, x, pos, True, cfg)
+    q, k, v = _project(lp, x, pos, kind, cfg)
+    sink = lp["sink"].reshape(G, R) if kind.sink else None
 
     with jax.named_scope("attn_window"):
         # a row at position 0 is idle, or the row a prefill is filling:
         # its ring stays as it is
         at = jnp.where(pos > 0, pos % W, W)
         rows = jnp.arange(B)
-        wk = cache["wk"].at[i, rows, at].set(k, mode="drop")
-        wv = cache["wv"].at[i, rows, at].set(v, mode="drop")
+        wk = cache["wk"].at[i, rows, at].set(
+            k.reshape((B,) + _kept(kind, Dh)), mode="drop")
+        wv = cache["wv"].at[i, rows, at].set(
+            v.reshape((B,) + _kept(kind, Dv)), mode="drop")
         entries = jnp.arange(W)[None, :]
         kpos = pos[:, None] - (pos[:, None] - entries) % W
-        out = _window_attend(q.reshape(B, 1, G, R, Dh), wk[i], wv[i],
-                             pos[:, None], kpos, W, cfg.dtype)
-    return _close(lp, x, out.reshape(B, G * R, Dh), cfg), \
-        dict(cache, wk=wk, wv=wv)
+        out, share = _window_attend(
+            q.reshape(B, 1, G, R, Dh), wk[i].reshape(B, W, G, Dh),
+            wv[i].reshape(B, W, G, Dv), pos[:, None], kpos, W, cfg.dtype,
+            sink)
+        cache = _count_sinks(dict(cache, wk=wk, wv=wv), share,
+                             (pos > 0)[:, None], cfg)
+    return _close(lp, x, out.reshape(B, G * R, Dv), cfg), cache
 
 
 # ---------------------------------------------------------------------------
@@ -504,9 +678,11 @@ def _through_layers(params, x, cache, live, is_tick, attend, cfg):
 
 
 def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
-                     cfg: ExaoneMoeConfig, pad_lo=None, slot=None,
+                     cfg, pad_lo=None, slot=None,
                      valid=None) -> Tuple[Any, Dict]:
-    """The model's paged step, under decode.paged_chunk_step's contract.
+    """The model's paged step, under decode.paged_chunk_step's contract
+    (`cfg`: an ExaoneMoeConfig, or any config that answers `kind`,
+    `sliding_windows` and the expert layer's fields as one does).
 
     `pos` a scalar: ONE row's chunk of T tokens starting there (T and
     `pos` whole pages) — single-row prefill.  It fills the row's pages
@@ -535,9 +711,10 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
         bt = block_tables[0]
 
         def attend(lp, x, windowed, i, c):
+            kind = cfg.kind(windowed)
             if windowed:
-                return _window_chunk(lp, x, i, c, pos, slot, valid, cfg)
-            return _global_chunk(lp, x, i, c, bt, pos, cfg)
+                return _window_chunk(lp, x, i, c, pos, slot, valid, kind, cfg)
+            return _global_chunk(lp, x, i, c, bt, pos, kind, cfg)
         logits, cache = _through_layers(
             params, embed(tokens[0]), cache, jnp.arange(t) < valid, False,
             attend, cfg)
@@ -548,9 +725,10 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
             "verify) need the window rings rolled back on rejection")
 
     def attend(lp, x, windowed, i, c):
+        kind = cfg.kind(windowed)
         if windowed:
-            return _window_tick(lp, x, i, c, pos, cfg)
-        return _global_tick(lp, x, i, c, block_tables, pos, cfg)
+            return _window_tick(lp, x, i, c, pos, kind, cfg)
+        return _global_tick(lp, x, i, c, block_tables, pos, kind, cfg)
     logits, cache = _through_layers(
         params, embed(tokens[:, 0]), cache, pos > 0, True, attend, cfg)
     return logits[:, None], cache

@@ -64,7 +64,9 @@ from ray_tpu.models.decode import _swiglu
 from ray_tpu.ops import ssm
 
 ATTN, MAMBA = "attention", "mamba"
-# what of `init_paged_cache` is state per decode row (engine.stats())
+# what of `init_paged_cache` is the pool (a page's bytes are these
+# arrays' together) and what is state per decode row (engine.stats())
+PAGE_KEYS = ("k", "v")
 ROW_STATE_KEYS = ("ssm", "conv")
 # Keys one span of an attention layer's softmax covers (whole pages): a
 # tick gathers a span's pages for every row of the call; a chunk scores

@@ -55,7 +55,9 @@ from ray_tpu.models.decode import _rope_at, _swiglu
 from ray_tpu.models.gpt import _rmsnorm
 
 ATTN, LIN = "minicpm4", "lightning-attn"
-# what of `init_paged_cache` is state per decode row (engine.stats())
+# what of `init_paged_cache` is the pool (a page's bytes are these
+# arrays' together) and what is state per decode row (engine.stats())
+PAGE_KEYS = ("k", "v", "kc")
 ROW_STATE_KEYS = ("state",)
 _HI = lax.Precision.HIGHEST
 _DENSE_SPAN_KEYS = 4096      # keys one softmax part of a dense chunk spans

@@ -435,6 +435,11 @@ class EngineStats:
     # attend: `resident` over it is the share of its context a row still
     # holds (1 unless some layer forgets: a window layer's ring).
     attn_keys_context: int = 0
+    # ...and `gathered` and `resident` again in the PAGED layers alone,
+    # for a model some of whose layers keep a ring a row instead (its
+    # `attn_keys_paged`; 0 for every other model).
+    attn_keys_gathered_paged: int = 0
+    attn_keys_resident_paged: int = 0
     prefill_tokens: int = 0           # prompt tokens run through prefill
     prefill_pad_tokens: int = 0       # ...and the columns those chunks
     #                                   computed that held no prompt token
@@ -444,6 +449,15 @@ class EngineStats:
     # model names in ROW_STATE_KEYS): resident whatever the traffic, and
     # in no page count.  0 for a model whose pages are all its state.
     row_state_bytes: int = 0
+    # Bytes of the page pool (without the trash page): `kv_pages` x what
+    # the cache's pool arrays hold of one page, whatever their shapes.
+    kv_pool_bytes: int = 0
+    # A model whose window softmaxes have a sink counts on the device
+    # too: the share of its softmax each sink took, summed over ticks'
+    # live rows, chunks' real tokens, heads and window layers, and the
+    # softmaxes that sums.  Their ratio is the mean share a sink takes.
+    attn_sink_mass: float = 0.0
+    attn_sink_softmaxes: int = 0
     # A model that routes tokens to experts counts on the device, inside
     # its cache (its `read_counters`): (token, expert) pairs routed and
     # those whose expert this replica holds, summed over live rows,
@@ -797,16 +811,24 @@ class GenerationEngine:
             digest_depth=_cfg.serve_affinity_digest_depth)
             if enable_prefix_cache else None)
         # --- KV tier hierarchy (T0 pool / T1 host arena / T2 store) ---
+        # A page's bytes are what the cache's pool arrays hold of it
+        # (the dense pool's k and v; a model that declares its own cache
+        # names them in PAGE_KEYS): no config is asked for a head count
+        # or a head width, which a model may have two of.
+        self._page_nbytes = sum(
+            int(self._cache[k].nbytes)
+            for k in getattr(self._model, "PAGE_KEYS", ("k", "v"))
+        ) // (self.kv_pages + 1)
         # One page's at-rest frame: K then V bytes of [L, psz, Hkv, Dh].
-        self._page_dtype = np.dtype(cfg.dtype)
-        # (Of the dense pool only: a model that declares its own cache
+        # Of the dense pool only: a model that declares its own cache
         # has no frame yet, and every path that would build one refuses
-        # it by name.)
-        self._page_kshape = (cfg.n_layers, self.page_size,
-                             decode._kv_heads(cfg), cfg.head_dim)
-        self._page_k_nbytes = (int(np.prod(self._page_kshape))
-                               * self._page_dtype.itemsize)
-        self._page_nbytes = 2 * self._page_k_nbytes
+        # it by name.
+        self._page_dtype = np.dtype(cfg.dtype)
+        self._page_kshape = self._page_k_nbytes = None
+        if self._model is None:
+            self._page_kshape = (cfg.n_layers, self.page_size,
+                                 decode._kv_heads(cfg), cfg.head_dim)
+            self._page_k_nbytes = self._page_nbytes // 2
         self._tiering = bool(_cfg.serve_kv_tiering
                              if kv_tiering is None else kv_tiering) \
             and enable_prefix_cache and decode.pages_are_kv(cfg)
@@ -897,6 +919,8 @@ class GenerationEngine:
         self._keys_resident = 0
         self._keys_gathered = 0
         self._keys_context = 0
+        self._keys_gathered_paged = 0
+        self._keys_resident_paged = 0
         self._prefill_tokens = 0
         self._prefill_pad_tokens = 0
         self._prefill_tokens_sparse = 0
@@ -1804,12 +1828,18 @@ class GenerationEngine:
             attn_keys_resident=self._keys_resident,
             attn_keys_gathered=self._keys_gathered,
             attn_keys_context=self._keys_context,
+            attn_keys_gathered_paged=self._keys_gathered_paged,
+            attn_keys_resident_paged=self._keys_resident_paged,
             prefill_tokens=self._prefill_tokens,
             prefill_pad_tokens=self._prefill_pad_tokens,
             prefill_tokens_sparse=self._prefill_tokens_sparse,
             state_resets=self._state_resets,
             row_state_bytes=self._row_state_bytes,
-            **{"moe_" + k: v for k, v in self._model_counters.items()})
+            kv_pool_bytes=self.kv_pages * self._page_nbytes,
+            # (a counter of another layer than the experts carries its
+            # own prefix)
+            **{k if k.startswith("attn_") else "moe_" + k: v
+               for k, v in self._model_counters.items()})
 
     # ------------------------------------------------------------------
     # Worker thread
@@ -2458,6 +2488,14 @@ class GenerationEngine:
         self._keys_attended += read
         self._keys_resident += held
         self._keys_gathered += gathered
+        # (a model whose layers are not all paged says the same two of
+        # the layers that are, so that rings do not dilute the ratio)
+        paged = getattr(self._model, "attn_keys_paged", None)
+        if paged is not None:
+            gathered, held = paged(self.cfg, pos, self._pos,
+                                   self.page_size, self._max_blocks)
+            self._keys_gathered_paged += gathered
+            self._keys_resident_paged += held
         # (a model that mixes in layers of another kind says how many
         # attend: `n_attn`)
         self._keys_context += (int(pos.sum()) + len(actives)) \

@@ -203,6 +203,39 @@ def test_prefill_chunks_then_ticks_are_one_reference_forward(
     assert 0 < counts["experts_touched"] <= counts["experts_held"]
 
 
+# sha256 over the float32 and bfloat16 logits, rings and pages of the
+# four CASES as the program stood at PR 49 (commit ff20a51), taken on
+# that commit's own code and again on PR 51's, on this container's CPU.
+PR49_DIGEST = "c012394a01a5b4f70dcf6d0ff6614fe9425986d64c670fc6540989638ee4496a"
+# ...and two of those logits, for a machine whose CPU rounds otherwise
+PR49_SAMPLE = (520.5582275390625, 216.810302734375)
+
+
+def test_the_generalised_functions_leave_kexaone_bit_equal(arch):
+    """PR 51 taught this module's attention a second width, a head count
+    a kind, partial RoPE, a value scale and a sink, for
+    models/mimo_v2_flash.py.  K-EXAONE runs the defaults, and its
+    numbers are what they were: every logit, ring entry and page of the
+    four cases, in float32 and in bfloat16, to the bit."""
+    import hashlib
+    cfg = arch.build(C, 128, remat=False)
+    digest, sums = hashlib.sha256(), []
+    for dtype in (jnp.float32, jnp.bfloat16):
+        params = _bumped(arch.init(cfg, jax.random.PRNGKey(7), dtype))
+        typed = em.ExaoneMoeConfig(**{**cfg.__dict__, "dtype": dtype})
+        for case, (psz, chunk, n_prompt, n_decode) in CASES.items():
+            toks = _tokens(n_prompt + n_decode, seed=len(case))
+            drv = Driver(typed, params, psz, chunk)
+            got = _one_sequence(drv, 1, toks, n_prompt)
+            for a in (got, drv.cache["wk"], drv.cache["k"]):
+                digest.update(np.asarray(a, np.float32).tobytes())
+            sums.append(float(np.abs(got).sum()))
+    if digest.hexdigest() != PR49_DIGEST:
+        np.testing.assert_allclose(sums[-2:], PR49_SAMPLE, rtol=1e-3)
+        pytest.skip("this CPU rounds otherwise than the one the digest "
+                    "was taken on; the numbers agree")
+
+
 def test_a_model_of_window_layers_alone_holds_no_page(arch, reference):
     """Every layer a window layer: the pool has no layer at all, and
     logits 60 tokens in are still the reference's, so a window layer

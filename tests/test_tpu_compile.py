@@ -385,6 +385,79 @@ def test_kexaone_paged_step_compiles(chip, step, monkeypatch):
     assert not wide, wide[:4]
 
 
+# The seventh configuration (benchmarks/configs/mimo-v2-flash-ep16-d7.json):
+# two full layers that page 4 heads of 192-wide keys and 128-wide values
+# beside five window layers whose rings hold 8, a sink in every window
+# softmax, and an expert layer that holds 16 of 256 experts, built as the
+# benchmark builds it, at its sizes (K-EXAONE's functions: what they
+# learned must still compile for the chip at two widths).
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_mimo_paged_step_compiles(chip, step, monkeypatch):
+    """Both programs of mimo-v2-flash-ep16-d7 with the grouped matmul as
+    the chip runs it: 6.86 GB of weights, the TWO full layers' pool (k
+    and v of different widths) and 0.21 GB of rings are resident, a step
+    holds under 0.5 GiB beside them, none of the four cache arrays is
+    re-laid or copied, and no array is as wide as the table."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import deepseek_v2
+    monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs",
+                           "mimo-v2-flash-ep16-d7.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+    # (a token's heads side by side: an array that ends in [4, 192] is
+    # given a layout with the pages innermost and re-laid every step)
+    assert cache["k"].shape[0] == 2 and cache["k"].shape[3:] == (4 * 192,)
+    assert cache["v"].shape[3:] == (4 * 128,)
+    assert cache["wk"].shape == (5, rows, 128, 8 * 192)
+    assert cache["wv"].shape == (5, rows, 128, 8 * 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if step == "decode_tick":
+        lowered = engine._paged_tick.lower(
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
+            with_logits=False)
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 29, mem.temp_size_in_bytes / 2**30
+    # weights 6.40 GiB + the full layers' pool + rings 0.20
+    held = sum(cache[k].size * 2 for k in ("k", "v", "wk", "wv")) / 2**30
+    assert 6.35 + held < mem.argument_size_in_bytes / 2**30 < 6.5 + held
+    text = compiled.as_text()
+    # three grouped matmuls an expert layer
+    assert text.count("tpu_custom_call") >= 3 * cfg.n_moe
+    for name in ("k", "v", "wk", "wv"):
+        held = "bf16[%s]" % ",".join(map(str, cache[name].shape))
+        layouts = set(re.findall(re.escape(held) + r"\{([\d,]+)", text))
+        assert layouts == {"3,2,1,0"}, (name, layouts)     # never re-laid
+        moved = [ln for ln in text.splitlines()
+                 if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
+        assert not moved, moved[:4]
+    # nothing is as wide as the table (27,648 columns, 432 blocks of
+    # pages but for the block tables themselves)
+    shapes = [s.split(",") for s in re.findall(r" = \w+\[([\d,]+)\]", text)]
+    wide = [s for s in shapes if str(blocks * e["page_size"]) in s]
+    assert not wide, wide[:4]
+
+
 # The sixth configuration (benchmarks/configs/jamba2-3b.json): 26 Mamba
 # layers that hold a float32 scan state and a convolution tail a decode
 # row beside two attention layers that page one key-value head, built as
