@@ -39,8 +39,11 @@ A tick reads, in a window layer, the row's ring and nothing else (W keys
 a row); a prefill chunk of T tokens reads the W ring entries before it
 (the oldest is out of every query's window: W - 1 visible) beside its
 own T keys, in blocks of W queries against 2 W keys, and leaves its last
-W real tokens in the ring.  A global layer walks the row's pages in
-spans, as the dense body of models/decode.py does.
+W real tokens in the ring.  A global layer's chunk walks the row's pages
+in spans, as the dense body of models/decode.py does; its tick reads, on
+a TPU, each row's own pages through the kernel of ops/paged_attention.py
+and, where there is none, spans to the deepest row's depth for every row
+(`_span_tick`).
 
 The expert layer is told which experts it holds (`experts_held`,
 `expert_offset`): the router scores ALL `n_routed_experts` with a
@@ -69,11 +72,14 @@ from jax import lax
 
 from ray_tpu.models import deepseek_v2 as _ds
 from ray_tpu.models.decode import _rope_at, _swiglu
+from ray_tpu.ops import paged_attention as _pa
 
 _HI = lax.Precision.HIGHEST
 # Keys one span of a GLOBAL layer's attention covers (whole pages).  A
-# tick gathers a span's pages for every row of the call; a chunk scores
-# all its queries against a span in float32, [heads, queries, keys].
+# tick WITHOUT A TPU gathers a span's pages for every row of the call
+# (on one, `ops/paged_attention.py` walks each row's own pages); a chunk
+# scores all its queries against a span in float32, [heads, queries,
+# keys].
 _TICK_SPAN_KEYS = 256
 _CHUNK_SPAN_KEYS = 256
 
@@ -207,6 +213,10 @@ class ExaoneMoeConfig:
     row_state = True      # the rings: state per decode row, not paged
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _kind_index(cfg: ExaoneMoeConfig, l: int) -> Tuple[bool, int]:
     """(is layer `l` a window layer, its index among layers of its
     kind)."""
@@ -231,20 +241,23 @@ def attn_keys_paged(cfg: ExaoneMoeConfig, pos: np.ndarray,
                     ) -> Tuple[int, int]:
     """(keys gathered, keys held) in the GLOBAL layers alone by one tick
     whose active rows stand at `pos`: the layers whose keys live in
-    pages.  Gathered: for EVERY row of the call (`all_pos` of all decode
-    rows, idle ones at 0), whole spans up to the deepest row's token,
-    the trip count the program reads from the positions."""
+    pages.  Gathered, for EVERY row of the call (`all_pos` of all decode
+    rows, idle ones at 0): on a TPU each row's own blocks of pages, what
+    the kernel copies; elsewhere whole spans up to the deepest row's
+    token, the trip count the span loop reads from the positions."""
+    held = int((np.asarray(pos, np.int64) + 1).sum()) * cfg.n_global
+    if _on_tpu():
+        return _pa.keys_copied(all_pos, page_size, nblk) * cfg.n_global, held
     cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
     spans = -(-(int(np.asarray(all_pos).max()) + 1) // cols)
-    held = int((np.asarray(pos, np.int64) + 1).sum())
-    return len(all_pos) * spans * cols * cfg.n_global, held * cfg.n_global
+    return len(all_pos) * spans * cols * cfg.n_global, held
 
 
 def attn_keys_gathered(cfg: ExaoneMoeConfig, pos: np.ndarray,
                        page_size: int, nblk: int) -> int:
     """Keys one tick pulls from the cache (`pos` of all decode rows): in
-    a global layer whole spans (attn_keys_paged); in a window layer
-    every row's whole ring."""
+    a global layer what attn_keys_paged says; in a window layer every
+    row's whole ring."""
     return attn_keys_paged(cfg, pos, pos, page_size, nblk)[0] \
         + len(pos) * cfg.window * cfg.n_window
 
@@ -498,8 +511,6 @@ def _global_tick(lp, x, i, cache, bt, pos, kind: AttnKind, cfg):
     B = x.shape[0]
     G, Dh, Dv = kind.n_kv_heads, kind.head_dim, kind.v_head_dim
     psz = cache["k"].shape[2]
-    R = cfg.n_heads // G
-    dt = cfg.dtype
     q, k, v = _project(lp, x, pos, kind, cfg)
     page = jnp.take_along_axis(bt, (pos // psz)[:, None], axis=1)[:, 0]
     ck = cache["k"].at[i, page, pos % psz].set(
@@ -508,44 +519,61 @@ def _global_tick(lp, x, i, cache, bt, pos, kind: AttnKind, cfg):
         v.reshape((B,) + _kept(kind, Dv)))
 
     with jax.named_scope("attn_global"):
-        nblk = bt.shape[1]
-        span = _span_pages(_TICK_SPAN_KEYS, psz, nblk)
-        width = span * psz
-        qg = q.reshape(B, G, R, Dh)
-        kept = _kept(kind, Dh)
-        if kind.flat:
-            # Keys whose heads lie side by side are scored as they lie:
-            # a head's query stands in its own head's lanes of a row as
-            # wide as all of them, zeros elsewhere.  G x the multiplies,
-            # in a call the gathered bytes bound; re-laying every
-            # gathered span to [G, Dh] instead made a 64-row tick 37.5
-            # ms, not 25.2 (PERF.md section 6, PR 51).
-            qg = jnp.einsum("bgrd,gh->bgrhd", qg, jnp.eye(G, dtype=dt)
-                            ).reshape((B, G, R) + kept)
-        scores = "bgrd,bsd->bgrs" if kind.flat else "bgrd,bsgd->bgrs"
-
-        def attend(j, part):
-            first = jnp.minimum(j * span, nblk - span)
-            pg = lax.dynamic_slice(bt, (0, first), (B, span))
-            ks = ck[i, pg].reshape((B, width) + kept)
-            vs = cv[i, pg].reshape(B, width, G, Dv)
-            s = jnp.einsum(scores, qg, ks,
-                           preferred_element_type=jnp.float32) * Dh ** -0.5
-            kcols = first * psz + jnp.arange(width)
-            seen = (kcols[None, :] <= pos[:, None]) \
-                & (kcols[None, :] >= j * width)
-            s = jnp.where(seen[:, None, None], s, -jnp.inf)
-            return _merge(part, s, lambda e: jnp.einsum(
-                "bgrs,bsgd->bgrd", e.astype(dt), vs,
-                preferred_element_type=jnp.float32))
-
-        stat = jnp.full((B, G, R), -jnp.inf, jnp.float32)
-        _, total, acc = lax.fori_loop(
-            0, (jnp.max(pos) + width) // width, attend,
-            (stat, jnp.zeros_like(stat),
-             jnp.zeros((B, G, R, Dv), jnp.float32)))
-        out = (acc / total[..., None]).astype(dt).reshape(B, G * R, Dv)
+        if _on_tpu():
+            out = _pa.paged_attention(q, ck, cv, i, bt, pos, n_kv_heads=G)
+        else:
+            out = _span_tick(q, ck, cv, i, bt, pos, kind)
     return _close(lp, x, out, cfg), dict(cache, k=ck, v=cv)
+
+
+def _span_tick(q, ck, cv, i, bt, pos, kind: AttnKind):
+    """The tick's attention over the pages of layer `i` of the pools ck,
+    cv [n, P, page, ...] where there is no TPU, and what the kernel is
+    held equal to: a loop whose trip count is the DEEPEST row's depth
+    gathers, for every row of the call, a span of `_TICK_SPAN_KEYS` keys
+    a trip and masks what a shallower row does not hold.  q [B, H, Dh]
+    -> [B, H, Dv]."""
+    B, H, _ = q.shape
+    G, Dh, Dv = kind.n_kv_heads, kind.head_dim, kind.v_head_dim
+    R = H // G
+    psz, nblk = ck.shape[2], bt.shape[1]
+    dt = q.dtype
+    span = _span_pages(_TICK_SPAN_KEYS, psz, nblk)
+    width = span * psz
+    kept = _kept(kind, Dh)
+    if kind.flat:
+        # Keys whose heads lie side by side are scored as they lie: a
+        # head's query stands in its own head's lanes of a row as wide
+        # as all of them, zeros elsewhere.  G x the multiplies, in a
+        # call the gathered bytes bound; re-laying every gathered span
+        # to [G, Dh] instead made a 64-row tick 37.5 ms, not 25.2
+        # (PERF.md section 6, PR 51).
+        qg = _pa.widen(q, G).reshape((B, G, R) + kept)
+    else:
+        qg = q.reshape(B, G, R, Dh)
+    scores = "bgrd,bsd->bgrs" if kind.flat else "bgrd,bsgd->bgrs"
+
+    def attend(j, part):
+        first = jnp.minimum(j * span, nblk - span)
+        pg = lax.dynamic_slice(bt, (0, first), (B, span))
+        ks = ck[i, pg].reshape((B, width) + kept)
+        vs = cv[i, pg].reshape(B, width, G, Dv)
+        s = jnp.einsum(scores, qg, ks,
+                       preferred_element_type=jnp.float32) * Dh ** -0.5
+        kcols = first * psz + jnp.arange(width)
+        seen = (kcols[None, :] <= pos[:, None]) \
+            & (kcols[None, :] >= j * width)
+        s = jnp.where(seen[:, None, None], s, -jnp.inf)
+        return _merge(part, s, lambda e: jnp.einsum(
+            "bgrs,bsgd->bgrd", e.astype(dt), vs,
+            preferred_element_type=jnp.float32))
+
+    stat = jnp.full((B, G, R), -jnp.inf, jnp.float32)
+    _, total, acc = lax.fori_loop(
+        0, (jnp.max(pos) + width) // width, attend,
+        (stat, jnp.zeros_like(stat),
+         jnp.zeros((B, G, R, Dv), jnp.float32)))
+    return (acc / total[..., None]).astype(dt).reshape(B, H, Dv)
 
 
 def _window_attend(qg, k, v, qpos, kpos, W, dt, sink=None):

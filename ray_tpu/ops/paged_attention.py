@@ -1,0 +1,233 @@
+"""A decode tick's attention over pages, ragged: each row of the call
+reads ITS OWN pages up to ITS OWN position, whatever the deepest row of
+the call holds (`paged_attention`).
+
+The pool stays as the model keeps it, [layers, P, page, ...], in HBM: the
+kernel is handed the whole of it and the layer's index as a prefetched
+scalar (as `ops/ssm.step_pallas` is handed every layer's states), so no
+layer is sliced out and nothing is copied or re-laid.  One instance of
+the kernel is one row.  It walks the row's block-table entries (a
+prefetched scalar array, as its position is) in blocks of `_BLOCK_KEYS`
+keys, copies each page of keys and of values of a block from HBM into a
+VMEM buffer with one DMA a page, two blocks in flight (the next block's
+copies, or the next ROW's first, are started before this block's are
+waited for), and keeps a running softmax (maximum, sum, float32
+accumulator) in VMEM.  A row costs its own blocks and nothing for the
+table's width: the walk's trip count is read from the row's position.
+
+A token's heads reach the kernel in one of the two forms the models
+keep them in (`models/exaone_moe._kept`), told apart by the pool's
+shape:
+
+  [P, page, G, Dh]   heads in ROWS (K-EXAONE's [8, 128]): a page is read
+                     as [page x G, Dh], one row a (token, head), which
+                     is the array as it lies.  Every query head is
+                     scored against every row, and a row of another
+                     key-value head is masked: G x the multiplies, no
+                     slice across sublanes
+  [P, page, G x Dh]  heads side by side in the LANES (MiMo's 4 x 192,
+                     values 4 x 128): a page is read as [page, G x Dh].
+                     A query stands in its own head's lanes of a row as
+                     wide as all of them, zeros elsewhere (the caller
+                     lays it so: `widen`), and the weighted sum is taken
+                     of the whole value row, of which a head keeps its
+                     own head's lanes: again G x the multiplies, and no
+                     re-laying of a 192-wide head
+
+so both are one algorithm, Q [H, Wk] against K [n, Wk] under a mask and
+P [H, n] against V [n, Wv], whose tile shapes follow the head widths.
+The bytes bound the call in both (PERF.md section 6, PR 52).
+
+A position past the row's own is masked, so what a page holds behind
+the row's last token, and what the trash page holds, is never seen.  An
+idle row (position 0, its table on the trash page) reads one block.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Keys one block of the walk covers (whole pages): what one trip of a
+# row's loop copies, scores and merges.  Measured on a v5e (PERF.md
+# section 6, PR 52): K-EXAONE's 128 rows (4 KB a token) read 2.53 ms a
+# layer at 256 and 320, 2.54 at 384, 2.60 at 512, 3.03 at 128; MiMo's 64
+# rows (2.5 KB a token) 1.27 at 384-512, 1.34 at 320, 1.42 at 256, 2.01
+# at 128.
+_BLOCK_KEYS = 384
+
+
+def block_pages(page_size: int, nblk: int) -> int:
+    """Whole pages one block of the walk covers."""
+    return max(1, min(_BLOCK_KEYS // page_size, nblk))
+
+
+def keys_copied(pos, page_size: int, nblk: int) -> int:
+    """Keys the kernel copies from one layer's pool for rows at `pos`
+    (every row of the call, idle ones at 0): each row's own blocks, the
+    last one whole."""
+    width = block_pages(page_size, nblk) * page_size
+    return int((np.asarray(pos, np.int64) // width + 1).sum()) * width
+
+
+def widen(q, n_kv_heads: int):
+    """q [B, H, Dh] -> [B, H, G x Dh]: each head's query in its own
+    key-value head's lanes, zeros elsewhere: what scores keys whose
+    heads lie side by side as they lie."""
+    B, H, Dh = q.shape
+    G = n_kv_heads
+    wide = jnp.einsum("bgrd,gh->bgrhd", q.reshape(B, G, H // G, Dh),
+                      jnp.eye(G, dtype=q.dtype))
+    return wide.reshape(B, H, G * Dh)
+
+
+def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, v_hbm,
+            o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref, *,
+            pages: int, nblk: int, page_size: int, in_rows: int,
+            group: int, scale: float):
+    b = pl.program_id(0)
+    rows = pl.num_programs(0)
+    layer = layer_ref[0]
+    per = kbuf.shape[1] // pages          # rows of the buffer a page fills
+    width = pages * page_size             # keys a block
+    H, Dv = o_ref.shape
+    pos = pos_ref[b]
+    trips = pos // width + 1
+
+    def copies(row, j, slot):
+        """The DMAs of block `j` of `row` into buffer `slot`."""
+        out = []
+        for p in range(pages):
+            # (past the table's end: any page; every key of it is masked)
+            at = jnp.minimum(j * pages + p, nblk - 1)
+            page = bt_ref[row * nblk + at]
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[layer, page], kbuf.at[slot, pl.ds(p * per, per)],
+                sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[layer, page], vbuf.at[slot, pl.ds(p * per, per)],
+                sems.at[1, slot]))
+        return out
+
+    def start(row, j, slot):
+        for c in copies(row, j, slot):
+            c.start()
+
+    @pl.when(b == 0)
+    def _():
+        start(0, 0, 0)
+
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[...]                                           # [H, Wk]
+    n = kbuf.shape[1]
+    col = lax.broadcasted_iota(jnp.int32, (H, n), 1)
+    if in_rows > 1:
+        # a row of the buffer is a (token, key-value head)
+        own = col % in_rows == lax.broadcasted_iota(
+            jnp.int32, (H, n), 0) // group
+        col = col // in_rows
+    else:
+        own = None
+
+    def block(j, carry):
+        slot = (first_ref[b] + j) % 2
+
+        @pl.when(j + 1 < trips)
+        def _():
+            start(b, j + 1, 1 - slot)
+
+        @pl.when((j + 1 == trips) & (b + 1 < rows))
+        def _():
+            start(b + 1, 0, 1 - slot)
+
+        for c in copies(b, j, slot):
+            c.wait()
+        s = lax.dot_general(q, kbuf[slot], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        seen = j * width + col <= pos
+        if own is not None:
+            seen &= own
+        s = jnp.where(seen, s, -jnp.inf)
+        top = jnp.maximum(m_ref[...], s.max(-1, keepdims=True))
+        old = jnp.exp(m_ref[...] - top)
+        e = jnp.exp(s - top)
+        m_ref[...] = top
+        l_ref[...] = old * l_ref[...] + e.sum(-1, keepdims=True)
+        acc_ref[...] = old * acc_ref[...] + jnp.dot(
+            e.astype(vbuf.dtype), vbuf[slot],
+            preferred_element_type=jnp.float32)
+        return carry
+
+    lax.fori_loop(0, trips, block, 0)
+    if acc_ref.shape[1] == Dv:
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+    else:
+        # of the whole value row a head keeps its own head's lanes
+        for g in range(H // group):
+            at = slice(g * group, (g + 1) * group)
+            o_ref[at, :] = (acc_ref[at, g * Dv:(g + 1) * Dv]
+                            / l_ref[at, :]).astype(o_ref.dtype)
+
+
+def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
+                    n_kv_heads: int, interpret: bool = False):
+    """q [B, H, Dh] (one token a row) over the pages of layer `layer` of
+    k_pool [L, P, page, G, Dh] / v_pool [L, P, page, G, Dv], or the same
+    with a token's heads side by side ([L, P, page, G x Dh]): row b
+    attends to positions 0..pos[b] of the pages block_tables[b] names.
+    Scores are scaled by Dh ** -0.5; matmul inputs are the pool's dtype,
+    the softmax and the accumulator float32.  Returns [B, H, Dv] in q's
+    dtype."""
+    B, H, Dh = q.shape
+    G = n_kv_heads
+    L, P, psz = k_pool.shape[:3]
+    nblk = block_tables.shape[1]
+    pages = block_pages(psz, nblk)
+    if k_pool.ndim == 5:
+        # (token, head) rows: the pool as it lies (a merge of the two
+        # dimensions above a head's lanes moves nothing)
+        in_rows, Dv = G, v_pool.shape[-1]
+        k_pool = k_pool.reshape(L, P, psz * G, Dh)
+        v_pool = v_pool.reshape(L, P, psz * G, Dv)
+    else:
+        in_rows, Dv = 1, v_pool.shape[-1] // G
+        q = widen(q, G)
+    per, Wk, Wv = k_pool.shape[2], k_pool.shape[3], v_pool.shape[3]
+    pos = pos.astype(jnp.int32)
+    trips = pos // (pages * psz) + 1
+    # the buffer a row's first block lands in: blocks alternate between
+    # the two through the whole call
+    first = (jnp.cumsum(trips) - trips) % 2
+    row = lambda w: pl.BlockSpec((None, H, w),  # noqa: E731
+                                 lambda b, *_: (b, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_kernel, pages=pages, nblk=nblk, page_size=psz,
+                          in_rows=in_rows, group=H // G, scale=Dh ** -0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B,),
+            in_specs=[row(Wk), pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=row(Dv),
+            scratch_shapes=[pltpu.VMEM((2, pages * per, Wk), k_pool.dtype),
+                            pltpu.VMEM((2, pages * per, Wv), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, Wv), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        name="paged_attention",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos,
+      first.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),
+      q, k_pool, v_pool)

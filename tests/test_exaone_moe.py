@@ -177,7 +177,7 @@ CASES = {
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_prefill_chunks_then_ticks_are_one_reference_forward(
-        model, reference, case):
+        model, reference, case, tick_attention):
     """A prompt longer than two windows and two pages in several chunks,
     then ticks that wrap the rings: every position's logits against the
     reference's full-mask forward, so a chunk reads what earlier chunks

@@ -189,7 +189,7 @@ CASES = {
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_prefill_chunks_then_ticks_are_one_reference_forward(
-        model, reference, case):
+        model, reference, case, tick_attention):
     """Every position's logits against the reference's full-mask
     forward: a chunk reads what earlier chunks left in the rings (8
     heads) and in the pages (2 heads, values narrower than keys), a

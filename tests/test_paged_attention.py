@@ -1,0 +1,171 @@
+"""`ops/paged_attention.py`, the ragged kernel of a decode tick's paged
+attention layers, interpreted on the CPU: against the span loop of
+`models/exaone_moe.py` (what runs where there is no TPU) and against a
+plain float32 softmax over each row's own keys, in both forms a model
+keeps a token's heads in; what it reads of the pool and what it never
+touches; and the counters that say what it copies."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import exaone_moe as em
+from ray_tpu.ops import paged_attention as pa
+
+PAGE, NBLK, LAYERS, LAYER = 16, 12, 2, 1
+BLOCK = 2 * PAGE                      # keys a block of the walk, here
+# heads, key-value heads, key width, value width, heads side by side
+KINDS = {"heads-in-rows": (8, 2, 128, 128, False),    # K-EXAONE's [G, 128]
+         "heads-in-lanes": (8, 2, 192, 128, True)}    # MiMo's G x 192, G x 128
+# positions of the call's rows (an idle row stands at 0 on the trash
+# page, 0, whatever else the call holds)
+CASES = {
+    "unequal-depths": [0, 17, 150, 40, 100],
+    "mid-page": [PAGE * 3 + 5, 7],
+    "last-block-of-the-table": [PAGE * NBLK - 1, 3],
+    "idle-row-on-the-trash-page": [0, 0, 61],
+    "one-row-alone": [77],
+    "block-edges": [BLOCK - 1, BLOCK, 2 * BLOCK - 1, 2 * BLOCK],
+}
+
+
+def _state(kind, pos, seed=0, dtype=jnp.bfloat16):
+    """(q, k pool, v pool, block tables, positions): every row on pages
+    of its own in a drawn order, an idle row's table on page 0."""
+    H, G, Dh, Dv, flat = KINDS[kind]
+    akind = em.AttnKind(G, Dh, Dv, flat=flat)
+    rng = np.random.default_rng(seed)
+    B = len(pos)
+    P = B * NBLK + 1
+    k = jnp.asarray(rng.normal(size=(LAYERS, P, PAGE) + em._kept(akind, Dh)),
+                    dtype)
+    v = jnp.asarray(rng.normal(size=(LAYERS, P, PAGE) + em._kept(akind, Dv)),
+                    dtype)
+    q = jnp.asarray(rng.normal(size=(B, H, Dh)), dtype)
+    bt = 1 + rng.permutation(B * NBLK).reshape(B, NBLK).astype(np.int32)
+    pos = np.asarray(pos, np.int32)
+    bt[pos == 0] = 0
+    return akind, q, k, v, bt, pos
+
+
+def _plain(akind, q, k, v, bt, pos):
+    """A float32 softmax over each row's own keys, row by row."""
+    G, Dh, Dv = akind.n_kv_heads, akind.head_dim, akind.v_head_dim
+    H = q.shape[1]
+    out = []
+    for b, p in enumerate(pos):
+        n = int(p) + 1
+        pages = bt[b, :-(-n // PAGE)]
+        keys = np.asarray(k[LAYER, pages], np.float32).reshape(-1, G, Dh)[:n]
+        vals = np.asarray(v[LAYER, pages], np.float32).reshape(-1, G, Dv)[:n]
+        s = np.einsum("grd,sgd->grs", np.asarray(q[b], np.float32)
+                      .reshape(G, H // G, Dh), keys) * Dh ** -0.5
+        e = np.exp(s - s.max(-1, keepdims=True))
+        out.append(np.einsum("grs,sgd->grd", e / e.sum(-1, keepdims=True),
+                             vals).reshape(H, Dv))
+    return np.stack(out)
+
+
+def _kernel(akind, q, k, v, bt, pos):
+    return np.asarray(jax.jit(
+        lambda *a: pa.paged_attention(*a, n_kv_heads=akind.n_kv_heads,
+                                      interpret=True))(
+        q, k, v, jnp.int32(LAYER), jnp.asarray(bt), jnp.asarray(pos)),
+        np.float32)
+
+
+@pytest.fixture(autouse=True)
+def small_blocks(monkeypatch):
+    monkeypatch.setattr(pa, "_BLOCK_KEYS", BLOCK)
+    monkeypatch.setattr(em, "_TICK_SPAN_KEYS", 3 * PAGE)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_the_kernel_is_the_span_loop_and_a_plain_softmax(kind, case):
+    """Rows of unequal depth, a depth that ends inside a page, a row at
+    the table's last block, an idle row on the trash page, one row
+    alone, depths on both sides of a block's edge: each row's output is
+    a softmax over ITS keys, in bfloat16 as the chip holds them and in
+    float32."""
+    state = _state(kind, CASES[case], seed=len(case))
+    akind, q, k, v, bt, pos = state
+    got = _kernel(*state)
+    span = np.asarray(em._span_tick(q, k, v, LAYER, jnp.asarray(bt),
+                                    jnp.asarray(pos), akind), np.float32)
+    # (bfloat16 weights into the weighted sum and a bfloat16 result:
+    # one block's rounding is not another span's)
+    np.testing.assert_allclose(got, span, atol=2e-2)
+    np.testing.assert_allclose(got, _plain(*state), atol=2e-2)
+    exact = _state(kind, CASES[case], seed=len(case), dtype=jnp.float32)
+    np.testing.assert_allclose(_kernel(*exact), _plain(*exact), atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_row_reads_its_own_blocks_and_nothing_past_its_position(kind):
+    """What the walk visits: not-a-number in every page past a row's
+    last BLOCK (and in every page no table names) changes nothing, so
+    none of it is copied; other keys in the row's own last block, past
+    its position, are copied and masked; a key at or before its
+    position is read."""
+    state = _state(kind, CASES["unequal-depths"], seed=3)
+    akind, q, k, v, bt, pos = state
+    want = _kernel(*state)
+    blocks = pos // BLOCK + 1
+    own = np.zeros(k.shape[1], bool)         # pages some row's walk visits
+    for b in range(len(pos)):
+        own[bt[b, :blocks[b] * BLOCK // PAGE]] = True
+    poison = lambda a: a.at[:, ~own].set(jnp.nan)  # noqa: E731
+    np.testing.assert_array_equal(
+        _kernel(akind, q, poison(k), poison(v), bt, pos), want)
+    # past the position, inside the last block: masked, whatever is there
+    row = 1                                   # position 17: page 1, slot 1
+    page = int(bt[row, pos[row] // PAGE])
+    after = (slice(None), page, slice(int(pos[row]) % PAGE + 1, None))
+    np.testing.assert_array_equal(
+        _kernel(akind, q, k.at[after].set(60.0), v.at[after].set(-60.0),
+                bt, pos), want)
+    # the position itself is read
+    at = (LAYER, page, int(pos[row]) % PAGE)
+    moved = _kernel(akind, q, k, v.at[at].add(4.0), bt, pos)
+    assert np.abs(moved[row] - want[row]).max() > 1e-3
+    np.testing.assert_array_equal(np.delete(moved, row, 0),
+                                  np.delete(want, row, 0))
+    # and only the layer asked for
+    np.testing.assert_array_equal(
+        _kernel(akind, q, k.at[0].set(jnp.nan), v.at[0].set(jnp.nan), bt,
+                pos), want)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_counters_are_what_the_kernel_copies(case, monkeypatch):
+    """`attn_keys_paged` and `attn_keys_gathered` on a TPU: each row's
+    own blocks, the last one whole, in every paged layer: the pages the
+    walk of the test above visits for the same positions (an idle row's
+    one block too); without one, the span loop's spans to the deepest
+    row for every row."""
+    cfg = em.ExaoneMoeConfig(max_seq=PAGE * NBLK, n_layers=8)
+    pos = np.asarray(CASES[case], np.int32)
+    active = pos[pos > 0]
+    held = int((active + 1).sum()) * cfg.n_global
+    cols = 3 * PAGE
+    spans = len(pos) * -(-(int(pos.max()) + 1) // cols) * cols
+    assert em.attn_keys_paged(cfg, active, pos, PAGE, NBLK) == (
+        spans * cfg.n_global, held)
+    monkeypatch.setattr(em, "_on_tpu", lambda: True)
+    visited = sum(len(range(0, int(p) + 1, BLOCK)) for p in pos) * BLOCK
+    assert pa.keys_copied(pos, PAGE, NBLK) == visited
+    assert em.attn_keys_paged(cfg, active, pos, PAGE, NBLK) == (
+        visited * cfg.n_global, held)
+    assert em.attn_keys_gathered(cfg, pos, PAGE, NBLK) == (
+        visited * cfg.n_global + len(pos) * cfg.window * cfg.n_window)
+    # what is pulled for each key held: under one block a row over 1
+    assert visited - int((pos + 1).sum()) <= len(pos) * BLOCK
+
+
+def test_a_block_is_whole_pages_of_the_table():
+    assert pa.block_pages(PAGE, NBLK) == BLOCK // PAGE
+    assert pa.block_pages(PAGE // 2, 432) == 2 * BLOCK // PAGE
+    assert pa.block_pages(4 * BLOCK, NBLK) == 1      # a page over a block
+    assert pa.block_pages(1, 5) == 5                 # a table under one

@@ -89,6 +89,33 @@ def _pool_results(text, pool_shape):
     return per_layer, moved
 
 
+def _ragged_tick_holds(text, cfg, cache, rows, blocks, page_size):
+    """What a compiled tick of `models/exaone_moe.py` holds to on a TPU:
+    one `ops/paged_attention.py` kernel a paged layer beside the expert
+    layers' three grouped matmuls each; the pool reaches the kernel as
+    it lies (K-EXAONE's [page, 8, 128] merged to [page x 8, 128] by a
+    bitcast, never by a copy); and no array is as wide as the table's
+    keys, but for the table itself, which the kernel is handed flat
+    ([rows x blocks] page ids in scalar memory)."""
+    kernels = [ln for ln in text.splitlines()
+               if "custom-call(" in ln and "tpu_custom_call" in ln]
+    ragged = [ln for ln in kernels if " %paged_attention" in ln]
+    assert len(ragged) == cfg.n_global, len(ragged)
+    assert len(kernels) == 3 * cfg.n_moe + cfg.n_global, len(kernels)
+    for pool in (cache["k"], cache["v"]):
+        if pool.ndim != 5:
+            continue                  # heads side by side: handed as it is
+        L, P, psz, G, width = pool.shape
+        merged = " = bf16[%d,%d,%d,%d]" % (L, P, psz * G, width)
+        made = [ln for ln in text.splitlines() if merged in ln]
+        assert made and all(" bitcast(" in ln for ln in made), made[:4]
+    shapes = [(kind, dims.split(",")) for kind, dims in
+              re.findall(r" = (\w+)\[([\d,]+)\]", text)]
+    wide = [s for s in shapes if str(blocks * page_size) in s[1]
+            and s != ("s32", [str(rows * blocks)])]
+    assert not wide, wide[:4]
+
+
 # model, engine rows, blocks a row, pages (the engine's kv_pages + the
 # trash page)
 STEP_MODELS = {"gpt737m": (CFG, gpt, 8, 1024 // 16, 8 * 64 + 1),
@@ -333,8 +360,9 @@ def test_kexaone_paged_step_compiles(chip, step, monkeypatch):
     import os
 
     from benchmarks.lib.registry import arch_of
-    from ray_tpu.models import deepseek_v2
+    from ray_tpu.models import deepseek_v2, exaone_moe
     monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    monkeypatch.setattr(exaone_moe, "_on_tpu", lambda: True)
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks")
     with open(os.path.join(bench, "configs", "k-exaone-ep8-d5.json")) as f:
@@ -378,6 +406,11 @@ def test_kexaone_paged_step_compiles(chip, step, monkeypatch):
         moved = [ln for ln in text.splitlines()
                  if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
         assert not moved, moved[:4]
+    if step == "decode_tick":
+        # 13 MiB (the span loop's gathered spans made it 43)
+        assert mem.temp_size_in_bytes < 1 << 25, mem.temp_size_in_bytes
+        _ragged_tick_holds(text, cfg, cache, rows, blocks, e["page_size"])
+        return
     # nothing is as wide as the table (14,336 columns, 224 blocks of
     # pages but for the block tables themselves)
     shapes = [s.split(",") for s in re.findall(r" = \w+\[([\d,]+)\]", text)]
@@ -402,8 +435,9 @@ def test_mimo_paged_step_compiles(chip, step, monkeypatch):
     import os
 
     from benchmarks.lib.registry import arch_of
-    from ray_tpu.models import deepseek_v2
+    from ray_tpu.models import deepseek_v2, exaone_moe
     monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    monkeypatch.setattr(exaone_moe, "_on_tpu", lambda: True)
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks")
     with open(os.path.join(bench, "configs",
@@ -451,6 +485,12 @@ def test_mimo_paged_step_compiles(chip, step, monkeypatch):
         moved = [ln for ln in text.splitlines()
                  if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
         assert not moved, moved[:4]
+    if step == "decode_tick":
+        # 23 MiB (18 with the span loop: the queries laid into their
+        # heads' lanes, [64, 64, 768], go through HBM once a full layer)
+        assert mem.temp_size_in_bytes < 1 << 25, mem.temp_size_in_bytes
+        _ragged_tick_holds(text, cfg, cache, rows, blocks, e["page_size"])
+        return
     # nothing is as wide as the table (27,648 columns, 432 blocks of
     # pages but for the block tables themselves)
     shapes = [s.split(",") for s in re.findall(r" = \w+\[([\d,]+)\]", text)]
