@@ -71,6 +71,7 @@ from jax.experimental.pallas.ops.tpu.megablox import gmm
 
 from ray_tpu.models.decode import _swiglu
 from ray_tpu.models.gpt import _rmsnorm
+from ray_tpu.ops import paged_attention as _pa
 
 _HI = lax.Precision.HIGHEST
 # Keys one span of attention covers (whole pages), chosen on a v5e at
@@ -225,9 +226,14 @@ def attn_keys(cfg: DeepseekV2Config, pos: np.ndarray) -> Tuple[int, int]:
 
 def attn_keys_gathered(cfg: DeepseekV2Config, pos: np.ndarray,
                        page_size: int, nblk: int) -> int:
-    """Latents one tick pulls from the pool: for EVERY row of the call
-    (`pos` of all decode rows, idle ones at 0) whole spans up to the
-    deepest row's token, the trip count the program reads from `pos`."""
+    """Latents one tick pulls from the pool, for EVERY row of the call
+    (`pos` of all decode rows, idle ones at 0): on a TPU each row's own
+    blocks of pages, what the kernel copies; elsewhere whole spans up to
+    the deepest row's token, the trip count the span loop reads from
+    `pos`."""
+    if _on_tpu():
+        token = _lat_width(cfg) * jnp.dtype(cfg.dtype).itemsize
+        return _pa.keys_copied(pos, page_size, nblk, token) * cfg.n_layers
     cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
     spans = -(-(int(np.asarray(pos).max()) + 1) // cols)
     return len(pos) * spans * cols * cfg.n_layers
@@ -542,46 +548,61 @@ def _attn_chunk(lp, x, l, cache, bt, start, cfg: DeepseekV2Config):
 
 
 def _attn_tick(lp, x, l, cache, bt, pos, cfg: DeepseekV2Config):
-    B = x.shape[0]
-    H, psz, kr = cfg.n_heads, cache["lat"].shape[2], cfg.kv_lora_rank
+    psz, kr = cache["lat"].shape[2], cfg.kv_lora_rank
     dt = cfg.dtype
     q_nope, q_pe, ckv, kpe = _project(lp, x, pos, cfg)
     page = jnp.take_along_axis(bt, (pos // psz)[:, None], axis=1)[:, 0]
     lat = cache["lat"].at[l, page, pos % psz].set(_lat_row(ckv, kpe, cfg))
 
     with jax.named_scope("mla_absorb_attend"):
-        nblk = bt.shape[1]
-        span = _span_pages(_TICK_SPAN_KEYS, psz, nblk)
-        width = span * psz
         # wk_b into the query, wv_b into the output: scores and values
         # are taken against the cached rows themselves
         q_row = _lat_row(jnp.einsum("bhn,hnc->bhc", q_nope,
                                     lp["wk_b"].astype(dt)), q_pe, cfg)
-
-        def attend(i, part):
-            first = jnp.minimum(i * span, nblk - span)
-            pg = lax.dynamic_slice(bt, (0, first), (B, span))
-            rows = lat[l, pg].reshape(B, width, -1)
-            s = jnp.einsum("bhc,bsc->bhs", q_row, rows,
-                           preferred_element_type=jnp.float32) \
-                * cfg.softmax_scale
-            kcols = first * psz + jnp.arange(width)
-            seen = (kcols[None, :] <= pos[:, None]) \
-                & (kcols[None, :] >= i * width)
-            s = jnp.where(seen[:, None], s, -jnp.inf)
-            return _merge(part, s, lambda e: jnp.einsum(
-                "bhs,bsc->bhc", e.astype(dt), rows[..., :kr],
-                preferred_element_type=jnp.float32))
-
-        stat = jnp.full((B, H), -jnp.inf, jnp.float32)
-        _, total, acc = lax.fori_loop(
-            0, (jnp.max(pos) + width) // width, attend,
-            (stat, jnp.zeros_like(stat),
-             jnp.zeros((B, H, kr), jnp.float32)))
-        o_lat = (acc / total[..., None]).astype(dt)           # [B, H, 512]
+        if _on_tpu():
+            o_lat = _pa.paged_attention(
+                q_row, lat, None, l, bt, pos, n_kv_heads=1, value_width=kr,
+                scale=cfg.softmax_scale)
+        else:
+            o_lat = _span_tick(q_row, lat, l, bt, pos, cfg)
         out = jnp.einsum("bhc,hcv->bhv", o_lat, lp["wv_b"].astype(dt))
     x = x + jnp.einsum("bhv,hvd->bd", out, lp["wo"].astype(dt))
     return x, dict(cache, lat=lat)
+
+
+def _span_tick(q_row, lat, l, bt, pos, cfg: DeepseekV2Config):
+    """The tick's attention over the latent pages of layer `l` where
+    there is no TPU, and what the kernel is held equal to: a loop whose
+    trip count is the DEEPEST row's depth gathers, for every row of the
+    call, a span of `_TICK_SPAN_KEYS` latents a trip and masks what a
+    shallower row does not hold.  q_row [B, H, 640] (laid as a cached
+    row is) -> the weighted latents [B, H, 512]."""
+    B, H, _ = q_row.shape
+    psz, nblk, kr = lat.shape[2], bt.shape[1], cfg.kv_lora_rank
+    dt = cfg.dtype
+    span = _span_pages(_TICK_SPAN_KEYS, psz, nblk)
+    width = span * psz
+
+    def attend(i, part):
+        first = jnp.minimum(i * span, nblk - span)
+        pg = lax.dynamic_slice(bt, (0, first), (B, span))
+        rows = lat[l, pg].reshape(B, width, -1)
+        s = jnp.einsum("bhc,bsc->bhs", q_row, rows,
+                       preferred_element_type=jnp.float32) \
+            * cfg.softmax_scale
+        kcols = first * psz + jnp.arange(width)
+        seen = (kcols[None, :] <= pos[:, None]) \
+            & (kcols[None, :] >= i * width)
+        s = jnp.where(seen[:, None], s, -jnp.inf)
+        return _merge(part, s, lambda e: jnp.einsum(
+            "bhs,bsc->bhc", e.astype(dt), rows[..., :kr],
+            preferred_element_type=jnp.float32))
+
+    stat = jnp.full((B, H), -jnp.inf, jnp.float32)
+    _, total, acc = lax.fori_loop(
+        0, (jnp.max(pos) + width) // width, attend,
+        (stat, jnp.zeros_like(stat), jnp.zeros((B, H, kr), jnp.float32)))
+    return (acc / total[..., None]).astype(dt)
 
 
 # ---------------------------------------------------------------------------

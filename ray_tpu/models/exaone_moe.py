@@ -247,7 +247,11 @@ def attn_keys_paged(cfg: ExaoneMoeConfig, pos: np.ndarray,
     token, the trip count the span loop reads from the positions."""
     held = int((np.asarray(pos, np.int64) + 1).sum()) * cfg.n_global
     if _on_tpu():
-        return _pa.keys_copied(all_pos, page_size, nblk) * cfg.n_global, held
+        kind = cfg.kind(False)
+        token = kind.n_kv_heads * (kind.head_dim + kind.v_head_dim) \
+            * jnp.dtype(cfg.dtype).itemsize
+        return _pa.keys_copied(all_pos, page_size, nblk, token) \
+            * cfg.n_global, held
     cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
     spans = -(-(int(np.asarray(all_pos).max()) + 1) // cols)
     return len(all_pos) * spans * cols * cfg.n_global, held

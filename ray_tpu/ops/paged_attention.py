@@ -8,16 +8,18 @@ scalar (as `ops/ssm.step_pallas` is handed every layer's states), so no
 layer is sliced out and nothing is copied or re-laid.  One instance of
 the kernel is one row.  It walks the row's block-table entries (a
 prefetched scalar array, as its position is) in blocks of `_BLOCK_KEYS`
-keys, copies each page of keys and of values of a block from HBM into a
-VMEM buffer with one DMA a page, two blocks in flight (the next block's
-copies, or the next ROW's first, are started before this block's are
-waited for), and keeps a running softmax (maximum, sum, float32
-accumulator) in VMEM.  A row costs its own blocks and nothing for the
-table's width: the walk's trip count is read from the row's position.
+keys (more of a thin token's: `block_pages`), copies each page of keys
+and of values of a block from HBM into a VMEM buffer with one DMA a page
+and pool, two blocks in flight (the next block's copies, or the next
+ROW's first, are started before this block's are waited for), and keeps
+a running softmax (maximum, sum, float32 accumulator) in VMEM.  A row
+costs its own blocks and nothing for the table's width: the walk's trip
+count is read from the row's position.
 
-A token's heads reach the kernel in one of the two forms the models
-keep them in (`models/exaone_moe._kept`), told apart by the pool's
-shape:
+A token's heads reach the kernel in one of the three forms the models
+keep them in (`models/exaone_moe._kept`, `models/deepseek_v2._lat_row`),
+told apart by what the call hands in, the pool's shape and whether
+there is a pool of values at all:
 
   [P, page, G, Dh]   heads in ROWS (K-EXAONE's [8, 128]): a page is read
                      as [page x G, Dh], one row a (token, head), which
@@ -33,10 +35,21 @@ shape:
                      of the whole value row, of which a head keeps its
                      own head's lanes: again G x the multiplies, and no
                      re-laying of a 192-wide head
+  [P, page, Dh],     a LATENT page (DeepSeek-V2's [512 | 64 | 64 zeros]
+  no value pool      of one head that all 128 absorbed query heads
+                     share): a token's value is the first `value_width`
+                     lanes of its key.  The lanes form with one head and
+                     ONE pool: a page is copied once, into the one pair
+                     of buffers there is, scored over all its lanes, and
+                     the weighted sum is taken of a whole-tile slice of
+                     the same buffer; the scale is the caller's (YaRN's
+                     factor is in it)
 
-so both are one algorithm, Q [H, Wk] against K [n, Wk] under a mask and
+so all are one algorithm, Q [H, Wk] against K [n, Wk] under a mask and
 P [H, n] against V [n, Wv], whose tile shapes follow the head widths.
-The bytes bound the call in both (PERF.md section 6, PR 52).
+The bytes bound the call in the first two (PERF.md section 6, PR 52);
+128 query heads over a 1.25 KB token make the MXU the bound of the
+third (PR 53).
 
 A position past the row's own is masked, so what a page holds behind
 the row's last token, and what the trash page holds, is never seen.  An
@@ -46,6 +59,7 @@ idle row (position 0, its table on the trash page) reads one block.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,18 +75,29 @@ from jax.experimental.pallas import tpu as pltpu
 # rows (2.5 KB a token) 1.27 at 384-512, 1.34 at 320, 1.42 at 256, 2.01
 # at 128.
 _BLOCK_KEYS = 384
+# ...and the bytes of the pool a block holds at least.  A trip costs
+# ~0.66 us whatever it holds (the two products' fill and drain, the
+# reductions between them, the accumulator's rescale), which hides under
+# the copy of a thick token's block and not beside a thin one's
+# arithmetic: DeepSeek-V2's 64 rows of 1.25 KB latents read 0.93 ms a
+# layer at 384 keys, 0.81 / 0.75 / 0.68 / 0.66 at 512 / 640 / 768 / 1,024
+# (PERF.md section 6, PR 53).  What 384 of MiMo's tokens are, the
+# smallest block measured on its plateau, is 768 latents.
+_BLOCK_BYTES = 384 * 2560
 
 
-def block_pages(page_size: int, nblk: int) -> int:
-    """Whole pages one block of the walk covers."""
-    return max(1, min(_BLOCK_KEYS // page_size, nblk))
+def block_pages(page_size: int, nblk: int, token_bytes: int) -> int:
+    """Whole pages one block of the walk covers, of a pool that keeps
+    `token_bytes` a token and layer."""
+    keys = max(_BLOCK_KEYS, _BLOCK_BYTES // token_bytes)
+    return max(1, min(keys // page_size, nblk))
 
 
-def keys_copied(pos, page_size: int, nblk: int) -> int:
+def keys_copied(pos, page_size: int, nblk: int, token_bytes: int) -> int:
     """Keys the kernel copies from one layer's pool for rows at `pos`
     (every row of the call, idle ones at 0): each row's own blocks, the
     last one whole."""
-    width = block_pages(page_size, nblk) * page_size
+    width = block_pages(page_size, nblk, token_bytes) * page_size
     return int((np.asarray(pos, np.int64) // width + 1).sum()) * width
 
 
@@ -87,10 +112,15 @@ def widen(q, n_kv_heads: int):
     return wide.reshape(B, H, G * Dh)
 
 
-def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, v_hbm,
-            o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref, *,
+def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, *rest,
             pages: int, nblk: int, page_size: int, in_rows: int,
-            group: int, scale: float):
+            group: int, scale: float, values_in_keys: bool):
+    if values_in_keys:
+        # values are the first lanes of the keys: one pool, one buffer
+        o_ref, kbuf, sems, m_ref, l_ref, acc_ref = rest
+        vbuf = kbuf
+    else:
+        v_hbm, o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
     rows = pl.num_programs(0)
     layer = layer_ref[0]
@@ -110,9 +140,10 @@ def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, v_hbm,
             out.append(pltpu.make_async_copy(
                 k_hbm.at[layer, page], kbuf.at[slot, pl.ds(p * per, per)],
                 sems.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[layer, page], vbuf.at[slot, pl.ds(p * per, per)],
-                sems.at[1, slot]))
+            if not values_in_keys:
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[layer, page],
+                    vbuf.at[slot, pl.ds(p * per, per)], sems.at[1, slot]))
         return out
 
     def start(row, j, slot):
@@ -162,7 +193,7 @@ def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, v_hbm,
         m_ref[...] = top
         l_ref[...] = old * l_ref[...] + e.sum(-1, keepdims=True)
         acc_ref[...] = old * acc_ref[...] + jnp.dot(
-            e.astype(vbuf.dtype), vbuf[slot],
+            e.astype(vbuf.dtype), vbuf[slot, :, :acc_ref.shape[1]],
             preferred_element_type=jnp.float32)
         return carry
 
@@ -178,29 +209,43 @@ def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, v_hbm,
 
 
 def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
-                    n_kv_heads: int, interpret: bool = False):
+                    n_kv_heads: int, value_width: Optional[int] = None,
+                    scale: Optional[float] = None, interpret: bool = False):
     """q [B, H, Dh] (one token a row) over the pages of layer `layer` of
     k_pool [L, P, page, G, Dh] / v_pool [L, P, page, G, Dv], or the same
     with a token's heads side by side ([L, P, page, G x Dh]): row b
     attends to positions 0..pos[b] of the pages block_tables[b] names.
-    Scores are scaled by Dh ** -0.5; matmul inputs are the pool's dtype,
-    the softmax and the accumulator float32.  Returns [B, H, Dv] in q's
-    dtype."""
+    `v_pool` None: a value is the first `value_width` lanes of its key,
+    k_pool [L, P, page, Dh] of one head every query head shares.
+    Scores are scaled by `scale` (default Dh ** -0.5); matmul inputs are
+    the pool's dtype, the softmax and the accumulator float32.  Returns
+    [B, H, Dv] in q's dtype."""
     B, H, Dh = q.shape
     G = n_kv_heads
     L, P, psz = k_pool.shape[:3]
     nblk = block_tables.shape[1]
-    pages = block_pages(psz, nblk)
-    if k_pool.ndim == 5:
-        # (token, head) rows: the pool as it lies (a merge of the two
-        # dimensions above a head's lanes moves nothing)
-        in_rows, Dv = G, v_pool.shape[-1]
-        k_pool = k_pool.reshape(L, P, psz * G, Dh)
-        v_pool = v_pool.reshape(L, P, psz * G, Dv)
+    if v_pool is None:
+        if G != 1 or k_pool.ndim != 4 or not 0 < (value_width or 0) <= Dh:
+            raise ValueError(
+                f"keys that hold their values are one head of [L, P, page, "
+                f"Dh] and a value width within it, got {k_pool.shape}, "
+                f"{G} heads, value_width={value_width}")
+        in_rows, Dv, Wv, pools = 1, value_width, value_width, [k_pool]
     else:
-        in_rows, Dv = 1, v_pool.shape[-1] // G
-        q = widen(q, G)
-    per, Wk, Wv = k_pool.shape[2], k_pool.shape[3], v_pool.shape[3]
+        if k_pool.ndim == 5:
+            # (token, head) rows: the pool as it lies (a merge of the two
+            # dimensions above a head's lanes moves nothing)
+            in_rows, Dv = G, v_pool.shape[-1]
+            k_pool = k_pool.reshape(L, P, psz * G, Dh)
+            v_pool = v_pool.reshape(L, P, psz * G, Dv)
+        else:
+            in_rows, Dv = 1, v_pool.shape[-1] // G
+            q = widen(q, G)
+        pools, Wv = [k_pool, v_pool], v_pool.shape[3]
+    per, Wk = k_pool.shape[2], k_pool.shape[3]
+    # (the bytes a token of the pools as they lie: a page's over its tokens)
+    pages = block_pages(psz, nblk, sum(
+        per * pool.shape[3] * pool.dtype.itemsize for pool in pools) // psz)
     pos = pos.astype(jnp.int32)
     trips = pos // (pages * psz) + 1
     # the buffer a row's first block lands in: blocks alternate between
@@ -208,20 +253,24 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
     first = (jnp.cumsum(trips) - trips) % 2
     row = lambda w: pl.BlockSpec((None, H, w),  # noqa: E731
                                  lambda b, *_: (b, 0, 0))
+    # a pair of buffers and a pair of semaphores a pool
+    buffers = [pltpu.VMEM((2, pages * per, pool.shape[3]), pool.dtype)
+               for pool in pools]
     return pl.pallas_call(
         functools.partial(_kernel, pages=pages, nblk=nblk, page_size=psz,
-                          in_rows=in_rows, group=H // G, scale=Dh ** -0.5),
+                          in_rows=in_rows, group=H // G,
+                          scale=Dh ** -0.5 if scale is None else scale,
+                          values_in_keys=v_pool is None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(B,),
-            in_specs=[row(Wk), pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[row(Wk)] + [pl.BlockSpec(memory_space=pl.ANY)
+                                  for _ in pools],
             out_specs=row(Dv),
-            scratch_shapes=[pltpu.VMEM((2, pages * per, Wk), k_pool.dtype),
-                            pltpu.VMEM((2, pages * per, Wv), v_pool.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, Wv), jnp.float32)]),
+            scratch_shapes=buffers + [
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, Wv), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
@@ -230,4 +279,4 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
         name="paged_attention",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos,
       first.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),
-      q, k_pool, v_pool)
+      q, *pools)

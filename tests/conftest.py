@@ -81,28 +81,34 @@ def ray_start_cluster():
 @pytest.fixture(params=["span", "kernel"])
 def tick_attention(request, monkeypatch):
     """How a tick's paged attention layers of `models/exaone_moe.py`
-    attend: the span loop, what runs where there is no TPU, or the
-    ragged kernel of `ops/paged_attention.py` as a TPU runs it,
-    interpreted, in blocks small enough that a toy row walks several.
-    The engine's jitted tick is traced anew on both sides of the
-    kernel's turn."""
+    and `models/deepseek_v2.py` attend: the span loop, what runs where
+    there is no TPU, or the ragged kernel of `ops/paged_attention.py` as
+    a TPU runs it, interpreted, in blocks small enough that a toy row
+    walks several.  The engine's jitted tick is traced anew on both
+    sides of the kernel's turn."""
     if request.param == "span":
         yield request.param
         return
     import functools
 
-    from ray_tpu.models import exaone_moe
+    from ray_tpu.models import deepseek_v2, exaone_moe
     from ray_tpu.ops import paged_attention as pa
     from ray_tpu.serve.llm import engine
-    kernel, traced = pa.paged_attention, []
+    kernel, gmm, traced = pa.paged_attention, deepseek_v2.gmm, []
 
     @functools.wraps(kernel)
     def interpreted(q, *args, **kw):
         traced.append(q.shape)
         return kernel(q, *args, interpret=True, **kw)
     monkeypatch.setattr(exaone_moe, "_on_tpu", lambda: True)
+    # (deepseek_v2 asks the same of its grouped matmul, which has no TPU
+    # to run on here either)
+    monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    monkeypatch.setattr(deepseek_v2, "gmm", lambda *a, **kw: gmm(
+        *a, **dict(kw, interpret=True)))
     monkeypatch.setattr(pa, "paged_attention", interpreted)
     monkeypatch.setattr(pa, "_BLOCK_KEYS", 8)
+    monkeypatch.setattr(pa, "_BLOCK_BYTES", 0)
     engine._paged_tick.clear_cache()
     yield request.param
     engine._paged_tick.clear_cache()

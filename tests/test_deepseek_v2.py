@@ -162,10 +162,11 @@ def _through_the_programs(case, cfg, params):
 @pytest.mark.parametrize("case", ["whole-chunks", "partial-last-chunk",
                                   "two-rows"])
 def test_prefill_chunks_then_ticks_are_one_reference_forward(
-        model, reference, case):
+        model, reference, case, tick_attention):
     """Chunks of 16 through the EXPANDED attention, then ticks through
-    the ABSORBED one, over one cache: every position's logits against
-    the reference's expanded forward — so absorbed = expanded, a chunk
+    the ABSORBED one (the span loop, and the ragged kernel as a TPU runs
+    it), over one cache: every position's logits against the
+    reference's expanded forward — so absorbed = expanded, a chunk
     reads what earlier chunks cached, a
     padded last chunk routes no pad, and a second row changes nothing."""
     cfg, params = model

@@ -1,15 +1,19 @@
 """`ops/paged_attention.py`, the ragged kernel of a decode tick's paged
-attention layers, interpreted on the CPU: against the span loop of
-`models/exaone_moe.py` (what runs where there is no TPU) and against a
-plain float32 softmax over each row's own keys, in both forms a model
-keeps a token's heads in; what it reads of the pool and what it never
-touches; and the counters that say what it copies."""
+attention layers, interpreted on the CPU: against the span loops of
+`models/exaone_moe.py` and `models/deepseek_v2.py` (what runs where
+there is no TPU) and against a plain float32 softmax over each row's own
+keys, in both forms a model keeps a token's heads in and over a latent
+page whose values are a prefix of its keys; what it reads of the pool
+and what it never touches; and the counters that say what it copies."""
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models import deepseek_v2 as ds
 from ray_tpu.models import exaone_moe as em
 from ray_tpu.ops import paged_attention as pa
 
@@ -17,7 +21,12 @@ PAGE, NBLK, LAYERS, LAYER = 16, 12, 2, 1
 BLOCK = 2 * PAGE                      # keys a block of the walk, here
 # heads, key-value heads, key width, value width, heads side by side
 KINDS = {"heads-in-rows": (8, 2, 128, 128, False),    # K-EXAONE's [G, 128]
-         "heads-in-lanes": (8, 2, 192, 128, True)}    # MiMo's G x 192, G x 128
+         "heads-in-lanes": (8, 2, 192, 128, True),    # MiMo's G x 192, G x 128
+         # DeepSeek-V2's latent row [512 | 64 | 64 zeros]: one head every
+         # query head shares, its values its own first 512 lanes
+         "latent": (8, 1, 640, 512, True)}
+# the latent step's own scale: of a 192-wide head, times YaRN's factor
+LATENT = ds.DeepseekV2Config(max_seq=PAGE * NBLK, n_layers=5)
 # positions of the call's rows (an idle row stands at 0 on the trash
 # page, 0, whatever else the call holds)
 CASES = {
@@ -31,8 +40,9 @@ CASES = {
 
 
 def _state(kind, pos, seed=0, dtype=jnp.bfloat16):
-    """(q, k pool, v pool, block tables, positions): every row on pages
-    of its own in a drawn order, an idle row's table on page 0."""
+    """(kind, q, k pool, v pool, block tables, positions): every row on
+    pages of its own in a drawn order, an idle row's table on page 0.  A
+    latent page has no value pool (None)."""
     H, G, Dh, Dv, flat = KINDS[kind]
     akind = em.AttnKind(G, Dh, Dv, flat=flat)
     rng = np.random.default_rng(seed)
@@ -40,45 +50,67 @@ def _state(kind, pos, seed=0, dtype=jnp.bfloat16):
     P = B * NBLK + 1
     k = jnp.asarray(rng.normal(size=(LAYERS, P, PAGE) + em._kept(akind, Dh)),
                     dtype)
-    v = jnp.asarray(rng.normal(size=(LAYERS, P, PAGE) + em._kept(akind, Dv)),
-                    dtype)
+    v = None if kind == "latent" else jnp.asarray(
+        rng.normal(size=(LAYERS, P, PAGE) + em._kept(akind, Dv)), dtype)
     q = jnp.asarray(rng.normal(size=(B, H, Dh)), dtype)
     bt = 1 + rng.permutation(B * NBLK).reshape(B, NBLK).astype(np.int32)
     pos = np.asarray(pos, np.int32)
     bt[pos == 0] = 0
-    return akind, q, k, v, bt, pos
+    return kind, q, k, v, bt, pos
 
 
-def _plain(akind, q, k, v, bt, pos):
+def _scale(kind):
+    return LATENT.softmax_scale if kind == "latent" \
+        else KINDS[kind][2] ** -0.5
+
+
+def _plain(kind, q, k, v, bt, pos):
     """A float32 softmax over each row's own keys, row by row."""
-    G, Dh, Dv = akind.n_kv_heads, akind.head_dim, akind.v_head_dim
-    H = q.shape[1]
+    H, G, Dh, Dv, _ = KINDS[kind]
     out = []
     for b, p in enumerate(pos):
         n = int(p) + 1
         pages = bt[b, :-(-n // PAGE)]
         keys = np.asarray(k[LAYER, pages], np.float32).reshape(-1, G, Dh)[:n]
-        vals = np.asarray(v[LAYER, pages], np.float32).reshape(-1, G, Dv)[:n]
+        vals = keys[..., :Dv] if v is None else np.asarray(
+            v[LAYER, pages], np.float32).reshape(-1, G, Dv)[:n]
         s = np.einsum("grd,sgd->grs", np.asarray(q[b], np.float32)
-                      .reshape(G, H // G, Dh), keys) * Dh ** -0.5
+                      .reshape(G, H // G, Dh), keys) * _scale(kind)
         e = np.exp(s - s.max(-1, keepdims=True))
         out.append(np.einsum("grs,sgd->grd", e / e.sum(-1, keepdims=True),
                              vals).reshape(H, Dv))
     return np.stack(out)
 
 
-def _kernel(akind, q, k, v, bt, pos):
+def _kernel(kind, q, k, v, bt, pos):
+    _, G, _, Dv, _ = KINDS[kind]
+    how = dict(value_width=Dv, scale=_scale(kind)) if v is None else {}
     return np.asarray(jax.jit(
-        lambda *a: pa.paged_attention(*a, n_kv_heads=akind.n_kv_heads,
-                                      interpret=True))(
+        lambda *a: pa.paged_attention(*a, n_kv_heads=G, interpret=True,
+                                      **how))(
         q, k, v, jnp.int32(LAYER), jnp.asarray(bt), jnp.asarray(pos)),
         np.float32)
+
+
+def _span(kind, q, k, v, bt, pos):
+    """The model's own span loop: what runs where there is no TPU."""
+    bt, pos = jnp.asarray(bt), jnp.asarray(pos)
+    if v is None:
+        cfg = dataclasses.replace(LATENT, dtype=q.dtype)
+        return np.asarray(ds._span_tick(q, k, LAYER, bt, pos, cfg),
+                          np.float32)
+    _, G, Dh, Dv, flat = KINDS[kind]
+    return np.asarray(em._span_tick(q, k, v, LAYER, bt, pos,
+                                    em.AttnKind(G, Dh, Dv, flat=flat)),
+                      np.float32)
 
 
 @pytest.fixture(autouse=True)
 def small_blocks(monkeypatch):
     monkeypatch.setattr(pa, "_BLOCK_KEYS", BLOCK)
+    monkeypatch.setattr(pa, "_BLOCK_BYTES", 0)
     monkeypatch.setattr(em, "_TICK_SPAN_KEYS", 3 * PAGE)
+    monkeypatch.setattr(ds, "_TICK_SPAN_KEYS", 3 * PAGE)
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -90,13 +122,10 @@ def test_the_kernel_is_the_span_loop_and_a_plain_softmax(kind, case):
     a softmax over ITS keys, in bfloat16 as the chip holds them and in
     float32."""
     state = _state(kind, CASES[case], seed=len(case))
-    akind, q, k, v, bt, pos = state
     got = _kernel(*state)
-    span = np.asarray(em._span_tick(q, k, v, LAYER, jnp.asarray(bt),
-                                    jnp.asarray(pos), akind), np.float32)
     # (bfloat16 weights into the weighted sum and a bfloat16 result:
     # one block's rounding is not another span's)
-    np.testing.assert_allclose(got, span, atol=2e-2)
+    np.testing.assert_allclose(got, _span(*state), atol=2e-2)
     np.testing.assert_allclose(got, _plain(*state), atol=2e-2)
     exact = _state(kind, CASES[case], seed=len(case), dtype=jnp.float32)
     np.testing.assert_allclose(_kernel(*exact), _plain(*exact), atol=2e-5)
@@ -110,32 +139,34 @@ def test_a_row_reads_its_own_blocks_and_nothing_past_its_position(kind):
     its position, are copied and masked; a key at or before its
     position is read."""
     state = _state(kind, CASES["unequal-depths"], seed=3)
-    akind, q, k, v, bt, pos = state
+    _, q, k, v, bt, pos = state
     want = _kernel(*state)
     blocks = pos // BLOCK + 1
     own = np.zeros(k.shape[1], bool)         # pages some row's walk visits
     for b in range(len(pos)):
         own[bt[b, :blocks[b] * BLOCK // PAGE]] = True
-    poison = lambda a: a.at[:, ~own].set(jnp.nan)  # noqa: E731
+    poison = lambda a: a if a is None else (  # noqa: E731
+        a.at[:, ~own].set(jnp.nan))
     np.testing.assert_array_equal(
-        _kernel(akind, q, poison(k), poison(v), bt, pos), want)
+        _kernel(kind, q, poison(k), poison(v), bt, pos), want)
     # past the position, inside the last block: masked, whatever is there
     row = 1                                   # position 17: page 1, slot 1
     page = int(bt[row, pos[row] // PAGE])
     after = (slice(None), page, slice(int(pos[row]) % PAGE + 1, None))
     np.testing.assert_array_equal(
-        _kernel(akind, q, k.at[after].set(60.0), v.at[after].set(-60.0),
-                bt, pos), want)
+        _kernel(kind, q, k.at[after].set(60.0),
+                v if v is None else v.at[after].set(-60.0), bt, pos), want)
     # the position itself is read
     at = (LAYER, page, int(pos[row]) % PAGE)
-    moved = _kernel(akind, q, k, v.at[at].add(4.0), bt, pos)
+    moved = _kernel(kind, q, *((k.at[at].add(4.0), v) if v is None else
+                               (k, v.at[at].add(4.0))), bt, pos)
     assert np.abs(moved[row] - want[row]).max() > 1e-3
     np.testing.assert_array_equal(np.delete(moved, row, 0),
                                   np.delete(want, row, 0))
     # and only the layer asked for
     np.testing.assert_array_equal(
-        _kernel(akind, q, k.at[0].set(jnp.nan), v.at[0].set(jnp.nan), bt,
-                pos), want)
+        _kernel(kind, q, k.at[0].set(jnp.nan),
+                v if v is None else v.at[0].set(jnp.nan), bt, pos), want)
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -155,7 +186,7 @@ def test_the_counters_are_what_the_kernel_copies(case, monkeypatch):
         spans * cfg.n_global, held)
     monkeypatch.setattr(em, "_on_tpu", lambda: True)
     visited = sum(len(range(0, int(p) + 1, BLOCK)) for p in pos) * BLOCK
-    assert pa.keys_copied(pos, PAGE, NBLK) == visited
+    assert pa.keys_copied(pos, PAGE, NBLK, 4096) == visited
     assert em.attn_keys_paged(cfg, active, pos, PAGE, NBLK) == (
         visited * cfg.n_global, held)
     assert em.attn_keys_gathered(cfg, pos, PAGE, NBLK) == (
@@ -164,8 +195,57 @@ def test_the_counters_are_what_the_kernel_copies(case, monkeypatch):
     assert visited - int((pos + 1).sum()) <= len(pos) * BLOCK
 
 
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_latent_counter_is_what_the_kernel_copies(case, monkeypatch):
+    """`deepseek_v2.attn_keys_gathered` on a TPU: each row's own blocks
+    in every layer, what the kernel copies; without one, the span loop's
+    spans to the deepest row for every row."""
+    pos = np.asarray(CASES[case], np.int32)
+    cols = 3 * PAGE
+    spans = len(pos) * -(-(int(pos.max()) + 1) // cols) * cols
+    assert ds.attn_keys_gathered(LATENT, pos, PAGE, NBLK) == (
+        spans * LATENT.n_layers)
+    monkeypatch.setattr(ds, "_on_tpu", lambda: True)
+    visited = sum(len(range(0, int(p) + 1, BLOCK)) for p in pos) * BLOCK
+    assert ds.attn_keys_gathered(LATENT, pos, PAGE, NBLK) == (
+        pa.keys_copied(pos, PAGE, NBLK, 1280) * LATENT.n_layers) == (
+        visited * LATENT.n_layers)
+
+
+def test_keys_that_hold_their_values_are_one_head():
+    """No value pool means a value width inside a key of ONE head: a
+    pool of several heads, or a width past the key's, is refused by
+    name, before anything is traced into a kernel."""
+    _, q, k, _, bt, pos = _state("latent", [5])
+    call = lambda **kw: pa.paged_attention(  # noqa: E731
+        q, k, None, jnp.int32(LAYER), jnp.asarray(bt), jnp.asarray(pos),
+        interpret=True, **kw)
+    for kw in (dict(n_kv_heads=2, value_width=512),
+               dict(n_kv_heads=1, value_width=641),
+               dict(n_kv_heads=1)):
+        with pytest.raises(ValueError, match="keys that hold their values"):
+            call(**kw)
+
+
 def test_a_block_is_whole_pages_of_the_table():
-    assert pa.block_pages(PAGE, NBLK) == BLOCK // PAGE
-    assert pa.block_pages(PAGE // 2, 432) == 2 * BLOCK // PAGE
-    assert pa.block_pages(4 * BLOCK, NBLK) == 1      # a page over a block
-    assert pa.block_pages(1, 5) == 5                 # a table under one
+    assert pa.block_pages(PAGE, NBLK, 4096) == BLOCK // PAGE
+    assert pa.block_pages(PAGE // 2, 432, 4096) == 2 * BLOCK // PAGE
+    assert pa.block_pages(4 * BLOCK, NBLK, 4096) == 1  # a page over a block
+    assert pa.block_pages(1, 5, 4096) == 5             # a table under one
+
+
+@pytest.mark.parametrize("token_bytes,keys", [
+    (8 * (128 + 128) * 2, 384),       # K-EXAONE's [8, 128] keys and values
+    (4 * (192 + 128) * 2, 384),       # MiMo's 4 x 192 and 4 x 128
+    (640 * 2, 768),                   # DeepSeek-V2's latent row
+    (8 * (128 + 128) * 4, 384)])      # float32: never under 384 keys
+def test_a_thin_token_makes_a_block_of_more_keys(token_bytes, keys,
+                                                 monkeypatch):
+    """The block as the chip runs it (the constants themselves, not the
+    small blocks of this file): 384 keys of the two pools PR 52 measured
+    it on, whose programs stay what they were, and as many 1.25 KB
+    latents as fill what 384 of MiMo's tokens do."""
+    monkeypatch.undo()                # the real _BLOCK_KEYS, _BLOCK_BYTES
+    assert pa.block_pages(64, 432, token_bytes) * 64 == keys
+    assert pa.keys_copied([0, 767, 768], 64, 432, token_bytes) == (
+        {384: 1 + 2 + 3, 768: 1 + 1 + 2}[keys] * keys)

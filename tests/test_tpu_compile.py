@@ -289,7 +289,10 @@ def test_dsv2_paged_step_compiles(chip, step, monkeypatch):
     and a 2.5 GB latent pool are resident, a step holds under 0.5 GiB
     beside them, the pool is never re-laid or copied, and the tick
     forms no key or value of head width from the latents
-    ([rows, keys, 128, 128 or 192]): it attends in the latent space."""
+    ([rows, keys, 128, 128 or 192]): it attends in the latent space,
+    through one `ops/paged_attention.py` kernel a layer that is handed
+    the latent pool alone, as it lies (values are its keys' first 512
+    lanes: no second pool, no gathered span of the table's rows)."""
     import json
     import os
 
@@ -342,6 +345,17 @@ def test_dsv2_paged_step_compiles(chip, step, monkeypatch):
         expanded = [s for s in shapes if len(s) == 4 and s[0] == str(rows)
                     and s[-1] in ("128", "192") and "128" in s[1:3]]
         assert not expanded, expanded[:4]
+        ragged = [ln for ln in text.splitlines() if "custom-call(" in ln
+                  and "tpu_custom_call" in ln and " %paged_attention" in ln]
+        assert len(ragged) == cfg.n_layers, len(ragged)
+        assert text.count("tpu_custom_call") == 3 * cfg.n_moe + cfg.n_layers
+        # each is handed the pool once, and no span of it is gathered
+        # for the call's rows ([rows, keys of a span, 640])
+        assert all(ln.count(pool) == 1 for ln in ragged), ragged[:1]
+        width = str(cache["lat"].shape[-1])
+        spans = [s for s in shapes if len(s) >= 3 and s[0] == str(rows)
+                 and s[-1] == width and s[1:-1] != [str(cfg.n_heads)]]
+        assert not spans, spans[:4]
 
 
 # The fifth configuration (benchmarks/configs/k-exaone-ep8-d5.json):
