@@ -22,15 +22,18 @@ positions, fused QKV) and LLaMA (RoPE, GQA, SwiGLU).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Any, Dict, Optional, Tuple
+import operator
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ray_tpu.models import llama as llama_mod
-from ray_tpu.models.gpt import _rmsnorm
+from ray_tpu.models.gpt import GPTConfig, _rmsnorm
 
 
 # ---------------------------------------------------------------------------
@@ -39,38 +42,87 @@ from ray_tpu.models.gpt import _rmsnorm
 
 def _is_llama(cfg) -> bool:
     """Which adapter the DENSE body below uses (llama: RoPE, GQA, SwiGLU;
-    else GPT).  Not the model switch: see paged_model."""
+    else GPT).  Not the model switch: see paged_body."""
     return isinstance(cfg, llama_mod.LlamaConfig)
 
 
-def paged_model(cfg):
-    """The module that runs `cfg` through a paged cache when it is not
-    the dense body of this file: a config names it as `cfg.paged_model`
-    (models/minicpm_sala.py, models/deepseek_v2.py).  Such a module
-    declares its own cache
-    (`init_paged_cache(cfg, num_pages, page_size, num_slots)`), brings
-    `paged_chunk_step` under the contract of the one below, checks the
-    engine's paging against its layout (`check_paging`), counts the
-    keys a tick's rows read and hold (`attn_keys`) and says whether a
-    prefill chunk selects pages (`chunk_selects`).  None for the dense
-    models."""
-    return getattr(cfg, "paged_model", None)
+# ---------------------------------------------------------------------------
+# The seam between the serving engine and a model body
 
 
-def has_row_state(cfg) -> bool:
-    """True when part of a sequence's state lives outside its pages (a
-    recurrent state per decode row): everything that treats a page as
-    the whole of a sequence's state must refuse such a model."""
-    return bool(getattr(cfg, "row_state", False))
+def _selects_no_pages(cfg, start: int) -> bool:
+    return False
 
 
-def pages_are_kv(cfg) -> bool:
-    """False when a model's pages are not K then V of [page, Hkv, Dh]
-    (a latent page: models/deepseek_v2.py).  The radix prefix cache
-    hands out page ids and does not care; what frames a page's bytes
-    (tiers, kv_export / kv_import, migration) must refuse such a model
-    (kv_tier.refuse_unframed)."""
-    return bool(getattr(cfg, "pages_are_kv", True))
+@dataclasses.dataclass(frozen=True)
+class PagedBody:
+    """What a model body owes the serving engine (serve/llm/engine.py,
+    kv_tier.py, kv_transfer.py), declared once by the body's module and
+    named by its config as `cfg.paged_body`; `paged_body(cfg)` below is
+    how everyone finds it, and DENSE_BODY is this file's own.  Every
+    default is written here and nowhere else.  `cfg` is the config that
+    named the body; the host-side counters are given `pos`, the active
+    rows' positions, and `last`, the last column of the call for EVERY
+    decode row (idle ones at 0)."""
+    # (cfg, num_pages, page_size, num_slots) -> `engine._cache`, one
+    # pytree: the pool and, where the body has it, state per decode row
+    init_paged_cache: Callable[..., Dict]
+    # under the contract of `paged_chunk_step` below
+    paged_chunk_step: Callable[..., Tuple[Any, Dict]]
+    # (cfg, *, page_size, prefill_chunk, speculate_k): raises for paging
+    # the body's layout cannot serve
+    check_paging: Callable[..., None]
+    # (cfg, pos) -> (keys read, keys held) by a tick's rows
+    attn_keys: Callable[..., Tuple[int, int]]
+    # (cfg, start) -> whether the prefill chunk at `start` selects pages
+    chunk_selects: Callable[..., bool] = _selects_no_pages
+    # the cache entries that are the pool: a page's bytes are theirs
+    page_keys: Tuple[str, ...] = ("k", "v")
+    # ...and those that are state of a decode row.  A body that names
+    # any HAS ROW STATE: what treats a page as the whole of a sequence's
+    # state refuses it (kv_tier.refuse_row_state)
+    row_state_keys: Tuple[str, ...] = ()
+    # the pool is `k` and `v` of [L, P, page, Hkv, Dh], all a sequence
+    # keeps: tiers, kv_export / kv_import, migration and sessions can
+    # frame a page (kv_tier.refuse_unframed refuses a body that is not)
+    framed: bool = False
+    # the single-row chunk takes `slot` (the decode row it fills) and
+    # `valid` (its real tokens); a body that takes neither is given
+    # neither, and lowers to the program it always was
+    chunk_takes_row: bool = True
+    # (cfg) -> how many layers attend, of a body that mixes in others
+    n_attn: Callable[..., int] = operator.attrgetter("n_layers")
+    # (cfg, last, page_size, nblk) -> keys the call pulled from the
+    # cache; None: no more than it reads (`keys_gathered`)
+    attn_keys_gathered: Optional[Callable[..., int]] = None
+    # (cfg, pos, last, page_size, nblk) -> (gathered, held) in the paged
+    # layers alone, of a body whose layers are not all paged
+    attn_keys_paged: Optional[Callable[..., Tuple[int, int]]] = None
+    # (cache) -> a copy of the device counters on its way to the host,
+    # taken behind a tick; (that copy, cfg) -> {name: number} for
+    # engine.stats().  None, both: nothing is counted on the device
+    snapshot_counters: Optional[Callable[..., Any]] = None
+    read_counters: Optional[Callable[..., Dict[str, Any]]] = None
+
+    @property
+    def has_row_state(self) -> bool:
+        return bool(self.row_state_keys)
+
+    def keys_gathered(self, cfg, read, last, page_size, nblk) -> int:
+        """Keys a tick pulled from the cache to read `read` of them."""
+        if self.attn_keys_gathered is None:
+            return read
+        return self.attn_keys_gathered(cfg, last, page_size, nblk)
+
+
+def paged_body(cfg) -> PagedBody:
+    """The body that runs `cfg` through a paged cache: this file's dense
+    one for the two dense configs, else the one the config names
+    (minicpm_sala.py, deepseek_v2.py, exaone_moe.py, jamba.py;
+    mimo_v2_flash.py names exaone_moe's).  Never None."""
+    if isinstance(cfg, (GPTConfig, llama_mod.LlamaConfig)):
+        return DENSE_BODY
+    return cfg.paged_body
 
 
 def _kv_heads(cfg) -> int:
@@ -184,27 +236,28 @@ def insert_cache_slot(cache: Dict, row_cache: Dict, slot) -> Dict:
 
 def init_paged_cache(cfg, num_pages: int, page_size: int,
                      num_slots: Optional[int] = None) -> Dict:
-    """Paged KV pool: k/v [L, P, page_size, Hkv, Dh] in cfg.dtype (a
-    model with its own paged step declares its own pytree, which may
-    hold per-row state for `num_slots` decode rows beside its pages).
+    """The cache `cfg`'s body declares.  (The serve engine reserves page
+    0 as a trash page for inactive rows' writes; no initializer cares.)"""
+    return paged_body(cfg).init_paged_cache(cfg, num_pages, page_size,
+                                            num_slots)
+
+
+def _dense_paged_cache(cfg, num_pages: int, page_size: int,
+                       num_slots: Optional[int] = None) -> Dict:
+    """Paged KV pool: k/v [L, P, page_size, Hkv, Dh] in cfg.dtype.
 
     Rows of a batch don't own contiguous cache rows here — each row owns
     a BLOCK TABLE of page ids, and attention gathers its keys/values
     through the table (vLLM's PagedAttention layout, expressed in the
     same masked static-shape style as the contiguous cache: gather
     spans of pages up to the width the rows hold, mask columns past the
-    row's position).  The
-    serve engine reserves page 0 as a trash page for inactive rows'
-    writes; this initializer doesn't care.
+    row's position).
 
     All layers live in ONE array per tensor, and the steps that use the
-    pool never take a layer out of it: paged_chunk_step indexes it at
+    pool never take a layer out of it: _dense_chunk_step indexes it at
     [l, pages] inside its layer scan (see there), page import/export at
     [:, pages].  On a chip the pool is gigabytes, and a step that
     formed cache["k"][l] would move a layer's worth of it per layer."""
-    model = paged_model(cfg)
-    if model is not None:
-        return model.init_paged_cache(cfg, num_pages, page_size, num_slots)
     shape = (cfg.n_layers, num_pages, page_size, _kv_heads(cfg),
              cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype),
@@ -263,7 +316,6 @@ def paged_read_stack(cache: Dict, page_ids) -> Any:
     The pool is an input of the gather and the device runs programs in
     order, so a step dispatched afterwards that rewrites these pages
     (through the donated cache) writes after the gather has read them."""
-    import numpy as np
     ids = np.asarray(page_ids, np.int32)
     pad = paged_read_batch(cache) - len(ids)
     if pad < 0:
@@ -282,7 +334,6 @@ def paged_read_pages_host(cache: Dict, page_ids) -> Tuple[Any, Any]:
     bytes as the tier demotion's landing, which does not wait (the
     engine hands each dispatched stack to its lander thread), so what a
     tier holds can never diverge from what the wire ships."""
-    import numpy as np
     size = paged_read_batch(cache)
     parts = [page_ids[lo:lo + size] for lo in range(0, len(page_ids), size)]
     stacks = [paged_read_stack(cache, part) for part in parts]
@@ -304,40 +355,73 @@ _SPAN_BYTES = 8 << 20
 _SPAN_COLS = 512
 
 
-def paged_span_blocks(cache: Dict, rows: int, nblk: int) -> int:
+def paged_span_blocks(key_bytes: int, psz: int, nblk: int) -> int:
     """How many consecutive block-table entries one span of the dense
-    paged step's attention covers, from the shapes alone: the pages
-    whose gathered K for all `rows` of the call is _SPAN_BYTES, at most
-    _SPAN_COLS columns and at most the whole table.  A 16-row tick over
-    16-token pages of 8 x 128 bf16 heads walks 16 blocks (256 columns)
-    a span, a single-row chunk 32 blocks.  The engine counts
-    `attn_keys_gathered` with it.  Of the DENSE pool only (one head
-    count and width for every layer): a model with its own paged step
-    sizes its own spans."""
-    _, _, psz, hkv, dh = cache["k"].shape
-    page = rows * psz * hkv * dh * cache["k"].dtype.itemsize
-    return max(1, min(nblk, _SPAN_BYTES // page, _SPAN_COLS // psz))
+    paged step's attention covers, from the shapes alone (`key_bytes`:
+    one page of `psz` tokens' K for all rows of the call): the pages
+    whose gathered K is _SPAN_BYTES, at most _SPAN_COLS columns and at
+    most the whole table.  A 16-row tick over 16-token pages of 8 x 128
+    bf16 heads walks 16 blocks (256 columns) a span, a single-row chunk
+    32 blocks.  Of the DENSE pool only (one head count and width for
+    every layer): a model with its own paged step sizes its own spans."""
+    return max(1, min(nblk, _SPAN_BYTES // key_bytes, _SPAN_COLS // psz))
+
+
+def _dense_check_paging(cfg, **paging) -> None:
+    if not _is_llama(cfg) and cfg.n_experts:
+        raise NotImplementedError(
+            "continuous batching runs the dense body for dense models "
+            "only (it has no expert layer; a model that routes brings "
+            "its own paged step)")
+
+
+def _dense_attn_keys(cfg, pos) -> Tuple[int, int]:
+    """(keys read, keys held): all a row holds, in every layer."""
+    keys = (int(pos.sum()) + len(pos)) * cfg.n_layers
+    return keys, keys
+
+
+def _dense_attn_keys_gathered(cfg, last, page_size: int, nblk: int) -> int:
+    """Keys one call pulls from the pool: for EVERY row whole spans (of
+    paged_span_blocks entries) up to the deepest row's last column, the
+    trip count _dense_chunk_step reads from the same positions."""
+    rows = len(last)
+    cols = page_size * paged_span_blocks(
+        rows * page_size * _kv_heads(cfg) * cfg.head_dim
+        * jnp.dtype(cfg.dtype).itemsize, page_size, nblk)
+    spans = -(-(int(last.max()) + 1) // cols)
+    return rows * cfg.n_layers * spans * cols
 
 
 def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
                      block_tables, cfg, pad_lo=None, **row
                      ) -> Tuple[Any, Dict]:
-    """Decode a chunk of t tokens [B, t] through a PAGED cache.
-
-    A config that names a `paged_model` is run by that module's step
-    (same arguments and results; `row` carries what only a model with
-    per-row state takes: the decode row a single-row chunk belongs to
-    and how many of its tokens are real).
+    """Decode a chunk of t tokens [B, t] through a PAGED cache, by the
+    step of `cfg`'s body (`row` carries what only a body whose
+    `chunk_takes_row` takes: the decode row a single-row chunk belongs
+    to and how many of its tokens are real).
 
     `block_tables` [B, nblk] maps each row's virtual cache columns to
     pages of the pool: virtual column c lives at
     (block_tables[b, c // page], c % page).  `pos` is a scalar (one
     shared start column — single-row prefill) or a [B] vector (each row
-    chunked at its own depth — the fused speculative verify).  Row b's
-    chunk K/V is scattered at columns pos[b]..pos[b]+t-1 through its
-    table; query i of row b then sees columns pad_lo[b]..pos[b]+i,
-    which hold bit-identical values to a contiguous cache, so paging is
-    invisible to results.
+    chunked at its own depth — the decode tick at t = 1, the fused
+    speculative verify).  Row b's chunk K/V is scattered at columns
+    pos[b]..pos[b]+t-1 through its table; query i of row b then sees
+    columns pad_lo[b]..pos[b]+i, which hold bit-identical values to a
+    contiguous cache, so paging is invisible to results.
+
+    Callers must keep pos+t within nblk*page (writes past the table
+    would clip into the last block).  Returns (logits [B, t, V] fp32,
+    updated cache)."""
+    return paged_body(cfg).paged_chunk_step(params, tokens, pos, cache,
+                                            block_tables, cfg,
+                                            pad_lo=pad_lo, **row)
+
+
+def _dense_chunk_step(params: Dict, tokens, pos, cache: Dict,
+                      block_tables, cfg, pad_lo=None) -> Tuple[Any, Dict]:
+    """The dense body's step (GPT, Llama), under paged_chunk_step's.
 
     Attention reads the width the rows HOLD, not the table's: it walks
     SPANS of paged_span_blocks consecutive table entries up to the
@@ -361,18 +445,7 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     xs/ys instead, every layer's pool is sliced out, updated and
     stacked into a second pool, which is copied whole after the loop:
     six pool-sized moves a call and ~4 GiB of temporaries at a 7B
-    model's widths (tests/test_tpu_compile.py holds the line).
-
-    Callers must keep pos+t within nblk*page (writes past the table
-    would clip into the last block).  Returns (logits [B, t, V] fp32,
-    updated cache)."""
-    model = paged_model(cfg)
-    if model is not None:
-        return model.paged_chunk_step(params, tokens, pos, cache,
-                                      block_tables, cfg, pad_lo=pad_lo,
-                                      **row)
-    if row:
-        raise TypeError(f"the dense paged step takes no {sorted(row)}")
+    model's widths (tests/test_tpu_compile.py holds the line)."""
     B, t = tokens.shape
     psz = cache["k"].shape[2]
     nblk = block_tables.shape[1]
@@ -388,7 +461,8 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     w_offs = cols % psz
 
     Hkv, Dh = cache["k"].shape[3:]
-    span = paged_span_blocks(cache, B, nblk)
+    span = paged_span_blocks(
+        B * psz * Hkv * Dh * cache["k"].dtype.itemsize, psz, nblk)
     span_cols = span * psz
     n_spans = (jnp.max(pos) + t + span_cols - 1) // span_cols
     span_offs = jnp.arange(span_cols)
@@ -445,16 +519,11 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     return _final_logits(params, x, cfg), {"k": ck, "v": cv}
 
 
-def paged_decode_step(params: Dict, token, pos, cache: Dict,
-                      block_tables, cfg, pad_lo=None
-                      ) -> Tuple[Any, Dict]:
-    """One token [B] at per-row cache columns pos [B] through a paged
-    cache — the continuous-batching tick.  A t=1 paged_chunk_step (the
-    SAME kernel the speculative verify runs, so a speculation-free tick
-    and a verify tick can never drift numerically)."""
-    logits, cache = paged_chunk_step(params, token[:, None], pos, cache,
-                                     block_tables, cfg, pad_lo=pad_lo)
-    return logits[:, 0], cache
+DENSE_BODY = PagedBody(
+    init_paged_cache=_dense_paged_cache, paged_chunk_step=_dense_chunk_step,
+    check_paging=_dense_check_paging, attn_keys=_dense_attn_keys,
+    framed=True, chunk_takes_row=False,
+    attn_keys_gathered=_dense_attn_keys_gathered)
 
 
 def _cached_attention(q, ck, cv, pos, pad_lo, cfg):
@@ -817,7 +886,6 @@ def generate(params: Dict, prompt, cfg, *, max_new_tokens: int,
                             prompt_lens, cfg, max_new_tokens,
                             float(temperature), int(top_k), key)
     if eos_token is not None:
-        import numpy as np
         arr = np.asarray(out)
         # one vectorized argmax over the hit mask, not an O(B) host
         # loop of np.where: rows without an EOS keep their full width.

@@ -3,10 +3,10 @@ leading dense SwiGLU layer, then layers of shared + routed SwiGLU experts
 with group-limited top-k routing that drops nothing.  This module is the
 model as the serving engine runs it: a config object, seeded weights,
 the cache it declares, and its own paged step for a prefill chunk and
-for a decode tick.  `models/decode.py` hands a config that names a
-`paged_model` to that module, so the engine's two jitted programs
-(`engine._prefill_chunk`, `engine._paged_tick`) run it as they run every
-model.
+for a decode tick, bound into one declared body (`BODY`, a
+decode.PagedBody) that the config names, so the engine's two jitted
+programs (`engine._prefill_chunk`, `engine._paged_tick`) run it as they
+run every model.
 
 The cache (one pytree, `engine._cache`):
 
@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -69,7 +68,7 @@ import numpy as np
 from jax import lax
 from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-from ray_tpu.models.decode import _swiglu
+from ray_tpu.models.decode import PagedBody, _swiglu
 from ray_tpu.models.gpt import _rmsnorm
 from ray_tpu.ops import paged_attention as _pa
 
@@ -90,7 +89,6 @@ _CHUNK_SPAN_KEYS = 128
 _GMM_ROWS = 128
 _GMM_K, _GMM_N = 1024, 512
 
-PAGE_KEYS = ("lat",)    # what of `init_paged_cache` is the pool
 COUNTERS = ("pairs_routed", "pairs_local", "experts_touched",
             "experts_held", "load_max")
 _WORD = 30      # a counter is [hi, lo] with lo < 2**30
@@ -163,16 +161,9 @@ class DeepseekV2Config:
         return self.head_dim ** -0.5 * _yarn_mscale(
             self.rope_factor, self.mscale_all_dim) ** 2
 
-    # -- what models/decode.py and the engine ask a model with its own
-    # paged step ------------------------------------------------------
     @property
-    def paged_model(self):
-        return sys.modules[__name__]
-
-    # A page here is latents, not K then V of [page, Hkv, Dh]: what
-    # frames pages (tiers, kv_export / kv_import, migration, session
-    # checkpoints) refuses this model by name (kv_tier.refuse_unframed).
-    pages_are_kv = False
+    def paged_body(self) -> PagedBody:
+        return BODY
 
 
 def _yarn_mscale(factor: float, mscale: float) -> float:
@@ -237,10 +228,6 @@ def attn_keys_gathered(cfg: DeepseekV2Config, pos: np.ndarray,
     cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
     spans = -(-(int(np.asarray(pos).max()) + 1) // cols)
     return len(pos) * spans * cols * cfg.n_layers
-
-
-def chunk_selects(cfg: DeepseekV2Config, start: int) -> bool:
-    return False          # attention reads all a row holds
 
 
 def check_paging(cfg: DeepseekV2Config, *, page_size: int,
@@ -662,3 +649,12 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
         lambda lp, x, l, c: _attn_tick(lp, x, l, c, block_tables, pos, cfg),
         cfg)
     return logits[:, None], cache
+
+
+# A page here is latents, not K then V of [page, Hkv, Dh] (not `framed`):
+# what frames pages refuses this model by name (kv_tier.refuse_unframed).
+BODY = PagedBody(
+    init_paged_cache=init_paged_cache, paged_chunk_step=paged_chunk_step,
+    check_paging=check_paging, attn_keys=attn_keys, page_keys=("lat",),
+    attn_keys_gathered=attn_keys_gathered,
+    snapshot_counters=snapshot_counters, read_counters=read_counters)

@@ -6,8 +6,8 @@ SwiGLU layer and then layers of one shared + routed SwiGLU experts with
 a sigmoid router that renormalises its top-k.  This module is the model
 as the serving engine runs it: a config object, seeded weights, the
 cache it declares, and its own paged step for a prefill chunk and for a
-decode tick.  `models/decode.py` hands a config that names a
-`paged_model` to that module, so the engine's two jitted programs
+decode tick, bound into one declared body (`BODY`, a decode.PagedBody)
+that the config names, so the engine's two jitted programs
 (`engine._prefill_chunk`, `engine._paged_tick`) run it as they run every
 model.
 
@@ -27,7 +27,7 @@ state:
   moe     [5, 2] int32                 the expert layers' counters
                                        (deepseek_v2.COUNTERS)
 
-A ring is state per decode row (`row_state`): what treats a page as the
+A ring is state per decode row (`row_state_keys`): what treats a page as the
 whole of a sequence's state (prefix cache, tiers, kv_export / kv_import,
 migration, session checkpoints) refuses this model by name
 (kv_tier.refuse_row_state).  Nothing zeroes a ring when a row changes
@@ -62,7 +62,6 @@ only.  The multi-token-prediction layer is not here.
 from __future__ import annotations
 
 import dataclasses
-import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -71,7 +70,7 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.models import deepseek_v2 as _ds
-from ray_tpu.models.decode import _rope_at, _swiglu
+from ray_tpu.models.decode import PagedBody, _rope_at, _swiglu
 from ray_tpu.ops import paged_attention as _pa
 
 _HI = lax.Precision.HIGHEST
@@ -84,10 +83,6 @@ _TICK_SPAN_KEYS = 256
 _CHUNK_SPAN_KEYS = 256
 
 COUNTERS = _ds.COUNTERS
-# what of `init_paged_cache` is the pool (a page's bytes are these
-# arrays' together) and what is state per decode row (engine.stats())
-PAGE_KEYS = ("k", "v")
-ROW_STATE_KEYS = ("wk", "wv")
 # A sink's share of a head's softmax is counted in units of 2^-10, so
 # that the counters stay whole numbers (deepseek_v2._count).
 _SINK_UNIT = 1 << 10
@@ -204,13 +199,9 @@ class ExaoneMoeConfig:
                         window=self.window if windowed else 0,
                         rope_theta=self.rope_theta if windowed else None)
 
-    # -- what models/decode.py and the engine ask a model with its own
-    # paged step ------------------------------------------------------
     @property
-    def paged_model(self):
-        return sys.modules[__name__]
-
-    row_state = True      # the rings: state per decode row, not paged
+    def paged_body(self) -> PagedBody:
+        return BODY
 
 
 def _on_tpu() -> bool:
@@ -264,10 +255,6 @@ def attn_keys_gathered(cfg: ExaoneMoeConfig, pos: np.ndarray,
     row's whole ring."""
     return attn_keys_paged(cfg, pos, pos, page_size, nblk)[0] \
         + len(pos) * cfg.window * cfg.n_window
-
-
-def chunk_selects(cfg: ExaoneMoeConfig, start: int) -> bool:
-    return False          # no layer chooses pages
 
 
 def check_paging(cfg: ExaoneMoeConfig, *, page_size: int,
@@ -764,3 +751,11 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
     logits, cache = _through_layers(
         params, embed(tokens[:, 0]), cache, pos > 0, True, attend, cfg)
     return logits[:, None], cache
+
+
+BODY = PagedBody(
+    init_paged_cache=init_paged_cache, paged_chunk_step=paged_chunk_step,
+    check_paging=check_paging, attn_keys=attn_keys,
+    row_state_keys=("wk", "wv"), attn_keys_gathered=attn_keys_gathered,
+    attn_keys_paged=attn_keys_paged, snapshot_counters=snapshot_counters,
+    read_counters=read_counters)

@@ -2,9 +2,9 @@
 attention layer every `attn_layer_period`, every layer followed by a
 SwiGLU feed-forward.  This module is the model as the serving engine
 runs it: a config object, seeded weights, the cache it declares, and its
-own paged step for a prefill chunk and for a decode tick.
-`models/decode.py` hands a config that names a `paged_model` to that
-module, so the engine's two jitted programs (`engine._prefill_chunk`,
+own paged step for a prefill chunk and for a decode tick, bound into
+one declared body (`BODY`, a decode.PagedBody) that the config names, so
+the engine's two jitted programs (`engine._prefill_chunk`,
 `engine._paged_tick`) run it as they run every model.
 
 A Mamba layer keeps, per sequence, a selective-scan state and the last
@@ -28,9 +28,9 @@ all query heads, and no positional encoding of any kind).  The cache
                                  reads one row's, re-laid the whole of
                                  it twice a call)
 
-State per decode row is `row_state`: the pool and the engine's
-reservation count the attention layers' pages alone, and what treats a
-page as the whole of a sequence's state refuses this model by name
+State per decode row is `BODY.row_state_keys`: the pool and the
+engine's reservation count the attention layers' pages alone, and what
+treats a page as the whole of a sequence's state refuses this model by name
 (kv_tier.refuse_row_state).  Here the state is most of the cache
 (E = 5120, N = 16: 9.3 MB a row over 26 layers, against 1 KB a token in
 pages), so the rows limit the batch, not the pool.
@@ -52,7 +52,6 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
-import sys
 from typing import Any, Dict, Tuple
 
 import jax
@@ -60,14 +59,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ray_tpu.models.decode import _swiglu
+from ray_tpu.models.decode import PagedBody, _swiglu
 from ray_tpu.ops import ssm
 
 ATTN, MAMBA = "attention", "mamba"
-# what of `init_paged_cache` is the pool (a page's bytes are these
-# arrays' together) and what is state per decode row (engine.stats())
-PAGE_KEYS = ("k", "v")
-ROW_STATE_KEYS = ("ssm", "conv")
 # Keys one span of an attention layer's softmax covers (whole pages): a
 # tick gathers a span's pages for every row of the call; a chunk scores
 # all its queries against a span in float32, [heads, queries, keys].
@@ -134,13 +129,9 @@ class JambaConfig:
             seen[kind] += n
         return tuple(out)
 
-    # -- what models/decode.py and the engine ask a model with its own
-    # paged step ------------------------------------------------------
     @property
-    def paged_model(self):
-        return sys.modules[__name__]
-
-    row_state = True      # scan state and convolution tail: per decode row
+    def paged_body(self) -> PagedBody:
+        return BODY
 
 
 def _span_pages(keys: int, page_size: int, nblk: int) -> int:
@@ -163,10 +154,6 @@ def attn_keys_gathered(cfg: JambaConfig, pos: np.ndarray, page_size: int,
     cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
     spans = -(-(int(np.asarray(pos).max()) + 1) // cols)
     return len(pos) * spans * cols * cfg.n_attn
-
-
-def chunk_selects(cfg: JambaConfig, start: int) -> bool:
-    return False          # no layer chooses pages
 
 
 def check_paging(cfg: JambaConfig, *, page_size: int, prefill_chunk: int,
@@ -492,3 +479,10 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
         lambda lp, x, i, c: _attn_tick(lp, x, i, c, block_tables, pos, cfg),
         lambda lp, x, i, c: _mamba_tick(lp, x, i, c, pos, cfg))
     return logits[:, None], cache
+
+
+BODY = PagedBody(
+    init_paged_cache=init_paged_cache, paged_chunk_step=paged_chunk_step,
+    check_paging=check_paging, attn_keys=attn_keys,
+    row_state_keys=("ssm", "conv"), n_attn=lambda cfg: cfg.n_attn,
+    attn_keys_gathered=attn_keys_gathered)

@@ -15,8 +15,8 @@ and seeded weights.  Everything that computes is `models/exaone_moe.py`'s
 (K-EXAONE: window rings beside pages, the same router), which reads one
 `AttnKind` a kind of layer from the config's `kind`: its paged step for a
 prefill chunk and for a decode tick, the cache it declares, the counters.
-`models/decode.py` hands a config that names a `paged_model` to that
-module, so the engine's two jitted programs (`engine._prefill_chunk`,
+The config names exaone_moe's declared body (`exaone_moe.BODY`) as its
+own, so the engine's two jitted programs (`engine._prefill_chunk`,
 `engine._paged_tick`) run it as they run every model.
 
 The cache (one pytree, `engine._cache`) is four arrays of four shapes:
@@ -33,7 +33,7 @@ The cache (one pytree, `engine._cache`) is four arrays of four shapes:
                                     sinks' (the share of their softmaxes
                                     they took; the softmaxes counted)
 
-A ring is state per decode row (`row_state`): the prefix cache, tiers,
+A ring is state per decode row (`row_state_keys`): the prefix cache, tiers,
 kv_export / kv_import, migration, session checkpoints and speculation
 refuse this model by name, as they refuse K-EXAONE.
 
@@ -47,7 +47,6 @@ position.  The multi-token-prediction layers are not here.
 from __future__ import annotations
 
 import dataclasses
-import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
@@ -55,21 +54,6 @@ import jax.numpy as jnp
 from ray_tpu.models import exaone_moe as _em
 
 AttnKind = _em.AttnKind
-COUNTERS = _em.COUNTERS
-PAGE_KEYS = _em.PAGE_KEYS
-ROW_STATE_KEYS = _em.ROW_STATE_KEYS
-# the cache, the step and what the engine asks of both: exaone_moe's,
-# which read this config's `kind`, `sliding_windows` and expert fields
-init_paged_cache = _em.init_paged_cache
-snapshot_counters = _em.snapshot_counters
-read_counters = _em.read_counters
-attn_keys = _em.attn_keys
-attn_keys_paged = _em.attn_keys_paged
-attn_keys_gathered = _em.attn_keys_gathered
-chunk_selects = _em.chunk_selects
-check_paging = _em.check_paging
-paged_chunk_step = _em.paged_chunk_step
-route = _em.route
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,13 +148,11 @@ class MimoV2FlashConfig:
         return AttnKind(self.n_kv_heads, self.head_dim, self.v_head_dim,
                         rope_theta=self.rope_theta, **shared)
 
-    # -- what models/decode.py and the engine ask a model with its own
-    # paged step ------------------------------------------------------
     @property
-    def paged_model(self):
-        return sys.modules[__name__]
-
-    row_state = True      # the rings: state per decode row, not paged
+    def paged_body(self) -> _em.PagedBody:
+        """exaone_moe's, which reads this config's `kind`,
+        `sliding_windows` and expert fields."""
+        return _em.BODY
 
 
 def init_params(cfg: MimoV2FlashConfig, key, dtype=None) -> Dict:

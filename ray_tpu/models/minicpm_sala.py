@@ -4,10 +4,10 @@ mixer) and lightning linear attention (the `lightning-attn` mixer) —
 under the MiniCPM family's muP scaling.  This module is the model as the
 serving engine runs it: a config object, seeded weights, the cache it
 declares, and its own paged step for a prefill chunk and for a decode
-tick.  `models/decode.py` hands a config that names a `paged_model` to
-that module instead of its own dense body, so the engine's two jitted
-programs (`engine._prefill_chunk`, `engine._paged_tick`) run it as they
-run every model.
+tick, bound into one declared body (`BODY`, a decode.PagedBody) that the
+config names, so the engine's two jitted programs
+(`engine._prefill_chunk`, `engine._paged_tick`) run it as they run every
+model.
 
 The cache (one pytree, `engine._cache`):
 
@@ -43,7 +43,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import sys
 from typing import Any, Dict, Tuple
 
 import jax
@@ -51,14 +50,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ray_tpu.models.decode import _rope_at, _swiglu
+from ray_tpu.models.decode import PagedBody, _rope_at, _swiglu
 from ray_tpu.models.gpt import _rmsnorm
 
 ATTN, LIN = "minicpm4", "lightning-attn"
-# what of `init_paged_cache` is the pool (a page's bytes are these
-# arrays' together) and what is state per decode row (engine.stats())
-PAGE_KEYS = ("k", "v", "kc")
-ROW_STATE_KEYS = ("state",)
 _HI = lax.Precision.HIGHEST
 _DENSE_SPAN_KEYS = 4096      # keys one softmax part of a dense chunk spans
 
@@ -137,13 +132,9 @@ class SalaConfig:
     def res_scale(self) -> float:
         return self.scale_depth / float(np.sqrt(self.mup_depth))
 
-    # -- what models/decode.py and the engine ask a model with its own
-    # paged step ------------------------------------------------------
     @property
-    def paged_model(self):
-        return sys.modules[__name__]
-
-    row_state = True      # part of a sequence's state lives outside pages
+    def paged_body(self) -> PagedBody:
+        return BODY
 
 
 def attn_keys(cfg: SalaConfig, pos: np.ndarray) -> Tuple[int, int]:
@@ -645,3 +636,10 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict, block_tables,
                                         cfg),
         lambda lp, x, li, c: _lin_tick(lp, x, li, c, pos, cfg))
     return _logits(params, x, cfg)[:, None], cache
+
+
+BODY = PagedBody(
+    init_paged_cache=init_paged_cache, paged_chunk_step=paged_chunk_step,
+    check_paging=check_paging, attn_keys=attn_keys,
+    chunk_selects=chunk_selects, page_keys=("k", "v", "kc"),
+    row_state_keys=("state",), n_attn=lambda cfg: cfg.n_attn)

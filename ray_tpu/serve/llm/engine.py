@@ -25,7 +25,7 @@ page granularity):
     prefilled ONE CHUNK PER TICK directly into their own pages through
     their block table (no scratch cache, no slot splice), so admission
     never stalls decoding for more than one chunk of prefill compute;
-  * every tick runs ONE fused paged_decode_step across all rows with a
+  * every tick runs ONE fused paged_chunk_step across all rows with a
     per-row position vector; when speculation is on and any greedy row
     has a prompt-lookup draft, the tick is instead ONE fused
     paged_chunk_step verifying (pending token + k drafts) per row —
@@ -446,7 +446,7 @@ class EngineStats:
     prefill_tokens_sparse: int = 0    # ...in chunks that selected pages
     state_resets: int = 0             # per-row recurrent states zeroed
     # Bytes of the cache that are state per decode row (the entries the
-    # model names in ROW_STATE_KEYS): resident whatever the traffic, and
+    # body names in `row_state_keys`): resident whatever the traffic, and
     # in no page count.  0 for a model whose pages are all its state.
     row_state_bytes: int = 0
     # Bytes of the page pool (without the trash page): `kv_pages` x what
@@ -590,11 +590,13 @@ def _lookup_draft(req: "_Request", ngram: int, k: int) -> List[int]:
                    donate_argnames=("cache",))
 def _paged_tick(params, token, pos, cache, block_tables, cfg,
                 with_logits):
-    """One paged decode_step across every row (per-row positions) +
+    """One token across every row (per-row positions), as a t=1
+    paged_chunk_step (the step the verify runs: the two cannot drift) +
     on-device greedy argmax; logits ride back to host only when a
     sampled-mode request is active."""
-    logits, cache = decode.paged_decode_step(params, token, pos, cache,
-                                             block_tables, cfg)
+    logits, cache = decode.paged_chunk_step(params, token[:, None], pos,
+                                            cache, block_tables, cfg)
+    logits = logits[:, 0]
     sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return sampled, (logits if with_logits else None), cache
 
@@ -639,8 +641,8 @@ def _paged_verify(params, chunk, pos, cache, block_tables, cfg,
 def _prefill_chunk(params, tokens, pos, cache, block_table, cfg,
                    slot=None, valid=None):
     """One single-row prefill chunk.  `slot` and `valid` are passed only
-    for a model with per-row state (decode.has_row_state): the decode
-    row whose state the chunk carries, and how many of the chunk's
+    to a body whose chunk takes them (PagedBody.chunk_takes_row): the
+    decode row whose state the chunk carries, and how many of its
     tokens are real; left out they mean row 0 and the full width."""
     row = {} if slot is None and valid is None \
         else {"slot": slot, "valid": valid}
@@ -738,13 +740,7 @@ class GenerationEngine:
         if speculate_k and speculate_ngram < 1:
             raise ValueError("speculate_ngram must be >= 1 when "
                              "speculate_k is set")
-        self._model = decode.paged_model(cfg)
-        if self._model is None and getattr(cfg, "n_experts", 0):
-            raise NotImplementedError(
-                "continuous batching runs the dense body for dense models "
-                "only (it has no expert layer; a model that routes brings "
-                "its own paged step)")
-        self._row_state = decode.has_row_state(cfg)
+        self._body = decode.paged_body(cfg)     # resolved once
         if enable_prefix_cache:
             refuse_row_state(cfg, "the prefix cache "
                                   "(enable_prefix_cache=True)")
@@ -773,10 +769,9 @@ class GenerationEngine:
         if self.kv_pages < 1:
             raise ValueError("kv_pages must be >= 1")
         self.prefill_chunk = min(prefill_chunk, self._s_virt)
-        if self._model is not None:
-            self._model.check_paging(cfg, page_size=self.page_size,
-                                     prefill_chunk=self.prefill_chunk,
-                                     speculate_k=self.speculate_k)
+        self._body.check_paging(cfg, page_size=self.page_size,
+                                prefill_chunk=self.prefill_chunk,
+                                speculate_k=self.speculate_k)
         self.default_max_new_tokens = default_max_new_tokens
         self.name = name
         # With kv_commit_factor >= 1 a lone request always fits the cap
@@ -798,13 +793,8 @@ class GenerationEngine:
         # Device + paging state (worker-thread-owned after start).
         # Page 0 is the trash page: every inactive row's block table
         # points at it, so the fused tick's scatter writes land there.
-        self._cache = decode.init_paged_cache(
+        self._cache = self._body.init_paged_cache(
             cfg, self.kv_pages + 1, self.page_size, num_slots)
-        # Columns one span of a tick's attention gathers for a row (the
-        # dense body's; attn_keys_gathered counts with it).
-        self._tick_span = None if self._model is not None else (
-            self.page_size * decode.paged_span_blocks(
-                self._cache, num_slots, self._max_blocks))
         self._alloc = BlockAllocator(self.kv_pages, first_page=1)
         self._prefix = (RadixPrefixCache(
             self.page_size, self._alloc,
@@ -812,26 +802,23 @@ class GenerationEngine:
             if enable_prefix_cache else None)
         # --- KV tier hierarchy (T0 pool / T1 host arena / T2 store) ---
         # A page's bytes are what the cache's pool arrays hold of it
-        # (the dense pool's k and v; a model that declares its own cache
-        # names them in PAGE_KEYS): no config is asked for a head count
+        # (the body's `page_keys`): no config is asked for a head count
         # or a head width, which a model may have two of.
         self._page_nbytes = sum(
-            int(self._cache[k].nbytes)
-            for k in getattr(self._model, "PAGE_KEYS", ("k", "v"))
+            int(self._cache[k].nbytes) for k in self._body.page_keys
         ) // (self.kv_pages + 1)
         # One page's at-rest frame: K then V bytes of [L, psz, Hkv, Dh].
-        # Of the dense pool only: a model that declares its own cache
-        # has no frame yet, and every path that would build one refuses
-        # it by name.
+        # Of a `framed` body only: any other has no frame yet, and every
+        # path that would build one refuses it by name.
         self._page_dtype = np.dtype(cfg.dtype)
         self._page_kshape = self._page_k_nbytes = None
-        if self._model is None:
-            self._page_kshape = (cfg.n_layers, self.page_size,
-                                 decode._kv_heads(cfg), cfg.head_dim)
+        if self._body.framed:
+            n_layers, _, *page = self._cache["k"].shape
+            self._page_kshape = (n_layers, *page)
             self._page_k_nbytes = self._page_nbytes // 2
         self._tiering = bool(_cfg.serve_kv_tiering
                              if kv_tiering is None else kv_tiering) \
-            and enable_prefix_cache and decode.pages_are_kv(cfg)
+            and enable_prefix_cache and self._body.framed
         self._kv_store_dir = kv_store_dir
         self._arena: Optional[HostKVArena] = None   # lazy (worker)
         self._store: Optional[KVPageStore] = None   # lazy (worker)
@@ -926,15 +913,11 @@ class GenerationEngine:
         self._prefill_tokens_sparse = 0
         self._state_resets = 0
         self._row_state_bytes = sum(
-            int(self._cache[k].nbytes)
-            for k in getattr(self._model, "ROW_STATE_KEYS", ()))
+            int(self._cache[k].nbytes) for k in self._body.row_state_keys)
+        self._n_attn = self._body.n_attn(cfg)
         # A routing model's device-side counters, as last fetched by
         # the worker thread (stats() must not touch a cache that every
         # step donates).
-        self._read_model_counters = getattr(self._model, "read_counters",
-                                            None)
-        self._snapshot_model_counters = getattr(
-            self._model, "snapshot_counters", None)
         self._model_counters: Dict[str, Any] = {}
         _jax_utils.install_compile_listener()
 
@@ -1966,8 +1949,8 @@ class GenerationEngine:
         # ...and what a tick read one turn late leaves behind it: its
         # tokens merged into the next tick's, its counters copied.
         _merge_tokens(sampled, tok, jnp.ones((self.num_slots,), bool))
-        if self._snapshot_model_counters is not None:
-            self._snapshot_model_counters(self._cache)
+        if self._body.snapshot_counters is not None:
+            self._body.snapshot_counters(self._cache)
         if self.speculate_k:
             chunk = jnp.zeros((self.num_slots, 1 + self.speculate_k),
                               jnp.int32)
@@ -2001,12 +1984,11 @@ class GenerationEngine:
 
     def _row_args(self, slot: int, valid: int) -> Dict:
         """What a prefill chunk takes beside the dense arguments when the
-        model brings its own step: the decode row the request will
-        occupy and the count of real tokens in the chunk (a recurrent
-        state cannot un-see a pad, and a pad is routed to no expert).
-        Nothing otherwise, so the dense models' program is the one it
-        always was."""
-        if self._model is None:
+        body's chunk takes them: the decode row the request will occupy
+        and the count of real tokens in the chunk (a recurrent state
+        cannot un-see a pad, and a pad is routed to no expert).  Nothing
+        otherwise: the dense models' program is the one it always was."""
+        if not self._body.chunk_takes_row:
             return {}
         return {"slot": jnp.int32(slot), "valid": jnp.int32(valid)}
 
@@ -2215,11 +2197,10 @@ class GenerationEngine:
         st.chunks += 1
         self._prefill_tokens += len(real)
         self._prefill_pad_tokens += width - len(real)
-        if self._model is not None \
-                and self._model.chunk_selects(self.cfg, start):
+        if self._body.chunk_selects(self.cfg, start):
             st.sparse_chunks += 1
             self._prefill_tokens_sparse += len(real)
-        if self._row_state and start == 0:
+        if self._body.has_row_state and start == 0:
             self._state_resets += 1   # the chunk at 0 zeroes the row's
         self._turns_with_chunk += 1   # at most one chunk a turn
         if st.next_start < L:
@@ -2420,10 +2401,10 @@ class GenerationEngine:
         self._launched()
         sampled.copy_to_host_async()
         counters = None
-        if self._snapshot_model_counters is not None:
+        if self._body.snapshot_counters is not None:
             # a 40-byte copy behind the tick, read with its tokens;
             # chunks dispatched before it are in the numbers
-            counters = self._snapshot_model_counters(self._cache)
+            counters = self._body.snapshot_counters(self._cache)
         self._count_keys(rows)
         self._pos[rows] += 1
         self._inflight = _Tick([(s, self._slots[s]) for s in rows],
@@ -2442,7 +2423,7 @@ class GenerationEngine:
         self._phase("device_wait")
         sampled = np.asarray(fl.sampled)
         if fl.counters is not None:
-            self._model_counters = self._read_model_counters(
+            self._model_counters = self._body.read_counters(
                 fl.counters, self.cfg)
         logits_np, row_of = self._ship_sample_logits(fl.logits,
                                                      fl.sample_rows)
@@ -2468,38 +2449,25 @@ class GenerationEngine:
 
     def _count_keys(self, actives, t: int = 1) -> None:
         """attn_keys_*: what this tick's rows hold, what their attention
-        layers read of it and what the call gathered from the pool to
-        read it (while the device runs the tick of `t` tokens a row).
-        The dense body reads all a row holds, in every layer, and
-        gathers for EVERY row of the call whole spans up to the deepest
-        row's last column: the trip count its program reads from the
-        same `_pos`.  A model with its own step reports what it reads."""
-        pos = self._pos[actives]
-        if self._model is None:
-            read = held = (int(pos.sum()) + len(actives)) * self.cfg.n_layers
-            spans = -(-(int(self._pos.max()) + t) // self._tick_span)
-            gathered = self.num_slots * self.cfg.n_layers \
-                * spans * self._tick_span
-        else:
-            read, held = self._model.attn_keys(self.cfg, pos)
-            counted = getattr(self._model, "attn_keys_gathered", None)
-            gathered = read if counted is None else counted(
-                self.cfg, self._pos, self.page_size, self._max_blocks)
+        layers read of it and what the call gathered from the cache to
+        read it (while the device runs the tick of `t` tokens a row), as
+        the body counts them (decode.PagedBody says with what)."""
+        body, pos = self._body, self._pos[actives]
+        last = self._pos if t == 1 else self._pos + (t - 1)
+        read, held = body.attn_keys(self.cfg, pos)
         self._keys_attended += read
         self._keys_resident += held
-        self._keys_gathered += gathered
+        self._keys_gathered += body.keys_gathered(
+            self.cfg, read, last, self.page_size, self._max_blocks)
         # (a model whose layers are not all paged says the same two of
         # the layers that are, so that rings do not dilute the ratio)
-        paged = getattr(self._model, "attn_keys_paged", None)
-        if paged is not None:
-            gathered, held = paged(self.cfg, pos, self._pos,
-                                   self.page_size, self._max_blocks)
+        if body.attn_keys_paged is not None:
+            gathered, held = body.attn_keys_paged(
+                self.cfg, pos, last, self.page_size, self._max_blocks)
             self._keys_gathered_paged += gathered
             self._keys_resident_paged += held
-        # (a model that mixes in layers of another kind says how many
-        # attend: `n_attn`)
         self._keys_context += (int(pos.sum()) + len(actives)) \
-            * getattr(self.cfg, "n_attn", self.cfg.n_layers)
+            * self._n_attn
 
     def _verify_tick(self, actives, spec_drafts):
         """One fused paged_chunk_step verifying every row's pending

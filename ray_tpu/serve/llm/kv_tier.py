@@ -49,6 +49,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ray_tpu._private import tracing as _tracing
+from ray_tpu.models import decode
 
 logger = logging.getLogger(__name__)
 
@@ -63,13 +64,12 @@ def refuse_row_state(cfg, what: str) -> None:
     then V of every layer for one page: the whole of a sequence's state
     for the models the tiers, the prefix cache, migration and session
     checkpoints were built for.  A model that also keeps a recurrent
-    state per decode row (decode.has_row_state) cannot be carried that
-    way: sharing or restoring a prefix would need the state as it stood
-    at the prefix's last page boundary, which nothing snapshots yet.
-    Refused by name rather than served wrong; a no-op for a model whose
-    pages are all of its state."""
-    from ray_tpu.models.decode import has_row_state   # jax: not at import
-    if not has_row_state(cfg):
+    state per decode row (its body declares `row_state_keys`) cannot be
+    carried that way: sharing or restoring a prefix would need the state
+    as it stood at the prefix's last page boundary, which nothing
+    snapshots yet.  Refused by name rather than served wrong; a no-op
+    for a model whose pages are all of its state."""
+    if not decode.paged_body(cfg).has_row_state:
         return
     raise NotImplementedError(
         f"{what} on a model with per-row recurrent state "
@@ -81,13 +81,12 @@ def refuse_unframed(cfg, what: str) -> None:
     """Everything that frames pages (this file's tiers, kv_export /
     kv_import, migration, session checkpoints) calls this first: it
     refuses a model with per-row state (refuse_row_state) and a model
-    whose pages are not K then V of [page, Hkv, Dh] at all
-    (decode.pages_are_kv: a latent page), by name, until a page is
-    opaque bytes of a size the model declares.  The radix prefix cache
-    only hands out page ids and serves such a model as it is."""
+    whose pages are not K then V of [page, Hkv, Dh] at all (its body is
+    not `framed`: a latent page), by name, until a page is opaque bytes
+    of a size the model declares.  The radix prefix cache only hands out
+    page ids and serves such a model as it is."""
     refuse_row_state(cfg, what)
-    from ray_tpu.models.decode import pages_are_kv
-    if pages_are_kv(cfg):
+    if decode.paged_body(cfg).framed:
         return
     raise NotImplementedError(
         f"{what} on a model whose pages are not K then V "

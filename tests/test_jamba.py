@@ -598,7 +598,7 @@ def test_costs_against_hand_counts(arch):
         == arch.total_params(c)
     cache = jax.eval_shape(lambda: decode.init_paged_cache(cfg, 2, 64, 128))
     assert sum(cache[k].size * cache[k].dtype.itemsize
-               for k in jamba.ROW_STATE_KEYS) \
+               for k in jamba.BODY.row_state_keys) \
         == 128 * arch.state_bytes_per_row(c)
     # a tick reads and writes the state: 2 x 9.32 MB a row, beside the
     # weights and the keys
@@ -747,7 +747,7 @@ def test_the_pool_and_the_reservation_count_attention_layers_only(model,
     assert st.kv_blocks_total == 96
     state = N_MAMBA * ROWS * (4 * 64 * 4 + 3 * 64 * 4)   # float32 toy tail
     assert st.row_state_bytes == state == sum(
-        int(served._cache[k].nbytes) for k in jamba.ROW_STATE_KEYS)
+        int(served._cache[k].nbytes) for k in jamba.BODY.row_state_keys)
 
 
 def test_the_engine_serves_it_and_admission_is_by_rows(model, served,

@@ -208,7 +208,7 @@ def test_prefill_chunks_then_ticks_are_one_reference_forward(
     # the program's own counters are the reference's routing, and every
     # window softmax of a real token counted its sink
     routes = np.asarray(routes)
-    counts = mm.read_counters(drv.cache, cfg)
+    counts = mm._em.read_counters(drv.cache, cfg)
     assert counts["pairs_routed"] == routes.size == len(toks) * K * N_MOE
     assert counts["pairs_local"] == int(((routes >= 2) & (routes < 4)).sum())
     assert counts["experts_held"] == n_decode * N_MOE * 2
@@ -265,7 +265,7 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(model, reference):
                                    jnp.float32))["layers"][1]
     assert "shared" not in whole
     h = jax.random.normal(jax.random.PRNGKey(6), (23, 32))
-    ids, w = mm.route(whole["router"], whole["router_bias"], h, cfg)
+    ids, w = mm._em.route(whole["router"], whole["router_bias"], h, cfg)
     live = jnp.ones((23,), bool)
     total, touched = 0, 0
     for share in range(16):
@@ -362,7 +362,7 @@ def test_a_page_is_both_arrays_and_a_ring_is_a_rows(arch, model):
     c = _real_config()
     e = c["serving"]["engine"]
     real = arch.build(c, e["max_seq"], remat=False)
-    shapes = jax.eval_shape(lambda: mm.init_paged_cache(
+    shapes = jax.eval_shape(lambda: decode.init_paged_cache(
         real, e["kv_pages"] + 1, e["page_size"], e["num_slots"]))
     P, psz, B = e["kv_pages"] + 1, e["page_size"], e["num_slots"]
     # four arrays of four shapes; a token's heads lie side by side
@@ -736,7 +736,8 @@ def test_the_engine_serves_it_and_counts(model, served, reference):
     assert 0.05 < gain["attn_sink_mass"] / gain["attn_sink_softmaxes"] < 0.95
     assert gain["state_resets"] == 5 and gain["prefill_tokens_sparse"] == 0
     assert served.stats().row_state_bytes == sum(
-        int(served._cache[k].nbytes) for k in mm.ROW_STATE_KEYS)
+        int(served._cache[k].nbytes)
+        for k in decode.paged_body(cfg).row_state_keys)
 
 
 # ------------------------------------- the toy configuration as a cell

@@ -427,6 +427,38 @@ def _loader():
     return gpt.init_params(cfg, jax.random.PRNGKey(0)), cfg
 
 
+def _loader_held_for_its_reader():
+    """_loader for a replica whose generation waits for its reader: once
+    a first token has been pushed, every decode tick is held until a
+    stream is cancelled (the replica-side generator's finally), so a
+    close after the first token always lands mid-generation, however
+    slowly it travels.  The engine does not wait for a reader by itself:
+    a toy generation is over in milliseconds."""
+    import threading
+
+    from ray_tpu.serve.llm import engine as engine_mod
+    pushed, cancelled = threading.Event(), threading.Event()
+    tick = engine_mod._paged_tick
+    push, cancel = engine_mod.TokenStream._push, engine_mod.TokenStream.cancel
+
+    def held_tick(*a, **k):
+        if pushed.is_set():
+            assert cancelled.wait(60), "the close never reached the stream"
+        return tick(*a, **k)
+
+    def noted_push(self, *a, **k):
+        push(self, *a, **k)
+        pushed.set()
+
+    def noted_cancel(self):
+        cancel(self)
+        cancelled.set()
+    engine_mod._paged_tick = held_tick
+    engine_mod.TokenStream._push = noted_push
+    engine_mod.TokenStream.cancel = noted_cancel
+    return _loader()
+
+
 def test_generic_stream_transport(serve_instance):
     """handle.stream() on a plain deployment: items arrive one by one
     (first item long before the generator finishes) and a mid-stream
@@ -575,14 +607,18 @@ def test_llm_deployment_generate_and_stream(serve_instance):
     assert st["requests_completed"] >= 2
 
     # Early close frees the engine slot (the replica-side generator's
-    # finally cancels its engine request).  The longest generation the
-    # cache allows, so the cancel has a wide window to land in.
-    s2 = handle.options("stream").stream(prompt, max_new_tokens=34)
+    # finally cancels its engine request), on a replica whose ticks wait
+    # for that cancel once the reader has its first token: the request
+    # cannot finish before the close has landed.
+    held = llm_deployment(
+        _loader_held_for_its_reader, name="llm-held",
+        engine_config=dict(ENGINE_KW)).deploy()
+    s2 = held.options("stream").stream(prompt, max_new_tokens=34)
     assert next(s2) == int(want[0])
     s2.close()
     deadline = time.monotonic() + 30
     while time.monotonic() < deadline:
-        st = handle.stats.remote().result(timeout=60)
+        st = held.stats.remote().result(timeout=60)
         if st["active_slots"] == 0 and st["requests_cancelled"] >= 1:
             break
         time.sleep(0.1)

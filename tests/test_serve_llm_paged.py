@@ -215,8 +215,9 @@ def test_paged_chunk_step_matches_contiguous(cfg):
             rtol=1e-6, atol=1e-7)
     toks = jnp.asarray([7, 8], jnp.int32)
     pos = jnp.asarray(lens, jnp.int32)
-    logits, pool = decode.paged_decode_step(params, toks, pos, pool,
-                                            jnp.asarray(tables), cfg)
+    logits, pool = decode.paged_chunk_step(params, toks[:, None], pos, pool,
+                                           jnp.asarray(tables), cfg)
+    logits = logits[:, 0]
     for i in range(2):
         np.testing.assert_allclose(np.asarray(logits[i]),
                                    np.asarray(solo[i][0][0]),
@@ -412,9 +413,9 @@ def _span_of(monkeypatch, pool, rows, nblk, blocks):
     calls of `rows` rows (at the tests' sizes the whole table is far
     under the bytes a span is sized from)."""
     _, _, psz, hkv, dh = pool["k"].shape
-    monkeypatch.setattr(decode, "_SPAN_BYTES", blocks * rows * psz * hkv
-                        * dh * pool["k"].dtype.itemsize)
-    assert decode.paged_span_blocks(pool, rows, nblk) == blocks
+    key_bytes = rows * psz * hkv * dh * pool["k"].dtype.itemsize
+    monkeypatch.setattr(decode, "_SPAN_BYTES", blocks * key_bytes)
+    assert decode.paged_span_blocks(key_bytes, psz, nblk) == blocks
 
 
 # Spans of 3 blocks = 12 columns.  name: (cfg, blocks a row, t, pos
@@ -634,7 +635,8 @@ def test_attn_keys_gathered_follows_the_deepest_row(monkeypatch):
     L, span = GPT_CFG.n_layers, 12
     _span_of(monkeypatch, decode.init_paged_cache(GPT_CFG, 31, 4), 2, 11, 3)
     eng = _parked_engine(**SPAN_KW)
-    assert eng._tick_span == span
+    assert decode.DENSE_BODY.attn_keys_gathered(
+        GPT_CFG, eng._pos, 4, 11) == 2 * L * span
 
     def tick(pos, actives, t=1):
         before = eng.stats()

@@ -14,6 +14,7 @@ path degrades to re-prefill, never to a corrupt cache.
 """
 
 import asyncio
+import dataclasses
 import threading
 import time
 
@@ -716,7 +717,8 @@ def test_an_engine_without_tiers_never_has_a_lander(monkeypatch, why):
     if why == "tiering_off":
         kw["kv_tiering"] = False
     else:
-        monkeypatch.setattr(decode, "pages_are_kv", lambda cfg: False)
+        unframed = dataclasses.replace(decode.DENSE_BODY, framed=False)
+        monkeypatch.setattr(decode, "paged_body", lambda cfg: unframed)
     before = set(threading.enumerate())
     eng = _engine(name=f"land-none-{why}", kv_pages=12, num_slots=2, **kw)
     with eng:
