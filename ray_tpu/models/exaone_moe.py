@@ -458,44 +458,55 @@ def _global_chunk(lp, x, i, cache, bt, start, kind: AttnKind, cfg):
     T = x.shape[0]
     G, Dh, Dv = kind.n_kv_heads, kind.head_dim, kind.v_head_dim
     psz = cache["k"].shape[2]
-    R = cfg.n_heads // G
-    dt = cfg.dtype
-    cols = start + jnp.arange(T)
-    q, k, v = _project(lp, x, cols, kind, cfg)
+    q, k, v = _project(lp, x, start + jnp.arange(T), kind, cfg)
     pages = lax.dynamic_slice(bt, (start // psz,), (T // psz,))
     paged = (T // psz, psz)
     ck = cache["k"].at[i, pages].set(k.reshape(paged + _kept(kind, Dh)))
     cv = cache["v"].at[i, pages].set(v.reshape(paged + _kept(kind, Dv)))
-
     with jax.named_scope("attn_global"):
-        nblk = bt.shape[0]
-        span = _span_pages(_CHUNK_SPAN_KEYS, psz, nblk)
-        width = span * psz
-        qg = q.reshape(T, G, R, Dh)
-
-        def attend(j, part):
-            first = jnp.minimum(j * span, nblk - span)   # as the slice clamps
-            pg = lax.dynamic_slice(bt, (first,), (span,))
-            ks = ck[i, pg].reshape(width, G, Dh)
-            vs = cv[i, pg].reshape(width, G, Dv)
-            s = jnp.einsum("tgrd,sgd->grts", qg, ks,
-                           preferred_element_type=jnp.float32) * Dh ** -0.5
-            kcols = first * psz + jnp.arange(width)
-            seen = (kcols[None, :] <= cols[:, None]) \
-                & (kcols[None, :] >= j * width)
-            s = jnp.where(seen[None, None], s, -jnp.inf)
-            return _merge(part, s, lambda e: jnp.einsum(
-                "grts,sgd->grtd", e.astype(dt), vs,
-                preferred_element_type=jnp.float32))
-
-        stat = jnp.full((G, R, T), -jnp.inf, jnp.float32)
-        _, total, acc = lax.fori_loop(
-            0, (start + T + width - 1) // width, attend,
-            (stat, jnp.zeros_like(stat),
-             jnp.zeros((G, R, T, Dv), jnp.float32)))
-        out = (acc / total[..., None]).astype(dt)             # [G, R, T, Dv]
-        out = jnp.moveaxis(out, 2, 0).reshape(T, G * R, Dv)
+        out = _span_chunk(q, ck, cv, i, bt, start, kind)
     return _close(lp, x, out, cfg), dict(cache, k=ck, v=cv)
+
+
+def _span_chunk(q, ck, cv, i, bt, start, kind: AttnKind):
+    """A single-row chunk's attention over the pages of layer `i` of the
+    pools ck, cv [n, P, page, ...], the chunk's own keys among them
+    (written before the call): q [T, H, Dh] at positions start.. over
+    the row's pages `bt` in spans of `_CHUNK_SPAN_KEYS` keys, each
+    query up to its own position -> [T, H, Dv].  Also models/zaya.py's
+    (pages of two heads side by side)."""
+    T, H, _ = q.shape
+    G, Dh, Dv = kind.n_kv_heads, kind.head_dim, kind.v_head_dim
+    R = H // G
+    psz, nblk = ck.shape[2], bt.shape[0]
+    dt = q.dtype
+    cols = start + jnp.arange(T)
+    span = _span_pages(_CHUNK_SPAN_KEYS, psz, nblk)
+    width = span * psz
+    qg = q.reshape(T, G, R, Dh)
+
+    def attend(j, part):
+        first = jnp.minimum(j * span, nblk - span)   # as the slice clamps
+        pg = lax.dynamic_slice(bt, (first,), (span,))
+        ks = ck[i, pg].reshape(width, G, Dh)
+        vs = cv[i, pg].reshape(width, G, Dv)
+        s = jnp.einsum("tgrd,sgd->grts", qg, ks,
+                       preferred_element_type=jnp.float32) * Dh ** -0.5
+        kcols = first * psz + jnp.arange(width)
+        seen = (kcols[None, :] <= cols[:, None]) \
+            & (kcols[None, :] >= j * width)
+        s = jnp.where(seen[None, None], s, -jnp.inf)
+        return _merge(part, s, lambda e: jnp.einsum(
+            "grts,sgd->grtd", e.astype(dt), vs,
+            preferred_element_type=jnp.float32))
+
+    stat = jnp.full((G, R, T), -jnp.inf, jnp.float32)
+    _, total, acc = lax.fori_loop(
+        0, (start + T + width - 1) // width, attend,
+        (stat, jnp.zeros_like(stat),
+         jnp.zeros((G, R, T, Dv), jnp.float32)))
+    out = (acc / total[..., None]).astype(dt)             # [G, R, T, Dv]
+    return jnp.moveaxis(out, 2, 0).reshape(T, G * R, Dv)
 
 
 def _global_tick(lp, x, i, cache, bt, pos, kind: AttnKind, cfg):

@@ -471,6 +471,13 @@ class EngineStats:
     moe_experts_held: int = 0
     moe_load_max: int = 0
     moe_load_mean: float = 0.0
+    # A model whose router's weights are not renormalised counts them
+    # too: the weight its chosen expert gave a token, summed over what
+    # `moe_pairs_routed` counts, and the pairs that sums.  Their ratio is
+    # the mean weight a chosen expert gets (1 if something renormalised
+    # a top-1).
+    moe_gate_mass: float = 0.0
+    moe_gate_tokens: int = 0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)

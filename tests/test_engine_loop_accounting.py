@@ -439,7 +439,8 @@ def test_tick_ahead_share_is_the_throughput_cells():
         "layer": "Scheduler (serve/llm/scheduler.py, engine.py admission)",
         "moves": "out_tok_per_s",
         "workloads": ["internlm2-batch", "sala-longdoc", "dsv2-decode",
-                      "kexaone-reason", "jamba2-chat", "mimo-agent"]}
+                      "kexaone-reason", "jamba2-chat", "mimo-agent",
+                      "zaya-reason"]}
     tput = next(m for m in spec["end_to_end"]
                 if m["name"] == "out_tok_per_s")
     assert entry["workloads"] == tput["workloads"]

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (decode, deepseek_v2, exaone_moe, gpt, jamba,
-                            llama, mimo_v2_flash, minicpm_sala)
+                            llama, mimo_v2_flash, minicpm_sala, zaya)
 from ray_tpu.serve.llm.engine import GenerationEngine
 
 # ------------------------------------------------------------- a fake body
@@ -169,6 +169,7 @@ REAL = {
     "jamba": (lambda: jamba.JambaConfig(max_seq=64), jamba.BODY, True),
     "mimo_v2_flash": (lambda: mimo_v2_flash.MimoV2FlashConfig(max_seq=64),
                       exaone_moe.BODY, True),
+    "zaya": (lambda: zaya.ZayaConfig(max_seq=64), zaya.BODY, True),
 }
 
 

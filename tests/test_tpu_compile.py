@@ -603,3 +603,89 @@ def test_jamba_paged_step_compiles(chip, step, monkeypatch):
     shapes = [s.split(",") for s in re.findall(r" = \w+\[([\d,]+)\]", text)]
     wide = [s for s in shapes if str(blocks * e["page_size"]) in s]
     assert not wide, wide[:4]
+
+
+# The eighth configuration (benchmarks/configs/zaya1-8b-pp2-d20.json):
+# twenty layers that EACH page 2 heads of 128 (a token's heads side by
+# side, 1 KB a token and layer) and keep three tails a decode row, top-1
+# of 16 experts through the shared grouped matmul, a tied head of
+# 262,272 rows, built as the benchmark builds it, at its sizes.
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_zaya_paged_step_compiles(chip, step, monkeypatch):
+    """Both programs of zaya1-8b-pp2-d20 with the grouped matmul and the
+    ragged kernel as the chip runs them: 9.4 GB of weights, every
+    layer's pool and 10 MB of tails are resident, neither the pool nor a
+    tail array is re-laid or copied, a tick is one paged-attention
+    kernel and three grouped matmuls a layer and holds no array as wide
+    as the table, and what a step holds beside its arguments is its
+    float32 logits ([rows or chunk, 262272]) and little else."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import deepseek_v2, exaone_moe
+    monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    monkeypatch.setattr(exaone_moe, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs", "zaya1-8b-pp2-d20.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+    L, pool = 20, (20, e["kv_pages"] + 1, e["page_size"], 2 * 128)
+    assert cache["k"].shape == cache["v"].shape == pool
+    assert cache["cz"].shape == cache["cc"].shape == (L, rows, 1280)
+    assert cache["cv"].shape == (L, rows, 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if step == "decode_tick":
+        lowered = engine._paged_tick.lower(
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
+            with_logits=False)
+        logits = rows * cfg.vocab_size * 4
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+        logits = 0      # the chunk's are its result, not a temporary
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < logits + (1 << 28), \
+        mem.temp_size_in_bytes / 2**30
+    held = sum(cache[k].size * 2 for k in ("k", "v", "cz", "cc", "cv"))
+    assert arch.weight_bytes(c) + held \
+        < mem.argument_size_in_bytes < arch.weight_bytes(c) + held + (1 << 26)
+    text = compiled.as_text()
+    for name in ("k", "v", "cz", "cc", "cv"):
+        held = "bf16[%s]" % ",".join(map(str, cache[name].shape))
+        moved = [ln for ln in text.splitlines()
+                 if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
+        if name in "kv":
+            layouts = set(re.findall(re.escape(held) + r"\{([\d,]+)", text))
+            assert layouts == {"3,2,1,0"}, (name, layouts)  # never re-laid
+            assert not moved, moved[:4]
+        else:
+            # a tail array (5 MB, or 0.5) may be taken into fast memory
+            # in a layout of the compiler's own: once in, once out (two
+            # of the three are of one shape)
+            assert len(moved) <= 4, moved[:4]
+    kernels = [ln for ln in text.splitlines()
+               if "custom-call(" in ln and "tpu_custom_call" in ln]
+    if step == "prefill_chunk":
+        assert len(kernels) == 3 * L, len(kernels)
+        return
+    ragged = [ln for ln in kernels if " %paged_attention" in ln]
+    assert len(ragged) == L and len(kernels) == 4 * L, len(kernels)
+    shapes = [(kind, dims.split(",")) for kind, dims in
+              re.findall(r" = (\w+)\[([\d,]+)\]", text)]
+    wide = [s for s in shapes if str(blocks * e["page_size"]) in s[1]
+            and s != ("s32", [str(rows * blocks)])]
+    assert not wide, wide[:4]
