@@ -26,10 +26,15 @@ The cache (one pytree, `engine._cache`):
                                 state, one per decode row (not paged)
 
 A page is one selection block (`page_size` must equal `cfg.block`).
-Attention gathers, per row (tick) or per token (sparse prefill) and per
-KV group, at most `dense_len / block` pages: the row's first ones below
-`dense_len` (a chunk: the power-of-two bucket of them that holds its last
-token), the `topk` chosen ones above it — never the virtual width.
+A tick gathers, per row and KV group, at most `dense_len / block` pages:
+the row's first ones below `dense_len`, the `topk` chosen ones above it
+— never the virtual width.  A chunk below `dense_len` attends to the
+row's first pages (the power-of-two bucket of them that holds its last
+token).  A chunk past it gathers nothing: the selection is a mask
+(`chosen_blocks`: the set `select_blocks` would list, found without a
+sort), and one kernel a layer (ops/paged_prefill_attention.py) walks the
+row's pages once for each query block, the 64 tokens of one page of the
+chunk, and scores them against a page under that mask.
 
 What the engine has to know: a row's state is zeroed by the chunk that
 starts at position 0 (inside the program); the chunk writes the state of
@@ -52,6 +57,7 @@ from jax import lax
 
 from ray_tpu.models.decode import PagedBody, _rope_at, _swiglu
 from ray_tpu.models.gpt import _rmsnorm
+from ray_tpu.ops.paged_prefill_attention import query_block_attention
 
 ATTN, LIN = "minicpm4", "lightning-attn"
 _HI = lax.Precision.HIGHEST
@@ -238,16 +244,16 @@ def lightning_slopes(n_heads: int):
 # Block selection (shared by the chunk and the tick)
 
 
-def select_blocks(q, kc, qpos, cfg: SalaConfig):
-    """The `topk` blocks each query attends to, per KV group.
+def block_scores(q, kc, qpos, cfg: SalaConfig):
+    """Each query's score of every block of its row, per KV group.
 
     q [N, G, R, Dh]; kc [N or 1, G, J, Dh] float32, the compressed keys
     in position order (kernel j covers tokens 16j .. 16j+31); qpos [N].
     Softmax over the kernels wholly at or before the query, summed over
     a group's heads; a block scores the maximum over the kernels that
-    overlap it; the first blocks and the local window are forced.
-    Returns block ids [N, G, topk] by falling score: the forced blocks
-    first (the sparse chunk reads those once a block of queries)."""
+    overlap it (a sum of probabilities, so 0 or more); the first blocks
+    and the local window are forced (1e9); a block past the query's own
+    scores -1.  Returns [N, G, J / 4] float32."""
     N, G, R, Dh = q.shape
     J = kc.shape[2]
     nb = J // 4
@@ -266,8 +272,49 @@ def select_blocks(q, kc, qpos, cfg: SalaConfig):
     bq = (qpos // cfg.block)[:, None]
     forced = (b < cfg.init_blocks) | ((b <= bq) & (b > bq - cfg.local_blocks))
     score = jnp.where(forced[:, None, :], 1e9, score)
-    score = jnp.where((b <= bq)[:, None, :], score, -1.0)
-    return lax.top_k(score, cfg.topk)[1].astype(jnp.int32)
+    return jnp.where((b <= bq)[:, None, :], score, -1.0)
+
+
+def select_blocks(q, kc, qpos, cfg: SalaConfig):
+    """The `topk` blocks each query attends to, per KV group (arguments
+    as `block_scores`): block ids [N, G, topk] by falling score, the
+    forced blocks first.  What a tick gathers a row's pages by."""
+    return lax.top_k(block_scores(q, kc, qpos, cfg),
+                     cfg.topk)[1].astype(jnp.int32)
+
+
+def chosen_blocks(score, topk: int):
+    """score [..., nb] float32 -> [..., nb] bool: the set of blocks that
+    `lax.top_k(score, topk)` names, found without sorting.  What a
+    sparse chunk masks a query block's walk over the row's pages by.
+
+    The `topk`-th largest score is found by bisection on the float32 bit
+    pattern (made an integer of the same order), 32 passes of compare
+    and count; a block is chosen if it scores more, or scores just that
+    and is among the first of its equals that fill the set, which is
+    `lax.top_k`'s own rule on ties (the lower index first)."""
+    bits = lax.bitcast_convert_type(score, jnp.int32)
+    key = bits ^ ((bits >> 31) & 0x7FFFFFFF)     # ordered as the floats are
+    # (rows of scores: a [512, 2, 528] array is tiled two rows at a time,
+    # and the passes over it took 0.58 ms on a v5e against 0.22 or less)
+    key = key.reshape(-1, key.shape[-1])
+
+    def bit(i, t):
+        # t less the lowest integer, unsigned, gains its bits from the top
+        # (the sum wraps)
+        more = t + (jnp.int32(1) << (31 - i))
+        return jnp.where((key >= more).sum(-1, keepdims=True) >= topk,
+                         more, t)
+    kth = lax.fori_loop(0, 32, bit,
+                        jnp.full((key.shape[0], 1), -2 ** 31, jnp.int32))
+    above, ties = key > kth, key == kth
+    room = topk - above.sum(-1, keepdims=True)
+    chosen = above | (ties & (jnp.cumsum(ties, axis=-1) <= room))
+    return chosen.reshape(score.shape)
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def _softmax_attend(q, k, v, mask, dt):
@@ -377,42 +424,28 @@ def _attn_chunk(lp, x, li, cache, bt, start, cfg):
         return branch
 
     def sparse(_):
-        """A block of queries (one page of the chunk) shares its forced
-        blocks — the initial ones and the window ending at its own page
-        — so those are read once a block of queries; only the
-        `topk - forced` blocks each token chose for itself are gathered
-        token by token.  One softmax over both."""
+        """Block-sparse attention a QUERY BLOCK (one page of the chunk)
+        at a time.  The selection hands over a mask and sorts nothing:
+        `chosen_blocks` of the scores `select_blocks` would sort; one
+        kernel a layer (ops/paged_prefill_attention.py) then walks the
+        row's pages once a query block and scores its 64 tokens x the
+        group's heads against each page under that mask (in the block's
+        own page: the keys up to the token), one softmax over all of it:
+        the forced blocks are pages like the others."""
         kcg = ckc[li, bt].swapaxes(0, 1).reshape(1, G, nblk * 4, Dh)
-        qb = psz
-        n_forced = cfg.init_blocks + cfg.local_blocks
-
-        def block(args):
-            qq, cc = args
-            with jax.named_scope("sparse_score"):
-                # top_k sorts: the forced blocks (score 1e9) come first
-                own = select_blocks(qq, kcg, cc, cfg)[..., n_forced:]
-            with jax.named_scope("sparse_attend"):
-                forced = jnp.concatenate([
-                    jnp.arange(cfg.init_blocks),
-                    cc[0] // psz - cfg.local_blocks + 1
-                    + jnp.arange(cfg.local_blocks)])
-                s_f, v_f = shared(qq, cc, bt[forced], forced * psz)
-                pg = bt[own]                             # [qb, G, own]
-                k_o = ck[li, pg, garange[None, :, None]].reshape(
-                    qb, G, -1, Dh)
-                v_o = cv[li, pg, garange[None, :, None]].reshape(
-                    qb, G, -1, Dh)
-                s_o = jnp.einsum("tgrd,tgsd->tgrs", qq, k_o,
-                                 preferred_element_type=jnp.float32) \
-                    * Dh ** -0.5              # all before the window
-                p = jax.nn.softmax(jnp.concatenate([s_f, s_o], -1), axis=-1
-                                   ).astype(dt)
-                nf = s_f.shape[-1]
-                return jnp.einsum("tgrs,gsd->tgrd", p[..., :nf], v_f) \
-                    + jnp.einsum("tgrs,tgsd->tgrd", p[..., nf:], v_o)
-        out = lax.map(block, (qg.reshape(T // qb, qb, G, R, Dh),
-                              cols.reshape(T // qb, qb)))
-        return out.reshape(T, G * R, Dh)
+        with jax.named_scope("sparse_score"):
+            score = lax.map(
+                lambda a: block_scores(a[0], kcg, a[1], cfg),
+                (qg.reshape(T // psz, psz, G, R, Dh),
+                 cols.reshape(T // psz, psz))).reshape(T, G, nblk)
+            chosen = chosen_blocks(score, cfg.topk)
+        with jax.named_scope("sparse_attend"):
+            # keys of each page a token sees: its own page up to itself
+            seen = jnp.clip(cols[:, None] + 1 - jnp.arange(nblk) * psz,
+                            0, psz)
+            visible = jnp.where(chosen, seen[:, None, :], 0)
+            return query_block_attention(q, ck, cv, li, bt, visible,
+                                         interpret=not _on_tpu())
 
     # Below dense_len the chunk attends to the row's first pages, as many
     # as a power-of-two bucket that holds the chunk's last token.

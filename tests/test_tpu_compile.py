@@ -226,16 +226,23 @@ def test_paged_step_compiles_at_a_32k_width(chip, step):
 # model with its own paged step and cache, built as the benchmark builds
 # it, at the configuration's sizes.
 @pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
-def test_sala_paged_step_compiles(chip, step):
+def test_sala_paged_step_compiles(chip, step, monkeypatch):
     """Both programs of minicpm-sala-d16: under 1 GiB of temporaries
     (11.4 GB of weights, pages and state are resident), the page pool
     never re-laid or copied, and in the tick no array as wide as a row's
     virtual sequence (`max_seq`): attention reads 128 page slots a row
-    and the scorer one compressed key per 16 tokens."""
+    and the scorer one compressed key per 16 tokens.  The chunk with its
+    sparse branch as the chip runs it (a Pallas kernel, not its
+    interpreter: `minicpm_sala._on_tpu` is patched true): one
+    `ops/paged_prefill_attention.py` kernel handed the pools as they
+    lie, and no sort anywhere in the program (the selection is a mask;
+    the tick's program keeps its `top_k`)."""
     import json
     import os
 
     from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import minicpm_sala
+    monkeypatch.setattr(minicpm_sala, "_on_tpu", lambda: True)
     bench = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks")
     with open(os.path.join(bench, "configs", "minicpm-sala-d16.json")) as f:
@@ -272,10 +279,18 @@ def test_sala_paged_step_compiles(chip, step):
     moved = [ln for ln in text.splitlines()
              if re.search(r"= " + re.escape(pool) + r"\S* copy\(", ln)]
     assert not moved, moved[:4]
+    sorts = [ln for ln in text.splitlines()
+             if re.search(r" (sort|topk)\(|TopK", ln)]
     if step == "decode_tick":
         wide = blocks * e["page_size"]
         shapes = re.findall(r" = \w+\[([\d,]+)\]", text)
         assert not [s for s in shapes if str(wide) in s.split(",")]
+        assert sorts                    # (what the chunk's check looks for)
+    else:
+        calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+                 and "query_block_attention" in ln]
+        assert calls and all(ln.count(pool) == 2 for ln in calls), calls[:2]
+        assert not sorts, sorts[:4]
 
 
 # The fourth configuration (benchmarks/configs/deepseek-v2-ep4-d5.json):
