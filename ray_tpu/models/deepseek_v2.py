@@ -22,7 +22,7 @@ The cache (one pytree, `engine._cache`):
                             whole pool twice a tick (0.5 GB each, by its
                             own account for a v5e); one array is also one
                             gather a span, not two
-  moe   [5, 2] int32                 the expert layers' own counters,
+  moe   [6, 2] int32                 the expert layers' own counters,
                                      cumulative (COUNTERS; two words
                                      each, so they do not wrap)
 
@@ -38,9 +38,11 @@ The expert layer is told which experts it holds (`experts_held`,
 are chosen among all of them, and the layer computes
 `shared(x) + sum over (top-k AND held) of w_i expert_i(x)`.  What the
 absent experts would add is left out; nothing stands in for them.
-Routed pairs are sorted by expert and run through one grouped matmul a
-projection (Pallas `megablox.gmm`), which visits only the experts that
-a token chose: an expert no token chose is not read.  `routed_experts`,
+Routed pairs are sorted by expert, the held ones first, and those run
+through one grouped matmul a projection (Pallas `megablox.gmm`) a slab
+of as many pairs as the call has tokens: the layer's work follows the
+pairs held here, not the pairs routed, and an expert no token chose is
+not read.  `routed_experts`,
 `count_routed` and the counters have a second caller, models/exaone_moe.py
 (a sigmoid router of its own over the same grouped matmul): they read
 `cfg.top_k`, `cfg.experts_held`, `cfg.expert_offset` and nothing else
@@ -90,7 +92,7 @@ _GMM_ROWS = 128
 _GMM_K, _GMM_N = 1024, 512
 
 COUNTERS = ("pairs_routed", "pairs_local", "experts_touched",
-            "experts_held", "load_max")
+            "experts_held", "load_max", "pairs_worked")
 _WORD = 30      # a counter is [hi, lo] with lo < 2**30
 
 
@@ -310,7 +312,7 @@ def init_paged_cache(cfg: DeepseekV2Config, num_pages: int, page_size: int,
 
 def read_counters(cache: Dict, cfg: DeepseekV2Config) -> Dict[str, Any]:
     """The expert layers' cumulative counters as the engine's stats
-    carry them (`moe_<name>`; a host copy of 40 bytes; only the thread
+    carry them (`moe_<name>`; a host copy of 48 bytes; only the thread
     that owns the cache may call it: every step donates the cache; or
     of a `snapshot_counters` of it, at any time).
     `pairs_routed`: tokens x top_k x expert layers, over ticks' live rows
@@ -318,7 +320,10 @@ def read_counters(cache: Dict, cfg: DeepseekV2Config) -> Dict[str, Any]:
     here; `experts_touched` / `experts_held`: held experts with a token /
     held experts, per tick and expert layer; `load_max`: the busiest
     held expert's tokens, per call and expert layer, and `load_mean`
-    the mean over the held beside it (= pairs_local / experts held)."""
+    the mean over the held beside it (= pairs_local / experts held);
+    `pairs_worked`: the rows `routed_experts`' slabs covered (trips x
+    slab), summed as `pairs_local` is: their ratio is what the layer
+    worked on for each pair it had to."""
     words = np.asarray(cache["moe"]).astype(np.int64)
     counts = {name: int((hi << _WORD) + lo)
               for name, (hi, lo) in zip(COUNTERS, words)}
@@ -391,47 +396,95 @@ def _grouped(rows, w, sizes, out_dtype, tm):
                interpret=not _on_tpu())
 
 
+def _slab(N: int, k: int) -> Tuple[int, int, int]:
+    """(the grouped matmul's row tile, the rows of one slab of
+    `routed_experts`, the slabs N x k pairs can fill) for N tokens of k
+    choices each: a slab is as many pairs as the call has tokens, in
+    whole tiles, so the pairs of a call take at most k trips and as few
+    as the chip's share of the experts asks."""
+    tm = min(_GMM_ROWS, -(-N * k // 8) * 8)
+    S = -(-N // tm) * tm
+    return tm, S, -(-N * k // S)
+
+
+def _trips(held, S: int, most: int):
+    """The slabs `held` pairs fill; where all the call's pairs fit one
+    slab there is nothing to walk and it runs once, whatever is held (a
+    loop whose trips the device counts, run once, read 0.3 ms more in a
+    tick of 20 top-1 expert layers than the same slab in line)."""
+    return 1 if most == 1 else -(-held // S)
+
+
 def routed_experts(experts, h, ids, weights, live, cfg: DeepseekV2Config):
     """The held experts' part of the layer: for each token of h [N, D]
     the sum over its chosen experts THAT ARE HELD HERE of weight x
     SwiGLU_expert(h); a token none of whose experts is held gets zeros,
     and so does a token that is not `live` [N] (an idle decode row, a
     chunk's pad), which also counts nowhere.  Nothing is dropped: the
-    (token, expert) pairs are sorted by expert and every one runs, in
-    one grouped matmul a projection sized for the worst case (all
-    N x top_k pairs local) that visits only the experts chosen.
+    (token, expert) pairs are sorted by expert, the held ones first,
+    and every held one runs.  The work follows the pairs held, not the
+    pairs routed: they are walked in slabs of `_slab` rows, as many
+    trips as they fill (`_trips`: none where nothing is held, one where
+    the call's pairs fit a slab), and a trip gathers
+    its rows, runs one grouped matmul a projection over its share of
+    each expert's group (an expert no token chose is not read) and adds
+    its weighted rows to their tokens.  No array of the call has a row
+    a routed pair.
     Returns ([N, D] float32, tokens on each held expert [experts_held])."""
     N, D = h.shape
     k, E = cfg.top_k, cfg.experts_held
     dt = h.dtype
+    P = N * k
+    tm, S, most = _slab(N, k)
+    Pp = most * S
     local = ids - cfg.expert_offset
     held = (local >= 0) & (local < E) & live[:, None]        # [N, k]
-    P = N * k
-    tm = min(_GMM_ROWS, -(-P // 8) * 8)
-    Pp = -(-P // tm) * tm
-    group = jnp.pad(jnp.where(held, local, E).reshape(P), (0, Pp - P),
-                    constant_values=E)            # E: not here, sorts last
-    order = jnp.argsort(group)
-    sizes = jnp.zeros((E + 1,), jnp.int32).at[group].add(1)[:E]
-    rows = h[jnp.minimum(order, P - 1) // k]                 # [Pp, D]
-    mid = jax.nn.silu(_grouped(rows, experts["w_gate"].astype(dt), sizes,
-                               dt, tm)) \
-        * _grouped(rows, experts["w_up"].astype(dt), sizes, dt, tm)
-    out = _grouped(mid, experts["w_down"].astype(dt), sizes, jnp.float32, tm)
-    out = jnp.where((jnp.arange(Pp) < sizes.sum())[:, None], out, 0.0)
-    back = jnp.zeros((Pp,), jnp.int32).at[order].set(jnp.arange(Pp))[:P]
-    pairs = out[back].reshape(N, k, D)
-    return (pairs * jnp.where(held, weights, 0.0)[..., None]).sum(1), sizes
+    # One sort of (group, pair) keys: E = not here, sorts last; a pad
+    # after them.  The held pairs are then order[:n], n = sizes.sum().
+    bits = (P - 1).bit_length()
+    keys = jnp.sort((jnp.where(held, local, E).reshape(P) << bits)
+                    | jnp.arange(P))
+    ends = jnp.searchsorted(keys, jnp.arange(1, E + 1) << bits
+                            ).astype(jnp.int32)              # [E]
+    sizes = jnp.diff(ends, prepend=0)
+    order = jnp.pad(keys & ((1 << bits) - 1), (0, Pp - P))
+    flat_w = weights.reshape(P)
+    w_gate, w_up, w_down = (experts[n].astype(dt)
+                            for n in ("w_gate", "w_up", "w_down"))
+
+    def trip(s, acc):
+        lo = s * S
+        pair = lax.dynamic_slice(order, (lo,), (S,))
+        tok = pair // k
+        # the groups, cut to the slab
+        part = jnp.diff(jnp.clip(ends - lo, 0, S), prepend=0)
+        rows = h[tok]                                        # [S, D]
+        mid = jax.nn.silu(_grouped(rows, w_gate, part, dt, tm)) \
+            * _grouped(rows, w_up, part, dt, tm)
+        out = _grouped(mid, w_down, part, jnp.float32, tm)
+        # rows past the held pairs come back undefined: zeros
+        out = jnp.where((lo + jnp.arange(S) < ends[-1])[:, None],
+                        out * flat_w[pair][:, None], 0.0)
+        to = (tok[None, :] == jnp.arange(N)[:, None]).astype(jnp.float32)
+        return acc + jnp.einsum("ns,sd->nd", to, out, precision=_HI)
+
+    none = jnp.zeros((N, D), jnp.float32)
+    if most == 1:
+        return trip(0, none), sizes
+    return lax.fori_loop(0, _trips(ends[-1], S, most), trip, none), sizes
 
 
 def count_routed(counts, live, sizes, is_tick: bool, cfg):
     """`counts` (a call's additions to COUNTERS so far) plus one expert
     layer's: `live` [N] the tokens routed, `sizes` [experts_held] the
-    tokens on each held expert (routed_experts' second result)."""
+    tokens on each held expert (routed_experts' second result), from
+    which the rows its slabs covered follow as its trips do."""
     tick = jnp.int32(is_tick)
+    _, S, most = _slab(live.shape[0], cfg.top_k)
     return [c + a for c, a in zip(counts, (
         live.sum() * cfg.top_k, sizes.sum(), tick * (sizes > 0).sum(),
-        tick * cfg.experts_held, sizes.max()))]
+        tick * cfg.experts_held, sizes.max(),
+        _trips(sizes.sum(), S, most) * S))]
 
 
 def _ffn(lp, x, live, is_tick, counts, cfg: DeepseekV2Config):

@@ -463,10 +463,13 @@ class EngineStats:
     # those whose expert this replica holds, summed over live rows,
     # real prompt tokens and expert layers; held experts that got a
     # token / held experts, per tick and expert layer; the busiest held
-    # expert's tokens and the mean over the held, per call and layer.
+    # expert's tokens and the mean over the held, per call and layer;
+    # the rows the expert layers' slabs covered, summed as the pairs
+    # held are (worked / local: rows worked on for each pair held).
     # As of the last decode tick.  Zeros for a model without experts.
     moe_pairs_routed: int = 0
     moe_pairs_local: int = 0
+    moe_pairs_worked: int = 0
     moe_experts_touched: int = 0
     moe_experts_held: int = 0
     moe_load_max: int = 0

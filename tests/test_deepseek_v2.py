@@ -286,6 +286,93 @@ def test_no_token_is_dropped_when_one_expert_takes_every_token(
     assert np.abs(np.asarray(out) - np.asarray(out)[0]).max() == 0
 
 
+def _held_pairs_case(case, n_tok, k, slab):
+    """(expert ids [n_tok, k] with experts 4..7 held, live [n_tok]) for
+    one case of the slab walk; ids are set by hand (a router would not
+    repeat an expert in a token, the layer does not care)."""
+    ids = np.full((n_tok, k), 9, np.int32)            # 9: held elsewhere
+    live = np.ones((n_tok,), bool)
+    flat = ids.reshape(-1)
+    spread = lambda n: np.random.default_rng(n).permutation(  # noqa: E731
+        n_tok * k)[:n]
+    if case == "none held":
+        pass
+    elif case == "a few held: one slab":
+        flat[spread(5)] = [4, 7, 7, 5, 4]
+    elif case == "exactly a slab":
+        flat[spread(slab)] = 4 + np.arange(slab) % 4
+    elif case == "a slab and one pair":
+        flat[spread(slab + 1)] = 4 + np.arange(slab + 1) % 3
+    elif case == "every pair on one held expert":
+        flat[:] = 6
+    elif case == "dead rows and a chunk's pad":
+        flat[:] = 4 + np.arange(n_tok * k) % 4        # all held ...
+        live[[0, 3, 4]] = False                       # ... idle rows
+        live[n_tok - 5:] = False                      # ... and the pad
+    return ids, live
+
+
+@pytest.mark.parametrize("tile", [8, 128])
+@pytest.mark.parametrize("case", [
+    "none held", "a few held: one slab", "exactly a slab",
+    "a slab and one pair", "every pair on one held expert",
+    "dead rows and a chunk's pad"])
+def test_the_experts_walk_the_held_pairs_in_slabs(model, monkeypatch, case,
+                                                  tile):
+    """`routed_experts` against a plain loop over tokens in float32,
+    with the grouped matmul's row tile cut to 8 so that 16 tokens x
+    top-3 make slabs of 16 rows of 48 pairs: the trips follow the pairs
+    held (none, one, one full, two, all three), nothing is dropped, a
+    dead row or a pad counts nowhere, and `pairs_worked` is trips x
+    slab.  At the tile as it stands the 48 pairs fit one slab, which
+    runs once whatever is held (no loop)."""
+    cfg, params = model
+    monkeypatch.setattr(ds, "_GMM_ROWS", tile)
+    n_tok, k = 16, cfg.top_k
+    tm, slab, most = ds._slab(n_tok, k)
+    assert (tm, slab, most) == ((8, 16, 3) if tile == 8 else (48, 48, 1))
+    # weights large enough that a missing or doubled pair shows
+    ex = jax.tree_util.tree_map(lambda a: 16 * a,
+                                params["layers"][1]["experts"])
+    h = jax.random.normal(jax.random.PRNGKey(11), (n_tok, 32))
+    w = jax.random.uniform(jax.random.PRNGKey(12), (n_tok, k),
+                           minval=0.5, maxval=2.0)
+    ids, live = _held_pairs_case(case, n_tok, k, 16)
+
+    out, sizes = jax.jit(
+        lambda *a: ds.routed_experts(ex, *a, cfg))(
+            h, jnp.asarray(ids), w, jnp.asarray(live))
+
+    want = np.zeros((n_tok, 32), np.float32)
+    on = np.zeros((4,), np.int64)
+    h32, w32 = np.asarray(h, np.float32), np.asarray(w, np.float32)
+    gate, up, down = (np.asarray(ex[n], np.float32)
+                      for n in ("w_gate", "w_up", "w_down"))
+    for t in range(n_tok):
+        for j in range(k):
+            e = ids[t, j] - cfg.expert_offset
+            if live[t] and 0 <= e < 4:
+                a = h32[t] @ gate[e]
+                want[t] += w32[t, j] * (
+                    (a / (1 + np.exp(-a)) * (h32[t] @ up[e])) @ down[e])
+                on[e] += 1
+    np.testing.assert_array_equal(np.asarray(sizes), on)
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-4)
+    assert np.abs(np.asarray(out)[~live]).max(initial=0) == 0
+    if on.sum():
+        assert np.abs(want).max() > 1
+    counted = dict(zip(ds.COUNTERS, ds.count_routed(
+        [jnp.int32(0)] * len(ds.COUNTERS), jnp.asarray(live), sizes, False,
+        cfg)))
+    trips = {"none held": 0, "a few held: one slab": 1, "exactly a slab": 1,
+             "a slab and one pair": 2, "every pair on one held expert": 3,
+             "dead rows and a chunk's pad": 2}[case]
+    assert -(-int(on.sum()) // 16) == trips
+    assert int(counted["pairs_worked"]) == (trips * 16 if tile == 8 else 48)
+    assert int(counted["pairs_local"]) == on.sum()
+    assert int(counted["pairs_routed"]) == live.sum() * k
+
+
 def test_the_shares_add_up_to_the_uncut_layer(model, reference):
     """Four chips hold experts 0-3, 4-7, 8-11, 12-15 of one layer.  The
     routed parts the four shares compute, plus the shared experts

@@ -89,6 +89,17 @@ def _pool_results(text, pool_shape):
     return per_layer, moved
 
 
+def _no_row_a_routed_pair(text, tokens, cfg, hidden):
+    """Of a step's optimised HLO: no float32 array has a row a routed
+    pair (`f32[tokens x top_k, hidden]`: 4096 x 4096 x 4 B = 64 MiB an
+    expert layer of mimo's chunk, where the parent masked, gathered and
+    summed three of them): the expert layer's arrays follow the slab it
+    walks, not what the call routed."""
+    pairs = "f32[%d,%d]" % (tokens * cfg.top_k, hidden)
+    held = [ln for ln in text.splitlines() if " = " + pairs in ln]
+    assert not held, held[:4]
+
+
 def _ragged_tick_holds(text, cfg, cache, rows, blocks, page_size):
     """What a compiled tick of `models/exaone_moe.py` holds to on a TPU:
     one `ops/paged_attention.py` kernel a paged layer beside the expert
@@ -354,6 +365,8 @@ def test_dsv2_paged_step_compiles(chip, step, monkeypatch):
     moved = [ln for ln in text.splitlines()
              if re.search(r"= " + re.escape(pool) + r"\S* copy\(", ln)]
     assert not moved, moved[:4]
+    _no_row_a_routed_pair(text, rows if step == "decode_tick"
+                          else e["prefill_chunk"], cfg, c["hidden_size"])
     if step == "decode_tick":
         shapes = [s.split(",") for s in
                   re.findall(r" = \w+\[([\d,]+)\]", text)]
@@ -435,6 +448,8 @@ def test_kexaone_paged_step_compiles(chip, step, monkeypatch):
         moved = [ln for ln in text.splitlines()
                  if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
         assert not moved, moved[:4]
+    _no_row_a_routed_pair(text, rows if step == "decode_tick"
+                          else e["prefill_chunk"], cfg, c["hidden_size"])
     if step == "decode_tick":
         # 13 MiB (the span loop's gathered spans made it 43)
         assert mem.temp_size_in_bytes < 1 << 25, mem.temp_size_in_bytes
@@ -514,6 +529,8 @@ def test_mimo_paged_step_compiles(chip, step, monkeypatch):
         moved = [ln for ln in text.splitlines()
                  if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
         assert not moved, moved[:4]
+    _no_row_a_routed_pair(text, rows if step == "decode_tick"
+                          else e["prefill_chunk"], cfg, c["hidden_size"])
     if step == "decode_tick":
         # 23 MiB (18 with the span loop: the queries laid into their
         # heads' lanes, [64, 64, 768], go through HBM once a full layer)
