@@ -15,9 +15,35 @@ What the harness asks an architecture for, all by these names
     param_specs(cfg), make_train_step(cfg, mesh, optimizer),
     batch_axes()                        training; a serve-only
                                         architecture leaves them out
+    check_logits(engine, seed, prompt_len, n_decode, c, reference)
+                                        optional: the serving cell's
+                                        logits check, for a body whose
+                                        step is not the default's
 
 Every function imports jax inside itself: the driver process loads this
 module for the yardstick alone and must not start a backend.
+
+Without a `check_logits` (this module has none) the check is
+`lib/checks.default`: the prompt through `engine._prefill_chunk` chunk
+by chunk, then `engine._paged_tick` one token a row a tick, against one
+full forward of `reference`.  A module that brings its own composes it
+from `lib/checks.py` (`seeded_prompt`, `borrowed_pages`, `prefill`,
+`tick_by_tick`, `full_forward`, `compare`) and owes the harness this:
+
+(a) it runs the engine's own jitted programs, the ones the timed window
+    runs, through the engine's own pool and rows, on the worker thread
+    with the engine idle (`engine.run_on_worker`), at the widths the
+    engine was built with;
+(b) it compares logits, never tokens, at every position it produced,
+    teacher-forced on what the program itself chose, with `reference`;
+(c) it returns `checks.compare`'s dictionary, so the numbers `run.py`
+    judges are the harness's arithmetic and not the architecture's;
+(d) it names the programs it ran under `programs`, as a device trace
+    shows them (`checks.program_name`).
+
+`replica.probe_check_logits` holds the result to that by name
+(`checks.hold`) and adds `procedure`; a traced run prints which of
+`programs` its trace did not hold (`check_programs_not_in_trace`).
 """
 
 from __future__ import annotations

@@ -106,7 +106,11 @@ def _serve_summary(obs: dict) -> dict:
             "window_opened_s_after_load": obs["t_w"] - obs["t_load"],
             "replica_start_s": obs["replica_start_s"],
             "compile_cache_entries": [obs["cache0"], obs["cache1"]],
-            "check": obs["check"]},
+            "check": obs["check"],
+            # did the check run what was timed?  (a traced run says)
+            "check_programs_not_in_trace": sorted(
+                set(obs["check"]["programs"])
+                - set(obs["trace"]["programs"])) if obs["trace"] else None},
         "correct": bool(obs["check"]["finite"]
                         and obs["check"]["max_abs_diff"]
                         <= obs["check"]["tolerance"]["max_abs_diff"]

@@ -16,13 +16,46 @@ import pytest
 
 import toy
 from benchmarks import run as bench_run
-from benchmarks.lib import stats, traffic
+from benchmarks.lib import checks, stats, traffic
 from benchmarks.lib.costs import BF16, min_time
 from benchmarks.lib.peaks import peaks_for
 from benchmarks.lib.registry import Registry, arch_of
 
 REPO = toy.REPO
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+
+
+# What `replica.probe_check_logits` returned on the parent commit (5701278,
+# before its body moved to lib/checks.py) for the toy cells at these seeds:
+# recorded on the CPU of this sandbox (jax 0.9.0, float32), twice, equal.
+# The move changed no program, order, prompt or arithmetic: the ten keys
+# are equal to the last bit.  (The differences are a few float32 ulps of
+# logits ~0.5: another CPU or XLA may round them otherwise; the equality
+# held is between two commits on one machine.)
+RECORDED = {
+    "mistral7b-chat": (2**31 + 11, (
+        24, 20, 4, True,
+        1.1920928955078125e-07, 1.1920928955078125e-07, 8.940696716308594e-08,
+        1.683522832252038e-08, 0.16065098345279694, 24)),
+    "internlm2-batch": (2**31 + 12, (
+        24, 20, 4, True,
+        1.2665987014770508e-07, 1.2665987014770508e-07, 8.940696716308594e-08,
+        1.7306327038113523e-08, 0.16282063722610474, 24)),
+    "mistral7b-doc": (2**31 + 13, (
+        24, 20, 4, True,
+        1.1920928955078125e-07, 1.1920928955078125e-07, 1.1920928955078125e-07,
+        1.687980422104829e-08, 0.15989892184734344, 24)),
+    "gpt-chat": (2**31 + 17, (
+        24, 20, 4, True,
+        1.4901161193847656e-07, 1.4901161193847656e-07, 1.1920928955078125e-07,
+        2.153040945529483e-08, 0.15856285393238068, 24)),
+}
+
+
+def held_to_the_parents(check: dict, cell: str) -> None:
+    assert tuple(check[k] for k in checks.COMPARED) == RECORDED[cell][1]
+    assert check["procedure"] == "benchmarks.lib.checks.default"
+    assert check["programs"] == ["jit__prefill_chunk", "jit__paged_tick"]
 
 
 @pytest.fixture(scope="module")
@@ -38,7 +71,8 @@ def test_run_prints_the_contracts_object(toy_reg, cell):
     """One whole run of each cell (serve.llm or JaxTrainer, reference
     check included) on the CPU at toy size."""
     lines = []
-    out = bench_run.run_cell(toy_reg, cell, seed=2**31 + 11, seconds=4.0,
+    seed = RECORDED[cell][0] if cell in RECORDED else 2**31 + 11
+    out = bench_run.run_cell(toy_reg, cell, seed=seed, seconds=4.0,
                              trace=False, platform="cpu",
                              init_kwargs={"num_cpus": 6}, emit=lines.append)
     assert set(out) == {"correct", "attempted", "failed", "metrics",
@@ -61,6 +95,8 @@ def test_run_prints_the_contracts_object(toy_reg, cell):
         assert diag["gaps_whole_window"]["n"] == sum(
             r["n"] for r in diag["gaps_by_subwindow"]["p90"])
         assert diag["check"]["max_abs_diff"] <= 1e-3
+        held_to_the_parents(diag["check"], cell)
+        assert diag["check_programs_not_in_trace"] is None    # untraced
         assert diag["compile_cache_entries"][0] == \
             diag["compile_cache_entries"][1]
 
@@ -406,7 +442,7 @@ def test_a_plain_reference_imports_nothing_of_the_program(path):
             imported |= {a.name.split(".")[0] for a in node.names}
         elif isinstance(node, ast.ImportFrom):
             imported.add("." if node.level else node.module.split(".")[0])
-    assert imported <= {"__future__", "jax"}, imported
+    assert imported <= {"__future__", "jax", "math"}, imported
 
 
 def test_every_architecture_of_the_benchmark_gives_what_the_harness_asks():
@@ -439,7 +475,8 @@ def test_a_second_architecture_serves_a_cell_against_its_own_reference(
     feed-forward matrices): the other arm of the program's decode
     path, brought by `archs/gpt/` in a temporary root."""
     lines = []
-    out = bench_run.run_cell(gpt_reg, "gpt-chat", seed=2**31 + 17,
+    out = bench_run.run_cell(gpt_reg, "gpt-chat",
+                             seed=RECORDED["gpt-chat"][0],
                              seconds=4.0, trace=False, platform="cpu",
                              init_kwargs={"num_cpus": 6}, emit=lines.append)
     assert out["correct"] is True and out["failed"] == 0
@@ -452,6 +489,7 @@ def test_a_second_architecture_serves_a_cell_against_its_own_reference(
     assert check["argmax_equal"] == 24
     # and the default architecture's reference does not fit these weights
     assert check["reference_logit_std"] > 100 * check["max_abs_diff"]
+    held_to_the_parents(check, "gpt-chat")
 
 
 def test_the_yardstick_readers_count_with_the_cells_architecture(gpt_reg):
@@ -502,3 +540,140 @@ def test_a_training_cell_on_a_serve_only_architecture_says_so(gpt_reg):
                        "param_specs, make_train_step, batch_axes"):
         bench_run.run_cell(gpt_reg, "gpt-train", seed=1, seconds=1.0,
                            trace=False, platform="cpu")
+
+
+# ------------------- an architecture that brings its own logits check
+
+# Two more, written into the temporary root as flat modules: `gpt_verify`
+# whole, but for the one name each replaces.
+VARIANT = '''
+import os
+
+from benchmarks.lib.registry import find_module
+
+_base = find_module("archs", "gpt_verify", (os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))),))
+globals().update({k: v for k, v in vars(_base).items()
+                  if not k.startswith("_")})
+
+'''
+OTHER_WEIGHTS = VARIANT + '''
+def reference(params, tokens, c):
+    """The plain reference, given a head that is not the program's."""
+    return _base.reference(dict(params, wlm=params["wlm"][:, ::-1]),
+                           tokens, c)
+'''
+DROPS_A_KEY = VARIANT + '''
+def check_logits(*args):
+    result = _base.check_logits(*args)
+    del result["mean_abs_diff"]
+    return result
+'''
+
+
+@pytest.fixture(scope="module")
+def checked_reg(tmp_path_factory):
+    before = sorted(os.listdir(toy.BENCH)), open(
+        os.path.join(REPO, "BENCHMARK.json")).read()
+    root = toy.add_gpt(toy.build(str(tmp_path_factory.mktemp("checked"))))
+    toy.add_checked(root)
+    toy.add_checked(root, "other_weights", OTHER_WEIGHTS)
+    toy.add_checked(root, "drops_a_key", DROPS_A_KEY)
+    assert before == (sorted(os.listdir(toy.BENCH)), open(
+        os.path.join(REPO, "BENCHMARK.json")).read())
+    assert not glob.glob(os.path.join(toy.BENCH, "archs", "gpt*"))
+    return Registry(root)
+
+
+def _checked_run(reg, cell):
+    lines = []
+    out = bench_run.run_cell(reg, cell, seed=2**31 + 58, seconds=3.0,
+                             trace=False, platform="cpu",
+                             init_kwargs={"num_cpus": 6}, emit=lines.append)
+    return out, json.loads(lines[0])["check"]
+
+
+def test_an_architectures_own_procedure_decides_correct(checked_reg):
+    """`archs/gpt_verify.py` decodes through `_paged_verify` at two
+    columns a row: the run reports that procedure and those programs,
+    and `correct` is its comparison's."""
+    out, check = _checked_run(checked_reg, "gpt_verify-chat")
+    assert out["correct"] is True and out["failed"] == 0
+    assert check["procedure"] == "archs.gpt_verify.check_logits"
+    assert check["programs"] == ["jit__prefill_chunk", "jit__paged_verify"]
+    assert set(check) == set(checks.COMPARED) | {"procedure", "programs",
+                                                 "tolerance"}
+    assert (check["positions"], check["prefill_positions"],
+            check["decode_positions"], check["argmax_equal"]) \
+        == (24, 20, 4, 24)
+    assert check["finite"] and check["max_abs_diff"] <= 1e-3
+
+
+def test_correct_is_false_when_the_reference_has_other_weights(checked_reg):
+    out, check = _checked_run(checked_reg, "other_weights-chat")
+    assert out["correct"] is False and out["failed"] == 0
+    assert check["procedure"] == "archs.other_weights.check_logits"
+    assert check["finite"] and check["positions"] == 24
+    assert check["max_abs_diff"] > 100 * check["tolerance"]["max_abs_diff"]
+
+
+def test_a_procedure_that_drops_a_key_fails_by_both_names(checked_reg):
+    """Not `correct: false`: the run ends, naming the key and the
+    architecture."""
+    with pytest.raises(Exception) as e:
+        _checked_run(checked_reg, "drops_a_key-chat")
+    assert "mean_abs_diff" in str(e.value) and "drops_a_key" in str(e.value)
+    assert "archs.drops_a_key.check_logits" in str(e.value)
+
+
+def test_the_contract_is_held_by_name():
+    """`checks.hold` on results as a procedure might hand them in."""
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    ref = rng.normal(size=(6, 16)).astype(np.float32)
+    got = ref + np.float32(0.25) * (np.arange(6)[:, None] == 5)
+    good = dict(checks.compare(got, ref, 4), programs=["jit__x"])
+    assert tuple(good)[:10] == checks.COMPARED
+    assert (good["positions"], good["prefill_positions"],
+            good["decode_positions"]) == (6, 4, 2)
+    assert good["max_abs_diff_prefill"] == 0.0
+    assert good["max_abs_diff_decode"] == good["max_abs_diff"] == 0.25
+    assert good["mean_abs_diff"] == pytest.approx(0.25 / 6)
+    assert good["argmax_equal"] >= 5 and good["finite"]
+
+    def mine():
+        pass
+
+    held = checks.hold(good, mine, "some_arch")
+    assert held["procedure"].startswith("archs.some_arch.")
+    assert held["procedure"].endswith("mine")
+    assert checks.hold(good, checks.default, "llama")["procedure"] \
+        == "benchmarks.lib.checks.default"
+    for key in checks.COMPARED + ("programs",):
+        with pytest.raises(KeyError, match=f"some_arch.*{key}"):
+            checks.hold({k: v for k, v in good.items() if k != key}, mine,
+                        "some_arch")
+    with pytest.raises(ValueError, match="some_arch"):       # no decode row
+        checks.hold(dict(good, decode_positions=0, prefill_positions=6),
+                    mine, "some_arch")
+    with pytest.raises(ValueError, match="some_arch"):
+        checks.hold(dict(good, positions=7), mine, "some_arch")
+    with pytest.raises(ValueError, match="names no program"):
+        checks.hold(dict(good, programs=[]), mine, "some_arch")
+    with pytest.raises(TypeError, match="some_arch"):
+        checks.hold(None, mine, "some_arch")
+    with pytest.raises(ValueError):                  # rows that do not pair
+        checks.compare(got, ref[:5], 4)
+
+
+def test_the_harness_imports_without_a_backend():
+    """The driver imports `benchmarks.lib` (checks and replica's seam
+    with it) and starts no jax backend: the chip is the replica's."""
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; import benchmarks.lib.checks, benchmarks.run; "
+         "import jax._src.xla_bridge as xb; "
+         "sys.exit(1 if xb._backends else 0)"],
+        cwd=REPO, capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]

@@ -141,18 +141,19 @@ def test_one_entry_a_quantity_and_the_metric_it_moves(reg):
     for name in RETIRED:              # their files stay: nothing is edited
         assert set(reg.metric(name)) == {"reader", "args"}
     # every cell that reports `out_tok_per_s` is in some `.tput` list, and
-    # a cell's list is what its tagged entries were, plus PR 38's two
+    # a cell of the table still reads what its tagged entries were, plus
+    # PR 38's two; cells and entries added since PR 46 are their PRs' own
     tput = {m["name"]: m["workloads"] for m in entries
             if m["name"].endswith(".tput")}
     on = next(m for m in spec["end_to_end"]
               if m["name"] == "out_tok_per_s")["workloads"]
     assert {c for ws in tput.values() for c in ws} == set(on)
-    for cell in on:
+    for cell in sorted({c for _, c in PAIRS} & set(on)):
         was = {new_name(o) for o, c in PAIRS
-               if c == cell and new_name(o) in tput}
+               if c == cell and new_name(o).endswith(".tput")}
         if cell == "kexaone-reason":
             was |= {"prefill_pad_share.tput", "jit_compile_ms.tput"}
-        assert {n for n, ws in tput.items() if cell in ws} == was, cell
+        assert was <= {n for n, ws in tput.items() if cell in ws}, cell
 
 
 def test_a_starts_seconds_read_through_the_real_registry(reg):

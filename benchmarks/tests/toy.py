@@ -86,6 +86,35 @@ def add_gpt(root: str) -> str:
     return root
 
 
+def add_checked(root: str, arch: str = "gpt_verify",
+                source: str = None) -> str:
+    """Into a root that `add_gpt` made, an architecture that brings its
+    own logits check: `archs/gpt_verify.py` came with rehearsal/; any
+    other `arch` is a flat module written here from `source`.  One
+    configuration `toy-<arch>` and one chat-mix cell `<arch>-chat`:
+    new files and appended entries."""
+    b = os.path.join(root, "bm")
+    if source is not None:
+        with open(os.path.join(b, "archs", arch + ".py"), "w") as f:
+            f.write(source)
+    with open(os.path.join(b, "configs", f"toy-{arch}.json"), "w") as f:
+        json.dump(dict(GPT_CONFIG, name=f"toy-{arch}", arch=arch), f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({"name": f"toy-{arch}", "source": "none",
+                            "file": f"bm/configs/toy-{arch}.json",
+                            "reduced": [], "why": "its own logits check"})
+    spec["workloads"].append(
+        {"name": f"{arch}-chat", "config": f"toy-{arch}", "traffic": "chat",
+         "chips": 1, "why": "the GPT block, checked by its own procedure"})
+    for m in spec["end_to_end"]:
+        if m["name"] == "itl_p90_ms":
+            m["workloads"].append(f"{arch}-chat")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec, f)
+    return root
+
+
 def build(root: str) -> str:
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         real = json.load(f)
