@@ -103,6 +103,14 @@ class PagedBody:
     # engine.stats().  None, both: nothing is counted on the device
     snapshot_counters: Optional[Callable[..., Any]] = None
     read_counters: Optional[Callable[..., Dict[str, Any]]] = None
+    # the columns a decode step takes of a row: 1, a token a row a tick,
+    # for every body but one that generates by diffusion over BLOCKS of
+    # so many positions (models/sdar_moe.py), whose step takes a row's
+    # whole block, fixes some of its positions and yields tokens only
+    # when a block is full (engine._paged_block_step); `mask_token` is
+    # the id that stands in a position not fixed yet (None at block 1)
+    block: int = 1
+    mask_token: Optional[int] = None
 
     @property
     def has_row_state(self) -> bool:
@@ -410,6 +418,16 @@ def paged_chunk_step(params: Dict, tokens, pos, cache: Dict,
     pos[b]..pos[b]+t-1 through its table; query i of row b then sees
     columns pad_lo[b]..pos[b]+i, which hold bit-identical values to a
     contiguous cache, so paging is invisible to results.
+
+    A body whose `block` B is not 1 keeps another mask: query i sees
+    every column of its own block of B and of the blocks before it.  Its
+    single-row chunk (scalar `pos`, whole pages, B dividing a page) shows
+    query i columns 0..min(((pos+i)//B + 1) B, pos+valid) - 1; its
+    per-row call is a BLOCK STEP, t = B columns a row at a `pos[b]` that
+    is a multiple of B: the row's block is written at pos[b]..pos[b]+B-1,
+    over whatever an earlier step wrote there, and every one of its B
+    queries sees columns 0..pos[b]+B-1.  The logits of column i are of
+    the token AT column i.  Such a body takes no other `t`.
 
     Callers must keep pos+t within nblk*page (writes past the table
     would clip into the last block).  Returns (logits [B, t, V] fp32,

@@ -454,7 +454,8 @@ def _close(lp, x, out, cfg):
     return x + jnp.einsum("nhk,hkd->nd", out, lp["wo"].astype(cfg.dtype))
 
 
-def _global_chunk(lp, x, i, cache, bt, start, kind: AttnKind, cfg):
+def _global_chunk(lp, x, i, cache, bt, start, kind: AttnKind, cfg,
+                  last=None):
     T = x.shape[0]
     G, Dh, Dv = kind.n_kv_heads, kind.head_dim, kind.v_head_dim
     psz = cache["k"].shape[2]
@@ -464,23 +465,25 @@ def _global_chunk(lp, x, i, cache, bt, start, kind: AttnKind, cfg):
     ck = cache["k"].at[i, pages].set(k.reshape(paged + _kept(kind, Dh)))
     cv = cache["v"].at[i, pages].set(v.reshape(paged + _kept(kind, Dv)))
     with jax.named_scope("attn_global"):
-        out = _span_chunk(q, ck, cv, i, bt, start, kind)
+        out = _span_chunk(q, ck, cv, i, bt, start, kind, last)
     return _close(lp, x, out, cfg), dict(cache, k=ck, v=cv)
 
 
-def _span_chunk(q, ck, cv, i, bt, start, kind: AttnKind):
+def _span_chunk(q, ck, cv, i, bt, start, kind: AttnKind, last=None):
     """A single-row chunk's attention over the pages of layer `i` of the
     pools ck, cv [n, P, page, ...], the chunk's own keys among them
     (written before the call): q [T, H, Dh] at positions start.. over
     the row's pages `bt` in spans of `_CHUNK_SPAN_KEYS` keys, each
-    query up to its own position -> [T, H, Dv].  Also models/zaya.py's
-    (pages of two heads side by side)."""
+    query up to its own position -> [T, H, Dv]; or up to `last` [T],
+    a column inside the chunk each, where a model's mask is not causal
+    (models/sdar_moe.py: through the end of the query's block).  Also
+    models/zaya.py's (pages of two heads side by side)."""
     T, H, _ = q.shape
     G, Dh, Dv = kind.n_kv_heads, kind.head_dim, kind.v_head_dim
     R = H // G
     psz, nblk = ck.shape[2], bt.shape[0]
     dt = q.dtype
-    cols = start + jnp.arange(T)
+    cols = start + jnp.arange(T) if last is None else last
     span = _span_pages(_CHUNK_SPAN_KEYS, psz, nblk)
     width = span * psz
     qg = q.reshape(T, G, R, Dh)

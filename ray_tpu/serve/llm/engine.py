@@ -36,6 +36,13 @@ page granularity):
     they lie on the device, then tick k's are read and emitted, so the
     host's share of a turn runs beside the chip (`_decode_tick`); a
     sampling row, speculation, a command and stop() read first;
+  * a body that generates by diffusion over BLOCKS (its
+    `decode.PagedBody.block` B > 1) runs no tick: its turn is ONE fused
+    block step (`_paged_block_step`: B columns a row, one position of a
+    row's block fixed on the device a step, the blocks kept on the
+    device from step to step), dispatched one turn ahead as a tick is
+    because the host counts every step's outcome from the static
+    schedule; a row's tokens are read a block at a time (`_block_turn`);
   * each sampled token is pushed to that request's TokenStream
     immediately; rows hitting EOS/max-tokens are evicted by FREEING
     their pages (host-side accounting only — stale K/V in a recycled
@@ -481,6 +488,20 @@ class EngineStats:
     # a top-1).
     moe_gate_mass: float = 0.0
     moe_gate_tokens: int = 0
+    # A body that generates by diffusion over blocks (its `block` > 1)
+    # runs block steps and no tick: the step programs dispatched; the
+    # live rows they ran, summed (a row's forward); of those the ones
+    # that fixed nothing and wrote a finished block's final keys; the
+    # columns the live rows ran (`block` a row); the positions fixed
+    # (one a denoising forward).  The host counts all of them ahead of
+    # the device, from the schedule.  `block_row_forwards` over
+    # `tokens_generated` is the forwards a token costs (1.25 for whole
+    # blocks of 4 in 4 steps).  Zeros for a body whose block is 1.
+    block_steps: int = 0
+    block_row_forwards: int = 0
+    block_row_writes: int = 0
+    block_columns: int = 0
+    block_positions_fixed: int = 0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -633,6 +654,52 @@ class _Tick(NamedTuple):
 
 @functools.partial(jax.jit, static_argnames=("cfg", "with_logits"),
                    donate_argnames=("cache",))
+def _paged_block_step(params, tokens, masked, host_tokens, host_masked,
+                      take_host, pos, cache, block_tables, cfg,
+                      with_logits):
+    """One step of every row's block, for a body that generates by
+    diffusion over blocks (its `block` B > 1): `tokens` [S, B] and
+    `masked` [S, B] are the blocks as the last step left them ON THE
+    DEVICE, with the host's rows over them where `take_host` [S] says so
+    (a row that joined, a row whose next block opens as masks).  One
+    paged_chunk_step of B columns a row; then of a row's positions still
+    masked the one whose largest softmax probability (float32) is
+    highest is fixed to its argmax: one a step, the static schedule.  A
+    row with nothing masked fixes nothing: its forward wrote the
+    finished block's final keys.  Returns the blocks after the step."""
+    tokens = jnp.where(take_host[:, None], host_tokens, tokens)
+    masked = jnp.where(take_host[:, None], host_masked, masked)
+    logits, cache = decode.paged_chunk_step(params, tokens, pos, cache,
+                                            block_tables, cfg)
+    with jax.named_scope("block_unmask"):
+        # (reduced as [S x B, V], the shape the head gives them: a
+        # float32 array that ends in [B, V] pads B to the chip's tile of
+        # 8 rows, and forming it cost 1.5 ms a step on a v5e)
+        flat = logits.reshape(-1, logits.shape[-1])
+        conf = jnp.exp(flat.max(-1) - jax.nn.logsumexp(flat, axis=-1)
+                       ).reshape(tokens.shape)
+        best = jnp.argmax(flat, axis=-1).astype(jnp.int32
+                                                ).reshape(tokens.shape)
+        pick = jnp.argmax(jnp.where(masked, conf, -1.0), axis=-1)
+        fix = masked & (jnp.arange(tokens.shape[1])[None, :]
+                        == pick[:, None])
+        tokens = jnp.where(fix, best, tokens)
+        masked = masked & ~fix
+    return tokens, masked, (logits if with_logits else None), cache
+
+
+class _BlockStep(NamedTuple):
+    """A dispatched block step whose result has not been read: the rows
+    it ran (slot, request, and the block's columns that hold new tokens
+    where this step fixed the block's last position, else None), the
+    blocks it left on the device and the counters' copy."""
+    rows: List
+    tokens: Any
+    counters: Any
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "with_logits"),
+                   donate_argnames=("cache",))
 def _paged_verify(params, chunk, pos, cache, block_tables, cfg,
                   with_logits):
     """Fused speculative tick: each row's (pending token + k draft
@@ -751,6 +818,9 @@ class GenerationEngine:
             raise ValueError("speculate_ngram must be >= 1 when "
                              "speculate_k is set")
         self._body = decode.paged_body(cfg)     # resolved once
+        # The columns a decode step takes of a row (1: a token a row a
+        # tick; B > 1: a block step, `_block_turn`).
+        self._block = int(self._body.block)
         if enable_prefix_cache:
             refuse_row_state(cfg, "the prefix cache "
                                   "(enable_prefix_cache=True)")
@@ -861,6 +931,12 @@ class GenerationEngine:
         self._pos = np.zeros((num_slots,), np.int32)
         self._tok = np.zeros((num_slots,), np.int32)
         self._slots: List[Optional[_Request]] = [None] * num_slots
+        if self._block > 1:
+            self._init_block_state()
+        self._block_steps = 0
+        self._block_row_forwards = 0
+        self._block_row_writes = 0
+        self._block_positions_fixed = 0
         self._prefill: Optional[_PrefillState] = None
         # The loop reads a tick one turn late (worker thread only): the
         # tick whose result is still on the device, and a finished
@@ -1037,7 +1113,10 @@ class GenerationEngine:
         return self._thread is not None and self._thread.is_alive()
 
     def _blocks_for(self, prompt_len: int, max_new: int) -> int:
-        return -(-(prompt_len + max_new + self._slack) // self.page_size)
+        # (a block body's row holds whole blocks: through the end of
+        # the last one, which a page holds whole)
+        end = -(-(prompt_len + max_new) // self._block) * self._block
+        return -(-(end + self._slack) // self.page_size)
 
     def submit(self, prompt: Sequence[int], *,
                max_new_tokens: Optional[int] = None,
@@ -1081,6 +1160,19 @@ class GenerationEngine:
                              f"got {temperature}")
         if top_k < 0:
             raise ValueError(f"top_k must be >= 0, got {top_k}")
+        if self._block > 1:
+            if temperature > 0:
+                raise NotImplementedError(
+                    f"temperature > 0 on a model that generates by "
+                    f"diffusion over blocks ({type(self.cfg).__name__}): "
+                    f"a block's positions are fixed on the device, "
+                    f"greedily; missing: sampling a block on the device")
+            if len(prompt) < self._block:
+                raise ValueError(
+                    f"a prompt shorter than one block "
+                    f"({len(prompt)} < {self._block}) would open its "
+                    f"first block at position 0, where a decode row is "
+                    f"idle")
         req = _Request(request_id or uuid.uuid4().hex[:12], prompt,
                        max_new, temperature, top_k, eos_token, seed,
                        n_blocks, session=session_id, rng_state=rng_state)
@@ -1829,6 +1921,11 @@ class GenerationEngine:
             state_resets=self._state_resets,
             row_state_bytes=self._row_state_bytes,
             kv_pool_bytes=self.kv_pages * self._page_nbytes,
+            block_steps=self._block_steps,
+            block_row_forwards=self._block_row_forwards,
+            block_row_writes=self._block_row_writes,
+            block_columns=self._block_row_forwards * self._block,
+            block_positions_fixed=self._block_positions_fixed,
             # (a counter of another layer than the experts carries its
             # own prefix)
             **{k if k.startswith("attn_") else "moe_" + k: v
@@ -1952,13 +2049,18 @@ class GenerationEngine:
         pos = jnp.zeros((self.num_slots,), jnp.int32)
         bt = jnp.asarray(self._block_tables)
         t0 = time.time()
-        sampled, _, self._cache = _paged_tick(
-            self.params, tok, pos, self._cache, bt, self.cfg,
-            with_logits=False)
-        programs.append(("engine.warm.tick", t0, time.time()))
-        # ...and what a tick read one turn late leaves behind it: its
-        # tokens merged into the next tick's, its counters copied.
-        _merge_tokens(sampled, tok, jnp.ones((self.num_slots,), bool))
+        if self._block > 1:
+            # a block body runs block steps and never a tick
+            self._run_block_step()
+            programs.append(("engine.warm.block_step", t0, time.time()))
+        else:
+            sampled, _, self._cache = _paged_tick(
+                self.params, tok, pos, self._cache, bt, self.cfg,
+                with_logits=False)
+            programs.append(("engine.warm.tick", t0, time.time()))
+            # ...and what a tick read one turn late leaves behind it: its
+            # tokens merged into the next tick's, its counters copied.
+            _merge_tokens(sampled, tok, jnp.ones((self.num_slots,), bool))
         if self._body.snapshot_counters is not None:
             self._body.snapshot_counters(self._cache)
         if self.speculate_k:
@@ -2236,8 +2338,11 @@ class GenerationEngine:
             # chunks are no-ops; this request's duplicates stay private.
             self._prefix.insert(req.prompt,
                                 req.pages[:L // self.page_size])
-        row = logits[0, len(real) - 1]
-        row.copy_to_host_async()
+        row = None
+        if self._block == 1:
+            row = logits[0, len(real) - 1]
+            row.copy_to_host_async()
+        # (a block body's prefill yields no token: nothing is read)
         self._first = (st, row, t_fc)
 
     def _first_token(self):
@@ -2247,6 +2352,9 @@ class GenerationEngine:
         self._first = None
         with self._cond:
             self._prefill = None
+        if self._block > 1:
+            self._join_block(st, t_fc)
+            return
         self._phase("device_wait")
         row = np.asarray(row)
         self._phase("emit")
@@ -2305,7 +2413,11 @@ class GenerationEngine:
         host's share of a turn runs beside the chip and not between two
         ticks.  What a turn holds decides: a sampling row (the host
         draws from logits) or speculation (drafts come from the tokens)
-        first reads what is in flight and then runs tick by tick."""
+        first reads what is in flight and then runs tick by tick.  A
+        body whose `block` is not 1 takes `_block_turn` instead."""
+        if self._block > 1:
+            self._block_turn()
+            return
         fl = self._inflight
         ahead = fl is not None and not self.speculate_k \
             and (self._first is None
@@ -2430,6 +2542,9 @@ class GenerationEngine:
                 return
         if fl is self._inflight:
             self._inflight = None
+        if isinstance(fl, _BlockStep):
+            self._read_block_step(fl)
+            return
         self._phase("device_wait")
         sampled = np.asarray(fl.sampled)
         if fl.counters is not None:
@@ -2454,6 +2569,175 @@ class GenerationEngine:
                 else:
                     t = int(sampled[s])
                 self._advance(s, req, [t], now)
+        finally:
+            self._flush_emits()
+
+    # -- a body that generates by diffusion over blocks ----------------
+    # Its decode turn is a BLOCK STEP: every live row's current block of
+    # `_block` columns through `_paged_block_step`.  The blocks (tokens,
+    # which positions are still masked) live on the device from step to
+    # step; under the static schedule the host knows BY COUNT what every
+    # step does to a row (not which position it fixes, nor to what): a
+    # row with m masked positions runs m denoising forwards, each fixing
+    # one, and then one that fixes nothing and writes the finished
+    # block's final keys; the host then moves the row on by a block and
+    # opens the next one as masks.  So a step is dispatched before the
+    # one before it has been read, as a tick is, and a row's tokens are
+    # read, a whole block at a time, one turn after its block's last
+    # position was fixed.
+
+    def _init_block_state(self):
+        S, B = self.num_slots, self._block
+        self._blk_dev = (jnp.zeros((S, B), jnp.int32),
+                         jnp.zeros((S, B), bool))
+        # the host's rows of the next step, taken where _blk_take says
+        self._blk_host_tok = np.zeros((S, B), np.int32)
+        self._blk_host_masked = np.zeros((S, B), bool)
+        self._blk_take = np.zeros((S,), bool)
+        # per row, by count: positions of its block still masked once
+        # every dispatched step has run; the block's columns that hold
+        # new tokens; the tokens it will have generated by then; whether
+        # that is all it asked for; when its prefill ended
+        self._blk_left = np.zeros((S,), np.int32)
+        self._blk_cols: List = [()] * S
+        self._blk_gen = np.zeros((S,), np.int64)
+        self._blk_ending = np.zeros((S,), bool)
+        self._blk_t_fc = [0.0] * S
+
+    def _join_block(self, st: _PrefillState, t_fc: float):
+        """A finished prefill joins the decode rows with its first block
+        open: the prompt's last `L mod B` tokens stand in it as fixed
+        positions (the chunk wrote keys for them too, which the first
+        step overwrites), the rest are masks.  No token is emitted."""
+        req, s, B = st.req, st.slot, self._block
+        L = len(req.prompt)
+        start = L // B * B
+        fixed = L - start
+        self._block_tables[s] = st.bt_row
+        self._pos[s] = start
+        self._blk_host_tok[s] = self._body.mask_token
+        self._blk_host_tok[s, :fixed] = req.prompt[start:]
+        self._blk_host_masked[s] = np.arange(B) >= fixed
+        self._blk_take[s] = True
+        self._blk_left[s] = B - fixed
+        self._blk_cols[s] = tuple(range(fixed, B))
+        self._blk_gen[s] = 0
+        self._blk_ending[s] = False
+        self._blk_t_fc[s] = t_fc
+        req.tokens = list(req.prompt)
+        self._slots[s] = req
+        self._update_occupancy()
+
+    def _block_turn(self):
+        """This turn's block step: dispatched from the blocks as the
+        last step left them on the device, then the last step's finished
+        blocks are read and emitted."""
+        fl = self._inflight
+        if fl is None and self._first is not None:
+            self._first_token()       # nothing to read first: it joins
+        rows = [s for s, req in enumerate(self._slots)
+                if req is not None and not self._blk_ending[s]]
+        if rows:
+            self._dispatch_block_step(rows)
+        if fl is not None:
+            self._read_tick(fl)
+        if self._first is not None:
+            self._first_token()
+
+    def _run_block_step(self):
+        """Hand one `_paged_block_step` to the device, of the engine's
+        state as it stands (host arrays as COPIES: the loop writes to
+        them again while the step is in flight)."""
+        tokens, masked, _, self._cache = _paged_block_step(
+            self.params, *self._blk_dev,
+            jnp.asarray(self._blk_host_tok.copy()),
+            jnp.asarray(self._blk_host_masked.copy()),
+            jnp.asarray(self._blk_take.copy()),
+            jnp.asarray(self._pos.copy()), self._cache,
+            jnp.asarray(self._block_tables.copy()), self.cfg,
+            with_logits=False)
+        self._blk_dev = (tokens, masked)
+        self._blk_take[:] = False
+        return tokens
+
+    def _dispatch_block_step(self, rows):
+        """Dispatch one block step over `rows`, leave its result on the
+        device (`_inflight`) and advance the host's count of every row:
+        `_pos`, `_block_tables` and the `_blk_*` arrays are the host's."""
+        self._phase("tick_dispatch")
+        if self._inflight is not None:
+            self._turns_ahead += 1
+        B = self._block
+        tokens = self._run_block_step()
+        self._launched()
+        tokens.copy_to_host_async()
+        counters = None
+        if self._body.snapshot_counters is not None:
+            counters = self._body.snapshot_counters(self._cache)
+        self._count_keys(rows, B)
+        self._block_steps += 1
+        self._block_row_forwards += len(rows)
+        ran = []
+        for s in rows:
+            req, cols = self._slots[s], None
+            if self._blk_left[s]:
+                # a denoising forward: one position fixed
+                self._blk_left[s] -= 1
+                self._block_positions_fixed += 1
+                if not self._blk_left[s]:
+                    cols = self._blk_cols[s]
+                    self._blk_gen[s] += len(cols)
+                    if self._blk_gen[s] >= req.max_new_tokens:
+                        # The row's last block: its writing forward is
+                        # not run.  It sits out, pointed at the trash
+                        # page as an evicted row is; its slot and pages
+                        # are freed when its tokens have been read.
+                        self._blk_ending[s] = True
+                        self._pos[s] = 0
+                        self._block_tables[s, :] = 0
+            else:
+                # the writing forward: the finished block's keys are
+                # final; the row moves on and its next block opens
+                self._block_row_writes += 1
+                self._pos[s] += B
+                self._blk_left[s] = B
+                self._blk_cols[s] = tuple(range(B))
+                self._blk_host_tok[s] = self._body.mask_token
+                self._blk_host_masked[s] = True
+                self._blk_take[s] = True
+            ran.append((s, req, cols))
+        self._inflight = _BlockStep(ran, tokens, counters)
+
+    def _read_block_step(self, fl: _BlockStep):
+        """Read a dispatched block step: the rows whose block it
+        finished hand `_advance` the block's new tokens, in order (a
+        `max_new_tokens` that ends inside the block drops the surplus
+        and evicts the row); flush once."""
+        self._phase("device_wait")
+        tokens = np.asarray(fl.tokens)
+        if fl.counters is not None:
+            self._model_counters = self._body.read_counters(
+                fl.counters, self.cfg)
+        self._phase("emit")
+        self._read_at = now = time.monotonic()
+        try:
+            for s, req, cols in fl.rows:
+                if self._slots[s] is not req:
+                    continue      # ended at an earlier read: a stray step
+                if req.stream.cancelled:
+                    self._evict(s, "cancelled")
+                    continue
+                if cols is None:
+                    continue
+                if not req.emitted:
+                    # TTFT stage 3 of 3 for a block body: prefill's end
+                    # to the first block's tokens (its denoising steps)
+                    t_fc = self._blk_t_fc[s]
+                    _span_for(req, "engine.first_block", t_fc, now - t_fc,
+                              args={"request_id": req.id,
+                                    "tokens": len(cols)})
+                self._advance(s, req, [int(tokens[s, c]) for c in cols],
+                              now)
         finally:
             self._flush_emits()
 
@@ -2623,6 +2907,9 @@ class GenerationEngine:
         self._pos[slot] = 0
         self._tok[slot] = 0
         self._block_tables[slot, :] = 0
+        if self._block > 1:
+            self._blk_left[slot] = 0
+            self._blk_take[slot] = self._blk_ending[slot] = False
         # Durable sessions checkpoint BEFORE the pages are released —
         # publishing them into the radix tree needs the refs alive.
         self._maybe_checkpoint_session(req)
@@ -2704,6 +2991,8 @@ class GenerationEngine:
         self._flush_emits()
         self._pos[:] = 0
         self._tok[:] = 0
+        if self._block > 1:
+            self._init_block_state()
         # Rebuild device state: the donated cache may be mid-flight.
         self._cache = decode.init_paged_cache(
             self.cfg, self.kv_pages + 1, self.page_size, self.num_slots)

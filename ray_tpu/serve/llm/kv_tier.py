@@ -86,8 +86,16 @@ def refuse_unframed(cfg, what: str) -> None:
     of a size the model declares.  The radix prefix cache only hands out
     page ids and serves such a model as it is."""
     refuse_row_state(cfg, what)
-    if decode.paged_body(cfg).framed:
+    body = decode.paged_body(cfg)
+    if body.framed:
         return
+    if body.block > 1:
+        raise NotImplementedError(
+            f"{what} on a model that generates by diffusion over blocks "
+            f"({type(cfg).__name__}): a row's last block is rewritten "
+            f"until every position of it is fixed, so a page that holds "
+            f"it is not final; missing: a frame that stops at the last "
+            f"block whose keys are final")
     raise NotImplementedError(
         f"{what} on a model whose pages are not K then V "
         f"({type(cfg).__name__}: a latent page): tiers and the wire frame "

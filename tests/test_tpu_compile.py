@@ -721,3 +721,93 @@ def test_zaya_paged_step_compiles(chip, step, monkeypatch):
     wide = [s for s in shapes if str(blocks * e["page_size"]) in s[1]
             and s != ("s32", [str(rows * blocks)])]
     assert not wide, wide[:4]
+
+
+# The ninth configuration (benchmarks/configs/sdar-30b-a3b-pp8-d6.json):
+# generation by diffusion over blocks: the engine's third program.
+@pytest.mark.parametrize("step", ["block_step", "prefill_chunk"])
+def test_sdar_paged_step_compiles(chip, step, monkeypatch):
+    """Both programs of sdar-30b-a3b-pp8-d6 as the chip runs them: 8.7
+    GB of weights and a 3.6 GB pool are resident and never re-laid or
+    copied; a block step (`engine._paged_block_step`, 128 rows x 4
+    columns) is one `ops/paged_attention.py` kernel a layer, handed the
+    pool as it lies and 4 x 32 query heads a row, beside three grouped
+    matmuls, holds no array as wide as the table, and what it holds
+    beside its arguments is its float32 logits ([512, 151936], their
+    softmax's reductions) and little else; a chunk's logits are its
+    result."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import deepseek_v2, sdar_moe
+    monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    monkeypatch.setattr(sdar_moe, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs",
+                           "sdar-30b-a3b-pp8-d6.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    B = cfg.block_length
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+    L, pool = 6, (6, e["kv_pages"] + 1, e["page_size"], 4 * 128)
+    assert cache["k"].shape == cache["v"].shape == pool
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def i32(*shape):
+        return arr(jnp.int32, *shape)
+
+    if step == "block_step":
+        lowered = engine._paged_block_step.lower(
+            params, i32(rows, B), arr(bool, rows, B), i32(rows, B),
+            arr(bool, rows, B), arr(bool, rows), i32(rows), cache,
+            i32(rows, blocks), cfg, with_logits=False)
+        logits = rows * B * cfg.vocab_size * 4
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+        logits = 0      # the chunk's are its result, not a temporary
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * logits + (1 << 29), \
+        mem.temp_size_in_bytes / 2**30
+    held = sum(cache[k].size * 2 for k in ("k", "v"))
+    assert arch.weight_bytes(c) + held \
+        < mem.argument_size_in_bytes < arch.weight_bytes(c) + held + (1 << 26)
+    text = compiled.as_text()
+    per_layer, moved = _pool_results(text, pool)
+    assert not per_layer and not moved, (per_layer[:2], moved[:2])
+    held = "bf16[%s]" % ",".join(map(str, pool))
+    layouts = set(re.findall(re.escape(held) + r"\{([\d,]+)", text))
+    assert layouts == {"3,2,1,0"}, layouts              # never re-laid
+    kernels = [ln for ln in text.splitlines()
+               if "custom-call(" in ln and "tpu_custom_call" in ln]
+    if step == "prefill_chunk":
+        assert len(kernels) == 3 * L, len(kernels)
+        return
+    ragged = [ln for ln in kernels if " %paged_attention" in ln]
+    assert len(ragged) == L and len(kernels) == 4 * L, len(kernels)
+    # a row's call holds B x 32 query heads, each in its own key-value
+    # head's lanes of a row as wide as the four
+    assert all("bf16[%d,%d,512]" % (rows, B * 32) in ln for ln in ragged)
+    shapes = [(kind, dims.split(",")) for kind, dims in
+              re.findall(r" = (\w+)\[([\d,]+)\]", text)]
+    # (the table's 64 x 64 keys are as many as the step's routed pairs,
+    # 512 x 8: the expert layer's flat pair ids are not the table)
+    wide = [s for s in shapes if str(blocks * e["page_size"]) in s[1]
+            and len(s[1]) > 1]
+    assert not wide, wide[:4]
+    # the logits are reduced as the head gives them, [rows x B, V]:
+    # an array that ends in [B, V] would pad B to the tile's 8 rows
+    cubes = [s for s in shapes if s[1][-2:] == [str(B), str(cfg.vocab_size)]]
+    assert not cubes, cubes[:4]
