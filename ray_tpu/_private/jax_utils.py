@@ -119,19 +119,32 @@ def bind_tpu_chips(ids) -> None:
     _BOUND["ids"] = ids
 
 
+def _import_pallas() -> None:
+    """What every kernel under ray_tpu/ops is built with."""
+    from jax.experimental import pallas  # noqa: F401
+    from jax.experimental.pallas import tpu  # noqa: F401
+
+
 def open_backend() -> None:
     """In a TPU worker (the one kind of process pinned to `tpu`: its
     first jax call opens the lease's chips, whoever makes it), make that
     call here, once, and leave a `jax.backend_init` span under the
     worker's boot: LLMServer and the JaxTrainer's mesh builder come
     here first, so the chip's opening has a name and a length instead
-    of hiding in a loader's first array.  Any other process is left
-    alone: its code may still configure jax before first use."""
+    of hiding in a loader's first array; Pallas is imported on a thread
+    beside it (`_import_pallas`).  Any other process is left alone: its
+    code may still configure jax before first use."""
     if os.environ.get("JAX_PLATFORMS") != "tpu" or _BOUND.get("opened"):
         return
     import jax
     t0 = time.time()
+    # The opening is seconds of waiting on the device with the
+    # interpreter free: what the worker's first trace of a kernel would
+    # stop to import (Pallas, a second of Python) is imported beside it.
+    beside = threading.Thread(target=_import_pallas, daemon=True)
+    beside.start()
     devices = jax.devices()
+    beside.join()
     _BOUND["opened"] = True
     tracing.start_record(
         "jax", "jax.backend_init", t0, time.time(),

@@ -93,7 +93,9 @@ class PagedBody:
     # (cfg) -> how many layers attend, of a body that mixes in others
     n_attn: Callable[..., int] = operator.attrgetter("n_layers")
     # (cfg, last, page_size, nblk) -> keys the call pulled from the
-    # cache; None: no more than it reads (`keys_gathered`)
+    # cache; None: no more than it reads (`keys_gathered`).  A call that
+    # is not the body's decode step (a verify of t tokens a row, which
+    # only a body that speculates takes) hands it `t` too
     attn_keys_gathered: Optional[Callable[..., int]] = None
     # (cfg, pos, last, page_size, nblk) -> (gathered, held) in the paged
     # layers alone, of a body whose layers are not all paged
@@ -116,11 +118,15 @@ class PagedBody:
     def has_row_state(self) -> bool:
         return bool(self.row_state_keys)
 
-    def keys_gathered(self, cfg, read, last, page_size, nblk) -> int:
-        """Keys a tick pulled from the cache to read `read` of them."""
+    def keys_gathered(self, cfg, read, last, page_size, nblk,
+                      t: int = 1) -> int:
+        """Keys a call of `t` tokens a row pulled from the cache to read
+        `read` of them."""
         if self.attn_keys_gathered is None:
             return read
-        return self.attn_keys_gathered(cfg, last, page_size, nblk)
+        if t == self.block:
+            return self.attn_keys_gathered(cfg, last, page_size, nblk)
+        return self.attn_keys_gathered(cfg, last, page_size, nblk, t)
 
 
 def paged_body(cfg) -> PagedBody:
@@ -350,8 +356,9 @@ def paged_read_pages_host(cache: Dict, page_ids) -> Tuple[Any, Any]:
     return kv[:, 0], kv[:, 1]
 
 
-# What sizes a span of the dense paged step's attention
-# (paged_span_blocks): the K it gathers for every row of a call, in
+# What sizes a span of the dense paged step's attention where it walks
+# spans (paged_span_blocks: a chunk, a verify, a tick where there is no
+# TPU): the K it gathers for every row of a call, in
 # bytes, and its width in columns.  A turn of the span loop costs ~5 us
 # beside its bytes and a call reads up to one span past its deepest row,
 # so a span is as wide as the gathered K and V stay cheap to hold and
@@ -371,7 +378,9 @@ def paged_span_blocks(key_bytes: int, psz: int, nblk: int) -> int:
     most the whole table.  A 16-row tick over 16-token pages of 8 x 128
     bf16 heads walks 16 blocks (256 columns) a span, a single-row chunk
     32 blocks.  Of the DENSE pool only (one head count and width for
-    every layer): a model with its own paged step sizes its own spans."""
+    every layer): a model with its own paged step sizes its own spans.
+    A tick on a TPU walks no span (`_reads_own_pages`): each row's own
+    blocks are `ops/paged_attention.block_pages`'s."""
     return max(1, min(nblk, _SPAN_BYTES // key_bytes, _SPAN_COLS // psz))
 
 
@@ -389,14 +398,35 @@ def _dense_attn_keys(cfg, pos) -> Tuple[int, int]:
     return keys, keys
 
 
-def _dense_attn_keys_gathered(cfg, last, page_size: int, nblk: int) -> int:
-    """Keys one call pulls from the pool: for EVERY row whole spans (of
-    paged_span_blocks entries) up to the deepest row's last column, the
-    trip count _dense_chunk_step reads from the same positions."""
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _reads_own_pages(t: int, head_dim: int) -> bool:
+    """Does a per-row call of `t` tokens a row read each row's own pages
+    through `ops/paged_attention.py`?  The tick (one token a row) on a
+    TPU, of heads that fill the chip's 128 lanes (its compiler refuses
+    the copy of a narrower page: "must be aligned to tiling (128)");
+    every other call walks spans."""
+    return t == 1 and head_dim % 128 == 0 and _on_tpu()
+
+
+def _dense_attn_keys_gathered(cfg, last, page_size: int, nblk: int,
+                              t: int = 1) -> int:
+    """Keys one call of `t` tokens a row pulls from the pool.  A tick on
+    a TPU: each live row's own blocks of pages, what the kernel copies
+    (an idle row, at 0: none).  Every other call: for EVERY row whole
+    spans (of paged_span_blocks entries) up to the deepest row's last
+    column, the trip count _dense_chunk_step reads from the same
+    positions."""
     rows = len(last)
-    cols = page_size * paged_span_blocks(
-        rows * page_size * _kv_heads(cfg) * cfg.head_dim
-        * jnp.dtype(cfg.dtype).itemsize, page_size, nblk)
+    key = _kv_heads(cfg) * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
+    if _reads_own_pages(t, cfg.head_dim):
+        from ray_tpu.ops import paged_attention as _pa
+        return _pa.keys_copied(last, page_size, nblk, 2 * key) \
+            * cfg.n_layers
+    cols = page_size * paged_span_blocks(rows * page_size * key,
+                                         page_size, nblk)
     spans = -(-(int(last.max()) + 1) // cols)
     return rows * cfg.n_layers * spans * cols
 
@@ -441,9 +471,18 @@ def _dense_chunk_step(params: Dict, tokens, pos, cache: Dict,
                       block_tables, cfg, pad_lo=None) -> Tuple[Any, Dict]:
     """The dense body's step (GPT, Llama), under paged_chunk_step's.
 
-    Attention reads the width the rows HOLD, not the table's: it walks
-    SPANS of paged_span_blocks consecutive table entries up to the
-    deepest row's last column, `ceil((max(pos) + t) / span columns)` of
+    Attention reads the width the rows HOLD, not the table's.  A TICK on
+    a TPU (one token a row at per-row positions, no left padding:
+    `_reads_own_pages`) reads each row's OWN pages to its own position:
+    a layer's attention is one `ops/paged_attention.py` kernel, handed
+    the pools as they lie ([L, P, page, Hkv, Dh] is its "heads in rows"
+    form) and the layer's index, queries and pages in the pool's type,
+    softmax and accumulator float32; a row at position 0 is idle and
+    reads nothing.  Every other call (the single-row chunk, the
+    speculative verify, left-padded rows, a backend that is no TPU)
+    walks SPANS of paged_span_blocks consecutive table entries (the
+    reference the kernel is held equal to: tests/test_decode.py) up to
+    the deepest row's last column, `ceil((max(pos) + t) / span columns)` of
     them — a trip count read from `pos` inside the one compiled
     program, so a call's bytes follow the tokens cached and no table
     width compiles a program of its own.  Each span's pages are
@@ -456,8 +495,9 @@ def _dense_chunk_step(params: Dict, tokens, pos, cache: Dict,
     The pool rides the layer scan as part of its CARRY — (x, k, v) with
     k/v the whole [L, P, page, Hkv, Dh] tensors and the layer index l
     scanned from arange(L) — and each layer scatters its chunk at
-    [l, w_pages, w_offs] before the span loop, which only reads it, at
-    [l, the span's pages]; no per-layer slice cache["k"][l] is formed.
+    [l, w_pages, w_offs] before the span loop (or the kernel), which
+    only reads it, at [l, the span's pages]; no per-layer slice
+    cache["k"][l] is formed.
     The compiler then updates the donated buffer in place and a call
     touches only the pages it writes and reads.  Held as the scan's
     xs/ys instead, every layer's pool is sliced out, updated and
@@ -471,6 +511,8 @@ def _dense_chunk_step(params: Dict, tokens, pos, cache: Dict,
     offs = jnp.arange(t)
     cols = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)) + offs[None, :],
                             (B, t))                    # global columns
+    ragged = pos.ndim == 1 and pad_lo is None \
+        and _reads_own_pages(t, cache["k"].shape[4])
     if pad_lo is None:
         pad_lo = jnp.zeros((B,), jnp.int32)
     positions = cols - pad_lo[:, None]
@@ -485,13 +527,18 @@ def _dense_chunk_step(params: Dict, tokens, pos, cache: Dict,
     n_spans = (jnp.max(pos) + t + span_cols - 1) // span_cols
     span_offs = jnp.arange(span_cols)
 
-    def layer(carry, inputs):
-        x, ck_all, cv_all = carry                # [L, P, psz, Hkv, Dh]
-        lp, l = inputs
-        h = _rmsnorm(x, lp["ln1"])
-        q, k, v = _qkv(lp, h, positions, cfg)
-        ck_all = ck_all.at[l, w_pages, w_offs].set(k.astype(ck_all.dtype))
-        cv_all = cv_all.at[l, w_pages, w_offs].set(v.astype(cv_all.dtype))
+    def own_pages(q, ck_all, cv_all, l):
+        """The tick's attention of layer l: each row's own pages.  (The
+        kernel's module is imported where a TPU's tick is traced, as
+        models/gpt.py imports its flash kernel: Pallas is a second of
+        imports that no other process of a dense model needs.)"""
+        from ray_tpu.ops import paged_attention as _pa
+        return _pa.paged_attention(
+            q[:, 0].astype(ck_all.dtype), ck_all, cv_all, l, block_tables,
+            pos, n_kv_heads=Hkv)[:, None]
+
+    def spans(q, ck_all, cv_all, l):
+        """Layer l's attention span by span, to the deepest row."""
         rep = q.shape[2] // Hkv
         qg = q.reshape(B, t, Hkv, rep, Dh).astype(jnp.float32)
 
@@ -526,7 +573,18 @@ def _dense_chunk_step(params: Dict, tokens, pos, cache: Dict,
             (stat, jnp.zeros_like(stat),
              jnp.zeros((B, Hkv, rep, t, Dh), jnp.float32)))
         out = (acc / total[..., None]).astype(cv_all.dtype)
-        out = jnp.moveaxis(out, 3, 1).reshape(B, t, q.shape[2], Dh)
+        return jnp.moveaxis(out, 3, 1).reshape(B, t, q.shape[2], Dh)
+
+    def layer(carry, inputs):
+        x, ck_all, cv_all = carry                # [L, P, psz, Hkv, Dh]
+        lp, l = inputs
+        h = _rmsnorm(x, lp["ln1"])
+        q, k, v = _qkv(lp, h, positions, cfg)
+        ck_all = ck_all.at[l, w_pages, w_offs].set(k.astype(ck_all.dtype))
+        cv_all = cv_all.at[l, w_pages, w_offs].set(v.astype(cv_all.dtype))
+        with jax.named_scope("dense_attn"):
+            out = own_pages(q, ck_all, cv_all, l) if ragged \
+                else spans(q, ck_all, cv_all, l)
         x = x + _attn_out(lp, out, cfg)
         x = _ffn(lp, x, cfg)
         return (x, ck_all, cv_all), None

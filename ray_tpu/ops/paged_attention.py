@@ -14,14 +14,20 @@ and pool, two blocks in flight (the next block's copies, or the next
 ROW's first, are started before this block's are waited for), and keeps
 a running softmax (maximum, sum, float32 accumulator) in VMEM.  A row
 costs its own blocks and nothing for the table's width: the walk's trip
-count is read from the row's position.
+count is read from the row's position.  A row at position 0 is IDLE (the
+engine's convention: a live row has a prompt behind it; its table is on
+the trash page) and holds nothing, so it reads nothing: no trip, no
+copy (the chain of first copies passes over it to the next live row),
+and its output is zeros.
 
 A token's heads reach the kernel in one of the three forms the models
 keep them in (`models/exaone_moe._kept`, `models/deepseek_v2._lat_row`),
 told apart by what the call hands in, the pool's shape and whether
 there is a pool of values at all:
 
-  [P, page, G, Dh]   heads in ROWS (K-EXAONE's [8, 128]): a page is read
+  [P, page, G, Dh]   heads in ROWS (K-EXAONE's [8, 128], and the dense
+                     body's pool of `models/decode.py`, Mistral's and
+                     InternLM2's [8, 128]): a page is read
                      as [page x G, Dh], one row a (token, head), which
                      is the array as it lies.  Every query head is
                      scored against every row, and a row of another
@@ -52,8 +58,7 @@ The bytes bound the call in the first two (PERF.md section 6, PR 52);
 third (PR 53).
 
 A position past the row's own is masked, so what a page holds behind
-the row's last token, and what the trash page holds, is never seen.  An
-idle row (position 0, its table on the trash page) reads one block.
+the row's last token, and what the trash page holds, is never seen.
 """
 
 from __future__ import annotations
@@ -93,12 +98,18 @@ def block_pages(page_size: int, nblk: int, token_bytes: int) -> int:
     return max(1, min(keys // page_size, nblk))
 
 
+def _trips(pos, width: int):
+    """Blocks of `width` keys the walk of each row at `pos` takes (numpy
+    or traced): its own up to its position, none where it is idle."""
+    return (pos // width + 1) * (pos > 0)
+
+
 def keys_copied(pos, page_size: int, nblk: int, token_bytes: int) -> int:
     """Keys the kernel copies from one layer's pool for rows at `pos`
-    (every row of the call, idle ones at 0): each row's own blocks, the
-    last one whole."""
+    (every row of the call, idle ones at 0): each live row's own blocks,
+    the last one whole."""
     width = block_pages(page_size, nblk, token_bytes) * page_size
-    return int((np.asarray(pos, np.int64) // width + 1).sum()) * width
+    return int(_trips(np.asarray(pos, np.int64), width).sum()) * width
 
 
 def widen(q, n_kv_heads: int):
@@ -112,7 +123,8 @@ def widen(q, n_kv_heads: int):
     return wide.reshape(B, H, G * Dh)
 
 
-def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, *rest,
+def _kernel(layer_ref, pos_ref, first_ref, live_ref, bt_ref, q_ref, k_hbm,
+            *rest,
             pages: int, nblk: int, page_size: int, in_rows: int,
             group: int, scale: float, values_in_keys: bool):
     if values_in_keys:
@@ -128,7 +140,7 @@ def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, *rest,
     width = pages * page_size             # keys a block
     H, Dv = o_ref.shape
     pos = pos_ref[b]
-    trips = pos // width + 1
+    trips = _trips(pos, width)
 
     def copies(row, j, slot):
         """The DMAs of block `j` of `row` into buffer `slot`."""
@@ -150,9 +162,10 @@ def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, *rest,
         for c in copies(row, j, slot):
             c.start()
 
-    @pl.when(b == 0)
+    # live_ref[i]: the first live row at or after row i (`rows`: none)
+    @pl.when((b == 0) & (live_ref[0] < rows))
     def _():
-        start(0, 0, 0)
+        start(live_ref[0], 0, 0)
 
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -175,9 +188,9 @@ def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, *rest,
         def _():
             start(b, j + 1, 1 - slot)
 
-        @pl.when((j + 1 == trips) & (b + 1 < rows))
+        @pl.when((j + 1 == trips) & (live_ref[b + 1] < rows))
         def _():
-            start(b + 1, 0, 1 - slot)
+            start(live_ref[b + 1], 0, 1 - slot)
 
         for c in copies(b, j, slot):
             c.wait()
@@ -198,14 +211,16 @@ def _kernel(layer_ref, pos_ref, first_ref, bt_ref, q_ref, k_hbm, *rest,
         return carry
 
     lax.fori_loop(0, trips, block, 0)
+    # (an idle row summed nothing: zeros over one, not over zero)
+    total = jnp.where(trips > 0, l_ref[...], 1.0)
     if acc_ref.shape[1] == Dv:
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / total).astype(o_ref.dtype)
     else:
         # of the whole value row a head keeps its own head's lanes
         for g in range(H // group):
             at = slice(g * group, (g + 1) * group)
             o_ref[at, :] = (acc_ref[at, g * Dv:(g + 1) * Dv]
-                            / l_ref[at, :]).astype(o_ref.dtype)
+                            / total[at, :]).astype(o_ref.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
@@ -214,7 +229,8 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
     """q [B, H, Dh] (one token a row) over the pages of layer `layer` of
     k_pool [L, P, page, G, Dh] / v_pool [L, P, page, G, Dv], or the same
     with a token's heads side by side ([L, P, page, G x Dh]): row b
-    attends to positions 0..pos[b] of the pages block_tables[b] names.
+    attends to positions 0..pos[b] of the pages block_tables[b] names; a
+    row at position 0 is idle, reads nothing and returns zeros.
     `v_pool` None: a value is the first `value_width` lanes of its key,
     k_pool [L, P, page, Dh] of one head every query head shares.
     Scores are scaled by `scale` (default Dh ** -0.5); matmul inputs are
@@ -247,10 +263,13 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
     pages = block_pages(psz, nblk, sum(
         per * pool.shape[3] * pool.dtype.itemsize for pool in pools) // psz)
     pos = pos.astype(jnp.int32)
-    trips = pos // (pages * psz) + 1
+    trips = _trips(pos, pages * psz)
     # the buffer a row's first block lands in: blocks alternate between
     # the two through the whole call
     first = (jnp.cumsum(trips) - trips) % 2
+    # the first live row at or after each row, and after the last: B
+    live = lax.cummin(jnp.where(pos > 0, jnp.arange(B), B), reverse=True)
+    live = jnp.concatenate([live, jnp.full((1,), B, live.dtype)])
     row = lambda w: pl.BlockSpec((None, H, w),  # noqa: E731
                                  lambda b, *_: (b, 0, 0))
     # a pair of buffers and a pair of semaphores a pool
@@ -262,7 +281,7 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
                           scale=Dh ** -0.5 if scale is None else scale,
                           values_in_keys=v_pool is None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(B,),
+            num_scalar_prefetch=5, grid=(B,),
             in_specs=[row(Wk)] + [pl.BlockSpec(memory_space=pl.ANY)
                                   for _ in pools],
             out_specs=row(Dv),
@@ -278,5 +297,5 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
             vmem_limit_bytes=64 << 20),
         name="paged_attention",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos,
-      first.astype(jnp.int32), block_tables.reshape(-1).astype(jnp.int32),
-      q, *pools)
+      first.astype(jnp.int32), live.astype(jnp.int32),
+      block_tables.reshape(-1).astype(jnp.int32), q, *pools)

@@ -2752,7 +2752,7 @@ class GenerationEngine:
         self._keys_attended += read
         self._keys_resident += held
         self._keys_gathered += body.keys_gathered(
-            self.cfg, read, last, self.page_size, self._max_blocks)
+            self.cfg, read, last, self.page_size, self._max_blocks, t)
         # (a model whose layers are not all paged says the same two of
         # the layers that are, so that rings do not dilute the ratio)
         if body.attn_keys_paged is not None:

@@ -226,3 +226,132 @@ def test_generate_bounds_checked():
     with pytest.raises(ValueError):
         decode.generate(params, jnp.zeros((1, 4), jnp.int32), GPT_CFG,
                         max_new_tokens=0)
+
+
+# ---------------------------------------------------------------------------
+# The dense paged tick on a TPU: each row's own pages through
+# ops/paged_attention.py (interpreted here), held to the span loop
+
+# heads that fill the chip's lanes, as the kernel path asks
+WIDE_CFG = llama.LlamaConfig(vocab_size=97, d_model=256, n_heads=2,
+                             n_kv_heads=1, n_layers=2, d_ff=64,
+                             max_seq=256, dtype=jnp.float32, remat=False,
+                             use_flash=False)
+PAGE, TICKS = 16, 32
+DEPTHS = [70, 0, 5, 33, 0, 120]       # a prompt a row; 0: the row is idle
+
+
+def _as_on_a_tpu(monkeypatch, calls):
+    """`decode` believes it is on a TPU; the kernel runs interpreted, in
+    blocks of two pages, and counts how often it is traced."""
+    from ray_tpu.ops import paged_attention as pa
+
+    real = pa.paged_attention
+
+    def counted(*a, **kw):
+        calls.append(a[0].shape)
+        return real(*a, interpret=True, **kw)
+
+    monkeypatch.setattr(decode, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pa, "paged_attention", counted)
+    monkeypatch.setattr(pa, "_BLOCK_KEYS", 2 * PAGE)
+    monkeypatch.setattr(pa, "_BLOCK_BYTES", 0)
+
+
+def _prefilled(cfg, params):
+    """(pool, block tables, positions, first tokens): every live row's
+    prompt prefilled by single-row chunks, on pages of its own."""
+    rng = np.random.default_rng(7)
+    nblk = cfg.max_seq // PAGE
+    B = len(DEPTHS)
+    pool = decode.init_paged_cache(cfg, B * nblk + 1, PAGE)
+    bt = 1 + rng.permutation(B * nblk).reshape(B, nblk).astype(np.int32)
+    first = np.zeros(B, np.int32)
+    for b, n in enumerate(DEPTHS):
+        if not n:
+            bt[b] = 0
+            continue
+        prompt = jnp.asarray(rng.integers(1, cfg.vocab_size, size=(1, n)),
+                             jnp.int32)
+        logits, pool = decode.paged_chunk_step(
+            params, prompt, jnp.int32(0), pool, jnp.asarray(bt[b:b + 1]),
+            cfg)
+        first[b] = int(jnp.argmax(logits[0, -1]))
+    return pool, bt, np.asarray(DEPTHS, np.int32), first
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_paged_tick_through_the_kernel_is_the_span_loops(dtype, monkeypatch):
+    """32 ticks of a toy Llama over rows of unequal depth, two of them
+    idle: the tick through `ops/paged_attention.py` gives the span
+    loop's logits at every tick (teacher-forced on the kernel path's
+    own greedy tokens), within float32's rounding and within
+    bfloat16's, and in float32 the same greedy tokens."""
+    import dataclasses
+    cfg = dataclasses.replace(WIDE_CFG, dtype=dtype)
+    params = jax.tree_util.tree_map(lambda x: x.astype(dtype),
+                                    _params(cfg))
+    pool, bt, pos, tok = _prefilled(cfg, params)
+    live, bt = pos > 0, jnp.asarray(bt)
+
+    def ticks(pool, feed):
+        """TICKS ticks from `pool` (a new program each time this is
+        called), fed `feed[i]` at tick i, or else their own greedy
+        tokens: (logits a tick, the tokens fed a tick)."""
+        tick = jax.jit(lambda *a: decode.paged_chunk_step(*a, cfg))
+        rows, fed, t = [], [], tok
+        for i in range(TICKS):
+            t = t if feed is None else feed[i]
+            at = jnp.asarray(np.where(live, pos + i, 0))
+            logits, pool = tick(params, jnp.asarray(t)[:, None], at, pool,
+                                bt)
+            rows.append(np.asarray(logits[:, 0]))
+            fed.append(t)
+            t = np.where(live, rows[-1].argmax(-1), 0).astype(np.int32)
+        return np.stack(rows), fed
+
+    calls = []
+    with monkeypatch.context() as m:
+        _as_on_a_tpu(m, calls)
+        got, fed = ticks(pool, None)
+    assert calls == [(len(pos), 2, 128)]         # traced once, 32 ticks
+    want, _ = ticks(pool, fed)
+    assert len(calls) == 1                       # ...and not again
+    assert np.isfinite(got).all()
+    assert (got[:, live] != want[:, live]).any()    # two programs
+    np.testing.assert_allclose(
+        got[:, live], want[:, live],
+        atol=2e-4 if dtype == jnp.float32 else 6e-2)
+    if dtype == jnp.float32:
+        np.testing.assert_array_equal(got[:, live].argmax(-1),
+                                      want[:, live].argmax(-1))
+
+
+def test_the_verify_the_chunk_and_padded_rows_walk_spans(monkeypatch):
+    """On a TPU too, only the tick (one token a row at per-row
+    positions, no left padding) calls the kernel: the speculative
+    verify (t > 1 a row), the single-row chunk (one shared start) and a
+    call with left-padded rows trace the span loop, and so does a tick
+    of heads narrower than the chip's lanes."""
+    cfg, calls = WIDE_CFG, []
+    params = _params(cfg)
+    pool, bt, pos, tok = _prefilled(cfg, params)
+    _as_on_a_tpu(monkeypatch, calls)
+    step = lambda *a, **kw: decode.paged_chunk_step(  # noqa: E731
+        params, *a, cfg, **kw)
+    bt, at = jnp.asarray(bt), jnp.asarray(pos)
+    B = len(pos)
+    step(jnp.ones((B, 4), jnp.int32), at, pool, bt)             # verify
+    step(jnp.ones((1, 8), jnp.int32), jnp.int32(3), pool, bt[:1])  # chunk
+    step(jnp.asarray(tok)[:, None], at, pool, bt,
+         pad_lo=jnp.zeros((B,), jnp.int32))
+    assert calls == []
+    step(jnp.asarray(tok)[:, None], at, pool, bt)               # the tick
+    assert calls == [(B, 2, 128)]
+    narrow = _params(LLAMA_CFG)
+    thin = decode.init_paged_cache(LLAMA_CFG, 9, PAGE)
+    decode.paged_chunk_step(
+        narrow, jnp.ones((2, 1), jnp.int32), jnp.asarray([3, 5]), thin,
+        jnp.asarray([[1, 2], [3, 4]], jnp.int32), LLAMA_CFG)
+    assert len(calls) == 1

@@ -4,15 +4,21 @@ attention layers, interpreted on the CPU: against the span loops of
 there is no TPU) and against a plain float32 softmax over each row's own
 keys, in both forms a model keeps a token's heads in and over a latent
 page whose values are a prefix of its keys; what it reads of the pool
-and what it never touches; and the counters that say what it copies."""
+and what it never touches (an idle row's pages: all of them); the
+counters that say what it copies; and, at the dense body's shapes
+(`models/decode.py`: pages of 16 tokens x 8 heads x 128, 16 or 32 query
+heads, the block the chip runs), against the dense step's own span
+loop."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models import decode, llama
 from ray_tpu.models import deepseek_v2 as ds
 from ray_tpu.models import exaone_moe as em
 from ray_tpu.ops import paged_attention as pa
@@ -24,11 +30,15 @@ KINDS = {"heads-in-rows": (8, 2, 128, 128, False),    # K-EXAONE's [G, 128]
          "heads-in-lanes": (8, 2, 192, 128, True),    # MiMo's G x 192, G x 128
          # DeepSeek-V2's latent row [512 | 64 | 64 zeros]: one head every
          # query head shares, its values its own first 512 lanes
-         "latent": (8, 1, 640, 512, True)}
+         "latent": (8, 1, 640, 512, True),
+         # the dense pool's [8, 128] under InternLM2's and Mistral's heads
+         "dense-16": (16, 8, 128, 128, False),
+         "dense-32": (32, 8, 128, 128, False)}
+POOLS = ["heads-in-rows", "heads-in-lanes", "latent"]
 # the latent step's own scale: of a 192-wide head, times YaRN's factor
 LATENT = ds.DeepseekV2Config(max_seq=PAGE * NBLK, n_layers=5)
 # positions of the call's rows (an idle row stands at 0 on the trash
-# page, 0, whatever else the call holds)
+# page, 0, whatever else the call holds, and reads nothing)
 CASES = {
     "unequal-depths": [0, 17, 150, 40, 100],
     "mid-page": [PAGE * 3 + 5, 7],
@@ -39,7 +49,7 @@ CASES = {
 }
 
 
-def _state(kind, pos, seed=0, dtype=jnp.bfloat16):
+def _state(kind, pos, seed=0, dtype=jnp.bfloat16, NBLK=NBLK):
     """(kind, q, k pool, v pool, block tables, positions): every row on
     pages of its own in a drawn order, an idle row's table on page 0.  A
     latent page has no value pool (None)."""
@@ -69,6 +79,9 @@ def _plain(kind, q, k, v, bt, pos):
     H, G, Dh, Dv, _ = KINDS[kind]
     out = []
     for b, p in enumerate(pos):
+        if p == 0:                    # idle: nothing read, zeros
+            out.append(np.zeros((H, Dv), np.float32))
+            continue
         n = int(p) + 1
         pages = bt[b, :-(-n // PAGE)]
         keys = np.asarray(k[LAYER, pages], np.float32).reshape(-1, G, Dh)[:n]
@@ -113,35 +126,129 @@ def small_blocks(monkeypatch):
     monkeypatch.setattr(ds, "_TICK_SPAN_KEYS", 3 * PAGE)
 
 
+def _holds(state, **kw):
+    """The kernel's live rows are the span loop's and a plain softmax's
+    (the span loop gives an idle row the trash page's first key); its
+    idle rows are zeros."""
+    got, pos = _kernel(*state), state[-1]
+    np.testing.assert_allclose(got[pos > 0], _span(*state)[pos > 0], **kw)
+    np.testing.assert_allclose(got, _plain(*state), **kw)
+    np.testing.assert_array_equal(got[pos == 0], 0.0)
+
+
 @pytest.mark.parametrize("case", list(CASES))
-@pytest.mark.parametrize("kind", list(KINDS))
+@pytest.mark.parametrize("kind", POOLS)
 def test_the_kernel_is_the_span_loop_and_a_plain_softmax(kind, case):
     """Rows of unequal depth, a depth that ends inside a page, a row at
     the table's last block, an idle row on the trash page, one row
-    alone, depths on both sides of a block's edge: each row's output is
-    a softmax over ITS keys, in bfloat16 as the chip holds them and in
-    float32."""
-    state = _state(kind, CASES[case], seed=len(case))
-    got = _kernel(*state)
+    alone, depths on both sides of a block's edge: each live row's
+    output is a softmax over ITS keys, in bfloat16 as the chip holds
+    them and in float32; an idle row's is zeros."""
     # (bfloat16 weights into the weighted sum and a bfloat16 result:
     # one block's rounding is not another span's)
-    np.testing.assert_allclose(got, _span(*state), atol=2e-2)
-    np.testing.assert_allclose(got, _plain(*state), atol=2e-2)
+    _holds(_state(kind, CASES[case], seed=len(case)), atol=2e-2)
     exact = _state(kind, CASES[case], seed=len(case), dtype=jnp.float32)
     np.testing.assert_allclose(_kernel(*exact), _plain(*exact), atol=2e-5)
 
 
-@pytest.mark.parametrize("kind", list(KINDS))
+# The dense body's shapes, under the block the chip runs (384 keys = 24
+# of its pages): rows that end exactly on, one short of and one past a
+# block's edge; idle rows between live ones; a call of idle rows alone.
+DENSE_NBLK = 50
+DENSE_CASES = {
+    "block-edges": [383, 384, 385, 767, 768],
+    "idle-rows-between-live-ones": [200, 0, 385, 0, 0, 17],
+    "every-row-idle": [0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("case", list(DENSE_CASES))
+@pytest.mark.parametrize("kind", ["dense-16", "dense-32"])
+def test_the_kernel_at_the_dense_pools_shapes(kind, case, monkeypatch):
+    """Pages of 16 tokens x 8 heads x 128 under 16 query heads (groups
+    of 2, InternLM2's) and 32 (groups of 4, Mistral's), blocks of 24
+    pages: live rows are the span loop's and a plain softmax's, idle
+    rows finite zeros, and a call whose every row is idle starts no
+    copy at all (a pool of not-a-number changes nothing)."""
+    monkeypatch.undo()                # the real _BLOCK_KEYS, _BLOCK_BYTES
+    assert pa.block_pages(PAGE, DENSE_NBLK, 8 * (128 + 128) * 2) == 24
+    state = _state(kind, DENSE_CASES[case], seed=len(case), NBLK=DENSE_NBLK)
+    _holds(state, atol=2e-2)
+    _, q, k, v, bt, pos = state
+    if not pos.any():
+        np.testing.assert_array_equal(
+            _kernel(kind, q, k.at[:].set(jnp.nan), v.at[:].set(jnp.nan),
+                    bt, pos), 0.0)
+
+
+def _dense_step(cfg, params, tok, pos, cache, bt, ragged, monkeypatch):
+    """One `decode._dense_chunk_step` of a token a row, through the
+    kernel (interpreted: `_on_tpu` says yes) or through the span loop."""
+    with monkeypatch.context() as m:
+        m.setattr(decode, "_on_tpu", lambda: ragged)
+        m.setattr(pa, "paged_attention", functools.partial(
+            pa.paged_attention, interpret=True))
+        # (a function of its own a call: jax finds a function it has
+        # traced again, whatever `_on_tpu` says by then)
+        return jax.jit(lambda *a: decode._dense_chunk_step(*a, cfg))(
+            params, tok[:, None], pos, cache, bt)
+
+
+@pytest.mark.parametrize("heads", [16, 32])
+def test_the_dense_tick_through_the_kernel_is_the_span_loops(heads,
+                                                             monkeypatch):
+    """The dense body's own step at its pool's shapes (one layer of 8 x
+    128 key-value heads under `heads` query heads, pages of 16, the real
+    block): a tick through the kernel gives the span loop's logits and
+    leaves the same pool, with idle rows among the live ones."""
+    monkeypatch.undo()
+    cfg = llama.LlamaConfig(vocab_size=64, d_model=heads * 128,
+                            n_heads=heads, n_kv_heads=8, n_layers=1,
+                            d_ff=128, max_seq=PAGE * DENSE_NBLK,
+                            dtype=jnp.bfloat16, remat=False,
+                            use_flash=False)
+    params = jax.tree_util.tree_map(
+        lambda x: x.astype(cfg.dtype),
+        llama.init_params(cfg, jax.random.PRNGKey(heads)))
+    pos = np.asarray(DENSE_CASES["idle-rows-between-live-ones"], np.int32)
+    rng = np.random.default_rng(heads)
+    B = len(pos)
+    bt = 1 + rng.permutation(B * DENSE_NBLK).reshape(B, DENSE_NBLK) \
+        .astype(np.int32)
+    bt[pos == 0] = 0
+    fill = lambda: {  # noqa: E731
+        name: jnp.asarray(np.random.default_rng(i).normal(
+            size=(1, B * DENSE_NBLK + 1, PAGE, 8, 128)), cfg.dtype)
+        for i, name in enumerate("kv")}
+    tok = jnp.asarray(rng.integers(1, 64, size=B), jnp.int32)
+    got, pool = _dense_step(cfg, params, tok, jnp.asarray(pos), fill(),
+                            jnp.asarray(bt), True, monkeypatch)
+    want, ref = _dense_step(cfg, params, tok, jnp.asarray(pos), fill(),
+                            jnp.asarray(bt), False, monkeypatch)
+    live = pos > 0
+    got, want = np.asarray(got), np.asarray(want)
+    # (two programs: an idle row attends to nothing in one and to the
+    # trash page's first key in the other)
+    assert (got[~live] != want[~live]).any()
+    np.testing.assert_allclose(got[live], want[live], atol=3e-2)
+    assert np.isfinite(got).all()
+    for name in "kv":
+        np.testing.assert_array_equal(
+            np.asarray(pool[name][:, 1:], np.float32),
+            np.asarray(ref[name][:, 1:], np.float32))
+
+
+@pytest.mark.parametrize("kind", POOLS)
 def test_a_row_reads_its_own_blocks_and_nothing_past_its_position(kind):
     """What the walk visits: not-a-number in every page past a row's
-    last BLOCK (and in every page no table names) changes nothing, so
-    none of it is copied; other keys in the row's own last block, past
-    its position, are copied and masked; a key at or before its
-    position is read."""
+    last BLOCK (and in every page no table names, and in the trash page
+    an idle row's table names) changes nothing, so none of it is copied;
+    other keys in the row's own last block, past its position, are
+    copied and masked; a key at or before its position is read."""
     state = _state(kind, CASES["unequal-depths"], seed=3)
     _, q, k, v, bt, pos = state
     want = _kernel(*state)
-    blocks = pos // BLOCK + 1
+    blocks = (pos // BLOCK + 1) * (pos > 0)
     own = np.zeros(k.shape[1], bool)         # pages some row's walk visits
     for b in range(len(pos)):
         own[bt[b, :blocks[b] * BLOCK // PAGE]] = True
@@ -173,9 +280,9 @@ def test_a_row_reads_its_own_blocks_and_nothing_past_its_position(kind):
 def test_the_counters_are_what_the_kernel_copies(case, monkeypatch):
     """`attn_keys_paged` and `attn_keys_gathered` on a TPU: each row's
     own blocks, the last one whole, in every paged layer: the pages the
-    walk of the test above visits for the same positions (an idle row's
-    one block too); without one, the span loop's spans to the deepest
-    row for every row."""
+    walk of the test above visits for the same positions (of an idle
+    row none); without one, the span loop's spans to the deepest row for
+    every row."""
     cfg = em.ExaoneMoeConfig(max_seq=PAGE * NBLK, n_layers=8)
     pos = np.asarray(CASES[case], np.int32)
     active = pos[pos > 0]
@@ -185,7 +292,7 @@ def test_the_counters_are_what_the_kernel_copies(case, monkeypatch):
     assert em.attn_keys_paged(cfg, active, pos, PAGE, NBLK) == (
         spans * cfg.n_global, held)
     monkeypatch.setattr(em, "_on_tpu", lambda: True)
-    visited = sum(len(range(0, int(p) + 1, BLOCK)) for p in pos) * BLOCK
+    visited = sum(len(range(0, int(p) + 1, BLOCK)) for p in active) * BLOCK
     assert pa.keys_copied(pos, PAGE, NBLK, 4096) == visited
     assert em.attn_keys_paged(cfg, active, pos, PAGE, NBLK) == (
         visited * cfg.n_global, held)
@@ -206,10 +313,42 @@ def test_the_latent_counter_is_what_the_kernel_copies(case, monkeypatch):
     assert ds.attn_keys_gathered(LATENT, pos, PAGE, NBLK) == (
         spans * LATENT.n_layers)
     monkeypatch.setattr(ds, "_on_tpu", lambda: True)
-    visited = sum(len(range(0, int(p) + 1, BLOCK)) for p in pos) * BLOCK
+    visited = sum(len(range(0, int(p) + 1, BLOCK))
+                  for p in pos[pos > 0]) * BLOCK
     assert ds.attn_keys_gathered(LATENT, pos, PAGE, NBLK) == (
         pa.keys_copied(pos, PAGE, NBLK, 1280) * LATENT.n_layers) == (
         visited * LATENT.n_layers)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_dense_counter_is_what_the_program_copies(case, monkeypatch):
+    """`decode.DENSE_BODY.keys_gathered`: a tick on a TPU counts each
+    live row's own blocks in every layer (an idle row's: none), what
+    the kernel copies; the verify (t tokens a row), heads narrower than
+    the chip's lanes and every other backend count the span loop's
+    spans to the deepest row for every row."""
+    cfg = llama.LlamaConfig(vocab_size=64, d_model=256, n_heads=2,
+                            n_kv_heads=1, n_layers=3, d_ff=64,
+                            max_seq=PAGE * NBLK, dtype=jnp.bfloat16)
+    narrow = dataclasses.replace(cfg, n_heads=4, n_kv_heads=2)
+    pos = np.asarray(CASES[case], np.int32)
+    rows, L = len(pos), cfg.n_layers
+    def gathered(c, last, t=1):
+        return decode.DENSE_BODY.keys_gathered(c, -1, last, PAGE, NBLK, t)
+
+    def spans(c, last):
+        cols = PAGE * decode.paged_span_blocks(
+            rows * PAGE * c.n_kv_heads * c.head_dim * 2, PAGE, NBLK)
+        return rows * L * -(-(int(last.max()) + 1) // cols) * cols
+
+    assert gathered(cfg, pos) == spans(cfg, pos)
+    monkeypatch.setattr(decode, "_on_tpu", lambda: True)
+    visited = sum(len(range(0, int(p) + 1, BLOCK))
+                  for p in pos[pos > 0]) * BLOCK
+    assert gathered(cfg, pos) == visited * L \
+        == pa.keys_copied(pos, PAGE, NBLK, 2 * 128 * 2) * L
+    assert gathered(cfg, pos + 3, t=4) == spans(cfg, pos + 3)
+    assert gathered(narrow, pos) == spans(narrow, pos)
 
 
 def test_keys_that_hold_their_values_are_one_head():
@@ -248,4 +387,4 @@ def test_a_thin_token_makes_a_block_of_more_keys(token_bytes, keys,
     monkeypatch.undo()                # the real _BLOCK_KEYS, _BLOCK_BYTES
     assert pa.block_pages(64, 432, token_bytes) * 64 == keys
     assert pa.keys_copied([0, 767, 768], 64, 432, token_bytes) == (
-        {384: 1 + 2 + 3, 768: 1 + 1 + 2}[keys] * keys)
+        {384: 0 + 2 + 3, 768: 0 + 1 + 2}[keys] * keys)

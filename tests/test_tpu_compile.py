@@ -100,6 +100,11 @@ def _no_row_a_routed_pair(text, tokens, cfg, hidden):
     assert not held, held[:4]
 
 
+def _ragged_kernels(text):
+    return [ln for ln in text.splitlines() if "custom-call(" in ln
+            and "tpu_custom_call" in ln and " %paged_attention" in ln]
+
+
 def _ragged_tick_holds(text, cfg, cache, rows, blocks, page_size):
     """What a compiled tick of `models/exaone_moe.py` holds to on a TPU:
     one `ops/paged_attention.py` kernel a paged layer beside the expert
@@ -110,8 +115,7 @@ def _ragged_tick_holds(text, cfg, cache, rows, blocks, page_size):
     ([rows x blocks] page ids in scalar memory)."""
     kernels = [ln for ln in text.splitlines()
                if "custom-call(" in ln and "tpu_custom_call" in ln]
-    ragged = [ln for ln in kernels if " %paged_attention" in ln]
-    assert len(ragged) == cfg.n_global, len(ragged)
+    assert len(_ragged_kernels(text)) == cfg.n_global
     assert len(kernels) == 3 * cfg.n_moe + cfg.n_global, len(kernels)
     for pool in (cache["k"], cache["v"]):
         if pool.ndim != 5:
@@ -138,7 +142,9 @@ def _compile_step(chip, step, cfg, mod, rows, blocks, pages):
     """The engine's own jitted decode tick ([rows, 1]) or prefill chunk
     ([1, 32], or [1, 256]: the width an engine takes when it is given
     none) of a dense model, compiled for the described chip from
-    shapes: (the compiled program, the pool's K as a shape)."""
+    shapes: (the compiled program, the pool's K as a shape).  Compiled
+    as on a TPU (`as_on_a_tpu`), a tick reads each row's own pages
+    through `ops/paged_attention.py`."""
     params = _on(chip, jax.eval_shape(
         lambda: jax.tree_util.tree_map(
             lambda x: x.astype(cfg.dtype),
@@ -164,26 +170,80 @@ def _compile_step(chip, step, cfg, mod, rows, blocks, pages):
 STEPS = ["decode_tick", "prefill_chunk", "prefill_chunk_default"]
 
 
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    """`decode._on_tpu` says yes while a dense step is traced (such a
+    compile's default backend is the CPU), and no program traced under
+    the other answer is found again."""
+    monkeypatch.setattr(decode, "_on_tpu", lambda: True)
+    engine._paged_tick.clear_cache()
+    yield
+    engine._paged_tick.clear_cache()
+
+
+def _dense_tick_holds(text, pool_shape, rows):
+    """What a compiled dense tick holds to on a TPU: one
+    `ops/paged_attention.py` kernel in the layer scan's body, handed K
+    and V as they lie (the pool's [page, heads, 128] merged to [page x
+    heads, 128] by a bitcast, never by a copy), and no float32 array a
+    span wide for every row ([rows, span columns, heads, 128]: what the
+    span loop cast its gathered keys to)."""
+    assert len(_ragged_kernels(text)) == 1
+    L, P, psz, G, Dh = pool_shape
+    merged = " = bf16[%d,%d,%d,%d]" % (L, P, psz * G, Dh)
+    made = [ln for ln in text.splitlines() if merged in ln]
+    assert len(made) == 2 and all(" bitcast(" in ln for ln in made), made[:4]
+    span = decode.paged_span_blocks(rows * psz * G * Dh * 2, psz, 1 << 20)
+    cast = " = f32[%d,%d,%d,%d]" % (rows, span * psz, G, Dh)
+    assert not [ln for ln in text.splitlines() if cast in ln]
+
+
 @pytest.mark.parametrize("step", STEPS)
 @pytest.mark.parametrize("model", list(STEP_MODELS))
-def test_paged_step_compiles(chip, model, step):
+def test_paged_step_compiles(chip, model, step, as_on_a_tpu):
     """The engine's own jitted decode tick ([rows, 1]) and prefill chunk
     ([1, 32] and the default [1, 256]): the 737M GPT over 8 rows x 1024
     tokens, and the benchmark's two configurations at their real widths
     and pools.  The pool is gigabytes there, and a step may hold no
     second one: no temporary the size of a layer's pool, no copy of the
-    whole."""
+    whole.  The tick's attention is the ragged kernel (PR 60), and it
+    holds less than the span loop's did; a chunk calls no kernel."""
     cfg, mod, rows, blocks, pages = STEP_MODELS[model]
     compiled, pool = _compile_step(chip, step, cfg, mod, rows, blocks, pages)
     mem = compiled.memory_analysis()
     # with the pool as the layer scan's xs/ys: 4.19 / 3.94 GiB (Mistral
     # tick / chunk), 3.38 / 3.13 (InternLM2); as its carry 0.126 / 0.0003;
     # attention span by span (PR 29) 0.0006 / 0.0005; a 256-token chunk
-    # (PR 33) 0.0007 / 0.0005
-    assert mem.temp_size_in_bytes < 1 << 29, mem.temp_size_in_bytes / 2**30
-    per_layer, moved = _pool_results(compiled.as_text(), pool.shape)
+    # (PR 33) 0.0007 / 0.0005; the tick through the kernel (PR 60)
+    # 0.00036 (0.369 MiB against the span loop's 0.616, both models)
+    text = compiled.as_text()
+    if step == "decode_tick":
+        assert mem.temp_size_in_bytes < 1 << 19, mem.temp_size_in_bytes
+        _dense_tick_holds(text, pool.shape, rows)
+    else:
+        assert mem.temp_size_in_bytes < 1 << 29, \
+            mem.temp_size_in_bytes / 2**30
+        assert not _ragged_kernels(text)
+    per_layer, moved = _pool_results(text, pool.shape)
     assert not per_layer, per_layer[:4]
     assert not moved, moved[:4]
+
+
+@pytest.mark.parametrize("model", ["mistral-d16", "internlm2"])
+def test_the_dense_tick_where_there_is_no_tpu_walks_spans(chip, model):
+    """The same tick traced as on any other backend: no kernel, the
+    span loop's temporaries (0.616 MiB), and still no second pool."""
+    cfg, mod, rows, blocks, pages = STEP_MODELS[model]
+    engine._paged_tick.clear_cache()
+    compiled, pool = _compile_step(chip, "decode_tick", cfg, mod, rows,
+                                   blocks, pages)
+    engine._paged_tick.clear_cache()
+    text = compiled.as_text()
+    assert not _ragged_kernels(text)
+    mem = compiled.memory_analysis()
+    assert 1 << 19 < mem.temp_size_in_bytes < 1 << 20
+    per_layer, moved = _pool_results(text, pool.shape)
+    assert not per_layer and not moved, (per_layer[:4], moved[:4])
 
 
 @pytest.mark.parametrize("model", ["mistral-d16", "internlm2"])
@@ -210,12 +270,15 @@ def test_tier_read_compiles_at_one_shape_and_moves_no_pool(chip, model):
 
 
 @pytest.mark.parametrize("step", STEPS)
-def test_paged_step_compiles_at_a_32k_width(chip, step):
+def test_paged_step_compiles_at_a_32k_width(chip, step, as_on_a_tpu):
     """InternLM2's tick and chunk over a virtual width of 32,768 (2,048
     blocks a row, the benchmark's 2,048-page pool): attention walks
-    spans of the table up to what the rows hold, so nothing the program
-    keeps is as wide as the table.  The same temporaries as at 4,096
-    (0.0006 / 0.0005 GiB), and no array with a dimension of the width;
+    each row's own pages (the tick) or spans of the table up to what
+    the rows hold (a chunk), so nothing the program keeps is as wide as
+    the table, but the table itself, which the tick's kernel is handed
+    flat ([16 rows x 2,048 blocks] page ids in scalar memory).  The
+    same temporaries as at 4,096 (0.0004 / 0.0005 GiB), and no other
+    array with a dimension of the width;
     the body that gathered the virtual width compiled here with 1.03 GiB
     of temporaries in the tick and 0.063 in the chunk, eight times what
     it held at 4,096.  What `internlm2-longctx` needs (ROADMAP R0)."""
@@ -229,8 +292,9 @@ def test_paged_step_compiles_at_a_32k_width(chip, step):
     text = compiled.as_text()
     per_layer, moved = _pool_results(text, pool.shape)
     assert not per_layer and not moved, (per_layer[:4], moved[:4])
-    shapes = re.findall(r" = \w+\[([\d,]+)\]", text)
-    assert not [s for s in shapes if str(wide) in s.split(",")]
+    shapes = re.findall(r" = (\w+)\[([\d,]+)\]", text)
+    assert not [s for s in shapes if str(wide) in s[1].split(",")
+                and s != ("s32", str(16 * (wide // 16)))]  # (the table)
 
 
 # The third configuration (benchmarks/configs/minicpm-sala-d16.json): a
