@@ -13,6 +13,7 @@ to the CPU without a word).
 
 from __future__ import annotations
 
+import collections
 import os
 import threading
 import time
@@ -194,6 +195,9 @@ _COMPILE_STAGES = ("/jax/core/compile/jaxpr_trace_duration",
 _CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
 _COMPILES = {"installed": False, "n": 0, "s": 0.0}
 _COMPILES_LOCK = threading.Lock()
+# The last stages' (start, end, stage, fun_name), epoch seconds, kept
+# outside the trace ring (a busy replica's ring turns over in seconds).
+_RECENT_STAGES: collections.deque = collections.deque(maxlen=256)
 _COMPILING = threading.local()   # per thread: .spans, .loaded
 
 
@@ -213,6 +217,8 @@ def _on_compile_stage(event, start_time, end_time, **kw) -> None:
         net -= spans.pop()[1]
     spans.append((start_time, end_time - start_time))
     del spans[:-64]
+    _RECENT_STAGES.append((start_time, end_time, event.rsplit("/", 1)[1],
+                           str(kw.get("fun_name", "?"))))
     backend = event == _COMPILE_STAGES[2]
     with _COMPILES_LOCK:
         _COMPILES["s"] += max(0.0, net)
@@ -254,6 +260,14 @@ def compile_counters() -> tuple:
     was installed: backend compiles plus cache loads, and the seconds
     spent tracing, lowering, compiling and loading."""
     return _COMPILES["n"], _COMPILES["s"]
+
+
+def compile_stages(since: float = 0.0) -> list:
+    """(start, end, stage, fun_name) of the stages (tracing, lowering,
+    backend compile or cache load), on any thread, that ended at or
+    after `since` (epoch seconds), oldest first; the last 256 are
+    kept."""
+    return [st for st in list(_RECENT_STAGES) if st[1] >= since]
 
 
 def device_facts() -> dict:

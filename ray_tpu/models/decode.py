@@ -340,17 +340,24 @@ def paged_read_stack(cache: Dict, page_ids) -> Any:
     return paged_read_pages(cache, ids)
 
 
-def paged_read_pages_host(cache: Dict, page_ids) -> Tuple[Any, Any]:
+def paged_read_pages_host(cache: Dict, page_ids, before_dispatch=None
+                          ) -> Tuple[Any, Any]:
     """The gather of `page_ids` (any count: ceil(n / paged_read_batch)
     stacks) + the host landing, BLOCKING: page-major numpy K and V
     stacks [n, L, page_size, Hkv, Dh] for a caller that needs the bytes
     now (migration export).  The same compiled program and the same
     bytes as the tier demotion's landing, which does not wait (the
     engine hands each dispatched stack to its lander thread), so what a
-    tier holds can never diverge from what the wire ships."""
+    tier holds can never diverge from what the wire ships.
+    `before_dispatch`, where given, is called before each stack leaves
+    (the engine's capture log marks its dispatches with it)."""
     size = paged_read_batch(cache)
     parts = [page_ids[lo:lo + size] for lo in range(0, len(page_ids), size)]
-    stacks = [paged_read_stack(cache, part) for part in parts]
+    stacks = []
+    for part in parts:
+        if before_dispatch:
+            before_dispatch()
+        stacks.append(paged_read_stack(cache, part))
     kv = np.concatenate([np.asarray(stack)[:len(part)]
                          for stack, part in zip(stacks, parts)])
     return kv[:, 0], kv[:, 1]

@@ -263,9 +263,14 @@ class LLMServer:
     def trace_spans(self, prefix: str = "engine.") -> List[Dict]:
         """Spans from THIS replica process's trace ring (the bench's
         TTFT-attribution probe: engine.queue / engine.prefill /
-        engine.first_tick live here, not in the client process)."""
-        from ray_tpu._private import tracing as _tracing
+        engine.first_tick live here, not in the client process), and
+        behind them the engine's capture log of the last profiler
+        capture (`tpu_profiler.start()` / `stop()`, or running now):
+        `engine.phase.<name>`, `engine.dispatch`, `engine.compile`,
+        `engine.capture_log` (GenerationEngine.capture_events), in the
+        ring's own form."""
         return [e for e in _tracing.ring().snapshot(clear=False)
+                + self.engine.capture_events()
                 if str(e.get("name", "")).startswith(prefix)]
 
     def check_health(self):

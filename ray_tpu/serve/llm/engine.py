@@ -67,6 +67,7 @@ import contextlib
 import dataclasses
 import functools
 import logging
+import os
 import threading
 import time
 import uuid
@@ -351,6 +352,15 @@ LOOP_PHASES = ("idle", "commands", "sweep", "admit", "prefill_dispatch",
                "tick_dispatch", "device_wait", "emit")
 # ...and each but `idle` is a region of that name in a profiler capture.
 _PHASE_ANNOTATION = {p: "engine." + p for p in LOOP_PHASES if p != "idle"}
+# Is a profiler capture running?  (The flag tpu_profiler.annotate
+# checks.)  The capture is the switch of the loop's capture log: while
+# one runs, every phase the loop closes and a mark before every program
+# it hands to the device are kept (`GenerationEngine.capture_events`),
+# so each idle gap of the chip in the capture can be put down to what
+# the host was doing.  Room for 10 s of the fastest loop so far (~165
+# turns/s x (8 phases + 3 marks)); what does not fit is counted.
+_capturing = jax.profiler.TraceAnnotation.is_enabled
+_CAPTURE_LOG_ROOM = 65536
 
 # A loop that reads one turn late keeps the chip busy without a break,
 # and the benchmark cannot read that: its device capture runs 0.05-0.15 s
@@ -978,6 +988,16 @@ class GenerationEngine:
         self._phase_name = "idle"
         self._phase_t: Optional[float] = None   # None: loop not running
         self._phase_ann = None        # the open profiler annotation
+        # The capture log (worker thread writes; capture_events()
+        # reads): filled only while a profiler capture runs, outside
+        # the ring, on the monotonic clock.
+        self._cap_on = False          # a capture ran at the last switch
+        self._cap_open = ("idle", 0.0)   # the phase open in the log
+        self._cap_tid = 0
+        self._cap_phases: List = []   # (name, start, end, turn)
+        self._cap_marks: List = []    # (program, time)
+        self._cap_dropped = 0
+        self._cap_clock = (0.0, 0.0)  # one (monotonic, epoch) pair
         self._turns = 0
         self._turns_with_chunk = 0
         self._turns_ahead = 0
@@ -1284,8 +1304,9 @@ class GenerationEngine:
             self._alloc.incref(p)
         try:
             if pool_pages:
-                k0, v0 = decode.paged_read_pages_host(self._cache,
-                                                      pool_pages)
+                k0, v0 = decode.paged_read_pages_host(
+                    self._cache, pool_pages, before_dispatch=functools.partial(
+                        self._mark, "paged_read_pages"))
             k = np.empty((len(usable),) + self._page_kshape,
                          self._page_dtype)
             v = np.empty_like(k)
@@ -1547,6 +1568,7 @@ class GenerationEngine:
             part = nodes[lo:lo + size]
             self._land_wait(lander.wait_room, size * self._page_nbytes,
                             self._inflight_cap)
+            self._mark("paged_read_pages")
             stack = decode.paged_read_stack(
                 self._cache, [n.page for n in part])
             entries = []
@@ -2015,21 +2037,121 @@ class GenerationEngine:
         interrupts a phase and resumes it.  The elapsed time goes to
         the closed phase's counter, so the phases partition the
         thread's time by construction; no ring event — stats() carries
-        the counters.  Each phase but `idle` is also a region
-        `engine.<name>` in a profiler capture (tpu_profiler.annotate):
-        with no capture running that is one flag check."""
+        the counters.  While a profiler capture runs (one flag check a
+        switch, and nothing else without one) each phase but `idle` is
+        also a region `engine.<name>` in the capture's host plane, and
+        every phase closed goes to the capture log."""
         now = time.monotonic()
         prev = self._phase_name
-        self._loop_s[prev] += now - self._phase_t
+        t0 = self._phase_t
+        self._loop_s[prev] += now - t0
         self._phase_t = now
         if name != prev:
-            if self._phase_ann is not None:
-                self._phase_ann.__exit__(None, None, None)
-            region = _PHASE_ANNOTATION.get(name)
-            self._phase_ann = region and \
-                _tpu_profiler.annotate(region).__enter__()
+            on = _capturing()
+            if on or self._cap_on:
+                self._log_phase(on, prev, t0, now, name)
+                if self._phase_ann is not None:
+                    self._phase_ann.__exit__(None, None, None)
+                    self._phase_ann = None
+                region = on and _PHASE_ANNOTATION.get(name)
+                if region:
+                    self._phase_ann = \
+                        _tpu_profiler.annotate(region).__enter__()
             self._phase_name = name
         return prev
+
+    def _log_phase(self, on: bool, prev: str, t0: float, now: float,
+                   name: str) -> None:
+        """The capture log's side of a switch from `prev` to `name` at
+        `now` (worker thread; a capture runs, or ran at the last
+        switch).  First seen up: a NEW capture, so the log is cleared,
+        the one clock pair is taken and the phase that was open counts
+        from its own start.  Seen down: the phase that was open is
+        closed here like any other, and the log rests until the next
+        capture."""
+        if not self._cap_on:
+            self._cap_on = True
+            self._cap_phases, self._cap_marks = [], []
+            self._cap_dropped = 0
+            self._cap_clock = (time.monotonic(), time.time())
+            self._cap_tid = threading.get_ident() & 0xFFFF
+            self._cap_open = (prev, t0)
+        self._log_keep(self._cap_phases,
+                       (prev, self._cap_open[1], now, self._turns))
+        self._cap_open = (name, now)
+        self._cap_on = on
+
+    def _log_keep(self, entries: List, entry) -> None:
+        """An entry of the capture log, or a count of it where the log
+        is full."""
+        if len(self._cap_phases) + len(self._cap_marks) \
+                < _CAPTURE_LOG_ROOM:
+            entries.append(entry)
+        else:
+            self._cap_dropped += 1
+
+    def _mark(self, program: str) -> None:
+        """A dispatch mark, kept while a capture runs: the loop is about
+        to hand `program` (the name XLA gives it, less `jit_`) to the
+        device.  Called immediately before the call."""
+        if self._cap_on:
+            self._log_keep(self._cap_marks, (program, time.monotonic()))
+
+    def capture_events(self) -> List[Dict]:
+        """The capture log of the last profiler capture (of the running
+        one, up to now), as ring-shaped events in epoch us: an
+        `engine.phase.<name>` complete event a phase closed (`turn` in
+        its arguments), an `engine.dispatch` instant a program handed
+        to the device (`program`), an `engine.compile` complete event a
+        stage of making a program runnable (tracing, lowering, backend
+        compile or cache load: `stage`, `fun_name`; any thread's, from
+        the compile listener's own books, on its own epoch clock) that
+        ended inside the log, and one `engine.capture_log` that
+        counts them, counts what found no room (`dropped`) and carries
+        the clock pair every time was mapped with.  Empty where no
+        capture has run.  Any thread: the lists are copied, the loop's
+        fields are not touched, and the phase still open is reported up
+        to now (as stats() does for the counters)."""
+        for _ in range(8):            # a copy no switch ran through
+            opened = self._cap_open
+            phases, marks = list(self._cap_phases), list(self._cap_marks)
+            on, dropped = self._cap_on, self._cap_dropped
+            mono0, epoch0 = self._cap_clock
+            if self._cap_open is opened:
+                break
+        if not phases and not on:
+            return []
+        now = time.monotonic()
+        if on and (not phases or phases[-1][2] <= opened[1] < now):
+            phases.append((opened[0], opened[1], now, self._turns))
+        pid, tid = os.getpid(), self._cap_tid
+
+        def us(t):
+            return (epoch0 + (t - mono0)) * 1e6
+
+        t_first, t_last = us(phases[0][1]), us(phases[-1][2])
+        out = [{"cat": "engine", "name": "engine.phase." + n, "ph": "X",
+                "pid": pid, "tid": tid, "ts": us(a), "dur": (b - a) * 1e6,
+                "args": {"turn": turn}} for n, a, b, turn in phases]
+        out += [{"cat": "engine", "name": "engine.dispatch", "ph": "i",
+                 "s": "t", "pid": pid, "tid": tid, "ts": us(t),
+                 "args": {"program": prog}} for prog, t in marks]
+        out += [{"cat": "engine", "name": "engine.compile", "ph": "X",
+                 "pid": pid, "tid": tid, "ts": a * 1e6,
+                 "dur": (b - a) * 1e6,
+                 "args": {"stage": stage, "fun_name": fun}}
+                for a, b, stage, fun in _jax_utils.compile_stages(
+                    t_first / 1e6) if a * 1e6 <= t_last]
+        out.append({"cat": "engine", "name": "engine.capture_log",
+                    "ph": "i", "s": "t", "pid": pid, "tid": tid,
+                    "ts": t_first,
+                    "args": {"phases": len(phases),
+                             "dispatches": len(marks),
+                             "dropped": dropped,
+                             "open": bool(on),
+                             "clock_monotonic_s": mono0,
+                             "clock_epoch_s": epoch0}})
+        return out
 
     def _warm_kernels(self):
         """Compile the fused tick kernels at worker startup, against the
@@ -2102,7 +2224,14 @@ class GenerationEngine:
         otherwise: the dense models' program is the one it always was."""
         if not self._body.chunk_takes_row:
             return {}
-        return {"slot": jnp.int32(slot), "valid": jnp.int32(valid)}
+        return {"slot": self._scalar(slot), "valid": self._scalar(valid)}
+
+    def _scalar(self, value: int):
+        """An int32 scalar of a chunk's call on the device: an eager
+        `convert_element_type`, the first program a chunk's turn hands
+        over, so where the chip has run dry it is what ends the gap."""
+        self._mark("convert_element_type")
+        return jnp.int32(value)
 
     def _has_work_locked(self) -> bool:
         return (self._scheduler.depth > 0 or self._prefill is not None
@@ -2300,10 +2429,13 @@ class GenerationEngine:
         real = req.prompt[start:start + width]
         chunk = np.zeros((1, width), np.int32)
         chunk[0, :len(real)] = real
+        tokens, table = jnp.asarray(chunk), jnp.asarray(st.bt_row[None, :])
+        pos, row_args = self._scalar(start), self._row_args(st.slot,
+                                                            len(real))
+        self._mark("_prefill_chunk")
         logits, self._cache = _prefill_chunk(
-            self.params, jnp.asarray(chunk), jnp.int32(start),
-            self._cache, jnp.asarray(st.bt_row[None, :]), self.cfg,
-            **self._row_args(st.slot, len(real)))
+            self.params, tokens, pos, self._cache, table, self.cfg,
+            **row_args)
         self._launched()
         st.next_start = start + width
         st.chunks += 1
@@ -2514,11 +2646,15 @@ class GenerationEngine:
             if not flying.issuperset(rows):   # rows that joined since
                 take_host = np.ones((self.num_slots,), bool)
                 take_host[list(flying)] = False
-                tok = _merge_tokens(tok, jnp.asarray(self._tok.copy()),
-                                    jnp.asarray(take_host))
+                host = (jnp.asarray(self._tok.copy()),
+                        jnp.asarray(take_host))
+                self._mark("_merge_tokens")
+                tok = _merge_tokens(tok, *host)
+        pos = jnp.asarray(self._pos.copy())
+        tables = jnp.asarray(self._block_tables.copy())
+        self._mark("_paged_tick")
         sampled, logits, self._cache = _paged_tick(
-            self.params, tok, jnp.asarray(self._pos.copy()), self._cache,
-            jnp.asarray(self._block_tables.copy()), self.cfg,
+            self.params, tok, pos, self._cache, tables, self.cfg,
             with_logits=bool(sample_rows))
         self._launched()
         sampled.copy_to_host_async()
@@ -2648,14 +2784,15 @@ class GenerationEngine:
         """Hand one `_paged_block_step` to the device, of the engine's
         state as it stands (host arrays as COPIES: the loop writes to
         them again while the step is in flight)."""
+        host = (jnp.asarray(self._blk_host_tok.copy()),
+                jnp.asarray(self._blk_host_masked.copy()),
+                jnp.asarray(self._blk_take.copy()),
+                jnp.asarray(self._pos.copy()))
+        tables = jnp.asarray(self._block_tables.copy())
+        self._mark("_paged_block_step")
         tokens, masked, _, self._cache = _paged_block_step(
-            self.params, *self._blk_dev,
-            jnp.asarray(self._blk_host_tok.copy()),
-            jnp.asarray(self._blk_host_masked.copy()),
-            jnp.asarray(self._blk_take.copy()),
-            jnp.asarray(self._pos.copy()), self._cache,
-            jnp.asarray(self._block_tables.copy()), self.cfg,
-            with_logits=False)
+            self.params, *self._blk_dev, *host, self._cache, tables,
+            self.cfg, with_logits=False)
         self._blk_dev = (tokens, masked)
         self._blk_take[:] = False
         return tokens
@@ -2776,9 +2913,11 @@ class GenerationEngine:
             chunk[s, 1:1 + len(d)] = d
         sample_rows = [s for s in actives
                        if self._slots[s].temperature > 0]
+        chunk, pos = jnp.asarray(chunk), jnp.asarray(self._pos)
+        tables = jnp.asarray(self._block_tables)
+        self._mark("_paged_verify")
         preds, logits0, self._cache = _paged_verify(
-            self.params, jnp.asarray(chunk), jnp.asarray(self._pos),
-            self._cache, jnp.asarray(self._block_tables), self.cfg,
+            self.params, chunk, pos, self._cache, tables, self.cfg,
             with_logits=bool(sample_rows))
         self._count_keys(actives, 1 + k)
         self._phase("device_wait")

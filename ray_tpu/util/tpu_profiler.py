@@ -16,14 +16,19 @@ profile is one directory tree.
     tpu_profiler.start(); ...; path = tpu_profiler.stop()
 
 A capture of a serving replica also holds the engine loop's phases:
-`serve.llm`'s worker thread wraps each phase of a loop turn in
-`annotate("engine.<phase>")`, so the host plane carries
+while one runs, `serve.llm`'s worker thread wraps each phase of a loop
+turn in `annotate("engine.<phase>")`, so the host plane carries
 `engine.commands`, `engine.sweep`, `engine.admit`,
 `engine.prefill_dispatch`, `engine.tick_dispatch`, `engine.device_wait`
 and `engine.emit` on the profiler's own clock, beside the device plane
-(the idle wait is the absence of all seven).
-`benchmarks/tools/host_gaps.py <file.xplane.pb>` names every idle gap
-of the chip by the phase the host was in.
+(the idle wait is the absence of all seven), and keeps a capture log of
+its own: every phase it closes, `idle` too, and a mark before every
+program it hands to the device.  Read the log after `stop()` with
+`LLMServer.trace_spans()` (`engine.phase.<name>`, `engine.dispatch`,
+`engine.capture_log`); `benchmarks/readers/idle_by_phase.py` joins it
+to the device plane's idle gaps, and
+`benchmarks/tools/host_gaps.py <file.xplane.pb>` splits them by the
+host plane's regions alone.
 """
 
 from __future__ import annotations

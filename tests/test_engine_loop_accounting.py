@@ -47,6 +47,15 @@ def _loop_s(stats):
     return sum(getattr(stats, f"loop_s_{p}") for p in LOOP_PHASES)
 
 
+def _engine_config():
+    """The config object the ENGINE reads (bound when its module was
+    imported): `config.GLOBAL_CONFIG` is rebound by every
+    `rt.init(_system_config=...)`, so a test that imports the name after
+    one in its worker would patch an object the engine never looks at."""
+    from ray_tpu.serve.llm import engine as engine_mod
+    return engine_mod._cfg
+
+
 def _engine_spans(name):
     return [e for e in tracing.ring().snapshot() if e.get("name") == name]
 
@@ -192,7 +201,7 @@ def _wait(cond, timeout=60.0):
 
 def test_stalled_gaps_rise_only_in_the_turn_of_a_sweep_that_moved_pages(
         monkeypatch):
-    from ray_tpu._private.config import GLOBAL_CONFIG as cfg
+    cfg = _engine_config()
     monkeypatch.setattr(cfg, "serve_kv_demote_idle_s", 0.0)
     monkeypatch.setattr(cfg, "serve_kv_tier_sweep_s", 3600.0)
     with _engine("acct-stall", kv_tiering=True, max_seq=400,
@@ -235,7 +244,7 @@ def test_stalled_gaps_rise_only_in_the_turn_of_a_sweep_that_moved_pages(
 
 
 def test_tier_sweep_span_matches_the_counters(monkeypatch):
-    from ray_tpu._private.config import GLOBAL_CONFIG as cfg
+    cfg = _engine_config()
     monkeypatch.setattr(cfg, "serve_kv_demote_idle_s", 0.0)
     monkeypatch.setattr(cfg, "serve_kv_tier_sweep_s", 3600.0)
     with _engine("acct-span", kv_tiering=True) as eng:

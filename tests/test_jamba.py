@@ -665,7 +665,9 @@ def test_the_new_cells_files_load_through_the_registry():
     assert {"row_state_gb.tput", "state_resets_per_s.tput",
             "paged_tick_roofline.tput", "prefill_chunk_roofline.tput",
             "hbm_filled_gb.tput", "replica_start_s", "start_warm_s"} <= names
-    assert len(names) == 28
+    # (28 when the cell came, PR 48; PR 61 appended it to
+    # `idle_host_work_share.tput`)
+    assert len(names) == 29
     assert {m["name"] for m in reg.metrics_for(
         "jamba2-chat", "end_to_end")} == {"out_tok_per_s", "setup_s"}
     for name in names:

@@ -180,16 +180,28 @@ def test_record_disabled_is_noop(monkeypatch):
 def test_min_dur_gate_keeps_linked_spans(monkeypatch):
     """The noise gate drops only UNLINKED blips — dropping a span that
     carries trace linkage would hole the request tree."""
-    from ray_tpu._private.config import GLOBAL_CONFIG as cfg
+    # The gate reads the config object `tracing` holds: the one that was
+    # `GLOBAL_CONFIG` when the module was imported.  An `rt.init(
+    # _system_config=...)` in an earlier test of this worker REBINDS
+    # `config.GLOBAL_CONFIG` (config.apply_system_config), so importing
+    # the name here patches an object the gate never looks at: what made
+    # this test red under six workers and green alone.
     ring = rt_tracing.TraceRing(capacity=64)
     monkeypatch.setattr(rt_tracing, "_RING", ring)
-    monkeypatch.setattr(cfg, "trace_min_dur_us", 1000.0)
+    monkeypatch.setattr(rt_tracing.cfg, "trace_min_dur_us", 1000.0)
+
+    def mine():
+        # (by name: a thread an earlier test left behind may record
+        # into the swapped-in module global too)
+        return [e["name"] for e in ring.snapshot()
+                if e.get("name") in ("blip", "linked")]
+
     rt_tracing.record("task", "blip", time.time(), 0.0001)
-    assert len(ring) == 0
+    assert mine() == []
     rt_tracing.record("task", "linked", time.time(), 0.0001,
                       trace={"trace_id": "t", "span_id": "s",
                              "parent_id": None})
-    assert len(ring) == 1
+    assert mine() == ["linked"]
 
 
 def test_drop_counter_exported_to_prometheus(monkeypatch):
