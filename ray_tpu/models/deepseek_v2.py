@@ -22,7 +22,7 @@ The cache (one pytree, `engine._cache`):
                             whole pool twice a tick (0.5 GB each, by its
                             own account for a v5e); one array is also one
                             gather a span, not two
-  moe   [6, 2] int32                 the expert layers' own counters,
+  moe   [7, 2] int32                 the expert layers' own counters,
                                      cumulative (COUNTERS; two words
                                      each, so they do not wrap)
 
@@ -39,14 +39,18 @@ are chosen among all of them, and the layer computes
 `shared(x) + sum over (top-k AND held) of w_i expert_i(x)`.  What the
 absent experts would add is left out; nothing stands in for them.
 Routed pairs are sorted by expert, the held ones first, and those run
-through one grouped matmul a projection (Pallas `megablox.gmm`) a slab
-of as many pairs as the call has tokens: the layer's work follows the
-pairs held here, not the pairs routed, and an expert no token chose is
-not read.  `routed_experts`,
+through one grouped matmul a projection (Pallas `megablox.gmm`) a slab:
+where a share of the experts is held a slab is as many pairs as the call
+has tokens, where all are held it is the whole call, in line.  The
+layer's work follows the pairs held here, not the pairs routed; an
+expert no token chose is not read, and one that is chosen is read once a
+slab, in blocks of a whole contraction (`_tiles`).  `routed_experts`,
 `count_routed` and the counters have a second caller, models/exaone_moe.py
 (a sigmoid router of its own over the same grouped matmul): they read
-`cfg.top_k`, `cfg.experts_held`, `cfg.expert_offset` and nothing else
-(it also borrows `_span_pages` and `_merge`, which read no config).
+`cfg.top_k`, `cfg.experts_held`, `cfg.n_routed_experts`,
+`cfg.expert_offset` and, for the counter of reads, `cfg.d_model`,
+`cfg.moe_d_ff` and `cfg.dtype`, and nothing else (it also borrows
+`_span_pages` and `_merge`, which read no config).
 
 Departures from the published modeling file, all relabellings under
 seeded weights: RoPE pairs are (i, i + d/2), not interleaved; kv_b_proj
@@ -85,14 +89,20 @@ _HI = lax.Precision.HIGHEST
 # 128 / 256 / 512 keys.
 _TICK_SPAN_KEYS = 512
 _CHUNK_SPAN_KEYS = 128
-# The grouped matmul's tiles: rows of routed pairs (a tick of 64 rows
-# reads the same at 32, 64 and 128), and the most of the contraction
-# and of the output a grid step holds.
+# The grouped matmul's tiles.  Rows of routed pairs a visit works on (a
+# tick of 64 rows reads the same at 32, 64 and 128), and the most bytes
+# of one expert's weights a grid step holds: the tile is the WHOLE
+# contraction wherever 128 columns of it fit those bytes, then as many
+# columns as do (`_tiles`), so an expert's block is fetched once however
+# many row tiles its group lies across, in as few grid steps as the
+# kernel's fast memory takes double-buffered beside its rows and its
+# float32 accumulator (tests/test_tpu_compile.py holds every
+# configuration's widest tile to that).
 _GMM_ROWS = 128
-_GMM_K, _GMM_N = 1024, 512
+_GMM_TILE_BYTES = 4 << 20
 
 COUNTERS = ("pairs_routed", "pairs_local", "experts_touched",
-            "experts_held", "load_max", "pairs_worked")
+            "experts_held", "load_max", "pairs_worked", "expert_reads")
 _WORD = 30      # a counter is [hi, lo] with lo < 2**30
 
 
@@ -312,7 +322,7 @@ def init_paged_cache(cfg: DeepseekV2Config, num_pages: int, page_size: int,
 
 def read_counters(cache: Dict, cfg: DeepseekV2Config) -> Dict[str, Any]:
     """The expert layers' cumulative counters as the engine's stats
-    carry them (`moe_<name>`; a host copy of 48 bytes; only the thread
+    carry them (`moe_<name>`; a host copy of 56 bytes; only the thread
     that owns the cache may call it: every step donates the cache; or
     of a `snapshot_counters` of it, at any time).
     `pairs_routed`: tokens x top_k x expert layers, over ticks' live rows
@@ -323,7 +333,12 @@ def read_counters(cache: Dict, cfg: DeepseekV2Config) -> Dict[str, Any]:
     the mean over the held beside it (= pairs_local / experts held);
     `pairs_worked`: the rows `routed_experts`' slabs covered (trips x
     slab), summed as `pairs_local` is: their ratio is what the layer
-    worked on for each pair it had to."""
+    worked on for each pair it had to; `expert_reads`: the times one
+    projection's walk fetched an expert's weights from the device's
+    memory (`_reads`: once a slab the expert has rows in, where a grid
+    step holds the whole contraction), per tick and expert layer as
+    `experts_touched` is: their ratio is 1 where every touched expert
+    crossed the memory once."""
     words = np.asarray(cache["moe"]).astype(np.int64)
     counts = {name: int((hi << _WORD) + lo)
               for name, (hi, lo) in zip(COUNTERS, words)}
@@ -386,24 +401,36 @@ def _tile(width: int, most: int) -> int:
     return width
 
 
+def _tiles(K: int, N: int, itemsize: int) -> Tuple[int, int]:
+    """(contraction, columns) of the weight block one grid step of the
+    grouped matmul holds for experts of [K, N]: the largest under
+    `_GMM_TILE_BYTES`, the contraction before the columns."""
+    tk = _tile(K, max(128, _GMM_TILE_BYTES // (128 * itemsize)))
+    return tk, _tile(N, max(128, _GMM_TILE_BYTES // (tk * itemsize)))
+
+
 def _grouped(rows, w, sizes, out_dtype, tm):
     """rows [M, K] sorted by group, w [G, K, N], sizes [G] -> [M, N]:
     row r of group g times w[g].  Rows past the last group are not
     visited and come back undefined."""
     return gmm(rows, w, sizes, preferred_element_type=out_dtype,
-               tiling=(tm, _tile(w.shape[1], _GMM_K),
-                       _tile(w.shape[2], _GMM_N)),
+               tiling=(tm,) + _tiles(w.shape[1], w.shape[2],
+                                     w.dtype.itemsize),
                interpret=not _on_tpu())
 
 
-def _slab(N: int, k: int) -> Tuple[int, int, int]:
+def _slab(N: int, k: int, cfg) -> Tuple[int, int, int]:
     """(the grouped matmul's row tile, the rows of one slab of
     `routed_experts`, the slabs N x k pairs can fill) for N tokens of k
-    choices each: a slab is as many pairs as the call has tokens, in
-    whole tiles, so the pairs of a call take at most k trips and as few
-    as the chip's share of the experts asks."""
+    choices each, in whole tiles.  Where every routed expert is held
+    here every pair is, and the slab is the call: one sort, one gather,
+    one grouped matmul a projection, each expert read once.  Where a
+    share is held a slab is as many pairs as the call has tokens, so the
+    pairs of a call take at most k trips and as few as the chip's share
+    of the experts asks."""
     tm = min(_GMM_ROWS, -(-N * k // 8) * 8)
-    S = -(-N // tm) * tm
+    rows = N * k if cfg.experts_held == cfg.n_routed_experts else N
+    S = -(-rows // tm) * tm
     return tm, S, -(-N * k // S)
 
 
@@ -425,17 +452,19 @@ def routed_experts(experts, h, ids, weights, live, cfg: DeepseekV2Config):
     and every held one runs.  The work follows the pairs held, not the
     pairs routed: they are walked in slabs of `_slab` rows, as many
     trips as they fill (`_trips`: none where nothing is held, one where
-    the call's pairs fit a slab), and a trip gathers
-    its rows, runs one grouped matmul a projection over its share of
-    each expert's group (an expert no token chose is not read) and adds
-    its weighted rows to their tokens.  No array of the call has a row
-    a routed pair.
+    the call's pairs fit a slab, which they do wherever every expert is
+    held), and a trip gathers its rows, runs one grouped matmul a
+    projection over its share of each expert's group (an expert no
+    token chose is not read, one whose group lies across row tiles is
+    read once: `_tiles`) and adds its weighted rows to their tokens.
+    Only a call that holds every expert has an array with a row a
+    routed pair.
     Returns ([N, D] float32, tokens on each held expert [experts_held])."""
     N, D = h.shape
     k, E = cfg.top_k, cfg.experts_held
     dt = h.dtype
     P = N * k
-    tm, S, most = _slab(N, k)
+    tm, S, most = _slab(N, k, cfg)
     Pp = most * S
     local = ids - cfg.expert_offset
     held = (local >= 0) & (local < E) & live[:, None]        # [N, k]
@@ -448,43 +477,74 @@ def routed_experts(experts, h, ids, weights, live, cfg: DeepseekV2Config):
                             ).astype(jnp.int32)              # [E]
     sizes = jnp.diff(ends, prepend=0)
     order = jnp.pad(keys & ((1 << bits) - 1), (0, Pp - P))
-    flat_w = weights.reshape(P)
     w_gate, w_up, w_down = (experts[n].astype(dt)
                             for n in ("w_gate", "w_up", "w_down"))
 
-    def trip(s, acc):
-        lo = s * S
+    def slab(lo):
+        """The S pairs from `lo` on, and their experts' outputs [S, D]
+        float32 (rows past the held pairs come back undefined)."""
         pair = lax.dynamic_slice(order, (lo,), (S,))
-        tok = pair // k
         # the groups, cut to the slab
         part = jnp.diff(jnp.clip(ends - lo, 0, S), prepend=0)
-        rows = h[tok]                                        # [S, D]
+        rows = h[pair // k]                                  # [S, D]
         mid = jax.nn.silu(_grouped(rows, w_gate, part, dt, tm)) \
             * _grouped(rows, w_up, part, dt, tm)
-        out = _grouped(mid, w_down, part, jnp.float32, tm)
-        # rows past the held pairs come back undefined: zeros
-        out = jnp.where((lo + jnp.arange(S) < ends[-1])[:, None],
-                        out * flat_w[pair][:, None], 0.0)
-        to = (tok[None, :] == jnp.arange(N)[:, None]).astype(jnp.float32)
+        return pair, _grouped(mid, w_down, part, jnp.float32, tm)
+
+    def trip(s, acc):
+        pair, out = slab(s * S)
+        out = jnp.where((s * S + jnp.arange(S) < ends[-1])[:, None],
+                        out * weights.reshape(P)[pair][:, None], 0.0)
+        to = ((pair // k)[None, :] == jnp.arange(N)[:, None]
+              ).astype(jnp.float32)
         return acc + jnp.einsum("ns,sd->nd", to, out, precision=_HI)
 
     none = jnp.zeros((N, D), jnp.float32)
-    if most == 1:
+    if most > 1:
+        return lax.fori_loop(0, _trips(ends[-1], S, most), trip, none), sizes
+    if k == 1:          # a token has one row: nothing to sum
         return trip(0, none), sizes
-    return lax.fori_loop(0, _trips(ends[-1], S, most), trip, none), sizes
+    # One slab of every pair: a token's k rows lie where the sort put
+    # them, so the sum back is each pair's rank (the sort's inverse) and
+    # a sum over k, not a [N, P] one-hot product (2.05 against 2.14 ms a
+    # layer at 512 tokens of top-8; PERF.md section 6, PR 62).
+    _, out = slab(0)
+    rank = jnp.argsort(order[:P])
+    return jnp.where(held[:, :, None], out[rank].reshape(N, k, D)
+                     * weights[:, :, None], 0.0).sum(1), sizes
 
 
 def count_routed(counts, live, sizes, is_tick: bool, cfg):
     """`counts` (a call's additions to COUNTERS so far) plus one expert
     layer's: `live` [N] the tokens routed, `sizes` [experts_held] the
     tokens on each held expert (routed_experts' second result), from
-    which the rows its slabs covered follow as its trips do."""
+    which the rows its slabs covered follow as its trips do, and the
+    times a projection's walk fetched an expert's weights (`_reads`)."""
     tick = jnp.int32(is_tick)
-    _, S, most = _slab(live.shape[0], cfg.top_k)
+    tm, S, most = _slab(live.shape[0], cfg.top_k, cfg)
+    touched = (sizes > 0).sum()
     return [c + a for c, a in zip(counts, (
-        live.sum() * cfg.top_k, sizes.sum(), tick * (sizes > 0).sum(),
+        live.sum() * cfg.top_k, sizes.sum(), tick * touched,
         tick * cfg.experts_held, sizes.max(),
-        _trips(sizes.sum(), S, most) * S))]
+        _trips(sizes.sum(), S, most) * S,
+        _reads(sizes, touched, tm, S, most, cfg) if is_tick else 0))]
+
+
+def _reads(sizes, touched, tm: int, S: int, most: int, cfg):
+    """How often the walk of one projection (gate's: [d_model, moe_d_ff]
+    an expert) fetches an expert's weights, as the grouped matmul's
+    schedule goes: within a slab it visits (expert, row tile) pairs in
+    order of expert.  Where a grid step holds the whole contraction a
+    visit that follows one of the same expert fetches nothing, so an
+    expert is read once a slab it has rows in; where it does not, every
+    visit reads the expert again."""
+    tk, _ = _tiles(cfg.d_model, cfg.moe_d_ff, jnp.dtype(cfg.dtype).itemsize)
+    unit = S if tk == cfg.d_model else tm
+    if most == 1 and unit == S:
+        return touched
+    ends = jnp.cumsum(sizes)
+    return jnp.where(sizes > 0,
+                     (ends - 1) // unit - (ends - sizes) // unit + 1, 0).sum()
 
 
 def _ffn(lp, x, live, is_tick, counts, cfg: DeepseekV2Config):

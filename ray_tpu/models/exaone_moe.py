@@ -24,7 +24,7 @@ state:
                                        rotated), so a row holds its last
                                        W = `window` tokens and nothing
                                        else, whatever its context
-  moe     [6, 2] int32                 the expert layers' counters
+  moe     [7, 2] int32                 the expert layers' counters
                                        (deepseek_v2.COUNTERS)
 
 A ring is state per decode row (`row_state_keys`): what treats a page as the

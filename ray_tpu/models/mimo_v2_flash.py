@@ -29,7 +29,7 @@ The cache (one pytree, `engine._cache`) is four arrays of four shapes:
                                     p mod W, keys already rotated)
                                     (a token's heads side by side:
                                     exaone_moe._kept says why)
-  moe [6, 2], sink [2, 2] int32     the expert layers' counters and the
+  moe [7, 2], sink [2, 2] int32     the expert layers' counters and the
                                     sinks' (the share of their softmaxes
                                     they took; the softmaxes counted)
 

@@ -13,7 +13,10 @@ seeded weights, the cache it declares and its own paged step, bound into
 a declared body (a decode.PagedBody whose `block` is the block length)
 that the config names.  Attention's projections, the span loops and the
 expert layer are models/exaone_moe.py's and models/deepseek_v2.py's: this
-file holds the mask, the router and the block step.
+file holds the mask, the router and the block step.  Every expert is
+held here, so every routed pair is: `deepseek_v2.routed_experts` runs a
+step's (and a chunk's) 4,096 pairs as one slab in line, one grouped
+matmul a projection, each chosen expert read once.
 
 The paged step has two shapes (decode.paged_chunk_step's contract):
 
@@ -45,7 +48,7 @@ checkpoints refuse it by name); whole prompt pages are final after
 prefill, so the prefix cache shares them as it does any model's.
 
 The cache (one pytree, `engine._cache`): k, v [L, P, page, G x Dh] (a
-token's four heads side by side: `kind` says why) and `moe` [6, 2] int32, the expert layers' counters (deepseek_v2.COUNTERS).
+token's four heads side by side: `kind` says why) and `moe` [7, 2] int32, the expert layers' counters (deepseek_v2.COUNTERS).
 """
 
 from __future__ import annotations

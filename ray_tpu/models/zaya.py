@@ -38,7 +38,7 @@ state per decode row.
   cc     [L, rows, 1280]         ...after the first convolution
   cv     [L, rows, 128]          the late value head as projected from
                                  the row's last token (the NEXT token's)
-  moe    [6, 2] int32            the expert layers' counters
+  moe    [7, 2] int32            the expert layers' counters
                                  (deepseek_v2.COUNTERS)
   gate   [2, 2] int32            the chosen experts' weights summed (in
                                  units of 2^-10) and the tokens counted

@@ -482,11 +482,15 @@ class EngineStats:
     # token / held experts, per tick and expert layer; the busiest held
     # expert's tokens and the mean over the held, per call and layer;
     # the rows the expert layers' slabs covered, summed as the pairs
-    # held are (worked / local: rows worked on for each pair held).
+    # held are (worked / local: rows worked on for each pair held); the
+    # times one projection's walk fetched an expert's weights, per tick
+    # and expert layer (reads / touched: 1 where every expert a tick
+    # touched crossed the device's memory once).
     # As of the last decode tick.  Zeros for a model without experts.
     moe_pairs_routed: int = 0
     moe_pairs_local: int = 0
     moe_pairs_worked: int = 0
+    moe_expert_reads: int = 0
     moe_experts_touched: int = 0
     moe_experts_held: int = 0
     moe_load_max: int = 0

@@ -286,6 +286,25 @@ def test_no_token_is_dropped_when_one_expert_takes_every_token(
     assert np.abs(np.asarray(out) - np.asarray(out)[0]).max() == 0
 
 
+def _experts_by_hand(ex, h, w, ids, live, held, offset=0):
+    """(the held experts' part of the layer [n_tok, D], tokens on each
+    held expert) by a masked loop over tokens in float32."""
+    want = np.zeros(h.shape, np.float32)
+    on = np.zeros((held,), np.int64)
+    h32, w32 = np.asarray(h, np.float32), np.asarray(w, np.float32)
+    gate, up, down = (np.asarray(ex[n], np.float32)
+                      for n in ("w_gate", "w_up", "w_down"))
+    for t in range(len(h32)):
+        for j in range(ids.shape[1]):
+            e = ids[t, j] - offset
+            if live[t] and 0 <= e < held:
+                a = h32[t] @ gate[e]
+                want[t] += w32[t, j] * (
+                    (a / (1 + np.exp(-a)) * (h32[t] @ up[e])) @ down[e])
+                on[e] += 1
+    return want, on
+
+
 def _held_pairs_case(case, n_tok, k, slab):
     """(expert ids [n_tok, k] with experts 4..7 held, live [n_tok]) for
     one case of the slab walk; ids are set by hand (a router would not
@@ -329,7 +348,7 @@ def test_the_experts_walk_the_held_pairs_in_slabs(model, monkeypatch, case,
     cfg, params = model
     monkeypatch.setattr(ds, "_GMM_ROWS", tile)
     n_tok, k = 16, cfg.top_k
-    tm, slab, most = ds._slab(n_tok, k)
+    tm, slab, most = ds._slab(n_tok, k, cfg)
     assert (tm, slab, most) == ((8, 16, 3) if tile == 8 else (48, 48, 1))
     # weights large enough that a missing or doubled pair shows
     ex = jax.tree_util.tree_map(lambda a: 16 * a,
@@ -343,19 +362,7 @@ def test_the_experts_walk_the_held_pairs_in_slabs(model, monkeypatch, case,
         lambda *a: ds.routed_experts(ex, *a, cfg))(
             h, jnp.asarray(ids), w, jnp.asarray(live))
 
-    want = np.zeros((n_tok, 32), np.float32)
-    on = np.zeros((4,), np.int64)
-    h32, w32 = np.asarray(h, np.float32), np.asarray(w, np.float32)
-    gate, up, down = (np.asarray(ex[n], np.float32)
-                      for n in ("w_gate", "w_up", "w_down"))
-    for t in range(n_tok):
-        for j in range(k):
-            e = ids[t, j] - cfg.expert_offset
-            if live[t] and 0 <= e < 4:
-                a = h32[t] @ gate[e]
-                want[t] += w32[t, j] * (
-                    (a / (1 + np.exp(-a)) * (h32[t] @ up[e])) @ down[e])
-                on[e] += 1
+    want, on = _experts_by_hand(ex, h, w, ids, live, 4, cfg.expert_offset)
     np.testing.assert_array_equal(np.asarray(sizes), on)
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-4)
     assert np.abs(np.asarray(out)[~live]).max(initial=0) == 0
@@ -371,6 +378,109 @@ def test_the_experts_walk_the_held_pairs_in_slabs(model, monkeypatch, case,
     assert int(counted["pairs_worked"]) == (trips * 16 if tile == 8 else 48)
     assert int(counted["pairs_local"]) == on.sum()
     assert int(counted["pairs_routed"]) == live.sum() * k
+    assert int(counted["expert_reads"]) == 0        # counted in ticks only
+    ticked = dict(zip(ds.COUNTERS, ds.count_routed(
+        [jnp.int32(0)] * len(ds.COUNTERS), jnp.asarray(live), sizes, True,
+        cfg)))
+    assert int(ticked["expert_reads"]) == _reads_by_hand(on, tm, slab, 1)
+    assert int(ticked["experts_touched"]) == (on > 0).sum()
+
+
+def _reads_by_hand(sizes, tm, slab, tiles_k):
+    """An expert's weight block's fetches in one projection's walk, as
+    the grouped matmul's schedule goes, on the host: slabs of `slab`
+    rows, in each the (expert, row tile) visits in order of expert; a
+    visit that follows one of the same expert re-uses the block it
+    holds where the block is the whole contraction (`tiles_k` 1)."""
+    ends = np.cumsum(sizes)
+    starts, n = ends - sizes, 0
+    for lo in range(0, int(ends[-1]), slab):
+        last = None
+        for g in range(len(sizes)):
+            a, b = max(starts[g], lo), min(ends[g], lo + slab)
+            for _ in range(a // tm, (b - 1) // tm + 1) if b > a else ():
+                n += tiles_k > 1 or last != g
+                last = g
+    return n
+
+
+def _walk_case(case, n_tok, k, n_all):
+    """(expert ids [n_tok, k] among `n_all`, live [n_tok]) by hand."""
+    rng = np.random.default_rng(len(case))
+    ids = np.stack([rng.permutation(n_all)[:k] for _ in range(n_tok)]
+                   ).astype(np.int32)
+    live = np.ones((n_tok,), bool)
+    if case == "every pair on one expert":
+        ids[:] = 5
+    elif case == "dead rows and a chunk's pad":
+        live[[0, 3, 4]] = False
+        live[n_tok - 5:] = False
+    elif case == "a group across three row tiles":
+        ids[:, 0] = 2                 # n_tok rows of expert 2, tile 16
+    return ids, live
+
+
+@pytest.mark.parametrize("held,d_model,budget", [
+    (32, 32, None), (8, 32, None), (32, 256, 1 << 16), (8, 256, 1 << 16)],
+    ids=["all held: one slab", "a share held: the walk",
+         "all held, the contraction in two tiles",
+         "a share held, the contraction in two tiles"])
+@pytest.mark.parametrize("case", [
+    "top-8 over many experts", "every pair on one expert",
+    "dead rows and a chunk's pad", "a group across three row tiles"])
+def test_the_walk_reads_a_held_expert_once_a_slab(monkeypatch, case, held,
+                                                  d_model, budget):
+    """`routed_experts` against the masked loop over tokens in float32
+    at top-8 of 32 experts, 40 tokens, a row tile of 16: where all 32
+    are held the 320 pairs are ONE slab of 20 tiles in line (groups of
+    ~10 rows lie across tiles; the sum back goes by the sort's
+    inverse), where 8 are held they are walked in slabs of 48 rows.
+    `expert_reads` is the kernel's schedule walked by hand: where a grid
+    step holds the whole contraction an expert is fetched once a slab it
+    has rows in (once a call in one slab), where it does not (the byte
+    budget cut to 128 of 256 rows) every visit fetches it again;
+    `pairs_worked` is trips x slab."""
+    monkeypatch.setattr(ds, "_GMM_ROWS", 16)
+    if budget:
+        monkeypatch.setattr(ds, "_GMM_TILE_BYTES", budget)
+    n_tok, k, n_all, F = 40, 8, 32, 16
+    cfg = ds.DeepseekV2Config(
+        max_seq=8, d_model=d_model, moe_d_ff=F, n_routed_experts=n_all,
+        n_group=1, topk_group=1, top_k=k, experts_held=held,
+        expert_offset=0, dtype=jnp.float32)
+    tiles_k = d_model // ds._tiles(d_model, F, 4)[0]
+    assert tiles_k == (2 if budget else 1)
+    tm, slab, most = ds._slab(n_tok, k, cfg)
+    assert (tm, slab, most) == ((16, 320, 1) if held == n_all
+                                else (16, 48, 7))
+    keys = jax.random.split(jax.random.PRNGKey(21), 5)
+    ex = {"w_gate": jax.random.normal(keys[0], (held, d_model, F)),
+          "w_up": jax.random.normal(keys[1], (held, d_model, F)),
+          "w_down": jax.random.normal(keys[2], (held, F, d_model))}
+    h = jax.random.normal(keys[3], (n_tok, d_model)) / np.sqrt(d_model)
+    w = jax.random.uniform(keys[4], (n_tok, k), minval=0.5, maxval=2.0)
+    ids, live = _walk_case(case, n_tok, k, n_all)
+
+    out, sizes = jax.jit(lambda *a: ds.routed_experts(ex, *a, cfg))(
+        h, jnp.asarray(ids), w, jnp.asarray(live))
+
+    want, on = _experts_by_hand(ex, h, w, ids, live, held)
+    np.testing.assert_array_equal(np.asarray(sizes), on)
+    assert np.abs(want).max() > 1
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-4)
+    assert np.abs(np.asarray(out)[~live]).max(initial=0) == 0
+    counted = dict(zip(ds.COUNTERS, ds.count_routed(
+        [jnp.int32(0)] * len(ds.COUNTERS), jnp.asarray(live), sizes, True,
+        cfg)))
+    trips = 1 if most == 1 else -(-int(on.sum()) // slab)
+    assert int(counted["pairs_worked"]) == trips * slab
+    assert int(counted["experts_touched"]) == (on > 0).sum()
+    reads = _reads_by_hand(on, tm, slab, tiles_k)
+    assert int(counted["expert_reads"]) == reads
+    if tiles_k == 1 and most == 1:
+        assert reads == (on > 0).sum()            # each once a call
+    if case == "a group across three row tiles" and tiles_k > 1:
+        assert reads > (on > 0).sum() + 1
 
 
 def test_the_shares_add_up_to_the_uncut_layer(model, reference):

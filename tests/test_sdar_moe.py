@@ -401,7 +401,49 @@ def test_the_engines_stream_is_the_familys_generation_token_for_token(
     ran = sum(PROMPTS) + B * (denoise + writes)
     assert gain["moe_pairs_routed"] == gain["moe_pairs_local"] \
         == ran * L * C["num_experts_per_tok"]
+    # ... so a call is one slab and reads each expert it touches once
+    assert gain["moe_expert_reads"] == gain["moe_experts_touched"] > 0
     assert gain["attn_keys_attended"] == gain["attn_keys_resident"] > 0
+
+
+def test_a_block_steps_expert_layer_is_one_slab_in_line(model):
+    """The expert layer at the block body's toy shape (3 rows x 4
+    columns, top-4 of 16, every expert held) against a masked loop over
+    tokens: the 48 pairs are one slab of `routed_experts` in line (no
+    walk), a dead row's columns get nothing and count nowhere, and the
+    layer's counters say each touched expert was read once."""
+    from ray_tpu.models import deepseek_v2 as ds
+    cfg, params = model
+    # weights large enough that a missing or doubled pair shows
+    lp = dict(params["layers"][1], experts=jax.tree_util.tree_map(
+        lambda a: 16 * a, params["layers"][1]["experts"]))
+    n, k = ROWS * B, cfg.top_k
+    assert ds._slab(n, k, cfg) == (48, 48, 1)
+    x = jax.random.normal(jax.random.PRNGKey(31), (n, D))
+    live = jnp.repeat(jnp.asarray([True, False, True]), B)
+    got, counts = sdar_moe._ffn(lp, x, live,
+                                [jnp.int32(0)] * len(ds.COUNTERS), cfg)
+    h = sdar_moe._em._rms(x, lp["ln2"], cfg)
+    ids, w = (np.asarray(a) for a in sdar_moe.route(lp["router"], h, cfg))
+    h32 = np.asarray(h, np.float32)
+    gate, up, down = (np.asarray(lp["experts"][m], np.float32)
+                      for m in ("w_gate", "w_up", "w_down"))
+    want, on = np.asarray(x, np.float32).copy(), np.zeros((E,), np.int64)
+    for t in np.flatnonzero(np.asarray(live)):
+        for e, wt in zip(ids[t], w[t]):
+            a = h32[t] @ gate[e]
+            want[t] += wt * ((a / (1 + np.exp(-a)) * (h32[t] @ up[e]))
+                             @ down[e])
+            on[e] += 1
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
+    assert np.abs(want - np.asarray(x)).max() > 1
+    np.testing.assert_array_equal(np.asarray(got)[B:2 * B],
+                                  np.asarray(x)[B:2 * B])
+    counts = dict(zip(ds.COUNTERS, (int(c) for c in counts)))
+    assert counts["pairs_routed"] == counts["pairs_local"] == 2 * B * k
+    assert counts["pairs_worked"] == 48
+    assert counts["expert_reads"] == counts["experts_touched"] \
+        == (on > 0).sum() > 1
 
 
 def test_a_shared_prefix_is_served_from_the_radix_cache_equal(model,

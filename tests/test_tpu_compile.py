@@ -867,11 +867,83 @@ def test_sdar_paged_step_compiles(chip, step, monkeypatch):
     shapes = [(kind, dims.split(",")) for kind, dims in
               re.findall(r" = (\w+)\[([\d,]+)\]", text)]
     # (the table's 64 x 64 keys are as many as the step's routed pairs,
-    # 512 x 8: the expert layer's flat pair ids are not the table)
-    wide = [s for s in shapes if str(blocks * e["page_size"]) in s[1]
-            and len(s[1]) > 1]
+    # 512 x 8, which the expert layer's one slab holds a row each:
+    # `[4096]` ids, `[4096, 2048]` and `[4096, 768]` rows are not the
+    # table, whose width would stand behind a dimension of rows)
+    assert rows * B * cfg.top_k == blocks * e["page_size"]
+    wide = [s for s in shapes if str(blocks * e["page_size"]) in s[1][1:]]
     assert not wide, wide[:4]
     # the logits are reduced as the head gives them, [rows x B, V]:
     # an array that ends in [B, V] would pad B to the tile's 8 rows
     cubes = [s for s in shapes if s[1][-2:] == [str(B), str(cfg.vocab_size)]]
     assert not cubes, cubes[:4]
+
+
+# The expert walk alone (deepseek_v2.routed_experts), at each of the five
+# expert configurations' two calls: the tile `_tiles` picks is what the
+# kernel's fast memory has to hold, so a width that outgrows it fails
+# here and not on the chip.
+@pytest.mark.parametrize("config", [
+    "deepseek-v2-ep4-d5", "k-exaone-ep8-d5", "mimo-v2-flash-ep16-d7",
+    "zaya1-8b-pp2-d20", "sdar-30b-a3b-pp8-d6"])
+def test_the_expert_walks_widest_tile_fits_fast_memory(chip, config,
+                                                       monkeypatch):
+    """`routed_experts` of one expert layer at the configuration's tick
+    (or block step) and chunk: every weight block a grid step holds is a
+    whole contraction of at most `_GMM_TILE_BYTES`, and two of them
+    beside two row tiles, two output tiles and the float32 accumulator
+    stay under the 16 MiB a kernel gets by default, which the chip's
+    compiler confirms by compiling both calls.  sdar's, which holds
+    every expert, is ONE slab of all 4,096 pairs (no loop in the call);
+    the four that hold a share walk slabs of as many pairs as tokens."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import deepseek_v2 as ds
+    monkeypatch.setattr(ds, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        c = json.load(f)
+    e = c["serving"]["engine"]
+    cfg = arch_of(c, bench).build(c, e["max_seq"], remat=False)
+    D, F, E, k = cfg.d_model, cfg.moe_d_ff, cfg.experts_held, cfg.top_k
+    size = jnp.dtype(cfg.dtype).itemsize
+    for K, N in ((D, F), (F, D)):
+        tk, tn = ds._tiles(K, N, size)
+        assert tk == K and N % tn == 0 and tk * tn * size \
+            <= ds._GMM_TILE_BYTES, (K, N, tk, tn)
+        rows = ds._GMM_ROWS
+        vmem = 2 * tk * tn * size + 2 * rows * tk * size \
+            + 2 * rows * tn * 4 + rows * tn * 4
+        assert vmem <= 16 << 20, (K, N, tk, tn, vmem)
+
+    def arr(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    experts = {"w_gate": arr(cfg.dtype, E, D, F),
+               "w_up": arr(cfg.dtype, E, D, F),
+               "w_down": arr(cfg.dtype, E, F, D)}
+    block = getattr(cfg, "block_length", 1)
+    for tokens in (e["num_slots"] * block, e["prefill_chunk"]):
+        tm, S, most = ds._slab(tokens, k, cfg)
+        if E == cfg.n_routed_experts:
+            assert most == 1 and S == -(-tokens * k // tm) * tm
+        else:
+            assert S == -(-tokens // tm) * tm and most > 1
+        text = jax.jit(lambda *a: ds.routed_experts(*a, cfg)).lower(
+            experts, arr(cfg.dtype, tokens, D), arr(jnp.int32, tokens, k),
+            arr(jnp.float32, tokens, k), arr(bool, tokens)
+        ).compile().as_text()
+        kernels = [ln for ln in text.splitlines()
+                   if "custom-call(" in ln and "tpu_custom_call" in ln]
+        assert len(kernels) == 3, len(kernels)
+        # (a `searchsorted` is a loop of its own: the sort's group ends,
+        # and the `repeat`s of the kernel's group metadata)
+        loops = [ln for ln in text.splitlines()
+                 if " while(" in ln and "searchsorted" not in ln]
+        if config == "sdar-30b-a3b-pp8-d6":
+            assert (S, tokens * k) == (4096, 4096) and not loops, loops[:2]
+        else:
+            assert bool(loops) == (most > 1)
