@@ -227,19 +227,21 @@ def attn_keys(cfg: DeepseekV2Config, pos: np.ndarray) -> Tuple[int, int]:
     return held, held
 
 
-def attn_keys_gathered(cfg: DeepseekV2Config, pos: np.ndarray,
-                       page_size: int, nblk: int) -> int:
+def attn_keys_gathered(cfg, pos: np.ndarray, page_size: int, nblk: int, *,
+                       layers: Optional[int] = None) -> int:
     """Latents one tick pulls from the pool, for EVERY row of the call
     (`pos` of all decode rows, idle ones at 0): on a TPU each row's own
     blocks of pages, what the kernel copies; elsewhere whole spans up to
     the deepest row's token, the trip count the span loop reads from
-    `pos`."""
+    `pos`.  In each of `layers` layers: all of them by default, the
+    latent ones of a model that mixes in others."""
+    layers = cfg.n_layers if layers is None else layers
     if _on_tpu():
         token = _lat_width(cfg) * jnp.dtype(cfg.dtype).itemsize
-        return _pa.keys_copied(pos, page_size, nblk, token) * cfg.n_layers
+        return _pa.keys_copied(pos, page_size, nblk, token) * layers
     cols = _span_pages(_TICK_SPAN_KEYS, page_size, nblk) * page_size
     spans = -(-(int(np.asarray(pos).max()) + 1) // cols)
-    return len(pos) * spans * cols * cfg.n_layers
+    return len(pos) * spans * cols * layers
 
 
 def check_paging(cfg: DeepseekV2Config, *, page_size: int,
@@ -299,12 +301,12 @@ def init_params(cfg: DeepseekV2Config, key, dtype=None) -> Dict:
             "ln_f": ones(D), "wlm": nrm((D, cfg.vocab_size), s)}
 
 
-def _lat_width(cfg: DeepseekV2Config) -> int:
+def _lat_width(cfg) -> int:
     """A cached row: latent + rotary key part, up to whole tiles."""
     return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
 
 
-def _lat_row(a, b, cfg: DeepseekV2Config):
+def _lat_row(a, b, cfg):
     """[..., 512] and [..., 64] side by side in a row of the cache's
     width (zeros after them): a token's latent and rotary key part, or a
     query's two parts against them."""
@@ -566,7 +568,13 @@ def _ffn(lp, x, live, is_tick, counts, cfg: DeepseekV2Config):
 
 # ---------------------------------------------------------------------------
 # Latent attention, for a single-row chunk of T tokens (x [T, D]) and
-# for a tick of B rows (x [B, D])
+# for a tick of B rows (x [B, D]).  A second model runs the same two
+# paths over the same cached row (models/bailing_hybrid.py: one full-rank
+# query projection, interleaved RoPE, a gate a head before `wo`): what
+# differs reaches `_attn_chunk` and `_attn_tick` as `project` (this
+# file's `_project` by default) and `gate` (none by default), and its
+# config answers `n_heads`, `kv_lora_rank`, `qk_rope_head_dim`,
+# `v_head_dim`, `softmax_scale` and `dtype` as this one does.
 
 
 def _project(lp, x, positions, cfg: DeepseekV2Config):
@@ -601,12 +609,16 @@ def _merge(part, scores, values_of):
             acc * keep[..., None] + values_of(e))
 
 
-def _attn_chunk(lp, x, l, cache, bt, start, cfg: DeepseekV2Config):
+def _attn_chunk(lp, x, l, cache, bt, start, cfg, project=None, gate=None):
+    """`project` (lp, x, positions, cfg) -> (q_nope, q_pe, the normed
+    latent, the rotated shared key part), `_project` by default; `gate`
+    (lp, x) -> a factor a token and head [T, H] on the heads' outputs
+    before `wo`, none by default."""
     T = x.shape[0]
     H, psz, kr = cfg.n_heads, cache["lat"].shape[2], cfg.kv_lora_rank
     dt = cfg.dtype
     cols = start + jnp.arange(T)
-    q_nope, q_pe, ckv, kpe = _project(lp, x, cols, cfg)
+    q_nope, q_pe, ckv, kpe = (project or _project)(lp, x, cols, cfg)
     pages = lax.dynamic_slice(bt, (start // psz,), (T // psz,))
     lat = cache["lat"].at[l, pages].set(
         _lat_row(ckv, kpe, cfg).reshape(T // psz, psz, -1))
@@ -643,14 +655,17 @@ def _attn_chunk(lp, x, l, cache, bt, start, cfg: DeepseekV2Config):
             (stat, jnp.zeros_like(stat),
              jnp.zeros((H, T, cfg.v_head_dim), jnp.float32)))
         out = (acc / total[..., None]).astype(dt)             # [H, T, dv]
+    if gate is not None:
+        out = out * gate(lp, x).T[:, :, None].astype(dt)
     x = x + jnp.einsum("htv,hvd->td", out, lp["wo"].astype(dt))
     return x, dict(cache, lat=lat)
 
 
-def _attn_tick(lp, x, l, cache, bt, pos, cfg: DeepseekV2Config):
+def _attn_tick(lp, x, l, cache, bt, pos, cfg, project=None, gate=None):
+    """`project` and `gate` as `_attn_chunk` takes them."""
     psz, kr = cache["lat"].shape[2], cfg.kv_lora_rank
     dt = cfg.dtype
-    q_nope, q_pe, ckv, kpe = _project(lp, x, pos, cfg)
+    q_nope, q_pe, ckv, kpe = (project or _project)(lp, x, pos, cfg)
     page = jnp.take_along_axis(bt, (pos // psz)[:, None], axis=1)[:, 0]
     lat = cache["lat"].at[l, page, pos % psz].set(_lat_row(ckv, kpe, cfg))
 
@@ -666,11 +681,13 @@ def _attn_tick(lp, x, l, cache, bt, pos, cfg: DeepseekV2Config):
         else:
             o_lat = _span_tick(q_row, lat, l, bt, pos, cfg)
         out = jnp.einsum("bhc,hcv->bhv", o_lat, lp["wv_b"].astype(dt))
+    if gate is not None:
+        out = out * gate(lp, x)[:, :, None].astype(dt)
     x = x + jnp.einsum("bhv,hvd->bd", out, lp["wo"].astype(dt))
     return x, dict(cache, lat=lat)
 
 
-def _span_tick(q_row, lat, l, bt, pos, cfg: DeepseekV2Config):
+def _span_tick(q_row, lat, l, bt, pos, cfg):
     """The tick's attention over the latent pages of layer `l` where
     there is no TPU, and what the kernel is held equal to: a loop whose
     trip count is the DEEPEST row's depth gathers, for every row of the

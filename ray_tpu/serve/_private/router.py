@@ -185,6 +185,7 @@ class ReplicaSet:
         self._suppress_ttl = float(
             env("RT_SERVE_REPLICA_SUPPRESS_S", "10"))
         self._suppressed: Dict[str, float] = {}
+        self._late_cancels: set = set()   # _cancel_once_started's tasks
         # KV pull addresses this router has OBSERVED in the membership
         # broadcast: current members, plus recently-departed ones kept
         # for a grace window (a dead replica leaves the broadcast
@@ -474,6 +475,25 @@ class ReplicaSet:
                 None, f"stream RPC gave no reply within "
                       f"{self._stream_poll_timeout}s") from None
 
+    def _cancel_once_started(self, actor, start) -> None:
+        """A stream whose consumer left while its start RPC was in
+        flight: the reply that names the stream arrives after anyone
+        waits for it, and a replica that never hears of the close runs
+        the generator to its end for nobody (an engine request that
+        holds a decode slot and its pages).  Wait for the reply off to
+        the side and cancel the stream by the id it brings."""
+        async def _cancel():
+            try:
+                started = await self._stream_rpc(start)
+            except Exception:
+                return      # never started, or its replica is gone
+            if "stream_id" in started:
+                actor.stream_cancel.options(num_returns=0).remote(
+                    started["stream_id"])
+        task = asyncio.ensure_future(_cancel())
+        self._late_cancels.add(task)    # the loop keeps a weak ref only
+        task.add_done_callback(self._late_cancels.discard)
+
     @staticmethod
     def _check_stream_failpoint():
         """`serve.stream_next` failpoint: deterministic chaos on the
@@ -722,10 +742,16 @@ class ReplicaSet:
                                 {"delivered": 0, "items": []}
                             resume_state["session"] = session
                         t_assign = time.time()
-                        started = await self._stream_rpc(
-                            actor.handle_request_streaming.remote(
-                                method_name, args, kwargs,
-                                resume_state))
+                        start = actor.handle_request_streaming.remote(
+                            method_name, args, kwargs, resume_state)
+                        try:
+                            started = await self._stream_rpc(start)
+                        except asyncio.CancelledError:
+                            # Closed while the replica was still
+                            # starting it: the finally below has no
+                            # stream id to cancel by.
+                            self._cancel_once_started(actor, start)
+                            raise
                         # serve.assign: replica chosen → stream started
                         # (the replica-side admission RPC round trip).
                         assign_args = {"deployment":

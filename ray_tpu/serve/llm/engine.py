@@ -502,6 +502,17 @@ class EngineStats:
     # a top-1).
     moe_gate_mass: float = 0.0
     moe_gate_tokens: int = 0
+    # A body with delta-rule layers (models/bailing_hybrid.py) counts,
+    # on the device: the decay a key channel took, averaged over a
+    # token's channels and summed over live rows, real prompt tokens and
+    # those layers, and how many (token, layer) that sums (their ratio
+    # is the mean decay: 1 if something dropped it); the row states a
+    # tick's step read and wrote, and those of the rows that yielded a
+    # token (their ratio is 1 where idle rows' state was left alone).
+    kda_decay_mass: float = 0.0
+    kda_decay_count: int = 0
+    kda_rows_stepped: int = 0
+    kda_rows_live: int = 0
     # A body that generates by diffusion over blocks (its `block` > 1)
     # runs block steps and no tick: the step programs dispatched; the
     # live rows they ran, summed (a row's forward); of those the ones
@@ -1954,7 +1965,7 @@ class GenerationEngine:
             block_positions_fixed=self._block_positions_fixed,
             # (a counter of another layer than the experts carries its
             # own prefix)
-            **{k if k.startswith("attn_") else "moe_" + k: v
+            **{k if k.startswith(("attn_", "kda_")) else "moe_" + k: v
                for k, v in self._model_counters.items()})
 
     # ------------------------------------------------------------------

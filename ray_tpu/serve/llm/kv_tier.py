@@ -83,10 +83,20 @@ def refuse_unframed(cfg, what: str) -> None:
     refuses a model with per-row state (refuse_row_state) and a model
     whose pages are not K then V of [page, Hkv, Dh] at all (its body is
     not `framed`: a latent page), by name, until a page is opaque bytes
-    of a size the model declares.  The radix prefix cache only hands out
+    of a size the model declares; a model that is both is told both.  The radix prefix cache only hands out
     page ids and serves such a model as it is."""
-    refuse_row_state(cfg, what)
     body = decode.paged_body(cfg)
+    if body.has_row_state and body.page_keys != ("k", "v"):
+        # both at once (models/bailing_hybrid.py): say both
+        raise NotImplementedError(
+            f"{what} on a model with per-row recurrent state "
+            f"({type(cfg).__name__}) whose pages are not K then V either "
+            f"(a latent page): a page is not the whole of a sequence's "
+            f"state there, and tiers and the wire frame K-then-V of "
+            f"[page, Hkv, Dh]; missing: state snapshots at page "
+            f"boundaries, and a page as opaque bytes of a size the model "
+            f"declares")
+    refuse_row_state(cfg, what)
     if body.framed:
         return
     if body.block > 1:

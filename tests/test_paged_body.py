@@ -15,8 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import (decode, deepseek_v2, exaone_moe, gpt, jamba,
-                            llama, mimo_v2_flash, minicpm_sala, zaya)
+from ray_tpu.models import (bailing_hybrid, decode, deepseek_v2, exaone_moe,
+                            gpt, jamba, llama, mimo_v2_flash, minicpm_sala,
+                            zaya)
 from ray_tpu.serve.llm.engine import GenerationEngine
 
 # ------------------------------------------------------------- a fake body
@@ -170,6 +171,8 @@ REAL = {
     "mimo_v2_flash": (lambda: mimo_v2_flash.MimoV2FlashConfig(max_seq=64),
                       exaone_moe.BODY, True),
     "zaya": (lambda: zaya.ZayaConfig(max_seq=64), zaya.BODY, True),
+    "bailing_hybrid": (lambda: bailing_hybrid.BailingHybridConfig(
+        max_seq=64), bailing_hybrid.BODY, True),
 }
 
 

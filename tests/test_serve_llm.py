@@ -510,6 +510,63 @@ def test_generic_stream_transport(serve_instance):
     assert rs.stats()["in_flight"] == 0, rs.stats()
 
 
+def test_a_stream_closed_while_the_replica_starts_it_is_cancelled(
+        serve_instance):
+    """Callers beyond `max_concurrent_queries` wait at the router; when
+    every caller leaves at once (a load generator closing its window),
+    a waiter can take the slot a closing stream just freed and be
+    cancelled while its start RPC is in flight, before the router knows
+    the stream's id.  The replica must still hear of the close: no
+    generator keeps running for nobody."""
+    from ray_tpu import serve
+
+    @serve.deployment(name="endless", max_concurrent_queries=2)
+    class Endless:
+        def __init__(self):
+            self.live = 0
+
+        async def ticks(self):
+            self.live += 1
+            try:
+                while True:
+                    await asyncio.sleep(0.02)
+                    yield 0
+            finally:
+                self.live -= 1
+
+        def live_now(self):
+            return self.live
+
+    handle = Endless.deploy()
+    sub = handle.options("ticks")
+
+    async def one():
+        stream = sub.stream()
+        try:
+            async for _ in stream:
+                pass
+        except asyncio.CancelledError:
+            await stream.aclose()
+            raise
+
+    async def drive():
+        tasks = [asyncio.ensure_future(one()) for _ in range(64)]
+        await asyncio.sleep(1.0)    # two stream, the rest wait for a slot
+        for t in tasks:
+            t.cancel()
+        await asyncio.gather(*tasks, return_exceptions=True)
+
+    asyncio.run(drive())
+    deadline = time.monotonic() + 10
+    while time.monotonic() < deadline:
+        live = handle.live_now.remote().result(timeout=30)
+        if live == 0:
+            break
+        time.sleep(0.1)
+    assert live == 0, live
+    assert sub._router.replica_set.stats()["in_flight"] == 0
+
+
 def test_replica_stream_ttl_sweep():
     """A stream whose consumer vanished (no polls, no cancel) is torn
     down at the next streaming admission instead of buffering forever."""

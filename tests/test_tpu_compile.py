@@ -883,6 +883,114 @@ def test_sdar_paged_step_compiles(chip, step, monkeypatch):
 # expert configurations' two calls: the tile `_tiles` picks is what the
 # kernel's fast memory has to hold, so a width that outgrows it fails
 # here and not on the chip.
+# The tenth configuration (benchmarks/configs/ling-3.0-flash-ep8-d6.json):
+# five Kimi-Delta-Attention layers whose 2 MiB-a-row float32 state is
+# stepped in place, one latent-attention layer that pages, 64 held of
+# 512 sigmoid-routed experts, built as the benchmark builds it, at its
+# sizes (256 rows: 2.68 GB of state).
+def test_kda_kernels_compile(chip):
+    """The tick's step as the chip runs it, at the mixer's widths (256
+    rows, 32 heads of 128 x 128): one Pallas call that holds nothing
+    beside its arguments and updates 5 layers' states in place; and the
+    chunk's plain-XLA form at both chunk widths."""
+    from ray_tpu.ops import kda
+
+    def on(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    f32 = jnp.float32
+    B, H, d = 256, 32, 128
+    vec = on(f32, B, H, d)
+    compiled = jax.jit(kda.step_pallas, donate_argnums=5).lower(
+        vec, vec, vec, vec, on(f32, B, H), on(f32, 5, B, H, d, d),
+        on(jnp.int32), on(jnp.bool_, B)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 24
+    assert mem.alias_size_in_bytes == 5 * B * H * d * d * 4
+    for T in (512, 1024):
+        tok = on(f32, T, H, d)
+        compiled = jax.jit(kda.chunk_xla).lower(
+            tok, tok, tok, tok, on(f32, T, H), on(f32, H, d, d)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
+
+
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_ling3_paged_step_compiles(chip, step, monkeypatch):
+    """Both programs of ling-3.0-flash-ep8-d6 with the step kernel, the
+    grouped matmul and the ragged kernel as the chip runs them: 4.85 GB
+    of weights, one layer's latent pool and 2.78 GB of state and tails
+    are resident; the delta-rule state is float32, donated through the
+    step and updated IN PLACE (one layout, no copy of [5, 256, 32, 128,
+    128]); the latent pool is never re-laid or copied; the tails keep one
+    layout; a tick is one step kernel a KDA layer, one paged-attention
+    kernel and three grouped matmuls an expert layer."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import deepseek_v2
+    from ray_tpu.ops import kda
+    monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs",
+                           "ling-3.0-flash-ep8-d6.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+    assert cache["lat"].shape == (1, e["kv_pages"] + 1, 64, 640)
+    assert cache["kda"].shape == (5, rows, 32, 128, 128)
+    assert cache["kda"].dtype == jnp.float32
+    assert cache["conv"].shape == (5, rows, 3 * 12288)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if step == "decode_tick":
+        lowered = engine._paged_tick.lower(
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
+            with_logits=False)
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3 << 29, mem.temp_size_in_bytes / 2**30
+    held = sum(cache[k].size * cache[k].dtype.itemsize
+               for k in ("lat", "kda", "conv"))
+    assert arch.weight_bytes(c) + held \
+        < mem.argument_size_in_bytes < arch.weight_bytes(c) + held + (1 << 26)
+    assert mem.alias_size_in_bytes >= held       # donated through the step
+    text = compiled.as_text()
+    for name, order in (("lat", "3,2,1,0"), ("kda", "4,3,2,1,0"),
+                        ("conv", "2,1,0")):
+        held = "%s[%s]" % ({"float32": "f32", "bfloat16": "bf16"}[
+            cache[name].dtype.name], ",".join(map(str, cache[name].shape)))
+        layouts = set(re.findall(re.escape(held) + r"\{([\d,]+)", text))
+        assert layouts == {order}, (name, layouts)         # never re-laid
+        moved = [ln for ln in text.splitlines()
+                 if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
+        assert not moved, moved[:4]
+    kernels = [ln for ln in text.splitlines()
+               if "custom-call(" in ln and "tpu_custom_call" in ln]
+    if step == "prefill_chunk":
+        assert len(kernels) == 3 * cfg.n_moe, len(kernels)
+        return
+    stepped = [ln for ln in kernels if " %kda_step" in ln]
+    assert len(stepped) == cfg.n_kda, len(stepped)
+    assert len(_ragged_kernels(text)) == cfg.n_mla
+    assert len(kernels) == cfg.n_kda + cfg.n_mla + 3 * cfg.n_moe
+
+
 @pytest.mark.parametrize("config", [
     "deepseek-v2-ep4-d5", "k-exaone-ep8-d5", "mimo-v2-flash-ep16-d7",
     "zaya1-8b-pp2-d20", "sdar-30b-a3b-pp8-d6"])
