@@ -33,7 +33,7 @@ outputs before `wo`.  The cache (one pytree, `engine._cache`):
                                         dtype (jamba.py's layout)
   moe    [7, 2] int32                   the expert layers' counters
                                         (deepseek_v2.COUNTERS)
-  kdac   [4, 2] int32                   KDA_COUNTERS, below
+  kdac   [6, 2] int32                   KDA_COUNTERS, below
 
 `kda` and `conv` are state per decode row (`row_state_keys`) and `lat`
 is a latent page (not `framed`): the FIRST body with both, so what
@@ -50,9 +50,11 @@ and the tail is read before the pads); a tick steps every row whose
 position is past 0 and leaves the others exactly as they are.
 
 Device code: `ray_tpu/ops/kda.py` (scopes `kda_conv`, `kda_gate`,
-`kda_chunk`, `kda_step`), deepseek_v2's latent attention
-(`mla_expand_attend`, `mla_absorb_attend`) and its expert walk
-(`moe_route`, `moe_experts`), the head under `lm_head`.
+`kda_chunk`, `kda_step`: on a TPU the last two are one Pallas kernel a
+layer each, the chunk's told `valid` so that it skips the chunks of 64
+tokens that are all pad; plain XLA elsewhere), deepseek_v2's latent
+attention (`mla_expand_attend`, `mla_absorb_attend`) and its expert
+walk (`moe_route`, `moe_experts`), the head under `lm_head`.
 
 The expert layer is told which experts it holds (`experts_held`,
 `expert_offset`), as deepseek_v2's is: the router scores ALL 512, and a
@@ -87,7 +89,12 @@ COUNTERS = _ds.COUNTERS
 # `decay_count`: how many (token, layer) that sums.  `rows_stepped`: the
 # row states a tick's step read and wrote, summed over KDA layers;
 # `rows_live`: those of the rows that yielded a token.
-KDA_COUNTERS = ("decay_mass", "decay_count", "rows_stepped", "rows_live")
+# `chunk_tokens_walked`: the tokens a prefill chunk's delta rule walked
+# (whole chunks of 64 up to the last real token where ops/kda.py's
+# kernel runs, every token of the call elsewhere), summed over KDA
+# layers; `chunk_tokens_real`: the real ones among them.
+KDA_COUNTERS = ("decay_mass", "decay_count", "rows_stepped", "rows_live",
+                "chunk_tokens_walked", "chunk_tokens_real")
 _UNIT = 1 << 10
 
 
@@ -350,7 +357,8 @@ def read_counters(cache: Dict, cfg) -> Dict[str, Any]:
     decay a channel a token, strictly between e^-5 and 1 on a live gate
     and exactly 1 if something dropped the decay; `kda_rows_stepped /
     kda_rows_live` is 1 where a tick's step touched the state of live
-    rows alone."""
+    rows alone; `kda_chunk_tokens_walked / kda_chunk_tokens_real` is 1
+    where a prefill chunk's delta rule walked no pad."""
     counts = _ds.read_counters(cache, cfg)
     for name, (hi, lo) in zip(KDA_COUNTERS,
                               np.asarray(cache["kdac"]).astype(np.int64)):
@@ -475,9 +483,12 @@ def _kda_chunk(lp, x, i, cache, start, slot, valid, kc,
         beta = jnp.where(real[:, None], beta, 0.0)
     with jax.named_scope("kda_chunk"):
         S0 = jnp.where(fresh, 0.0, cache["kda"][i, slot])
-        o, S = kda.kda_chunk(q, k, v, a, beta, S0)
+        o, S = kda.kda_chunk(q, k, v, a, beta, S0, valid)
         state = cache["kda"].at[i, slot].set(S)
-    kc = [kc[0] + _decay_mass(a, real), kc[1] + valid, kc[2], kc[3]]
+    # (a call with no real token, the engine's warm-up, counts nothing)
+    walked = jnp.where(valid > 0, kda.chunk_tokens_walked(*q.shape, valid), 0)
+    kc = [kc[0] + _decay_mass(a, real), kc[1] + valid, kc[2], kc[3],
+          kc[4] + walked, kc[5] + valid]
     return _kda_out(lp, x, o, gate, cfg), dict(cache, kda=state,
                                                conv=conv), kc
 
@@ -496,7 +507,7 @@ def _kda_tick(lp, x, i, cache, pos, kc, cfg: BailingHybridConfig):
                                          active)
     live = active.sum()
     kc = [kc[0] + _decay_mass(a, active), kc[1] + live, kc[2] + touched,
-          kc[3] + live]
+          kc[3] + live] + kc[4:]
     return _kda_out(lp, x, o, gate, cfg), dict(cache, kda=state,
                                                conv=conv), kc
 
@@ -547,7 +558,7 @@ def _mla_gate(lp, x, cfg: BailingHybridConfig):
 
 def _through_layers(params, x, cache, live, is_tick, mla, kda_mixer, cfg):
     counts = [jnp.int32(0)] * len(COUNTERS)
-    kc = [jnp.float32(0)] + [jnp.int32(0)] * 3
+    kc = [jnp.float32(0)] + [jnp.int32(0)] * (len(KDA_COUNTERS) - 1)
     seen = {KDA: 0, MLA: 0}
     for lp, kind in zip(params["layers"], cfg.kinds):
         i = seen[kind]

@@ -508,11 +508,16 @@ class EngineStats:
     # those layers, and how many (token, layer) that sums (their ratio
     # is the mean decay: 1 if something dropped it); the row states a
     # tick's step read and wrote, and those of the rows that yielded a
-    # token (their ratio is 1 where idle rows' state was left alone).
+    # token (their ratio is 1 where idle rows' state was left alone);
+    # the tokens a prefill chunk's delta rule walked, summed over those
+    # layers, and the real ones among them (their ratio is 1 where no
+    # pad was walked, padded / real where every one was).
     kda_decay_mass: float = 0.0
     kda_decay_count: int = 0
     kda_rows_stepped: int = 0
     kda_rows_live: int = 0
+    kda_chunk_tokens_walked: int = 0
+    kda_chunk_tokens_real: int = 0
     # A body that generates by diffusion over blocks (its `block` > 1)
     # runs block steps and no tick: the step programs dispatched; the
     # live rows they ran, summed (a row's forward); of those the ones

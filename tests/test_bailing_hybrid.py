@@ -201,6 +201,11 @@ def test_prefill_chunks_then_ticks_are_one_reference_forward(
     assert np.exp(-5) < mean < 0.999           # a live gate
     assert counts["kda_rows_live"] == n_decode * N_KDA
     assert counts["kda_rows_stepped"] == n_decode * N_KDA * ROWS
+    # the chunks' delta rule: the prompt's tokens are the real ones, and
+    # what was walked lies between them and the padded calls
+    assert counts["kda_chunk_tokens_real"] == n_prompt * N_KDA
+    assert n_prompt * N_KDA <= counts["kda_chunk_tokens_walked"] \
+        <= -(-n_prompt // chunk) * chunk * N_KDA
 
 
 def test_two_rows_in_one_tick_and_a_slot_that_changes_hands(model, arch):
@@ -454,7 +459,7 @@ def test_the_new_cells_files_load_through_the_registry():
     names = {m["name"] for m in reg.metrics_for("ling3-longtail",
                                                 "per_layer")}
     assert {"kda_decay_mean.tput", "kda_rows_stepped_ratio.tput",
-            "kda_step_roofline.tput",
+            "kda_step_roofline.tput", "kda_chunk_walked_ratio.tput",
             "row_state_gb.tput", "paged_tick_roofline.tput"} <= names
     decay = reg.metric("kda_decay_mean.tput")
     obs = {"stats0": {"kda_decay_mass": 10.0, "kda_decay_count": 20},
@@ -463,6 +468,18 @@ def test_the_new_cells_files_load_through_the_registry():
         == pytest.approx(0.9)
     assert reg.reader(decay["reader"])(
         {"stats0": {}, "stats1": {}}, **decay["args"]) is None
+    # (PR 64) the tokens the chunks' delta rule walked over the real ones:
+    # read from two stats dictionaries, null for a program without them
+    walked = reg.metric("kda_chunk_walked_ratio.tput")
+    obs = {"stats0": {"kda_chunk_tokens_walked": 2560,
+                      "kda_chunk_tokens_real": 2000},
+           "stats1": {"kda_chunk_tokens_walked": 2560 + 5 * 512,
+                      "kda_chunk_tokens_real": 2000 + 5 * 450}}
+    assert reg.reader(walked["reader"])(obs, **walked["args"]) \
+        == pytest.approx(512 / 450)
+    assert reg.reader(walked["reader"])(
+        {"stats0": {"kda_rows_live": 1}, "stats1": {"kda_rows_live": 2}},
+        **walked["args"]) is None
 
 
 @pytest.mark.parametrize("held,want", [
@@ -589,6 +606,10 @@ def test_the_engine_serves_it_and_counts(model, served, arch):
     assert np.exp(-5) < gain["kda_decay_mass"] / gain["kda_decay_count"] < 1
     assert gain["kda_rows_live"] == 5 * 8 * N_KDA
     assert gain["kda_rows_stepped"] >= gain["kda_rows_live"]
+    assert gain["kda_chunk_tokens_real"] == gain["prefill_tokens"] * N_KDA \
+        == sum(map(len, prompts)) * N_KDA
+    assert gain["kda_chunk_tokens_real"] <= gain["kda_chunk_tokens_walked"] \
+        <= (gain["prefill_tokens"] + gain["prefill_pad_tokens"]) * N_KDA
     assert gain["attn_keys_context"] == gain["attn_keys_attended"] \
         == gain["attn_keys_resident"] == gain["attn_keys_resident_paged"] \
         == context

@@ -1,6 +1,8 @@
 """ops/kda.py on the CPU at a small size, in float32: the chunked delta
-rule and the tick's step against the recurrence token by token, and the
-convolution's tail."""
+rule (plain XLA, and the kernel interpreted) and the tick's step against
+the recurrence token by token, and the convolution's tail."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -49,18 +51,39 @@ def close(got, want, tol=2e-5):
                                rtol=0)
 
 
+FORMS = ["xla", "pallas"]
+
+
+def chunk_form(form):
+    """(the chunk in that form, the heads it is tried at): the kernel is
+    interpreted here, and walks blocks of 8 heads."""
+    if form == "pallas":
+        return functools.partial(kda.chunk_pallas, interpret=True), \
+            kda._CHUNK_HEADS
+    return kda.chunk_xla, H
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("case", ["plain", "bound", "alpha_one", "beta_zero",
                                   "short"])
-def test_chunk_is_the_recurrence(case):
-    """`chunk_xla` over 128 tokens (two chunks of 64, the state carried
-    between them inside the call) equals the recurrence: with live
-    gates; with EVERY decay at the bound -5 for whole chunks (finite: no
-    exponent is taken but as a difference inside 16 tokens); with no
-    decay; with nothing written; and over one chunk shorter than 64."""
+def test_chunk_is_the_recurrence(case, form):
+    """The chunk over 128 tokens (two chunks of 64, the state carried
+    between them inside the call) equals the recurrence, in both forms:
+    with live gates; with EVERY decay at the bound -5 for whole chunks
+    (finite: no exponent is taken but as a difference that is <= 0);
+    with no decay; with nothing written; and over one chunk shorter than
+    64, which is `chunk_xla`'s alone: the kernel walks whole chunks of
+    64 and says so."""
+    chunk, heads = chunk_form(form)
     T = 32 if case == "short" else 128
-    args = draws(1, T, bound=case == "bound", alpha_one=case == "alpha_one",
+    args = draws(1, T, heads, bound=case == "bound",
+                 alpha_one=case == "alpha_one",
                  beta_zero=case == "beta_zero")
-    o, S = jax.jit(kda.chunk_xla)(*args)
+    if (form, case) == ("pallas", "short"):
+        with pytest.raises(ValueError, match="chunks of 64"):
+            chunk(*args)
+        return
+    o, S = jax.jit(chunk)(*args)
     want_o, want_S = recurrence(*args)
     close(o, want_o)
     close(S, want_S)
@@ -68,32 +91,94 @@ def test_chunk_is_the_recurrence(case):
         close(S, jnp.exp(args[3].sum(0))[..., None] * args[5], 1e-6)
 
 
-def test_chunk_carries_its_state_across_calls():
+@pytest.mark.parametrize("form", FORMS)
+def test_chunk_carries_its_state_across_calls(form):
     """Two calls of 64 tokens, the second from the state the first left,
     equal one call of 128 and the recurrence over all of them."""
-    q, k, v, a, beta, S0 = draws(2, 128)
-    first = kda.chunk_xla(q[:64], k[:64], v[:64], a[:64], beta[:64], S0)
-    second = kda.chunk_xla(q[64:], k[64:], v[64:], a[64:], beta[64:],
-                           first[1])
+    chunk, heads = chunk_form(form)
+    q, k, v, a, beta, S0 = draws(2, 128, heads)
+    first = chunk(q[:64], k[:64], v[:64], a[:64], beta[:64], S0)
+    second = chunk(q[64:], k[64:], v[64:], a[64:], beta[64:], first[1])
     want_o, want_S = recurrence(q, k, v, a, beta, S0)
     close(jnp.concatenate([first[0], second[0]]), want_o)
     close(second[1], want_S)
 
 
+def padded(args, valid):
+    """The call with its tokens at or past `valid` made pads as the
+    mixer makes them (a = 0, beta = 0)."""
+    q, k, v, a, beta, S0 = args
+    real = jnp.arange(q.shape[0]) < valid
+    return (q, k, v, jnp.where(real[:, None, None], a, 0.0),
+            jnp.where(real[:, None], beta, 0.0), S0)
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("valid", [1, 37, 64])
-def test_pads_move_nothing(valid):
-    """Tokens at or past `valid` made as the mixer makes them (a = 0,
-    beta = 0): the state after the chunk is the state after token
-    `valid - 1`, and the real tokens' outputs are theirs."""
-    q, k, v, a, beta, S0 = draws(3, 64)
-    real = (jnp.arange(64) < valid)
-    a = jnp.where(real[:, None, None], a, 0.0)
-    beta = jnp.where(real[:, None], beta, 0.0)
-    o, S = kda.chunk_xla(q, k, v, a, beta, S0)
+def test_pads_move_nothing(valid, form):
+    """Tokens at or past `valid` made as the mixer makes them: the state
+    after the chunk is the state after token `valid - 1`, and the real
+    tokens' outputs are theirs."""
+    chunk, heads = chunk_form(form)
+    q, k, v, a, beta, S0 = args = padded(draws(3, 64, heads), valid)
+    o, S = chunk(*args)
     want_o, want_S = recurrence(q[:valid], k[:valid], v[:valid], a[:valid],
                                 beta[:valid], S0)
     close(o[:valid], want_o)
     close(S, want_S)
+
+
+@pytest.mark.parametrize("valid", [0, 1, 64, 65, 449, 512])
+def test_a_chunk_past_valid_is_not_walked(valid):
+    """A call of 512 tokens told `valid`: the kernel leaves the state of
+    token `valid - 1` (the state it was given where no token is real:
+    the engine's warm-up), the real tokens' outputs are the
+    recurrence's, a chunk of 64 wholly past `valid` yields zeros, and
+    64 x ceil(valid / 64) tokens count as walked."""
+    q, k, v, a, beta, S0 = args = padded(draws(7, 512, kda._CHUNK_HEADS),
+                                         valid)
+    o, S = jax.jit(functools.partial(kda.chunk_pallas, interpret=True))(
+        *args, jnp.int32(valid))
+    want_o, want_S = recurrence(q[:valid], k[:valid], v[:valid], a[:valid],
+                                beta[:valid], S0)
+    close(o[:valid], want_o)
+    close(S, want_S)
+    walked = -(-valid // kda.CHUNK) * kda.CHUNK
+    assert not np.asarray(o[walked:]).any()
+    assert int(kda.chunks_walked(512, valid)) * kda.CHUNK == walked
+    if walked > valid:             # the walked chunk's own pads are read
+        assert np.asarray(o[valid:walked]).any()
+
+
+def test_kda_chunk_picks_its_form(monkeypatch):
+    """On a TPU (patched in, the kernel interpreted in its place) the
+    chunk is the kernel at the widths it tiles and `chunk_xla` at any
+    other; off it, `chunk_xla` always.  What it says it walked follows
+    the form: whole chunks up to `valid`, or every token."""
+    calls = []
+    real = functools.partial(kda.chunk_pallas, interpret=True)
+
+    def kernel(*a, **kw):
+        calls.append(a[0].shape)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(kda, "chunk_pallas", kernel)
+    wide = padded(draws(8, 512, 32), 100)
+    narrow = padded(draws(8, 128, 4), 100)
+    off, _ = kda.kda_chunk(*wide, jnp.int32(100))
+    assert not calls
+    assert int(kda.chunk_tokens_walked(512, 32, D, 100)) == 512
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+    on, _ = kda.kda_chunk(*wide, jnp.int32(100))
+    assert calls == [(512, 32, D)]
+    close(on[:100], off[:100])
+    assert not np.asarray(on[128:]).any() and np.asarray(off[128:]).any()
+    assert int(kda.chunk_tokens_walked(512, 32, D, 100)) == 128
+    for shape, args in (((128, 4, D), narrow),
+                        ((32, 32, D), padded(draws(8, 32, 32), 32))):
+        kda.kda_chunk(*args, jnp.int32(100))
+        assert calls == [(512, 32, D)], shape
+        assert int(kda.chunk_tokens_walked(*shape, 100)) == shape[0]
 
 
 @pytest.mark.parametrize("form", ["xla", "pallas"])
@@ -134,7 +219,6 @@ def test_kda_step_says_what_it_touched(monkeypatch):
     active = jnp.array([1, 0, 0, 0, 0, 0, 0, 0, 0, 0, 1, 1], bool)
     o, new, touched = kda.kda_step(q, k, v, a, beta, states, 0, active)
     assert int(touched) == B and not np.asarray(o[1]).any()
-    import functools
     monkeypatch.setattr(kda, "_on_tpu", lambda: True)
     monkeypatch.setattr(kda, "step_pallas", functools.partial(
         kda.step_pallas, interpret=True))

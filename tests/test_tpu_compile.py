@@ -892,7 +892,10 @@ def test_kda_kernels_compile(chip):
     """The tick's step as the chip runs it, at the mixer's widths (256
     rows, 32 heads of 128 x 128): one Pallas call that holds nothing
     beside its arguments and updates 5 layers' states in place; and the
-    chunk's plain-XLA form at both chunk widths."""
+    chunk at both chunk widths: the kernel (one Pallas call told `valid`,
+    the streams read as they lie: temporaries under 16 MiB, and the fast
+    memory it asks for is enough, or the compile raises) beside the
+    plain-XLA form the other backends run (allowed 512 MiB)."""
     from ray_tpu.ops import kda
 
     def on(dtype, *shape):
@@ -910,8 +913,14 @@ def test_kda_kernels_compile(chip):
     assert mem.alias_size_in_bytes == 5 * B * H * d * d * 4
     for T in (512, 1024):
         tok = on(f32, T, H, d)
-        compiled = jax.jit(kda.chunk_xla).lower(
-            tok, tok, tok, tok, on(f32, T, H), on(f32, H, d, d)).compile()
+        args = (tok, tok, tok, tok, on(f32, T, H), on(f32, H, d, d))
+        compiled = jax.jit(kda.chunk_pallas).lower(
+            *args, on(jnp.int32)).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1
+        assert " %kda_chunk" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 24
+        compiled = jax.jit(kda.chunk_xla).lower(*args).compile()
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 29
 
 
@@ -924,7 +933,9 @@ def test_ling3_paged_step_compiles(chip, step, monkeypatch):
     step and updated IN PLACE (one layout, no copy of [5, 256, 32, 128,
     128]); the latent pool is never re-laid or copied; the tails keep one
     layout; a tick is one step kernel a KDA layer, one paged-attention
-    kernel and three grouped matmuls an expert layer."""
+    kernel and three grouped matmuls an expert layer; a chunk is one
+    chunk kernel a KDA layer beside the grouped matmuls, and no
+    triangular solve."""
     import json
     import os
 
@@ -983,7 +994,10 @@ def test_ling3_paged_step_compiles(chip, step, monkeypatch):
     kernels = [ln for ln in text.splitlines()
                if "custom-call(" in ln and "tpu_custom_call" in ln]
     if step == "prefill_chunk":
-        assert len(kernels) == 3 * cfg.n_moe, len(kernels)
+        walked = [ln for ln in kernels if " %kda_chunk" in ln]
+        assert len(walked) == cfg.n_kda, len(walked)
+        assert len(kernels) == 3 * cfg.n_moe + cfg.n_kda, len(kernels)
+        assert "InvertDiagBlocksLowerTriangular" not in text
         return
     stepped = [ln for ln in kernels if " %kda_step" in ln]
     assert len(stepped) == cfg.n_kda, len(stepped)
