@@ -133,8 +133,8 @@ def paged_body(cfg) -> PagedBody:
     """The body that runs `cfg` through a paged cache: this file's dense
     one for the two dense configs, else the one the config names
     (minicpm_sala.py, deepseek_v2.py, exaone_moe.py, jamba.py, zaya.py,
-    sdar_moe.py, bailing_hybrid.py; mimo_v2_flash.py names
-    exaone_moe's).  Never None."""
+    sdar_moe.py, bailing_hybrid.py, glm_moe_dsa.py; mimo_v2_flash.py
+    names exaone_moe's).  Never None."""
     if isinstance(cfg, (GPTConfig, llama_mod.LlamaConfig)):
         return DENSE_BODY
     return cfg.paged_body

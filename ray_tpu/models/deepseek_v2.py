@@ -66,7 +66,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -574,7 +574,13 @@ def _ffn(lp, x, live, is_tick, counts, cfg: DeepseekV2Config):
 # differs reaches `_attn_chunk` and `_attn_tick` as `project` (this
 # file's `_project` by default) and `gate` (none by default), and its
 # config answers `n_heads`, `kv_lora_rank`, `qk_rope_head_dim`,
-# `v_head_dim`, `softmax_scale` and `dtype` as this one does.
+# `v_head_dim`, `softmax_scale` and `dtype` as this one does.  A third
+# (models/glm_moe_dsa.py: 64 heads of 192 + 64 | 256 over the same row)
+# CHOOSES the keys a query attends to and says which as `chosen`: a
+# chunk takes a 0/1 mask over its row's table width and attends
+# expanded under it, a tick a `Chosen` list of positions a row and
+# gathers those latents, and only those, out of the pool
+# (`_attend_chosen`); both under the scope `dsa_attend`.
 
 
 def _project(lp, x, positions, cfg: DeepseekV2Config):
@@ -609,11 +615,80 @@ def _merge(part, scores, values_of):
             acc * keep[..., None] + values_of(e))
 
 
-def _attn_chunk(lp, x, l, cache, bt, start, cfg, project=None, gate=None):
+class Chosen(NamedTuple):
+    """The keys each row of a tick attends to, where a model chooses
+    them a token (models/glm_moe_dsa.py's indexer), as lists: `idx`
+    [B, k] their positions in the row's own sequence, `ok` [B, k] which
+    of the k slots hold a choice (a row that holds fewer than k keys
+    fills fewer), and the rows attended at all: order[0] ..
+    order[n - 1] of `order`, a permutation of the rows, `n` a number
+    the device reads (a tick's live rows).  The others get zeros."""
+    idx: Any
+    ok: Any
+    n: Any
+    order: Any
+
+
+# Rows whose chosen latents one trip of a tick's gather holds side by
+# side: 8 x 2,048 rows of 640 are 21 MB, their float32 scores over 64
+# heads 4 MB.  (A trip a row read the same; a trip is ~14 device
+# operations, and a profiler's capture pays by the operation.)
+_CHOSEN_ROWS = 8
+
+
+def _live_block(order, n, j, qb: int):
+    """Trip j of a walk over the first `n` rows of `order`, `qb` at a
+    time: (the rows it takes [qb], which of them are among the n)."""
+    return (lax.dynamic_slice_in_dim(order, j * qb, qb),
+            j * qb + jnp.arange(qb) < n)
+
+
+def _attend_chosen(q_row, lat, l, bt, chosen: Chosen, cfg):
+    """Absorbed attention over the chosen keys and nothing else: q_row
+    [B, H, W] (laid as a cached row is, `wk_b` already in it) against
+    the latents at positions `chosen.idx` of each row's sequence,
+    gathered `_CHOSEN_ROWS` rows a trip out of layer `l` of the pool,
+    each through its own table `bt[row]` -> (the weighted latents [B,
+    H, kv_lora_rank], the latent rows the trips gathered AND weighed
+    for the `chosen.n` rows: their `ok` slots, counted as the trips
+    went).  The trip count follows `chosen.n`: a block of idle rows
+    costs nothing.  (A position's page is found by comparison with the
+    table's columns, a masked sum over `nblk`: the same lookup as a
+    gather of single numbers took 5 ns each on a v5e, a quarter of a
+    trip; PERF.md section 6, PR 65.)"""
+    B, H, W = q_row.shape
+    psz, nblk, kr = lat.shape[2], bt.shape[1], cfg.kv_lora_rank
+    dt = cfg.dtype
+    qb = math.gcd(B, _CHOSEN_ROWS)
+
+    def trip(j, carry):
+        out, took = carry
+        at, on = _live_block(chosen.order, chosen.n, j, qb)
+        idx, ok = chosen.idx[at], chosen.ok[at] & on[:, None]
+        page = jnp.where((idx // psz)[..., None] == jnp.arange(nblk),
+                         bt[at][:, None, :], 0).sum(-1)
+        rows = lat[l, page, idx % psz]                       # [qb, k, W]
+        s = jnp.einsum("qhc,qsc->qhs", q_row[at], rows,
+                       preferred_element_type=jnp.float32) \
+            * cfg.softmax_scale
+        p = jax.nn.softmax(jnp.where(ok[:, None, :], s, -jnp.inf), axis=-1)
+        o = jnp.einsum("qhs,qsc->qhc", p.astype(dt), rows[..., :kr])
+        # (a row past `n` in the last block has no slot filled)
+        return (out.at[at].set(jnp.where(on[:, None, None], o, 0)),
+                took + ok.sum(dtype=jnp.int32))
+
+    return lax.fori_loop(0, -(-chosen.n // qb), trip,
+                         (jnp.zeros((B, H, kr), dt), jnp.int32(0)))
+
+
+def _attn_chunk(lp, x, l, cache, bt, start, cfg, project=None, gate=None,
+                chosen=None):
     """`project` (lp, x, positions, cfg) -> (q_nope, q_pe, the normed
     latent, the rotated shared key part), `_project` by default; `gate`
     (lp, x) -> a factor a token and head [T, H] on the heads' outputs
-    before `wo`, none by default."""
+    before `wo`, none by default; `chosen` [T, S] bool over the table's
+    width S, where the model chooses the keys a query attends to: a
+    query's softmax then runs over its chosen keys alone."""
     T = x.shape[0]
     H, psz, kr = cfg.n_heads, cache["lat"].shape[2], cfg.kv_lora_rank
     dt = cfg.dtype
@@ -623,7 +698,8 @@ def _attn_chunk(lp, x, l, cache, bt, start, cfg, project=None, gate=None):
     lat = cache["lat"].at[l, pages].set(
         _lat_row(ckv, kpe, cfg).reshape(T // psz, psz, -1))
 
-    with jax.named_scope("mla_expand_attend"):
+    with jax.named_scope("mla_expand_attend" if chosen is None
+                         else "dsa_attend"):
         nblk = bt.shape[0]
         span = _span_pages(_CHUNK_SPAN_KEYS, psz, nblk)
         width = span * psz
@@ -644,6 +720,9 @@ def _attn_chunk(lp, x, l, cache, bt, start, cfg, project=None, gate=None):
             kcols = first * psz + jnp.arange(width)
             seen = (kcols[None, :] <= cols[:, None]) \
                 & (kcols[None, :] >= i * width)
+            if chosen is not None:
+                seen &= lax.dynamic_slice(chosen, (0, first * psz),
+                                          (T, width))
             s = jnp.where(seen[None], s, -jnp.inf)
             return _merge(part, s, lambda e: jnp.einsum(
                 "hts,shv->htv", e.astype(dt), v,
@@ -661,20 +740,26 @@ def _attn_chunk(lp, x, l, cache, bt, start, cfg, project=None, gate=None):
     return x, dict(cache, lat=lat)
 
 
-def _attn_tick(lp, x, l, cache, bt, pos, cfg, project=None, gate=None):
-    """`project` and `gate` as `_attn_chunk` takes them."""
+def _attn_tick(lp, x, l, cache, bt, pos, cfg, project=None, gate=None,
+               chosen=None):
+    """`project` and `gate` as `_attn_chunk` takes them; `chosen` a
+    `Chosen` list of positions a row, with which the latent rows
+    `_attend_chosen` gathered are returned third."""
     psz, kr = cache["lat"].shape[2], cfg.kv_lora_rank
     dt = cfg.dtype
     q_nope, q_pe, ckv, kpe = (project or _project)(lp, x, pos, cfg)
     page = jnp.take_along_axis(bt, (pos // psz)[:, None], axis=1)[:, 0]
     lat = cache["lat"].at[l, page, pos % psz].set(_lat_row(ckv, kpe, cfg))
 
-    with jax.named_scope("mla_absorb_attend"):
+    with jax.named_scope("mla_absorb_attend" if chosen is None
+                         else "dsa_attend"):
         # wk_b into the query, wv_b into the output: scores and values
         # are taken against the cached rows themselves
         q_row = _lat_row(jnp.einsum("bhn,hnc->bhc", q_nope,
                                     lp["wk_b"].astype(dt)), q_pe, cfg)
-        if _on_tpu():
+        if chosen is not None:
+            o_lat, gathered = _attend_chosen(q_row, lat, l, bt, chosen, cfg)
+        elif _on_tpu():
             o_lat = _pa.paged_attention(
                 q_row, lat, None, l, bt, pos, n_kv_heads=1, value_width=kr,
                 scale=cfg.softmax_scale)
@@ -684,7 +769,8 @@ def _attn_tick(lp, x, l, cache, bt, pos, cfg, project=None, gate=None):
     if gate is not None:
         out = out * gate(lp, x)[:, :, None].astype(dt)
     x = x + jnp.einsum("bhv,hvd->bd", out, lp["wo"].astype(dt))
-    return x, dict(cache, lat=lat)
+    cache = dict(cache, lat=lat)
+    return (x, cache) if chosen is None else (x, cache, gathered)
 
 
 def _span_tick(q_row, lat, l, bt, pos, cfg):

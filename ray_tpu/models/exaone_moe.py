@@ -273,8 +273,9 @@ def check_paging(cfg: ExaoneMoeConfig, *, page_size: int,
 # Weights and cache
 
 
-def seeded_draws(cfg, key, dtype):
-    """What a model of this family draws its seeded weights with:
+def seeded_draws(cfg, key, dtype, per_layer: int = 16):
+    """What a model of this family draws its seeded weights with, at
+    most `per_layer` arrays a layer:
     (`nrm(shape, scale, dt=dtype)`, a normal in float32 cast to `dt`,
     each call a key of its own in the order of the calls; `swiglu(width,
     *lead)`, three of them, std `s` in and `so` out; `s` 0.02; `so`
@@ -283,7 +284,7 @@ def seeded_draws(cfg, key, dtype):
     D = cfg.d_model
     s = 0.02
     so = s / np.sqrt(2 * cfg.n_layers)
-    keys = iter(jax.random.split(key, 2 + 16 * cfg.n_layers))
+    keys = iter(jax.random.split(key, 2 + per_layer * cfg.n_layers))
 
     def nrm(shape, scale, dt=dtype):
         return (scale * jax.random.normal(next(keys), shape, jnp.float32)

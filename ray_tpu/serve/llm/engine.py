@@ -518,6 +518,19 @@ class EngineStats:
     kda_rows_live: int = 0
     kda_chunk_tokens_walked: int = 0
     kda_chunk_tokens_real: int = 0
+    # A body that chooses keys a token (models/glm_moe_dsa.py) counts, on
+    # the device and summed over its layers: the (query, key) pairs its
+    # indexer scored, the keys its queries chose (chunks and ticks; then
+    # the ticks' alone), the latent rows a tick's attention gathered and
+    # weighed for them (over the ticks' keys chosen that reads 1 where
+    # attention touched the chosen and nothing else), and a tick's live
+    # rows past `index_topk` beside its live rows.
+    dsa_keys_scored: int = 0
+    dsa_keys_chosen: int = 0
+    dsa_tick_keys_chosen: int = 0
+    dsa_tick_keys_attended: int = 0
+    dsa_rows_selecting: int = 0
+    dsa_rows_live: int = 0
     # A body that generates by diffusion over blocks (its `block` > 1)
     # runs block steps and no tick: the step programs dispatched; the
     # live rows they ran, summed (a row's forward); of those the ones
@@ -1970,7 +1983,7 @@ class GenerationEngine:
             block_positions_fixed=self._block_positions_fixed,
             # (a counter of another layer than the experts carries its
             # own prefix)
-            **{k if k.startswith(("attn_", "kda_")) else "moe_" + k: v
+            **{k if k.startswith(("attn_", "kda_", "dsa_")) else "moe_" + k: v
                for k, v in self._model_counters.items()})
 
     # ------------------------------------------------------------------

@@ -449,7 +449,8 @@ def test_tick_ahead_share_is_the_throughput_cells():
         "moves": "out_tok_per_s",
         "workloads": ["internlm2-batch", "sala-longdoc", "dsv2-decode",
                       "kexaone-reason", "jamba2-chat", "mimo-agent",
-                      "zaya-reason", "sdar-blockgen", "ling3-longtail"]}
+                      "zaya-reason", "sdar-blockgen", "ling3-longtail",
+                      "glm5-longctx"]}
     tput = next(m for m in spec["end_to_end"]
                 if m["name"] == "out_tok_per_s")
     assert entry["workloads"] == tput["workloads"]

@@ -16,8 +16,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import (bailing_hybrid, decode, deepseek_v2, exaone_moe,
-                            gpt, jamba, llama, mimo_v2_flash, minicpm_sala,
-                            zaya)
+                            glm_moe_dsa, gpt, jamba, llama, mimo_v2_flash,
+                            minicpm_sala, zaya)
 from ray_tpu.serve.llm.engine import GenerationEngine
 
 # ------------------------------------------------------------- a fake body
@@ -173,6 +173,8 @@ REAL = {
     "zaya": (lambda: zaya.ZayaConfig(max_seq=64), zaya.BODY, True),
     "bailing_hybrid": (lambda: bailing_hybrid.BailingHybridConfig(
         max_seq=64), bailing_hybrid.BODY, True),
+    "glm_moe_dsa": (lambda: glm_moe_dsa.GlmMoeDsaConfig(max_seq=64),
+                    glm_moe_dsa.BODY, False),
 }
 
 

@@ -710,6 +710,60 @@ def test_llm_deployment_generate_and_stream(serve_instance):
     assert st["active_slots"] == 0, st
 
 
+def _glm5_loader():
+    """GLM-5 at toy widths: every layer chooses 12 keys a query with a
+    2-head indexer, 4 of 16 experts held."""
+    from ray_tpu.models import glm_moe_dsa
+    cfg = glm_moe_dsa.GlmMoeDsaConfig(
+        max_seq=128, n_layers=3, vocab_size=97, d_model=32, n_heads=2,
+        q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, index_n_heads=2,
+        index_head_dim=16, index_topk=12, d_ff=48, first_k_dense=1,
+        moe_d_ff=16, n_routed_experts=16, top_k=4, experts_held=4,
+        dtype=jnp.float32)
+    return glm_moe_dsa.init_params(cfg, jax.random.PRNGKey(0)), cfg
+
+
+def test_llm_deployment_serves_a_body_that_chooses_its_keys(serve_instance):
+    """models/glm_moe_dsa.py through serve, as every body is: a pool of
+    two arrays of unequal width under one block table.  Two prompts,
+    one longer than `index_topk`, generate and stream the tokens an
+    engine of its own yields for each alone, and the replica's stats
+    carry the selection's counters."""
+    params, cfg = _glm5_loader()
+    kw = dict(num_slots=2, page_size=8, prefill_chunk=16, kv_pages=40,
+              enable_prefix_cache=True)
+    prompts = [_prompt(5, 37, cfg), _prompt(6, 7, cfg)]
+    alone = GenerationEngine(params, cfg, **kw)
+    try:
+        want = [alone.submit(p, max_new_tokens=10).result(timeout=300)
+                for p in prompts]
+    finally:
+        alone.stop()
+    handle = llm_deployment(
+        _glm5_loader, name="llm-glm5", engine_config=kw,
+        default_generation={"max_new_tokens": 10}).deploy()
+    futures = [handle.generate.remote(p) for p in prompts]
+    for fut, w in zip(futures, want):
+        np.testing.assert_array_equal(np.asarray(fut.result(timeout=300)), w)
+    assert list(handle.options("stream").stream(prompts[0])) == want[0]
+    st = handle.stats.remote().result(timeout=60)
+    assert st["requests_completed"] == 3
+    ticks = [p for pr in (prompts[0], prompts[1], prompts[0])
+             for p in range(len(pr), len(pr) + 9)]
+    assert st["dsa_rows_live"] == len(ticks) * cfg.n_layers
+    assert st["dsa_rows_selecting"] == cfg.n_layers * sum(
+        p + 1 > cfg.index_topk for p in ticks)
+    assert st["dsa_tick_keys_attended"] == st["dsa_tick_keys_chosen"] > 0
+    assert st["dsa_keys_scored"] > st["dsa_keys_chosen"] \
+        > st["dsa_tick_keys_chosen"]
+    assert st["attn_keys_attended"] < st["attn_keys_resident"]
+    assert st["row_state_bytes"] == 0
+    assert st["prefix_hit_tokens"] >= 32     # the repeated prompt's pages
+    with pytest.raises(NotImplementedError, match="GlmMoeDsaConfig"):
+        GenerationEngine(params, cfg, kv_tiering=True, **kw)
+
+
 @pytest.mark.slow
 def test_llm_http_sse_wire_level(serve_instance):
     """The acceptance wire test: SSE through the real HTTP proxy —

@@ -431,6 +431,7 @@ def test_dsv2_paged_step_compiles(chip, step, monkeypatch):
     assert not moved, moved[:4]
     _no_row_a_routed_pair(text, rows if step == "decode_tick"
                           else e["prefill_chunk"], cfg, c["hidden_size"])
+    _nothing_of_the_selection(text, step)
     if step == "decode_tick":
         shapes = [s.split(",") for s in
                   re.findall(r" = \w+\[([\d,]+)\]", text)]
@@ -448,6 +449,16 @@ def test_dsv2_paged_step_compiles(chip, step, monkeypatch):
         spans = [s for s in shapes if len(s) >= 3 and s[0] == str(rows)
                  and s[-1] == width and s[1:-1] != [str(cfg.n_heads)]]
         assert not spans, spans[:4]
+
+
+def _nothing_of_the_selection(text, step):
+    """A latent-attention program of a model that chooses no keys is the
+    program it was before `deepseek_v2._attn_chunk` / `_attn_tick` took
+    `chosen`: its attention stands under its own scope and no
+    instruction under the selection's."""
+    assert "dsa_" not in text
+    assert ("mla_absorb_attend" if step == "decode_tick"
+            else "mla_expand_attend") in text
 
 
 # The fifth configuration (benchmarks/configs/k-exaone-ep8-d5.json):
@@ -991,6 +1002,7 @@ def test_ling3_paged_step_compiles(chip, step, monkeypatch):
         moved = [ln for ln in text.splitlines()
                  if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
         assert not moved, moved[:4]
+    _nothing_of_the_selection(text, step)
     kernels = [ln for ln in text.splitlines()
                if "custom-call(" in ln and "tpu_custom_call" in ln]
     if step == "prefill_chunk":
@@ -1003,6 +1015,89 @@ def test_ling3_paged_step_compiles(chip, step, monkeypatch):
     assert len(stepped) == cfg.n_kda, len(stepped)
     assert len(_ragged_kernels(text)) == cfg.n_mla
     assert len(kernels) == cfg.n_kda + cfg.n_mla + 3 * cfg.n_moe
+
+
+# The eleventh configuration (benchmarks/configs/glm-5-ep16-d5.json):
+# five layers that each choose 2,048 keys a query out of a pool of two
+# arrays of unequal width under one block table, 16 held of 256
+# sigmoid-routed experts, built as the benchmark builds it, at its sizes
+# (48 rows, a block table 544 pages wide).
+@pytest.mark.parametrize("step", ["decode_tick", "prefill_chunk"])
+def test_glm5_paged_step_compiles(chip, step, monkeypatch):
+    """Both programs of glm-5-ep16-d5 as the chip runs them, within its
+    memory: 7.83 GB of weights and 4.53 GB of pool (`lat` [5, P, 64,
+    640] and `idx` [5, P, 64, 128] under one table) are resident and
+    donated through the step; neither pool is ever re-laid or copied;
+    THE SELECTION DOES NOT SORT (`lax.top_k` of 2,048 lowers to a full
+    sort of [512, 34816] a layer): the only sorts left are the routers'
+    top-8 of 256 and the expert walk's, none under a `dsa_` scope and
+    none outside the expert layer; a tick holds under 0.25 GiB of
+    temporaries and a chunk under 1 GiB (its [512, 34816] float32 scores
+    are 68 MiB; no array of [queries, heads, table width] stands)."""
+    import json
+    import os
+
+    from benchmarks.lib.registry import arch_of
+    from ray_tpu.models import deepseek_v2
+    monkeypatch.setattr(deepseek_v2, "_on_tpu", lambda: True)
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs", "glm-5-ep16-d5.json")) as f:
+        c = json.load(f)
+    arch = arch_of(c, bench)
+    e = c["serving"]["engine"]
+    cfg = arch.build(c, e["max_seq"], remat=False)
+    params = _on(chip, jax.eval_shape(
+        lambda: arch.init(cfg, jax.random.PRNGKey(0), cfg.dtype)))
+    cache = _on(chip, jax.eval_shape(lambda: decode.init_paged_cache(
+        cfg, e["kv_pages"] + 1, e["page_size"], e["num_slots"])))
+    rows, blocks = e["num_slots"], -(-e["max_seq"] // e["page_size"])
+    assert (rows, blocks) == (48, 544)
+    assert cache["lat"].shape == (5, e["kv_pages"] + 1, 64, 640)
+    assert cache["idx"].shape == (5, e["kv_pages"] + 1, 64, 128)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+
+    if step == "decode_tick":
+        lowered = engine._paged_tick.lower(
+            params, i32(rows), i32(rows), cache, i32(rows, blocks), cfg,
+            with_logits=False)
+    else:
+        lowered = engine._prefill_chunk.lower(
+            params, i32(1, e["prefill_chunk"]), i32(), cache,
+            i32(1, blocks), cfg, slot=i32(), valid=i32())
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    limit = 1 << 28 if step == "decode_tick" else 1 << 30
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes / 2**30
+    held = sum(cache[k].size * cache[k].dtype.itemsize
+               for k in ("lat", "idx"))
+    assert arch.weight_bytes(c) + held \
+        < mem.argument_size_in_bytes < arch.weight_bytes(c) + held + (1 << 26)
+    assert mem.alias_size_in_bytes >= held       # donated through the step
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14 << 30
+    text = compiled.as_text()
+    for name in ("lat", "idx"):
+        held = "bf16[%s]" % ",".join(map(str, cache[name].shape))
+        layouts = set(re.findall(re.escape(held) + r"\{([\d,]+)", text))
+        assert len(layouts) == 1, (name, layouts)          # never re-laid
+        moved = [ln for ln in text.splitlines()
+                 if re.search(r"= " + re.escape(held) + r"\S* copy\(", ln)]
+        assert not moved, moved[:4]
+    for scope in ("dsa_index", "dsa_select", "dsa_attend"):
+        assert scope in text, scope
+    assert "mla_expand_attend" not in text \
+        and "mla_absorb_attend" not in text
+    sorts = [ln for ln in text.splitlines() if re.search(r" sort\(", ln)]
+    assert all("/moe_route/" in ln or "/moe_experts/" in ln
+               for ln in sorts), sorts[:2]
+    assert len(sorts) <= 2 * cfg.n_moe, len(sorts)
+    wide = re.findall(r"\[(?:512|48),(?:64|32),34816\]", text)
+    assert not wide, wide[:4]
+    kernels = [ln for ln in text.splitlines()
+               if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(kernels) == 3 * cfg.n_moe, len(kernels)     # grouped matmuls
 
 
 @pytest.mark.parametrize("config", [
