@@ -66,7 +66,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -578,9 +578,11 @@ def _ffn(lp, x, live, is_tick, counts, cfg: DeepseekV2Config):
 # (models/glm_moe_dsa.py: 64 heads of 192 + 64 | 256 over the same row)
 # CHOOSES the keys a query attends to and says which as `chosen`: a
 # chunk takes a 0/1 mask over its row's table width and attends
-# expanded under it, a tick a `Chosen` list of positions a row and
-# gathers those latents, and only those, out of the pool
-# (`_attend_chosen`); both under the scope `dsa_attend`.
+# expanded under it, a tick a `Chosen` (the same mask a row, and how to
+# list it) and either walks each row's own pages under the mask through
+# `ops/paged_attention.py` or gathers the listed latents, and only
+# those, out of the pool (`_attend_chosen`), whichever `walks` says is
+# cheaper at the rows' depths; all under the scope `dsa_attend`.
 
 
 def _project(lp, x, positions, cfg: DeepseekV2Config):
@@ -617,14 +619,20 @@ def _merge(part, scores, values_of):
 
 class Chosen(NamedTuple):
     """The keys each row of a tick attends to, where a model chooses
-    them a token (models/glm_moe_dsa.py's indexer), as lists: `idx`
-    [B, k] their positions in the row's own sequence, `ok` [B, k] which
-    of the k slots hold a choice (a row that holds fewer than k keys
-    fills fewer), and the rows attended at all: order[0] ..
-    order[n - 1] of `order`, a permutation of the rows, `n` a number
-    the device reads (a tick's live rows).  The others get zeros."""
-    idx: Any
-    ok: Any
+    them a token (models/glm_moe_dsa.py's indexer): `keep` [B, S] bool
+    over each row's OWN sequence positions, S the table's width in keys
+    (what a walk of the row's pages is masked by); `listed`, which turns
+    it into lists when called, on the branch that gathers and nowhere
+    else: (`idx` [B, k] the kept positions, `ok` [B, k] which of the k
+    slots hold one: a row that holds fewer than k keys fills fewer);
+    `walk`, a boolean the device reads: whether this tick walks
+    (`walks`, the same in every layer); and the rows attended at all:
+    order[0] .. order[n - 1] of `order`, a permutation of the rows, `n`
+    a number the device reads (a tick's live rows: those past position
+    0).  The others get zeros."""
+    keep: Any
+    listed: Callable[[], Tuple[Any, Any]]
+    walk: Any
     n: Any
     order: Any
 
@@ -634,6 +642,30 @@ class Chosen(NamedTuple):
 # heads 4 MB.  (A trip a row read the same; a trip is ~14 device
 # operations, and a profiler's capture pays by the operation.)
 _CHOSEN_ROWS = 8
+
+
+# What the two fetches of a selecting tick cost on a v5e, in ns: a key a
+# row HOLDS where `ops/paged_attention.py` walks the row's every page
+# under the mask, and a key a row CHOSE where `_attend_chosen` lists and
+# gathers it.  Measured at GLM-5's widths (64 heads over 1,280 B
+# latents, blocks of 768, 2,048 chosen a row; PERF.md section 6, PR 67):
+# the walk 2.08 / 1.98 / 2.01 / 2.02 / 1.98 a key held at 8k / 12k / 16k
+# / 20k / 34k keys a row (1 to 3 % of it the mask), the gather 18.6 a
+# key chosen while the rows' deepest lies in a quarter of the table,
+# 21.9 to 27.1 past 20k (the listing runs over the whole width and a
+# trip of eight rows is seldom full): 28 rows at 20k walk in 40 us a row
+# and gather in 50, 24 at 24k in 48 and 45, 16 at 34k in 67 and 49.
+_WALK_NS_A_KEY = 2.0
+_GATHER_NS_A_KEY = 22.0
+
+
+def walks(pos, k: int):
+    """Whether a tick of rows at `pos` (numpy or traced; idle ones at 0)
+    that each chose up to `k` keys fetches them by a walk of each row's
+    pages: where that costs less than the gather of k rows a live row,
+    whatever it holds.  Read from the input, once a tick."""
+    return pos.sum().astype("float32") * _WALK_NS_A_KEY \
+        < (pos > 0).sum().astype("float32") * (k * _GATHER_NS_A_KEY)
 
 
 def _live_block(order, n, j, qb: int):
@@ -660,11 +692,12 @@ def _attend_chosen(q_row, lat, l, bt, chosen: Chosen, cfg):
     psz, nblk, kr = lat.shape[2], bt.shape[1], cfg.kv_lora_rank
     dt = cfg.dtype
     qb = math.gcd(B, _CHOSEN_ROWS)
+    listed, filled = chosen.listed()
 
     def trip(j, carry):
         out, took = carry
         at, on = _live_block(chosen.order, chosen.n, j, qb)
-        idx, ok = chosen.idx[at], chosen.ok[at] & on[:, None]
+        idx, ok = listed[at], filled[at] & on[:, None]
         page = jnp.where((idx // psz)[..., None] == jnp.arange(nblk),
                          bt[at][:, None, :], 0).sum(-1)
         rows = lat[l, page, idx % psz]                       # [qb, k, W]
@@ -740,11 +773,31 @@ def _attn_chunk(lp, x, l, cache, bt, start, cfg, project=None, gate=None,
     return x, dict(cache, lat=lat)
 
 
+def _attend_under(q_row, lat, l, bt, pos, chosen: Chosen, cfg):
+    """The absorbed attention of a tick that chose its keys, by the
+    cheaper fetch (`chosen.walk`): each row's own pages walked under
+    `chosen.keep` by the ragged kernel, where there is a TPU to run it,
+    or the listed latents gathered (`_attend_chosen`) -> (the weighted
+    latents [B, H, kv_lora_rank], the keys weighed, counted where they
+    were, the rows that walked)."""
+    def walk():
+        o, weighed = _pa.paged_attention(
+            q_row, lat, None, l, bt, pos, n_kv_heads=1,
+            value_width=cfg.kv_lora_rank, scale=cfg.softmax_scale,
+            keep=chosen.keep)
+        return o, weighed.sum(), chosen.n
+
+    def gather():
+        return _attend_chosen(q_row, lat, l, bt, chosen, cfg) \
+            + (jnp.zeros_like(chosen.n),)
+    return lax.cond(chosen.walk, walk, gather) if _on_tpu() else gather()
+
+
 def _attn_tick(lp, x, l, cache, bt, pos, cfg, project=None, gate=None,
                chosen=None):
     """`project` and `gate` as `_attn_chunk` takes them; `chosen` a
-    `Chosen` list of positions a row, with which the latent rows
-    `_attend_chosen` gathered are returned third."""
+    `Chosen`, with which (the keys the attention weighed, the rows that
+    walked their pages for them) are returned third."""
     psz, kr = cache["lat"].shape[2], cfg.kv_lora_rank
     dt = cfg.dtype
     q_nope, q_pe, ckv, kpe = (project or _project)(lp, x, pos, cfg)
@@ -758,7 +811,8 @@ def _attn_tick(lp, x, l, cache, bt, pos, cfg, project=None, gate=None,
         q_row = _lat_row(jnp.einsum("bhn,hnc->bhc", q_nope,
                                     lp["wk_b"].astype(dt)), q_pe, cfg)
         if chosen is not None:
-            o_lat, gathered = _attend_chosen(q_row, lat, l, bt, chosen, cfg)
+            o_lat, *counted = _attend_under(q_row, lat, l, bt, pos, chosen,
+                                            cfg)
         elif _on_tpu():
             o_lat = _pa.paged_attention(
                 q_row, lat, None, l, bt, pos, n_kv_heads=1, value_width=kr,
@@ -770,7 +824,7 @@ def _attn_tick(lp, x, l, cache, bt, pos, cfg, project=None, gate=None,
         out = out * gate(lp, x)[:, :, None].astype(dt)
     x = x + jnp.einsum("bhv,hvd->bd", out, lp["wo"].astype(dt))
     cache = dict(cache, lat=lat)
-    return (x, cache) if chosen is None else (x, cache, gathered)
+    return (x, cache) if chosen is None else (x, cache, counted)
 
 
 def _span_tick(q_row, lat, l, bt, pos, cfg):

@@ -23,7 +23,7 @@ The cache (one pytree, `engine._cache`):
                              every past token
   moe   [7, 2] int32         the expert layers' counters
                              (deepseek_v2.COUNTERS)
-  dsa   [6, 2] int32         DSA_COUNTERS, below
+  dsa   [7, 2] int32         DSA_COUNTERS, below
 
 `lat` and `idx` are the pool (`page_keys`: two arrays of unequal width,
 one table) and nothing is state of a decode row.  A page is a latent row
@@ -38,10 +38,16 @@ against the row's cached `idx` keys span by span, its 32 heads reduced
 inside the span (`dsa_index`: I(t, s) = sum_j w_t,j relu(q_t,j . k_s),
 float32), and the `index_topk` largest are taken (`dsa_select`: a
 threshold found by bisection, no sort; every key while the row holds no
-more).  A TICK walks its LIVE rows alone, eight a trip, each over its
-own pages, LISTS the chosen positions and gathers their latents, AND
-ONLY THOSE, out of the paged pool, attending ABSORBED (`dsa_attend`,
-deepseek_v2._attend_chosen).  A CHUNK scores its 512
+more).  A TICK scores its LIVE rows alone, eight a trip, each over its
+own pages, and attends ABSORBED under the choice (`dsa_attend`,
+deepseek_v2._attend_under) by the cheaper of two fetches, read from the
+rows' depths once a tick (deepseek_v2.walks: the live rows' keys HELD
+x 2.0 ns against their keys CHOSEN x 22 ns, which cross where a row
+holds 11 x `index_topk`, ~22.5k keys): it WALKS each row's own latent pages under
+the 0/1 mask through `ops/paged_attention.py`, a page a copy, or,
+deeper, LISTS the chosen positions and GATHERS their latents, and only
+those, out of the paged pool (deepseek_v2._attend_chosen); without a
+TPU it gathers.  A CHUNK scores its 512
 queries against every span up to its last token and attends EXPANDED
 under the choice, a 0/1 mask over deepseek_v2's span loop (`dsa_attend`
 too): a gather of 512 x 2,048 latent rows, ~19 ms a layer whatever the
@@ -82,15 +88,19 @@ COUNTERS = _ds.COUNTERS
 # query against every span up to its last token); `keys_chosen`: the
 # keys chosen by ticks' live rows and chunks' real tokens, min(position
 # + 1, index_topk) each; `tick_keys_chosen`: the ticks' part of that;
-# `tick_keys_attended`: the latent rows a tick's gather fetched and its
-# attention weighed, counted by deepseek_v2._attend_chosen as its trips
-# go (the filled slots of the rows a trip visited), so over
+# `tick_keys_attended`: the keys a tick's attention weighed, counted
+# where it does: by `ops/paged_attention.py` where the mask is applied
+# on a walk, by deepseek_v2._attend_chosen as its trips go on a gather
+# (the filled slots of the rows a trip visited), so over
 # `tick_keys_chosen` it reads 1 where attention touched the chosen and
-# nothing else, and moves if the listing fills a slot too few or a trip
-# visits a row too many; `rows_selecting` / `rows_live`: a tick's live
-# rows past `index_topk`, and its live rows; all summed over layers.
+# nothing else, and moves if a mask bit is lost, the listing fills a
+# slot too few or a trip visits a row too many; `rows_selecting` /
+# `rows_live`: a tick's live rows past `index_topk`, and its live rows;
+# `rows_walked`: its live rows whose pages were walked under the mask
+# (the others' chosen latents were gathered); all summed over layers.
 DSA_COUNTERS = ("keys_scored", "keys_chosen", "tick_keys_chosen",
-                "tick_keys_attended", "rows_selecting", "rows_live")
+                "tick_keys_attended", "rows_selecting", "rows_live",
+                "rows_walked")
 # Keys one span of the indexer's scoring covers (whole pages that divide
 # the table).  A chunk scores all its queries against a span with 32
 # heads in float32, [queries, 32, keys], before they are reduced.
@@ -182,10 +192,15 @@ def attn_keys(cfg: GlmMoeDsaConfig, pos: np.ndarray) -> Tuple[int, int]:
 def attn_keys_gathered(cfg: GlmMoeDsaConfig, pos: np.ndarray,
                        page_size: int, nblk: int) -> int:
     """Latents one tick pulls from the pool (`pos` of all decode rows,
-    idle ones at 0): a live row gathers `index_topk` slots a layer,
-    whatever it holds; an idle row nothing."""
-    live = int((np.asarray(pos) > 0).sum())
-    return live * min(cfg.index_topk, nblk * page_size) * cfg.n_layers
+    idle ones at 0), by the fetch it takes (deepseek_v2.walks): where
+    it walks, each live row's own blocks of pages, what the kernel
+    copies; where it gathers, `index_topk` slots a live row and layer,
+    whatever the row holds; an idle row nothing."""
+    pos = np.asarray(pos)
+    topk = min(cfg.index_topk, nblk * page_size)
+    if _ds._on_tpu() and _ds.walks(pos, topk):
+        return _ds.attn_keys_gathered(cfg, pos, page_size, nblk)
+    return int((pos > 0).sum()) * topk * cfg.n_layers
 
 
 def chunk_selects(cfg: GlmMoeDsaConfig, start: int) -> bool:
@@ -575,7 +590,8 @@ def _attn_chunk(lp, x, l, cache, bt, start, valid, dc, cfg):
 
 def _attn_tick(lp, x, l, cache, bt, pos, dc, cfg):
     psz = cache["idx"].shape[2]
-    topk = min(cfg.index_topk, bt.shape[1] * psz)
+    S = bt.shape[1] * psz
+    topk = min(cfg.index_topk, S)
     qi, ki, wi = _index_project(lp, x, pos, cfg)
     page = jnp.take_along_axis(bt, (pos // psz)[:, None], axis=1)[:, 0]
     idx_pool = cache["idx"].at[l, page, pos % psz].set(ki)
@@ -590,15 +606,22 @@ def _attn_tick(lp, x, l, cache, bt, pos, dc, cfg):
         scores, scored = _index_tick(qi, wi, idx_pool, l, bt, pos, order,
                                      n_live)
     with jax.named_scope("dsa_select"):
-        idx, ok = _narrowest(
+        keep = _narrowest(
             scores, jnp.max(pos) + 1, topk,
-            lambda s: _listed_by_blocks(chosen_keys(s, topk), topk))
-    x, cache, gathered = _ds._attn_tick(
+            lambda s: jnp.pad(chosen_keys(s, topk),
+                              ((0, 0), (0, S - s.shape[1]))))
+
+    def listed():
+        with jax.named_scope("dsa_select"):
+            return _narrowest(keep, jnp.max(pos) + 1, topk,
+                              lambda m: _listed_by_blocks(m, topk))
+    x, cache, (attended, walked) = _ds._attn_tick(
         lp, x, l, dict(cache, idx=idx_pool), bt, pos, cfg, project=_project,
-        chosen=_ds.Chosen(idx, ok, n_live, order))
+        chosen=_ds.Chosen(keep, listed, _ds.walks(pos, topk), n_live, order))
     chose = jnp.where(live, jnp.minimum(pos + 1, topk), 0).sum()
-    dc = [dc[0] + scored, dc[1] + chose, dc[2] + chose, dc[3] + gathered,
-          dc[4] + (live & (pos + 1 > topk)).sum(), dc[5] + n_live]
+    dc = [dc[0] + scored, dc[1] + chose, dc[2] + chose, dc[3] + attended,
+          dc[4] + (live & (pos + 1 > topk)).sum(), dc[5] + n_live,
+          dc[6] + walked]
     return x, cache, dc
 
 

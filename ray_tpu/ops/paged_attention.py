@@ -59,6 +59,21 @@ third (PR 53).
 
 A position past the row's own is masked, so what a page holds behind
 the row's last token, and what the trash page holds, is never seen.
+
+A model that CHOOSES the keys a row attends to (models/glm_moe_dsa.py,
+2,048 of all a row holds) hands the walk a mask, `keep` [B, S] over each
+row's OWN sequence positions: in the third form (the latent page: the
+others refuse it by name) a key is weighed only where `keep` is set AND
+it is at or before the row's position.  The row's line of the mask
+reaches VMEM beside its query, a block of the walk a row of it; every
+page the row holds is still copied (that is the trade against a gather
+of the chosen latents, which the caller makes by depth:
+deepseek_v2.walks), a block that keeps no key merges nothing, a row that
+keeps none returns zeros, and the call also returns how many keys each
+row weighed, counted where the mask is applied.  `keep=None` is a static
+Python branch: without a mask every form traces to the program it was
+before there was one (equal jaxprs, and Mosaic modules equal byte for
+byte once locations are stripped; PERF.md section 6, PR 67).
 """
 
 from __future__ import annotations
@@ -126,8 +141,15 @@ def widen(q, n_kv_heads: int):
 def _kernel(layer_ref, pos_ref, first_ref, live_ref, bt_ref, q_ref, k_hbm,
             *rest,
             pages: int, nblk: int, page_size: int, in_rows: int,
-            group: int, scale: float, values_in_keys: bool):
-    if values_in_keys:
+            group: int, scale: float, values_in_keys: bool, masked: bool):
+    keep_ref = n_ref = None
+    if masked:
+        # (the latent form alone) the row's line of `keep`, a block of
+        # the walk a row of it; out: the keys weighed, lane by lane
+        keep_ref, o_ref, n_ref, kbuf, sems, m_ref, l_ref, acc_ref = rest
+        vbuf = kbuf
+        n_ref[...] = jnp.zeros_like(n_ref)
+    elif values_in_keys:
         # values are the first lanes of the keys: one pool, one buffer
         o_ref, kbuf, sems, m_ref, l_ref, acc_ref = rest
         vbuf = kbuf
@@ -196,13 +218,24 @@ def _kernel(layer_ref, pos_ref, first_ref, live_ref, bt_ref, q_ref, k_hbm,
             c.wait()
         s = lax.dot_general(q, kbuf[slot], (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        seen = j * width + col <= pos
-        if own is not None:
-            seen &= own
-        s = jnp.where(seen, s, -jnp.inf)
-        top = jnp.maximum(m_ref[...], s.max(-1, keepdims=True))
-        old = jnp.exp(m_ref[...] - top)
-        e = jnp.exp(s - top)
+        if masked:
+            # one line of the block's keys, the same for every head
+            weighed = (j * width + lax.broadcasted_iota(
+                jnp.int32, (1, n), 1) <= pos) \
+                & (keep_ref[pl.ds(j, 1), :] != 0)
+            n_ref[...] += weighed.astype(jnp.int32)
+            s = s + jnp.where(weighed, 0.0, -jnp.inf)
+            top = jnp.maximum(m_ref[...], s.max(-1, keepdims=True))
+            # (a block may hold no kept key, the row's first blocks too)
+            ref = jnp.where(top > -jnp.inf, top, 0.0)
+        else:
+            seen = j * width + col <= pos
+            if own is not None:
+                seen &= own
+            s = jnp.where(seen, s, -jnp.inf)
+            ref = top = jnp.maximum(m_ref[...], s.max(-1, keepdims=True))
+        old = jnp.exp(m_ref[...] - ref)
+        e = jnp.exp(s - ref)
         m_ref[...] = top
         l_ref[...] = old * l_ref[...] + e.sum(-1, keepdims=True)
         acc_ref[...] = old * acc_ref[...] + jnp.dot(
@@ -211,8 +244,10 @@ def _kernel(layer_ref, pos_ref, first_ref, live_ref, bt_ref, q_ref, k_hbm,
         return carry
 
     lax.fori_loop(0, trips, block, 0)
-    # (an idle row summed nothing: zeros over one, not over zero)
-    total = jnp.where(trips > 0, l_ref[...], 1.0)
+    # (an idle row summed nothing: zeros over one, not over zero; nor
+    # did a row none of whose keys is kept)
+    total = jnp.where(l_ref[...] > 0 if masked else trips > 0,
+                      l_ref[...], 1.0)
     if acc_ref.shape[1] == Dv:
         o_ref[...] = (acc_ref[...] / total).astype(o_ref.dtype)
     else:
@@ -225,7 +260,8 @@ def _kernel(layer_ref, pos_ref, first_ref, live_ref, bt_ref, q_ref, k_hbm,
 
 def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
                     n_kv_heads: int, value_width: Optional[int] = None,
-                    scale: Optional[float] = None, interpret: bool = False):
+                    scale: Optional[float] = None, keep=None,
+                    interpret: bool = False):
     """q [B, H, Dh] (one token a row) over the pages of layer `layer` of
     k_pool [L, P, page, G, Dh] / v_pool [L, P, page, G, Dv], or the same
     with a token's heads side by side ([L, P, page, G x Dh]): row b
@@ -235,7 +271,12 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
     k_pool [L, P, page, Dh] of one head every query head shares.
     Scores are scaled by `scale` (default Dh ** -0.5); matmul inputs are
     the pool's dtype, the softmax and the accumulator float32.  Returns
-    [B, H, Dv] in q's dtype."""
+    [B, H, Dv] in q's dtype.
+    `keep` [B, S] (0 or false: not kept; S the table's width in keys),
+    with keys that hold their values alone: row b attends to the kept
+    among its positions 0..pos[b] and to nothing else, zeros where it
+    keeps none.  Returns that and the keys each row weighed, [B] int32,
+    counted where the mask is applied."""
     B, H, Dh = q.shape
     G = n_kv_heads
     L, P, psz = k_pool.shape[:3]
@@ -262,8 +303,20 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
     # (the bytes a token of the pools as they lie: a page's over its tokens)
     pages = block_pages(psz, nblk, sum(
         per * pool.shape[3] * pool.dtype.itemsize for pool in pools) // psz)
+    width = pages * psz
+    if keep is not None:
+        if v_pool is not None or keep.shape != (B, nblk * psz):
+            raise ValueError(
+                f"`keep` is [rows, the table's width in keys] over keys "
+                f"that hold their values, got {keep.shape} for {B} rows of "
+                f"{nblk * psz} keys, a value pool: {v_pool is not None}")
+        # a row's line, a block of its walk a row of it (the table's
+        # last block may end past the table: nothing kept there)
+        lines = -(-nblk // pages)
+        keep = jnp.pad(keep.astype(jnp.int32), (
+            (0, 0), (0, lines * width - nblk * psz))).reshape(B, lines, width)
     pos = pos.astype(jnp.int32)
-    trips = _trips(pos, pages * psz)
+    trips = _trips(pos, width)
     # the buffer a row's first block lands in: blocks alternate between
     # the two through the whole call
     first = (jnp.cumsum(trips) - trips) % 2
@@ -275,22 +328,31 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
     # a pair of buffers and a pair of semaphores a pool
     buffers = [pltpu.VMEM((2, pages * per, pool.shape[3]), pool.dtype)
                for pool in pools]
-    return pl.pallas_call(
+    in_specs = [row(Wk)] + [pl.BlockSpec(memory_space=pl.ANY) for _ in pools]
+    out_specs, out_shape = row(Dv), jax.ShapeDtypeStruct((B, H, Dv), q.dtype)
+    if keep is not None:
+        in_specs.append(pl.BlockSpec((None,) + keep.shape[1:],
+                                     lambda b, *_: (b, 0, 0)))
+        out_specs = [out_specs, pl.BlockSpec((None, 1, width),
+                                             lambda b, *_: (b, 0, 0))]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((B, 1, width), jnp.int32)]
+    out = pl.pallas_call(
         functools.partial(_kernel, pages=pages, nblk=nblk, page_size=psz,
                           in_rows=in_rows, group=H // G,
                           scale=Dh ** -0.5 if scale is None else scale,
-                          values_in_keys=v_pool is None),
+                          values_in_keys=v_pool is None,
+                          masked=keep is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5, grid=(B,),
-            in_specs=[row(Wk)] + [pl.BlockSpec(memory_space=pl.ANY)
-                                  for _ in pools],
-            out_specs=row(Dv),
+            in_specs=in_specs,
+            out_specs=out_specs,
             scratch_shapes=buffers + [
                 pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, Wv), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, H, Dv), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -298,4 +360,6 @@ def paged_attention(q, k_pool, v_pool, layer, block_tables, pos, *,
         name="paged_attention",
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), pos,
       first.astype(jnp.int32), live.astype(jnp.int32),
-      block_tables.reshape(-1).astype(jnp.int32), q, *pools)
+      block_tables.reshape(-1).astype(jnp.int32), q, *pools,
+      *(() if keep is None else (keep,)))
+    return out if keep is None else (out[0], out[1].sum((1, 2)))

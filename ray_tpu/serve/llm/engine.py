@@ -521,16 +521,19 @@ class EngineStats:
     # A body that chooses keys a token (models/glm_moe_dsa.py) counts, on
     # the device and summed over its layers: the (query, key) pairs its
     # indexer scored, the keys its queries chose (chunks and ticks; then
-    # the ticks' alone), the latent rows a tick's attention gathered and
-    # weighed for them (over the ticks' keys chosen that reads 1 where
-    # attention touched the chosen and nothing else), and a tick's live
-    # rows past `index_topk` beside its live rows.
+    # the ticks' alone), the keys a tick's attention weighed for them
+    # (over the ticks' keys chosen that reads 1 where attention touched
+    # the chosen and nothing else), a tick's live rows past `index_topk`
+    # beside its live rows, and those of them whose own pages were
+    # walked under the choice's mask (the others' chosen latents were
+    # gathered out of the pool).
     dsa_keys_scored: int = 0
     dsa_keys_chosen: int = 0
     dsa_tick_keys_chosen: int = 0
     dsa_tick_keys_attended: int = 0
     dsa_rows_selecting: int = 0
     dsa_rows_live: int = 0
+    dsa_rows_walked: int = 0
     # A body that generates by diffusion over blocks (its `block` > 1)
     # runs block steps and no tick: the step programs dispatched; the
     # live rows they ran, summed (a row's forward); of those the ones

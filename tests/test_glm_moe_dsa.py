@@ -6,7 +6,9 @@ keep every ratio (five layers, one dense, `index_topk` 8 under contexts
 below, at and past it, 16 experts top-4 through a bias with 4 held,
 pages of 4), the chosen sets against the reference's, the layer against
 deepseek_v2's below `index_topk`, rows whose pages interleave in the
-pool, a slot that changes hands, the controls a comparison must catch,
+pool, a slot that changes hands, a tick on either side of the depth
+where walking a row's pages under the choice stops being cheaper than
+gathering the chosen, the controls a comparison must catch,
 the share test, the choice without a sort against `lax.top_k`, the
 yardstick against the program's shapes at the configuration's sizes, the
 refusals by declaration, and the toy configuration served to
@@ -26,6 +28,7 @@ import pytest
 
 from ray_tpu.models import decode
 from ray_tpu.models import glm_moe_dsa as gm
+from ray_tpu.ops import paged_attention as pa
 from ray_tpu.serve.llm import engine as engine_mod
 from ray_tpu.serve.llm import kv_transfer
 from ray_tpu.serve.llm.engine import GenerationEngine
@@ -379,6 +382,81 @@ def test_a_tick_of_sixteen_rows_takes_its_live_rows_eight_a_trip(
         n + i + 1 > TOPK for n in lens for i in range(5))
 
 
+# ---------------------------------- a tick walks or gathers, by the depth
+
+@pytest.mark.parametrize("tick_attention", ["kernel"], indirect=True)
+@pytest.mark.parametrize("side", ["under-the-crossing", "past-the-crossing"])
+def test_a_tick_walks_or_gathers_by_its_rows_depth_and_both_are_one_tick(
+        model, arch, tick_attention, monkeypatch, side):
+    """The tick as a TPU traces it (conftest's `tick_attention`: the
+    ragged kernel interpreted, in blocks of 8 keys so that a toy row
+    walks several).  Two live rows and an idle one between them, at
+    depths whose mean lies under the crossing of `deepseek_v2.walks` (a row's keys held x
+    the walk's cost a key against `index_topk` x the gather's) or past
+    it: the rule, read from the positions, takes the walk under it and
+    the gather past it (`dsa_rows_walked` counts every live row and
+    layer, or none), and the same ticks FORCED to the other fetch give
+    the same logits, the same two pools and the same `dsa_*` counters
+    but that one; the keys attention weighed are the keys chosen on
+    both, counted by the kernel on the walk; and `attn_keys_gathered`
+    says what the fetch taken copies: each live row's own blocks, or
+    `index_topk` slots a live row."""
+    cfg, params = model
+    ds = gm._ds
+    cross = TOPK * ds._GATHER_NS_A_KEY / ds._WALK_NS_A_KEY
+    assert 16 < cross < 180, cross            # both sides fit the toy table
+    walks = side == "under-the-crossing"
+    lens = [int(cross * f) for f in ((0.35, 0.8) if walks else (1.15, 1.35))]
+    ticks, psz, nblk = 3, 4, 256 // 4
+    seqs = {r: _tokens(n + ticks, seed=50 + r) for r, n in zip((0, 2), lens)}
+    token = gm._ds._lat_width(cfg) * jnp.dtype(cfg.dtype).itemsize
+
+    def run():
+        engine_mod._paged_tick.clear_cache()
+        drv = Driver(cfg, params, psz, 16)
+        for r, n in zip((0, 2), lens):
+            drv.admit(r, seqs[r][:n], n + ticks)
+        before = gm.read_counters(drv.cache, cfg)
+        rows, copied = [], []
+        for i in range(ticks):
+            copied.append(gm.attn_keys_gathered(cfg, drv.pos, psz, nblk))
+            assert bool(ds.walks(drv.pos, TOPK)) == bool(
+                ds.walks(jnp.asarray(drv.pos), TOPK))
+            rows.append(drv.tick({r: seqs[r][n + i]
+                                  for r, n in zip((0, 2), lens)}))
+        counts = gm.read_counters(drv.cache, cfg)
+        gain = {k: counts[k] - before[k] for k in counts
+                if k.startswith("dsa_")}
+        return np.stack(rows), drv, gain, copied
+
+    got, drv, gain, copied = run()
+    assert gain["dsa_rows_walked"] == (2 * ticks * L if walks else 0)
+    assert gain["dsa_rows_live"] == 2 * ticks * L
+    assert gain["dsa_tick_keys_attended"] == gain["dsa_tick_keys_chosen"] \
+        == 2 * ticks * L * TOPK
+    for i, n in enumerate(copied):
+        pos = np.asarray([lens[0] + i, 0, lens[1] + i])
+        assert n == (pa.keys_copied(pos, psz, nblk, token) * L if walks
+                     else 2 * TOPK * L)
+    # the walk weighs the reference's keys
+    for r in (0, 2):
+        want = np.asarray(arch.reference(params, jnp.asarray(seqs[r]), C))
+        np.testing.assert_allclose(got[:, r], want[-ticks:], atol=5e-5)
+    # ... and forced to the other fetch, the ticks are the same ticks
+    monkeypatch.setattr(
+        ds, "_GATHER_NS_A_KEY" if walks else "_WALK_NS_A_KEY", 0.0)
+    other, forced, gain2, copied2 = run()
+    assert gain2.pop("dsa_rows_walked") == (0 if walks else 2 * ticks * L)
+    del gain["dsa_rows_walked"]
+    assert gain2 == gain
+    assert copied2 != copied
+    np.testing.assert_allclose(other[:, [0, 2]], got[:, [0, 2]], atol=2e-5)
+    for name in ("lat", "idx"):
+        np.testing.assert_allclose(np.asarray(forced.cache[name][:, 1:]),
+                                   np.asarray(drv.cache[name][:, 1:]),
+                                   atol=2e-5)
+
+
 # ------------------------------------------------------------ the controls
 
 CONTROLS = {"indexer dropped": {"_no_selection": True},
@@ -608,20 +686,22 @@ def test_the_new_cells_files_load_through_the_registry():
                                                 "per_layer")}
     dsv2 = {m["name"] for m in reg.metrics_for("dsv2-decode", "per_layer")}
     new = {"dsa_attended_ratio.tput", "dsa_scored_per_chosen.tput",
-           "dsa_rows_selecting_share.tput"}
+           "dsa_rows_selecting_share.tput", "dsa_rows_walked_share.tput"}
     assert names == dsv2 | new and not dsv2 & new
     obs = {"stats0": {"dsa_tick_keys_attended": 10,
                       "dsa_tick_keys_chosen": 10, "dsa_keys_chosen": 50,
                       "dsa_keys_scored": 100, "dsa_rows_selecting": 1,
-                      "dsa_rows_live": 2},
+                      "dsa_rows_live": 2, "dsa_rows_walked": 2},
            "stats1": {"dsa_tick_keys_attended": 10 + 2048 * 19,
                       "dsa_tick_keys_chosen": 10 + 2048 * 19,
                       "dsa_keys_chosen": 50 + 2048 * 38,
                       "dsa_keys_scored": 100 + 38 * 20480,
-                      "dsa_rows_selecting": 20, "dsa_rows_live": 21}}
+                      "dsa_rows_selecting": 20, "dsa_rows_live": 21,
+                      "dsa_rows_walked": 21}}
     for name, want in (("dsa_attended_ratio.tput", 1.0),
                        ("dsa_scored_per_chosen.tput", 10.0),
-                       ("dsa_rows_selecting_share.tput", 100.0)):
+                       ("dsa_rows_selecting_share.tput", 100.0),
+                       ("dsa_rows_walked_share.tput", 100.0)):
         spec = reg.metric(name)
         assert reg.reader(spec["reader"])(obs, **spec["args"]) \
             == pytest.approx(want)

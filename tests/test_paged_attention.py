@@ -5,7 +5,11 @@ there is no TPU) and against a plain float32 softmax over each row's own
 keys, in both forms a model keeps a token's heads in and over a latent
 page whose values are a prefix of its keys; what it reads of the pool
 and what it never touches (an idle row's pages: all of them); the
-counters that say what it copies; and, at the dense body's shapes
+latent form under a 0/1 mask a row (`keep`: a softmax over the kept
+keys alone, the gather of `deepseek_v2._attend_chosen` on the same
+choice, the count of keys weighed) and without one (the program it
+always was); the counters that say what it copies; and, at the dense
+body's shapes
 (`models/decode.py`: pages of 16 tokens x 8 heads x 128, 16 or 32 query
 heads, the block the chip runs), against the dense step's own span
 loop."""
@@ -21,6 +25,7 @@ import pytest
 from ray_tpu.models import decode, llama
 from ray_tpu.models import deepseek_v2 as ds
 from ray_tpu.models import exaone_moe as em
+from ray_tpu.models import glm_moe_dsa as gm
 from ray_tpu.ops import paged_attention as pa
 
 PAGE, NBLK, LAYERS, LAYER = 16, 12, 2, 1
@@ -274,6 +279,196 @@ def test_a_row_reads_its_own_blocks_and_nothing_past_its_position(kind):
     np.testing.assert_array_equal(
         _kernel(kind, q, k.at[0].set(jnp.nan),
                 v if v is None else v.at[0].set(jnp.nan), bt, pos), want)
+
+
+# --------------------------------------------- the latent form under a mask
+
+S = PAGE * NBLK
+K = 24                                # keys a row may choose, here
+
+
+def _some(rng, pos, k=K):
+    """`k` positions drawn among each row's 0..pos (all it holds where
+    it holds no more)."""
+    keep = np.zeros((len(pos), S), bool)
+    for b, p in enumerate(pos):
+        keep[b, rng.choice(p + 1, min(k, p + 1), replace=False)] = True
+    return keep
+
+
+def _in_pages(pos, pages):
+    keep = np.zeros((len(pos), S), bool)
+    for page in pages:
+        keep[:, page * PAGE:(page + 1) * PAGE] = True
+    return keep & (np.arange(S) <= np.asarray(pos)[:, None])
+
+
+# positions of the call's rows, and the mask over each row's sequence
+KEPT = {
+    # (the row at 17 holds 18 keys, fewer than it may choose: all kept)
+    "ragged-depths-an-idle-row-between": (
+        [150, 0, 40, 191, 17], lambda rng, pos: _some(rng, pos)),
+    "rows-that-hold-fewer-than-k": (
+        [5, 23, 9], lambda rng, pos: _some(rng, pos)),
+    # bits set past a row's position, on an idle row too: never weighed
+    "bits-past-the-position": (
+        [70, 0, 33, 120], lambda rng, pos: rng.random((len(pos), S)) < 0.3),
+    # (page 3 is the second page of block 1: blocks 0 and 2.. keep none)
+    "a-choice-confined-to-one-page": (
+        [150, 100, 70], lambda rng, pos: _in_pages(pos, [3])),
+    "one-key-a-page": (
+        [150, 191, 37], lambda rng, pos: (np.arange(S) % PAGE == 5)
+        & (np.arange(S) <= np.asarray(pos)[:, None])),
+    "a-live-row-that-keeps-none": (
+        [60, 90], lambda rng, pos: _some(rng, pos)
+        & (np.arange(len(pos)) == 1)[:, None]),
+}
+
+
+def _kept_state(case, dtype):
+    pos, choose = KEPT[case]
+    state = _state("latent", pos, seed=len(case), dtype=dtype)
+    keep = choose(np.random.default_rng(len(case)), state[-1])
+    return state, np.asarray(keep, bool)
+
+
+def _kept_kernel(state, keep):
+    _, q, k, _, bt, pos = state
+    out, n = jax.jit(lambda *a: pa.paged_attention(
+        a[0], a[1], None, jnp.int32(LAYER), a[2], a[3], n_kv_heads=1,
+        value_width=512, scale=_scale("latent"), keep=a[4],
+        interpret=True))(q, k, jnp.asarray(bt), jnp.asarray(pos),
+                         jnp.asarray(keep))
+    return np.asarray(out, np.float32), np.asarray(n)
+
+
+def _kept_plain(state, keep):
+    """A float32 softmax over each row's kept keys at or before its
+    position, row by row; zeros where there is none."""
+    _, q, k, _, bt, pos = state
+    out = []
+    for b, p in enumerate(pos):
+        at = np.flatnonzero(keep[b] & (np.arange(S) <= p) & (p > 0))
+        if not len(at):
+            out.append(np.zeros((q.shape[1], 512), np.float32))
+            continue
+        keys = np.asarray(k[LAYER, bt[b]], np.float32).reshape(S, -1)[at]
+        s = np.asarray(q[b], np.float32) @ keys.T * _scale("latent")
+        e = np.exp(s - s.max(-1, keepdims=True))
+        out.append(e / e.sum(-1, keepdims=True) @ keys[:, :512])
+    return np.stack(out)
+
+
+def _kept_gather(state, keep):
+    """`deepseek_v2._attend_chosen` on the same choice, listed as the
+    selecting model lists it."""
+    _, q, k, _, bt, pos = state
+    live = pos > 0
+    seen = jnp.asarray(keep & (np.arange(S) <= pos[:, None]) & live[:, None])
+    slots = max(int(seen.sum(-1).max()), 1)
+    order = np.concatenate([np.flatnonzero(live), np.flatnonzero(~live)])
+    chosen = ds.Chosen(seen, lambda: gm._listed_by_blocks(seen, slots), True,
+                       jnp.int32(live.sum()), jnp.asarray(order, jnp.int32))
+    cfg = dataclasses.replace(LATENT, dtype=q.dtype)
+    out, took = jax.jit(lambda q, k, bt: ds._attend_chosen(
+        q, k, LAYER, bt, chosen, cfg))(q, k, jnp.asarray(bt))
+    return np.asarray(out, np.float32), int(took)
+
+
+@pytest.mark.parametrize("case", list(KEPT))
+def test_under_a_mask_the_walk_is_a_softmax_over_the_kept_keys(case):
+    """The latent form under `keep`: each live row's output is a softmax
+    over the kept keys at or before its position and over nothing else
+    (a float32 reference; the gather `deepseek_v2._attend_chosen` makes
+    of the same choice), in float32 and in bfloat16 as the chip holds
+    the pool; a bit past the position, or on an idle row, weighs
+    nothing; a block that keeps no key (a row's first ones too) and a
+    row that keeps none at all leave finite numbers (zeros for the
+    row); and the kernel's count of the keys it weighed, row by row, is
+    the mask's."""
+    state, keep = _kept_state(case, jnp.float32)
+    pos = state[-1]
+    got, n = _kept_kernel(state, keep)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, _kept_plain(state, keep), atol=2e-5)
+    weighed = (keep & (np.arange(S) <= pos[:, None])
+               & (pos > 0)[:, None]).sum(-1)
+    np.testing.assert_array_equal(n, weighed)
+    none = weighed == 0
+    # (a live row with no slot filled, which no choice makes: the
+    # gather's softmax over nothing is not a number)
+    gathered, took = _kept_gather(state, keep)
+    np.testing.assert_allclose(got[~none], gathered[~none], atol=2e-5)
+    assert took == weighed.sum()
+    np.testing.assert_array_equal(got[none], 0.0)
+    assert (np.abs(got[~none]).max(axis=(1, 2)) > 0).all()
+    state, keep = _kept_state(case, jnp.bfloat16)
+    got, n = _kept_kernel(state, keep)
+    np.testing.assert_allclose(got, _kept_plain(state, keep), atol=2e-2)
+    np.testing.assert_array_equal(n, weighed)
+
+
+def test_a_mask_of_every_key_is_the_walk_without_one():
+    """`keep` all ones weighs what the unmasked walk weighs: the same
+    output, and a count of `pos + 1` a live row."""
+    state = _state("latent", CASES["unequal-depths"], seed=2)
+    pos = state[-1]
+    got, n = _kept_kernel(state, np.ones((len(pos), S), bool))
+    np.testing.assert_allclose(got, _kernel(*state), atol=2e-2)
+    np.testing.assert_array_equal(n, (pos + 1) * (pos > 0))
+
+
+def _calls(jaxpr):
+    """The `pallas_call` equations of a jaxpr, wherever they nest."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _calls(sub)
+
+
+def _equations(jaxpr):
+    """The equations of a jaxpr, those of its loops and branches too."""
+    return sum(1 + sum(map(_equations, jax.core.jaxprs_in_params(e.params)))
+               for e in jaxpr.eqns)
+
+
+# the equations of the kernel's body at this file's shapes as the parent
+# commit of PR 67 (fb0acd1) traced them
+PARENT_BODY = {"heads-in-rows": 220, "heads-in-lanes": 186, "latent": 172}
+
+
+@pytest.mark.parametrize("kind", POOLS)
+def test_without_a_mask_the_call_is_the_program_it_was(kind):
+    """`keep=None` is a static branch: the call traces to the parent's
+    program in all three forms, held here by what PR 67's comparison of
+    whole jaxprs and Mosaic modules read off the parent commit (the
+    kernel's operands, its one output, the equations of its body), and
+    a mask adds to that: an operand, an output, equations."""
+    _, q, k, v, bt, pos = _state(kind, CASES["unequal-depths"])
+    _, G, _, Dv, _ = KINDS[kind]
+    how = dict(value_width=Dv, scale=_scale(kind)) if v is None else {}
+
+    def traced(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: pa.paged_attention(
+            *a, n_kv_heads=G, interpret=True, **how, **kw))(
+            q, k, v, jnp.int32(LAYER), jnp.asarray(bt), jnp.asarray(pos))
+        call, = _calls(jaxpr.jaxpr)
+        return (len(call.invars), len(call.outvars),
+                _equations(call.params["jaxpr"]))
+
+    pools = 1 if v is None else 2
+    operands, outputs, body = traced()
+    # five prefetched scalars, the query, the pools: and one output
+    assert (operands, outputs) == (6 + pools, 1)
+    assert body == PARENT_BODY[kind]
+    if v is None:
+        keep = jnp.ones((len(pos), S), bool)
+        assert traced(keep=keep)[:2] == (8, 2)
+        assert traced(keep=keep)[2] > body
+    else:
+        with pytest.raises(ValueError, match="keys that hold their values"):
+            traced(keep=jnp.ones((len(pos), S), bool))
 
 
 @pytest.mark.parametrize("case", list(CASES))

@@ -1031,7 +1031,9 @@ def test_glm5_paged_step_compiles(chip, step, monkeypatch):
     THE SELECTION DOES NOT SORT (`lax.top_k` of 2,048 lowers to a full
     sort of [512, 34816] a layer): the only sorts left are the routers'
     top-8 of 256 and the expert walk's, none under a `dsa_` scope and
-    none outside the expert layer; a tick holds under 0.25 GiB of
+    none outside the expert layer; a tick's attention under the choice
+    is one ragged kernel a layer on the branch that walks, handed the
+    latent pool and the mask; a tick holds under 0.25 GiB of
     temporaries and a chunk under 1 GiB (its [512, 34816] float32 scores
     are 68 MiB; no array of [queries, heads, table width] stands)."""
     import json
@@ -1097,7 +1099,15 @@ def test_glm5_paged_step_compiles(chip, step, monkeypatch):
     assert not wide, wide[:4]
     kernels = [ln for ln in text.splitlines()
                if "custom-call(" in ln and "tpu_custom_call" in ln]
-    assert len(kernels) == 3 * cfg.n_moe, len(kernels)     # grouped matmuls
+    # a tick's walk under the choice: one `ops/paged_attention.py`
+    # kernel a layer, handed the latent pool as it lies and a line of
+    # `keep` a block of 768 keys, beside the count of keys it weighed
+    walks = _ragged_kernels(text)
+    assert len(walks) == (cfg.n_layers if step == "decode_tick" else 0)
+    lat = "bf16[%s]" % ",".join(map(str, cache["lat"].shape))
+    assert all(lat in ln and "s32[48,46,768]" in ln
+               and "s32[48,1,768]" in ln for ln in walks), walks[:1]
+    assert len(kernels) == 3 * cfg.n_moe + len(walks), len(kernels)
 
 
 @pytest.mark.parametrize("config", [
